@@ -14,16 +14,12 @@ from paddle_tpu.core.enforce import EnforceNotMet, EOFException  # noqa: F401
 # fluid.core.EOFException is the reader-protocol loop terminator; users
 # catch it as core.EOFException, so expose it here
 
-# persistent XLA compilation cache: PADDLE_TPU_CACHE_DIR in the
-# environment (the elastic launcher sets it for workers) turns it on at
-# import, before any jit compiles — a restarted worker's compiles then
-# read the previous incarnation's on-disk entries instead of redoing XLA.
-# Never fatal: a bad dir (read-only volume, typo) must degrade to a cold
-# start, not crash every `import paddle_tpu` — under the elastic
-# launcher that would burn the whole restart budget re-dying at import.
-try:
-    compile_cache.enable_from_env()
-except Exception as _e:  # pragma: no cover - env-dependent
-    import warnings as _warnings
-    _warnings.warn(f"PADDLE_TPU_CACHE_DIR ignored "
-                   f"(compilation cache disabled): {_e}")
+# persistent XLA compilation cache: with JAX_COMPILATION_CACHE_DIR in the
+# environment (whoever runs the program placed the cache; the launcher
+# exports it to its workers) jax already points at that directory —
+# enable() sets none, it zeroes jax's caching thresholds and starts the
+# hit/miss counters before any jit compiles.
+import os as _os
+
+if _os.environ.get(compile_cache.ENV_VAR):
+    compile_cache.enable()

@@ -1,26 +1,24 @@
-"""Persistent XLA compilation cache (warm restarts).
+"""Persistent XLA compilation cache: where it lives, and whether it hit.
 
-The elastic launcher (distributed/launch.py) made restarts routine —
-a preempted or crashed worker comes back seconds later — but every
-incarnation used to recompile every jitted step from zero. This module
-wires jax's on-disk compilation cache so a restarted process compiles
-against the previous incarnation's cache entries: the retrace is
-Python-cheap, and the XLA compile (the seconds-to-minutes part) becomes
-a disk read.
+jax keeps compiled programs on disk once ``jax_compilation_cache_dir`` is
+set, so a second process (a restarted worker, the next chip call)
+compiles by reading a file. The directory is part of every entry's key:
+a cache that moves never hits. Hence one rule for where it lives:
 
-Activation, in priority order:
+- ``JAX_COMPILATION_CACHE_DIR`` set — jax's own handling of that
+  variable stands and nothing here sets a directory. Whoever runs the
+  program places the cache.
+- not set — the entry points that run on the chip (``chip_smoke.py``,
+  ``bench.py``, launcher workers) call :func:`enable`, which uses ONE
+  fixed path inside the checkout, :data:`DEFAULT_DIR` (git-ignored).
+  Never a path built from a temp dir, a pid or the clock.
 
-- ``PADDLE_TPU_CACHE_DIR`` env var (read at ``paddle_tpu.core`` import,
-  i.e. any ``import paddle_tpu``) — the launcher sets it for workers
-  (default: ``<log_dir>/xla_cache``) so restarted ranks inherit it;
-- an explicit ``enable(dirname)`` call — ``CheckpointManager`` calls
-  this with ``<checkpoint_dir>/xla_cache`` as the default home, pairing
-  "checkpoint often, restart anywhere" with "never recompile what an
-  earlier incarnation compiled".
-
-``stats()`` exposes hit/miss/request counters fed by jax's monitoring
-events; ``paddle_tpu.profiler`` surfaces them in its summary so a warm
-restart is verifiable (hits > 0), not vibes.
+``import paddle_tpu`` calls :func:`enable` when the variable is set (the
+launcher exports it to its workers), so a restarted rank's compiles hit
+the previous incarnation's entries. :func:`enable` also zeroes jax's
+"only cache slow/large compiles" thresholds and registers the listener
+behind :func:`stats`; ``paddle_tpu.profiler`` prints those counters, so
+a warm start is verifiable (hits > 0).
 """
 
 import os
@@ -29,12 +27,18 @@ import threading
 from paddle_tpu.monitor.registry import counter as _counter
 
 __all__ = ["enable", "disable", "is_enabled", "cache_dir", "stats",
-           "reset_stats", "ENV_VAR"]
+           "reset_stats", "ENV_VAR", "DEFAULT_DIR"]
 
-ENV_VAR = "PADDLE_TPU_CACHE_DIR"
+#: jax's own variable; read by jax at import, never written here
+ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
+
+#: <checkout>/.jax_cache — the cache's home when nobody placed it
+DEFAULT_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
 
 _lock = threading.Lock()
-_state = {"dir": None, "listening": False}
+_state = {"enabled": False, "listening": False}
 _counters = {"hits": 0, "misses": 0, "requests": 0}
 
 # registry mirrors of the jax-monitoring-fed counters, so /metrics and
@@ -70,64 +74,51 @@ def _on_event(event, **kw):
 
 
 def _ensure_listener():
-    # idempotent: one listener per process, registered lazily so plain
-    # `import paddle_tpu` without a cache dir never touches jax
-    # internals
+    # idempotent: one listener per process
     with _lock:
         if _state["listening"]:
             return
         _state["listening"] = True
-    try:
-        from jax._src import monitoring
-        monitoring.register_event_listener(_on_event)
-    except Exception:  # pragma: no cover - jax internals moved
-        with _lock:
-            _state["listening"] = False
+    from jax._src import monitoring
+    monitoring.register_event_listener(_on_event)
 
 
-def enable(dirname):
-    """Point jax's persistent compilation cache at ``dirname`` (created
-    if missing). Thresholds are zeroed so even sub-second test programs
-    cache — the warm-restart win scales with compile time, and caching
-    a tiny program costs one small file."""
+def enable():
+    """Turn the persistent compilation cache on, at the directory the
+    module docstring's rule gives, and return that directory.
+    Thresholds are zeroed so even sub-second programs cache — the win
+    scales with compile time, and a tiny program costs one small file."""
     import jax
     if _mid_process():
         # once per process, not per enable(): retry loops and tests
-        # re-point the cache freely and must not spam the log
+        # re-enable freely and must not spam the log
         from paddle_tpu.core.enforce import warn_once
         warn_once(
             "compile_cache_mid_process",
             "compilation cache enabled mid-process: computations "
             "compiled before enable() were not cached (jax's one-shot "
             "cache state is reset so later compiles are)")
-    dirname = os.path.abspath(dirname)
-    os.makedirs(dirname, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", dirname)
+    if not os.environ.get(ENV_VAR):
+        os.makedirs(DEFAULT_DIR, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", DEFAULT_DIR)
     # cache everything: the default 1s/0B floors exist to keep prod
     # caches small, but they would silently exclude the small programs
     # the warm-restart tests (and fast iteration loops) rely on
-    for knob, val in (("jax_persistent_cache_min_compile_time_secs", 0),
-                      ("jax_persistent_cache_min_entry_size_bytes", 0)):
-        try:
-            jax.config.update(knob, val)
-        except Exception:  # pragma: no cover - knob renamed upstream
-            pass
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
     _reset_jax_cache_state()
     _ensure_listener()
     with _lock:
-        _state["dir"] = dirname
-    return dirname
+        _state["enabled"] = True
+    return cache_dir()
 
 
 def _mid_process():
     """True when a jax backend already initialized — i.e. something may
     already have compiled, so this enable() is the 'mid-process' path
     whose earlier compiles the cache can never cover."""
-    try:
-        from jax._src import xla_bridge
-        return bool(xla_bridge._backends)
-    except Exception:  # pragma: no cover - jax internals moved
-        return False
+    from jax._src import xla_bridge
+    return bool(xla_bridge._backends)
 
 
 def _reset_jax_cache_state():
@@ -137,27 +128,29 @@ def _reset_jax_cache_state():
     # "no cache") and the config change would silently do nothing.
     # reset_cache() returns it to pristine so the next compile re-reads
     # the config.
-    try:
-        from jax._src import compilation_cache as _cc
-        _cc.reset_cache()
-    except Exception:  # pragma: no cover - jax internals moved
-        pass
+    from jax._src import compilation_cache as _cc
+    _cc.reset_cache()
 
 
 def disable():
+    """Stop caching in this process (tests restore state with it)."""
     import jax
     jax.config.update("jax_compilation_cache_dir", None)
     _reset_jax_cache_state()
     with _lock:
-        _state["dir"] = None
+        _state["enabled"] = False
 
 
 def is_enabled():
-    return _state["dir"] is not None
+    return _state["enabled"]
 
 
 def cache_dir():
-    return _state["dir"]
+    """The directory in force (jax's own config value), or None."""
+    if not _state["enabled"]:
+        return None
+    import jax
+    return jax.config.jax_compilation_cache_dir
 
 
 def stats():
@@ -172,12 +165,3 @@ def reset_stats():
     with _lock:
         for k in _counters:
             _counters[k] = 0
-
-
-def enable_from_env():
-    """Called from paddle_tpu.core import: activate iff the env asks.
-    Returns the cache dir or None."""
-    d = os.environ.get(ENV_VAR)
-    if d:
-        return enable(d)
-    return None

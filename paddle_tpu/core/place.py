@@ -11,6 +11,8 @@ import functools
 
 import jax
 
+from paddle_tpu.core.enforce import EnforceNotMet
+
 
 class Place:
     """Base device tag; wraps a jax.Device."""
@@ -31,12 +33,19 @@ class Place:
         return f"{type(self).__name__}({self.device_id})"
 
     def jax_device(self):
-        devs = [d for d in jax.devices() if _matches(d, self)]
-        if not devs:
-            # fall back to any available device (e.g. CPUPlace under
-            # tpu-only or TPUPlace under forced-cpu test runs)
-            devs = jax.local_devices()
-        return devs[min(self.device_id, len(devs) - 1)]
+        """The jax.Device this place names. A place that names a device
+        the process does not have is an error: TPUPlace(0) on a host
+        with no chip must not quietly become a CPU device."""
+        if self.device_kind == "cpu":
+            devs = jax.devices("cpu")
+        else:
+            devs = [d for d in jax.devices() if _matches(d, self)]
+        if self.device_id >= len(devs):
+            raise EnforceNotMet(
+                f"{self!r} names a device this process does not have: "
+                f"{len(devs)} {self.device_kind} device(s) visible "
+                f"(default platform {jax.devices()[0].platform!r})")
+        return devs[self.device_id]
 
 
 class CPUPlace(Place):

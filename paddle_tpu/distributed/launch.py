@@ -8,7 +8,10 @@ propagate the first failure. Two modes, like the reference:
   PADDLE_TRAINER_ID / PADDLE_TRAINERS_NUM / PADDLE_CURRENT_ENDPOINT /
   PADDLE_TRAINER_ENDPOINTS. On a TPU pod each process drives its own
   host's chips (JAX runtime discovers topology; the env is identity
-  metadata, not comm wiring — no gen_nccl_id exchange needed).
+  metadata, not comm wiring — no gen_nccl_id exchange needed). A chip
+  belongs to one process: the launcher itself never initialises a JAX
+  backend (asserted at every spawn), the default is ONE rank per host,
+  and with more, rank r is pinned to chip r (`_chip_env`).
 - ps (--server_num/--worker_num): pserver processes get
   TRAINING_ROLE=PSERVER + PADDLE_PSERVER_ENDPOINTS; workers get
   TRAINING_ROLE=TRAINER. Matches the reference's test_dist_base.py:429
@@ -69,7 +72,7 @@ import tempfile
 import threading
 import time
 
-from paddle_tpu.core.compile_cache import ENV_VAR as CACHE_ENV_VAR
+from paddle_tpu.core import compile_cache as _compile_cache
 from paddle_tpu.distributed import health
 from paddle_tpu.monitor import anomaly as _anomaly
 from paddle_tpu.monitor import exporter as _exporter
@@ -328,19 +331,19 @@ def _status_tick(hb_dir, log_dir, restarts, flagged_stragglers=None):
         _log(f"status tick failed (ignored): {type(e).__name__}: {e}")
 
 
-def _cache_dir_env(log_dir, env_extra):
-    """Default the workers' persistent XLA compilation-cache dir under
-    the log dir (one shared dir per job: cache keys are content hashes,
-    so ranks and *restarted incarnations* share entries safely). This is
-    what makes elastic restarts cheap — the respawned worker's step
-    compiles replay from disk instead of redoing XLA. An explicit
-    PADDLE_TPU_CACHE_DIR (ambient or via env_extra) wins; no log_dir
-    means no cache (nowhere durable to put it)."""
-    if not log_dir or os.environ.get(CACHE_ENV_VAR) \
-            or (env_extra and env_extra.get(CACHE_ENV_VAR)):
+def _cache_dir_env(env_extra):
+    """Where the workers keep their persistent XLA compilation cache.
+    Someone who set JAX_COMPILATION_CACHE_DIR (ambient or via env_extra)
+    placed it, and jax's own handling of the variable stands. Otherwise
+    the workers get the one fixed path inside the checkout
+    (core/compile_cache.py): a directory that moves — a log dir, a temp
+    dir — never hits, because the path is part of the key. Restarted
+    incarnations and later launches then replay their compiles from
+    disk."""
+    var = _compile_cache.ENV_VAR
+    if os.environ.get(var) or (env_extra and env_extra.get(var)):
         return {}
-    return {CACHE_ENV_VAR: os.path.join(os.path.abspath(log_dir),
-                                        "xla_cache")}
+    return {var: _compile_cache.DEFAULT_DIR}
 
 
 def find_free_ports(n, host="127.0.0.1"):
@@ -459,7 +462,63 @@ def _take_join_requests(join_dir, room):
     return taken
 
 
+class LauncherHoldsDeviceError(RuntimeError):
+    """The launcher parent initialised an accelerator backend. That
+    claims every local chip, and a chip belongs to one process: the
+    ranks about to be spawned would fail or hang waiting for it. (A
+    parent that touched only the CPU backend — the tests — holds
+    nothing.)"""
+
+
+class ChipAssignmentError(RuntimeError):
+    """The launcher cannot give every rank chips of its own."""
+
+
+#: libtpu's per-process chip selection; set for a whole gang they would
+#: point every rank at the same chips
+_LIBTPU_PIN_VARS = ("TPU_VISIBLE_CHIPS", "TPU_VISIBLE_DEVICES",
+                    "TPU_CHIPS_PER_PROCESS_BOUNDS", "TPU_PROCESS_BOUNDS")
+
+
+def _chip_env(rank, world, env):
+    """Device environment of one trainer rank. A chip belongs to one
+    process, so there are two legal layouts on a chip host: ONE rank
+    that drives every local chip (the JAX model, this launcher's
+    default, what the SPMD code in parallel/ wants), or several ranks
+    each pinned to the chip of its rank through libtpu's environment —
+    each then sees exactly one device, and is a one-chip world of its
+    own (two ranks on a v5e 2x2 host each report one local and one
+    global device, PR 21): nothing between such ranks rides ICI, so
+    SPMD over several chips wants the one-rank layout. Ranks bound for
+    the CPU (JAX_PLATFORMS=cpu) share the host freely and get
+    nothing."""
+    if world == 1 or env.get("JAX_PLATFORMS") == "cpu":
+        return {}
+    preset = [k for k in _LIBTPU_PIN_VARS if env.get(k)]
+    if preset:
+        raise ChipAssignmentError(
+            f"{', '.join(preset)} set for a gang of {world} ranks: "
+            f"every rank would claim the same chip(s). Unset it (the "
+            f"launcher pins rank r to chip r), or launch one rank.")
+    port = 8476 + rank
+    return {
+        "TPU_VISIBLE_CHIPS": str(rank),
+        "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+        "TPU_PROCESS_BOUNDS": "1,1,1",
+        # one libtpu runtime per rank: each needs its own controller port
+        "TPU_MESH_CONTROLLER_ADDRESS": f"localhost:{port}",
+        "TPU_MESH_CONTROLLER_PORT": str(port),
+    }
+
+
 def _spawn(cmd, env, log_prefix, log_dir, append=False):
+    xb = sys.modules.get("jax._src.xla_bridge")
+    held = sorted(p for p in (xb._backends if xb else ()) if p != "cpu")
+    if held:
+        raise LauncherHoldsDeviceError(
+            f"the launcher process initialised the JAX backend(s) "
+            f"{held} before spawning {log_prefix}; it must stay off "
+            f"jax.devices()/jit so its children can have the chips")
     if log_dir:
         os.makedirs(log_dir, exist_ok=True)
         out = open(os.path.join(log_dir, f"{log_prefix}.log"),
@@ -669,7 +728,7 @@ def launch_collective(script_args, nproc, started_port=None, ips="127.0.0.1",
             f"global_shuffle exchange endpoints)")
         allp = list(range(started_port, started_port + 2 * hi))
     hb_dir, hb_tmp = _make_hb_dir(log_dir)
-    cache_env = _cache_dir_env(log_dir, env_extra)
+    cache_env = _cache_dir_env(env_extra)
     pm_env = _postmortem_env(log_dir)
     tr_env = _trace_env(log_dir)
     gp_env = _goodput_env(log_dir)
@@ -696,6 +755,7 @@ def launch_collective(script_args, nproc, started_port=None, ips="127.0.0.1",
             for rank in range(world):
                 env = dict(os.environ, **(env_extra or {}), **cache_env,
                            **pm_env, **tr_env, **gp_env)
+                env.update(_chip_env(rank, world, env))
                 env.update({
                     "PADDLE_TRAINER_ID": str(rank),
                     "PADDLE_TRAINERS_NUM": str(world),
@@ -919,7 +979,7 @@ def launch_ps(script_args, server_num, worker_num, started_port=None,
     # (global_shuffle's sample exchange) rides these in PS mode too
     worker_eps = ",".join(f"{host}:{p}" for p in wports)
     hb_dir, hb_tmp = _make_hb_dir(log_dir)
-    cache_env = _cache_dir_env(log_dir, env_extra)
+    cache_env = _cache_dir_env(env_extra)
     pm_env = _postmortem_env(log_dir)
     tr_env = _trace_env(log_dir)
     # pserver failover (docs/ELASTIC_TRAINING.md "Pserver failover") is
@@ -970,6 +1030,9 @@ def launch_ps(script_args, server_num, worker_num, started_port=None,
     def spawn_server(i, attempt=0):
         env = dict(os.environ, **(env_extra or {}), **cache_env)
         env.update({
+            # a pserver is host-only: it must never take the chip from
+            # a trainer
+            "JAX_PLATFORMS": "cpu",
             "TRAINING_ROLE": "PSERVER",
             "PADDLE_TRAINER_ID": str(i),
             "PADDLE_TRAINERS_NUM": str(worker_num),
@@ -998,6 +1061,7 @@ def launch_ps(script_args, server_num, worker_num, started_port=None,
     def spawn_worker(i, attempt):
         env = dict(os.environ, **(env_extra or {}), **cache_env,
                    **pm_env, **tr_env)
+        env.update(_chip_env(i, worker_num, env))
         env.update({
             "TRAINING_ROLE": "TRAINER",
             "PADDLE_TRAINER_ID": str(i),
@@ -1402,9 +1466,13 @@ def _parse_args(argv):
         prog="paddle_tpu.distributed.launch",
         description="spawn one training process per rank (launch.py "
                     "parity) with elastic supervision")
-    ap.add_argument("--nproc_per_node", type=int, default=None,
-                    help="collective mode: trainers on this node "
-                         "(default: local device count)")
+    ap.add_argument("--nproc_per_node", type=int, default=1,
+                    help="collective mode: trainers on this node. "
+                         "Default 1: one process drives every local "
+                         "chip (the JAX model). With more, rank r is "
+                         "pinned to chip r through libtpu's "
+                         "environment and sees that one device; ranks "
+                         "on JAX_PLATFORMS=cpu share the host freely.")
     ap.add_argument("--ips", default="127.0.0.1")
     ap.add_argument("--started_port", type=int, default=None,
                     help="first port of the claimed range; collective "
@@ -1505,14 +1573,8 @@ def main(argv=None):
                        ps_min_servers=args.ps_min_servers,
                        ps_max_servers=args.ps_max_servers)
     else:
-        nproc = args.nproc_per_node
-        if nproc is None:
-            try:
-                import jax
-                nproc = max(jax.local_device_count(), 1)
-            except Exception:
-                nproc = 1
-        rc = launch_collective(script, nproc, args.started_port, args.ips,
+        rc = launch_collective(script, args.nproc_per_node,
+                               args.started_port, args.ips,
                                args.log_dir, timeout=args.timeout,
                                max_restarts=args.max_restarts,
                                hang_timeout=args.hang_timeout,

@@ -368,7 +368,7 @@ def export_aot(dirname, program, feed_names, fetch_names, scope,
     dir transparently with int8-resident params; the single-request
     ``Predictor`` keeps using the fp32 params file."""
     import jax
-    import jax.export  # not in the jax namespace by default on this pin
+    import jax.export
     from jax.experimental import serialize_executable as se
 
     from paddle_tpu.core.flags import get_flag

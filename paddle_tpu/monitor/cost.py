@@ -23,10 +23,10 @@ answers "how much of the hardware did we use". Sources:
 - ``estimate_mfu()`` divides achieved FLOP/s (flops_per_step over the
   ``executor_step_ms`` histogram's mean) by ``peak_flops()``.
 
-``peak_flops()`` is ``PADDLE_TPU_PEAK_FLOPS`` when set, else the v5e
-bf16 peak (197 TFLOP/s). On a CPU host that denominator is fiction —
-the MFU line is for TPU runs; docs/OBSERVABILITY.md spells out the
-caveats. jax is only imported inside functions: this module loads under
+``peak_flops()`` looks the device up in ``PEAK_FLOPS``, one table keyed
+by jax's ``device_kind``. A device that is not in the table (the CPU
+included) has no peak: ``peak_flops()`` raises and ``estimate_mfu()``
+is None. jax is only imported inside functions: this module loads under
 the stdlib-only launcher.
 """
 
@@ -41,11 +41,20 @@ __all__ = [
     "record_segment_comm", "segments", "flops_per_step",
     "bytes_per_step", "comm_bytes_per_step", "estimate_mfu",
     "peak_flops", "record_pass", "pass_evidence", "reset",
+    "PEAK_FLOPS", "UnknownDevicePeak",
 ]
 
-#: v5e bf16 peak, the chip this repo benches on (bench.py uses the same
-#: constant); override with PADDLE_TPU_PEAK_FLOPS for other hardware
-DEFAULT_PEAK_FLOPS = 197e12
+#: peak bf16 matmul FLOP/s of ONE chip, keyed by jax's ``device_kind``.
+#: The only peaks table in the repo (bench.py reads it too).
+PEAK_FLOPS = {
+    # Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16 per chip
+    "TPU v5 lite": 197e12,
+}
+
+
+class UnknownDevicePeak(LookupError):
+    """The device has no entry in ``PEAK_FLOPS``, so no utilization can
+    be stated for it."""
 
 _lock = threading.Lock()
 _segments = {}                  # group -> {index: {"flops","bytes"}}
@@ -59,6 +68,10 @@ _g_bytes = gauge(
     "segment_bytes",
     "Analytical bytes accessed per execution of each compiled device "
     "segment", labels=("segment",))
+_g_peak = gauge(
+    "device_peak_flops",
+    "Peak bf16 FLOP/s of one device of this rank's device_kind "
+    "(monitor/cost.PEAK_FLOPS); absent for a device with no entry")
 _g_comm = gauge(
     "segment_comm_bytes",
     "Estimated cross-device collective bytes per execution of each "
@@ -196,6 +209,11 @@ def record_segment(group, index, analysis):
         _latest_group = group
     _g_flops.set(analysis["flops"], segment=str(index))
     _g_bytes.set(analysis["bytes"], segment=str(index))
+    # the launcher's MFU line divides by this rank-published peak: the
+    # launcher itself never initialises a backend to ask for the device
+    peak = PEAK_FLOPS.get(_device_kind())
+    if peak is not None:
+        _g_peak.set(peak)
 
 
 def record_segment_comm(group, index, comm):
@@ -285,22 +303,34 @@ def pass_evidence():
         return {k: dict(v) for k, v in _pass_totals.items()}
 
 
-def peak_flops():
-    v = os.environ.get("PADDLE_TPU_PEAK_FLOPS")
-    try:
-        return float(v) if v else DEFAULT_PEAK_FLOPS
-    except ValueError:
-        return DEFAULT_PEAK_FLOPS
+def _device_kind():
+    import jax
+    return jax.devices()[0].device_kind
+
+
+def peak_flops(device_kind=None):
+    """Peak bf16 FLOP/s of one device of ``device_kind`` (default: this
+    process's first device). Raises ``UnknownDevicePeak`` for a device
+    that is not in ``PEAK_FLOPS`` — there is no default peak."""
+    kind = _device_kind() if device_kind is None else device_kind
+    if kind not in PEAK_FLOPS:
+        raise UnknownDevicePeak(
+            f"no peak FLOP/s on record for device_kind {kind!r} "
+            f"(known: {sorted(PEAK_FLOPS)}); add it to "
+            f"monitor/cost.PEAK_FLOPS with its source")
+    return PEAK_FLOPS[kind]
 
 
 def estimate_mfu(ms_per_step=None):
     """Model FLOPs utilization in [0, 1], or None when either side of
-    the ratio is missing. ``ms_per_step`` defaults to the mean of the
+    the ratio is missing — including the peak, on a device that is not
+    in ``PEAK_FLOPS``. ``ms_per_step`` defaults to the mean of the
     ``executor_step_ms`` histogram (wall time around dispatch — on a
     host-overhead-bound model this UNDERSTATES device utilization;
     see docs/OBSERVABILITY.md)."""
     flops = flops_per_step()
-    if not flops:
+    peak = PEAK_FLOPS.get(_device_kind())
+    if not flops or peak is None:
         return None
     if ms_per_step is None:
         from paddle_tpu.monitor.registry import REGISTRY
@@ -310,7 +340,7 @@ def estimate_mfu(ms_per_step=None):
         ms_per_step = h.sum() / h.count()
     if ms_per_step <= 0:
         return None
-    return flops / (ms_per_step / 1e3) / peak_flops()
+    return flops / (ms_per_step / 1e3) / peak
 
 
 def reset():

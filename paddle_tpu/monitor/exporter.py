@@ -289,8 +289,9 @@ def job_status_line(hb_dir, restarts=0, snaps=None, health=None,
 
     ``step`` is the max across ranks (they advance together in data
     parallel); ms/step pools every rank's histogram; mfu uses the
-    max-across-ranks per-step FLOPs (see ``monitor.cost`` for the
-    peak-FLOPs source and its CPU-host caveats); ``health`` comes from
+    max-across-ranks per-step FLOPs over the ranks' published
+    ``device_peak_flops`` (``monitor.cost.PEAK_FLOPS``; no entry for
+    the device, no mfu field); ``health`` comes from
     ``monitor.anomaly.job_health`` — anomaly trips any rank exported
     plus step-time-skew straggler detection over the same snapshots.
     Pass pre-read ``snaps`` and a pre-computed ``health`` string to
@@ -336,10 +337,11 @@ def job_status_line(hb_dir, restarts=0, snaps=None, health=None,
         if limit > 0:
             mem += f"/{limit / gb:.2f}"
         parts.append(mem + "GB")
-    if flops > 0 and ms > 0:
-        from paddle_tpu.monitor.cost import peak_flops
-        mfu = flops / (ms / 1e3) / peak_flops()
-        parts.append(f"mfu={mfu:.4f}")
+    # the ranks publish their device's peak (monitor/cost.PEAK_FLOPS);
+    # ranks on a device with no entry publish none, and get no mfu
+    peak = _max_matching(merged, "device_peak_flops")
+    if flops > 0 and ms > 0 and peak > 0:
+        parts.append(f"mfu={flops / (ms / 1e3) / peak:.4f}")
     from paddle_tpu.monitor import goodput as _goodput
     frac = _goodput.fraction_of(merged)
     if frac is not None:

@@ -397,12 +397,8 @@ def embedding(ids, weight, padding_idx=None, name=None):
     squeeze = False
     if ids.ndim and ids.shape[-1] == 1:
         ids, squeeze = ids[..., 0], True
-    from paddle_tpu.ops import pallas as _plk
     weight = jnp.asarray(weight)
-    if weight.ndim == 2 and _plk.use_pallas("embedding_gather"):
-        out = _plk.dispatch("embedding_gather", weight, ids)
-    else:
-        out = jnp.take(weight, ids, axis=0)
+    out = jnp.take(weight, ids, axis=0)
     if padding_idx is not None:
         if padding_idx < 0:  # fluid convention: -1 means last row
             padding_idx = weight.shape[0] + padding_idx

@@ -2,15 +2,15 @@
 "Pallas kernel layer").
 
 Importing this package registers every built-in kernel:
-fused_matmul / fused_matmul_int8 (matmul.py), embedding_gather /
-embedding_scatter_add (embedding.py), fused_sgd / fused_momentum /
+fused_matmul / fused_matmul_int8 (matmul.py), embedding_scatter_add
+(embedding.py), fused_sgd / fused_momentum /
 fused_adam (optimizer.py), and — via ops/pallas_kernels.py —
 flash_attention / fused_layer_norm / softmax_cross_entropy."""
 
 from paddle_tpu.ops.pallas.registry import (  # noqa: F401
     DEFAULT_VMEM_BUDGET, register_kernel, get_kernel, list_kernels,
     dispatch, get_body, selected_body, use_pallas, selection_mode,
-    override, platform, within_vmem_budget,
+    override, mesh_scope, platform, within_vmem_budget,
 )
 from paddle_tpu.ops.pallas import matmul as _matmul  # noqa: F401
 from paddle_tpu.ops.pallas import embedding as _embedding  # noqa: F401
@@ -30,6 +30,6 @@ except ImportError:  # pragma: no cover - circular during package init
 __all__ = [
     "register_kernel", "get_kernel", "list_kernels", "dispatch",
     "get_body", "selected_body", "use_pallas", "selection_mode",
-    "override", "platform", "try_fused_matmul",
+    "override", "mesh_scope", "platform", "try_fused_matmul",
     "within_vmem_budget", "DEFAULT_VMEM_BUDGET",
 ]

@@ -1,18 +1,18 @@
-"""Pallas bodies for the sparse embedding hot pair.
+"""Pallas body for the sparse embedding scatter-add.
 
-- ``embedding_gather`` — rows = table[ids]: scalar-prefetched index map
-  (PrefetchScalarGridSpec) so each grid step DMAs exactly the one table
-  row it emits; stock body is ``jnp.take(..., mode="clip")``.
-- ``embedding_scatter_add`` — dst[ids] += updates, the segment-sum /
-  ``.at[].add`` pattern behind merge_selected_rows, sparse SGD and the
-  NativeSparseTable apply path. The Pallas body reduces each
-  destination-row block with a one-hot [rows_block, n] @ [n, d] matmul —
-  duplicate indices are summed by the dot itself, so the result is
-  deterministic by construction (same property the stock segment_sum
-  gives, unlike loop-carried float adds).
+``embedding_scatter_add`` — dst[ids] += updates, the segment-sum /
+``.at[].add`` pattern behind merge_selected_rows, sparse SGD and the
+NativeSparseTable apply path. The Pallas body reduces each
+destination-row block with a one-hot [rows_block, n] @ [n, d] matmul —
+duplicate indices are summed by the dot itself, so the result is
+deterministic by construction (same property the stock segment_sum
+gives, unlike loop-carried float adds). Differentiable via custom_vjp
+(the backward is a stock-jnp gather, not a nested kernel).
 
-Both bodies are differentiable via custom_vjp (the backward of gather is
-scatter-add and vice versa — stock-jnp, not nested kernels)."""
+The lookup itself (rows = table[ids]) has no Pallas body: a one-row
+block breaks Mosaic's (8, 128) block rule, a one-row DMA its tile
+alignment, and an 8-row-group body moves 8x the bytes in one grid step
+per id. ``ops/nn.embedding`` takes ``jnp.take``."""
 
 import functools
 
@@ -22,94 +22,11 @@ from jax.experimental import pallas as pl
 
 from paddle_tpu.ops.pallas import registry as _registry
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PLTPU = True
-except Exception:  # pragma: no cover
-    pltpu = None
-    _HAS_PLTPU = False
-
 __all__ = []
 
 
 def _round_up(v, m):
     return -(-v // m) * m
-
-
-# -- gather ----------------------------------------------------------------
-
-def embedding_gather_reference(table, ids, interpret=None):
-    """Stock lookup (jnp.take default semantics: out-of-bounds rows fill
-    with NaN for float tables)."""
-    return jnp.take(jnp.asarray(table), jnp.asarray(ids), axis=0)
-
-
-def _gather_kernel(ids_ref, tbl_ref, o_ref):
-    del ids_ref  # consumed by the index map
-    o_ref[...] = tbl_ref[...]
-
-
-def _gather_call(table, ids, interpret):
-    n = ids.shape[0]
-    h, d = table.shape
-    dp = _round_up(d, 128)
-    if dp != d:
-        table = jnp.pad(table, ((0, 0), (0, dp - d)))
-    # clip to match jnp.take's default OOB mode
-    ids32 = jnp.clip(ids.astype(jnp.int32), 0, h - 1)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(n,),
-        in_specs=[pl.BlockSpec((1, dp), lambda i, idref: (idref[i], 0))],
-        out_specs=pl.BlockSpec((1, dp), lambda i, idref: (i, 0)),
-    )
-    out = pl.pallas_call(
-        _gather_kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n, dp), table.dtype),
-        interpret=interpret,
-    )(ids32, table)
-    return out[:, :d] if dp != d else out
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))
-def _gather(table, ids, shape, dtype_name, interpret):
-    return _gather_call(table, ids, interpret)
-
-
-def _gather_fwd(table, ids, shape, dtype_name, interpret):
-    return _gather_call(table, ids, interpret), ids
-
-
-def _gather_bwd(shape, dtype_name, interpret, ids, dy):
-    ids32 = jnp.clip(ids.astype(jnp.int32), 0, shape[0] - 1)
-    d_table = jnp.zeros(shape, jnp.float32).at[ids32].add(
-        dy.astype(jnp.float32))
-    return d_table.astype(dtype_name), None
-
-
-_gather.defvjp(_gather_fwd, _gather_bwd)
-
-
-def embedding_gather_pallas(table, ids, interpret=False):
-    """rows = table[ids] via a scalar-prefetched row-DMA kernel."""
-    table = jnp.asarray(table)
-    ids = jnp.asarray(ids)
-    lead = ids.shape
-    flat = ids.reshape(-1)
-    if flat.shape[0] == 0:
-        return jnp.zeros(lead + (table.shape[1],), table.dtype)
-    if not _HAS_PLTPU:  # pragma: no cover - interpret still needs pltpu spec
-        return embedding_gather_reference(table, ids)
-    out = _gather(table, flat, tuple(table.shape), table.dtype.name,
-                  bool(interpret))
-    if jnp.issubdtype(table.dtype, jnp.inexact):
-        # the kernel clips OOB ids to a real row; stock jnp.take fills
-        # them with NaN — mask outside the kernel so forward AND (via
-        # where's vjp zeroing the cotangent) backward match exactly
-        valid = (flat >= 0) & (flat < table.shape[0])
-        out = jnp.where(valid[:, None], out, jnp.nan)
-    return out.reshape(lead + (table.shape[1],))
 
 
 # -- scatter-add -----------------------------------------------------------
@@ -178,6 +95,7 @@ def _scatter_bwd(interpret, ids, dy):
 
 _scatter_add.defvjp(_scatter_fwd, _scatter_bwd)
 
+
 def embedding_scatter_add_pallas(dst, ids, updates, interpret=False):
     """dst[ids] += updates via per-row-block one-hot matmul reduction."""
     dst = jnp.asarray(dst)
@@ -195,9 +113,6 @@ def embedding_scatter_add_pallas(dst, ids, updates, interpret=False):
     return _scatter_add(dst, ids, updates, bool(interpret))
 
 
-_registry.register_kernel(
-    "embedding_gather", embedding_gather_reference, embedding_gather_pallas,
-    doc="rows = table[ids] (scalar-prefetched row DMA)")
 _registry.register_kernel(
     "embedding_scatter_add", embedding_scatter_add_reference,
     embedding_scatter_add_pallas,

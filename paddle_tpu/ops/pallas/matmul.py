@@ -22,15 +22,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from paddle_tpu.ops.pallas import registry as _registry
-
-try:  # pltpu import fails on some CPU-only builds; interpret mode works
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PLTPU = True
-except Exception:  # pragma: no cover
-    pltpu = None
-    _HAS_PLTPU = False
 
 __all__ = ["try_fused_matmul"]
 
@@ -50,8 +44,7 @@ _ACTS = {
 
 
 def _vmem_spec(*args, **kwargs):
-    if _HAS_PLTPU:
-        kwargs.setdefault("memory_space", pltpu.VMEM)
+    kwargs.setdefault("memory_space", pltpu.VMEM)
     return pl.BlockSpec(*args, **kwargs)
 
 

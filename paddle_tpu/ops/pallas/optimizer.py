@@ -17,15 +17,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from paddle_tpu.ops.pallas import registry as _registry
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PLTPU = True
-except Exception:  # pragma: no cover
-    pltpu = None
-    _HAS_PLTPU = False
 
 __all__ = []
 
@@ -33,8 +27,7 @@ _LANES = 128
 
 
 def _vmem_spec(*args, **kwargs):
-    if _HAS_PLTPU:
-        kwargs.setdefault("memory_space", pltpu.VMEM)
+    kwargs.setdefault("memory_space", pltpu.VMEM)
     return pl.BlockSpec(*args, **kwargs)
 
 

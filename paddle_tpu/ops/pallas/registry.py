@@ -6,7 +6,11 @@ Mirrors the op-registry pattern (``register_op`` in
 trace/compile time:
 
 - ``auto`` (default): Pallas body on an accelerator, stock reference on
-  CPU — tier-1 stays on the exact jnp semantics it always had.
+  CPU — tier-1 stays on the exact jnp semantics it always had. Inside a
+  :func:`mesh_scope` of more than one device ``auto`` also selects the
+  reference: Mosaic calls are not partitioned by GSPMD (the lowering
+  refuses them), so a step traced for a multi-device mesh takes the
+  bodies XLA can partition. Which body is faster there is not measured.
 - ``on``: force the Pallas body everywhere; on CPU it runs in Pallas
   interpreter mode (the same kernel code path the TPU compiles).
 - ``off``: force the stock reference everywhere.
@@ -31,14 +35,17 @@ from paddle_tpu.core.flags import define_flag, get_flag
 __all__ = [
     "register_kernel", "get_kernel", "list_kernels", "dispatch",
     "get_body", "selected_body", "use_pallas", "selection_mode",
-    "override", "platform", "within_vmem_budget",
+    "override", "mesh_scope", "platform", "within_vmem_budget",
     "DEFAULT_VMEM_BUDGET",
 ]
 
-#: fp32 elements a kernel body may hold whole in VMEM (~16 MB of a
-#: v5e core's ~16 MB/core VMEM at 4 B/element) — the shared default
-#: every budget-guarded kernel falls back past
-DEFAULT_VMEM_BUDGET = 4 << 20
+#: fp32 elements one operand may hold whole in VMEM: 8 MiB, half of
+#: Mosaic's 16 MiB scoped-VMEM limit per kernel (the limit stands unless
+#: the call raises it with pltpu.CompilerParams). The other half is for
+#: the streamed, double-buffered blocks and the body's intermediates; at
+#: 16 MiB the compiler refuses the kernel (tests/test_tpu_aot_compile.py
+#: compiles the budget's edge).
+DEFAULT_VMEM_BUDGET = 2 << 20
 
 _REGISTRY = {}
 _lock = threading.Lock()
@@ -105,10 +112,7 @@ def platform():
     backend registry on every call — on the per-step hot path (every
     kernel invocation) the probe must be paid exactly once."""
     import jax
-    try:
-        return jax.devices()[0].platform
-    except Exception:  # pragma: no cover - no backend at all
-        return "cpu"
+    return jax.devices()[0].platform
 
 
 def selection_mode():
@@ -135,6 +139,29 @@ def override(mode):
         stack.pop()
 
 
+@contextlib.contextmanager
+def mesh_scope(mesh):
+    """Declare that the code traced inside is lowered for ``mesh`` by
+    GSPMD (a jitted step with mesh-sharded operands). Entered by the
+    trainers and the executor INSIDE the function they jit, so it is
+    active while that function is traced. ``None`` and one-device meshes
+    change nothing. Not for shard_map bodies: there a Mosaic call runs
+    per shard and stays legal."""
+    stack = getattr(_tls, "mesh_sizes", None)
+    if stack is None:
+        stack = _tls.mesh_sizes = []
+    stack.append(1 if mesh is None else int(mesh.size))
+    try:
+        yield
+    finally:
+        stack.pop()
+
+
+def _partitioned():
+    stack = getattr(_tls, "mesh_sizes", None)
+    return bool(stack) and stack[-1] > 1
+
+
 def selected_body(name):
     """Which body a dispatch of ``name`` would run right now:
     'pallas' (compiled), 'pallas_interpret' (CPU interpreter mode), or
@@ -148,7 +175,7 @@ def selected_body(name):
     cpu = platform() == "cpu"
     if mode == "on":
         return "pallas_interpret" if cpu else "pallas"
-    return "reference" if cpu else "pallas"
+    return "reference" if cpu or _partitioned() else "pallas"
 
 
 def use_pallas(name):

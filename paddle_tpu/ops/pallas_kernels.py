@@ -32,32 +32,24 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from paddle_tpu.ops.pallas import registry as _registry
-
-try:  # pltpu import fails on some CPU-only builds; interpret mode works
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PLTPU = True
-except Exception:  # pragma: no cover
-    pltpu = None
-    _HAS_PLTPU = False
 
 __all__ = ["flash_attention", "fused_layer_norm", "softmax_cross_entropy"]
 
 _NEG_INF = -1e30
 
-
-def _auto_interpret(interpret):
-    if interpret is not None:
-        return interpret
-    # registry.platform() is the per-process cached probe — jax.devices()
-    # must not be re-walked on every kernel invocation (hot path)
-    return _registry.platform() == "cpu"
+#: scoped-VMEM limit for the three flash kernels. Each holds one head's
+#: whole-sequence operands resident (K/V forward and dQ; Q/dO and the
+#: lane-padded [S, 1] lse/delta columns in dK/dV), which passes Mosaic's
+#: 16 MiB default at S=4096 (16.16 MiB inside the BERT step). 64 MiB is
+#: half of a v5e core's 128 MiB of VMEM.
+_FLASH_COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=64 << 20)
 
 
 def _vmem_spec(*args, **kwargs):
-    if _HAS_PLTPU:
-        kwargs.setdefault("memory_space", pltpu.VMEM)
+    kwargs.setdefault("memory_space", pltpu.VMEM)
     return pl.BlockSpec(*args, **kwargs)
 
 
@@ -159,6 +151,7 @@ def _flash_fwd(q, k, v, bias, sm_scale, causal, block_q, block_k,
             jax.ShapeDtypeStruct(q.shape, q.dtype),
             jax.ShapeDtypeStruct((b, h, s, 1), jnp.float32),
         ],
+        compiler_params=_FLASH_COMPILER_PARAMS,
         interpret=interpret,
     )(q, k, v, bias[:, None, :])
     return o, lse4[..., 0]
@@ -307,6 +300,7 @@ def _flash_attention_bwd(sm_scale, causal, block_q, block_k, interpret,
             jax.ShapeDtypeStruct(v.shape, v.dtype),
             jax.ShapeDtypeStruct((b, h, s, 1), jnp.float32),
         ],
+        compiler_params=_FLASH_COMPILER_PARAMS,
         interpret=interpret,
     )(q, do, lse4, delta, k, v, bias3)
     kernel_q = functools.partial(
@@ -333,6 +327,7 @@ def _flash_attention_bwd(sm_scale, causal, block_q, block_k, interpret,
                        lambda ib, ih, iq: (ib, ih, iq, 0)),
         ],
         out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype)],
+        compiler_params=_FLASH_COMPILER_PARAMS,
         interpret=interpret,
     )(q, do, lse4, delta, k, v, bias3)[0]
     dbias = jnp.sum(dbh[..., 0], axis=1)                   # [B,S]

@@ -229,10 +229,7 @@ def _pallas_fused_update(opt, p, g, slots, lr, t):
     ``opt._update`` unchanged, so the flag-off path is bit-identical.
     Output dtypes are pinned to the stock rule's promotion behavior via
     ``jax.eval_shape`` over the registered reference body."""
-    try:
-        from paddle_tpu.ops import pallas as _plk
-    except Exception:  # pragma: no cover - partial build
-        return None
+    from paddle_tpu.ops import pallas as _plk
     cls = type(opt)
     if cls is SGDOptimizer:
         name, args, kw = "fused_sgd", (p, g, lr), {}

@@ -22,15 +22,13 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from paddle_tpu.core.enforce import EnforceNotMet
+from paddle_tpu.ops.pallas.registry import mesh_scope
 from paddle_tpu.parallel.mesh import DATA_AXIS, data_axes, get_mesh
 
-from paddle_tpu.parallel._compat import (
-    SHARD_MAP_CHECK_KW as _SHARD_MAP_CHECK_KW, shard_map,
-)
 
 __all__ = ["shard_batch", "replicate", "zero_param_specs",
            "DataParallelTrainer"]
@@ -183,9 +181,13 @@ class DataParallelTrainer:
             return jnp.mean(losses), grads, new_state
 
         def plain_step(params, opt_state, state, rng, batch):
-            loss, grads, new_state = fwd_bwd(params, state, rng, batch)
-            new_params, new_opt = self.opt.apply_gradients(
-                params, grads, opt_state)
+            # GSPMD partitions this step; zero_step's shard_map body
+            # runs per shard and needs no scope
+            with mesh_scope(self.mesh):
+                loss, grads, new_state = fwd_bwd(params, state, rng,
+                                                 batch)
+                new_params, new_opt = self.opt.apply_gradients(
+                    params, grads, opt_state)
             return loss, new_params, new_opt, new_state
 
         def zero_step(params, opt_state, state, rng, batch):
@@ -227,14 +229,11 @@ class DataParallelTrainer:
                 new_p, new_o = self.opt.apply_gradients(p_sh, g_sh, o_sh)
                 return loss, new_p, new_o, new_st
 
-            kwargs = dict(
-                mesh=self.mesh,
+            return shard_map(
+                body, mesh=self.mesh,
                 in_specs=(specs, opt_specs, state_specs, P(), batch_specs),
                 out_specs=(P(), specs, opt_specs, state_specs),
-            )
-            kwargs[_SHARD_MAP_CHECK_KW] = False
-            return shard_map(body, **kwargs)(
-                params, opt_state, state, rng, batch)
+                check_vma=False)(params, opt_state, state, rng, batch)
 
         def step(params, opt_state, state, rng, batch):
             if self._param_specs is None:

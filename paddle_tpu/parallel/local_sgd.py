@@ -19,8 +19,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from paddle_tpu.parallel._compat import CHECK_DISABLED as _CHECK_KW
-from paddle_tpu.parallel._compat import shard_map
+from jax import shard_map
 from paddle_tpu.parallel.mesh import DATA_AXIS, get_mesh
 
 __all__ = ["LocalSGDTrainer"]
@@ -61,7 +60,7 @@ class LocalSGDTrainer:
         @functools.partial(
             shard_map, mesh=mesh,
             in_specs=(pspec, P(), bspec), out_specs=(P(ax), P()),
-            **_CHECK_KW)
+            check_vma=False)
         def step(params, stepno, local_batch):
             p = jax.tree.map(lambda t: t[0], params)   # this replica's
             loss, grads = jax.value_and_grad(loss_fn)(p, local_batch)

@@ -18,8 +18,6 @@ Canonical axis names:
 import contextlib
 from dataclasses import dataclass, field
 
-import numpy as np
-
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -86,9 +84,9 @@ def make_mesh(config=None, devices=None):
       highest-bandwidth-demand axis ("model", default axis_order) sits
       innermost on the tightest ICI ring (the inter/exter ring split of
       parallel_executor.cc:158-180).
-    On real multi-slice TPU fleets the hybrid layout is taken from the
-    platform topology (mesh_utils.create_hybrid_device_mesh) when
-    available; virtual/CPU platforms use the order of jax.devices().
+    On a TPU the layout is taken from the platform topology
+    (mesh_utils.create_device_mesh; create_hybrid_device_mesh across
+    slices); virtual/CPU platforms use the order of jax.devices().
     """
     devices = devices if devices is not None else jax.devices()
     config = config or MeshConfig()
@@ -115,7 +113,10 @@ def make_mesh(config=None, devices=None):
     used = 1
     for s in shape:
         used *= s
-    arr = np.array(devices[:used]).reshape(shape)
+    # topology-aware on a TPU (the mesh axes follow the chips' ICI
+    # coordinates); a plain reshape of the device list elsewhere
+    from jax.experimental import mesh_utils
+    arr = mesh_utils.create_device_mesh(shape, devices[:used])
     return Mesh(arr, names)
 
 

@@ -29,11 +29,10 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
+from jax import lax, shard_map
 
 from paddle_tpu.core.flags import define_flag, get_flag
-from paddle_tpu.parallel._compat import CHECK_DISABLED as _CHECK_KW
-from paddle_tpu.parallel._compat import shard_map
+from paddle_tpu.ops.pallas.registry import mesh_scope
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from .mesh import DATA_AXIS, DCN_AXIS, PIPE_AXIS
@@ -177,7 +176,7 @@ def pipeline_apply(mesh, stage_fn, stacked_params, microbatches,
 
     return shard_map(f, mesh=mesh,
                      in_specs=(pspec, dspec), out_specs=dspec,
-                     **_CHECK_KW)(stacked_params, microbatches)
+                     check_vma=False)(stacked_params, microbatches)
 
 
 class PipelineModule:
@@ -278,8 +277,9 @@ class PipelineModule:
         @jax.jit
         def step(params, opt_state, batch_x, batch_y):
             loss, grads = loss_and_grads(params, batch_x, batch_y)
-            new_params, new_opt = optimizer.apply_gradients(
-                params, grads, opt_state)
+            with mesh_scope(mesh):
+                new_params, new_opt = optimizer.apply_gradients(
+                    params, grads, opt_state)
             return loss, new_params, new_opt
 
         def init_fn(params):
@@ -535,5 +535,5 @@ def pipeline_train_1f1b(mesh, stage_fn, stacked_params, microbatches,
         in_specs=(pspec, dspec,
                   jax.tree.map(lambda _: lspec, labels), hspec),
         out_specs=(P(), pspec, hspec, dspec),
-        **_CHECK_KW)(stacked_params, microbatches, labels,
+        check_vma=False)(stacked_params, microbatches, labels,
                      head_params)

@@ -27,7 +27,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
-from paddle_tpu.parallel._compat import shard_map
+from jax import shard_map
 
 from .mesh import DATA_AXIS, MODEL_AXIS, SEQ_AXIS
 

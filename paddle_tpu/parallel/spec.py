@@ -11,8 +11,7 @@ layer consumes:
   ``CompiledProgram.with_mesh_sharding(spec)`` program places its
   persistable state per ``param_spec``, shards feed batches per
   ``feed_spec``, and pins the spec'd names inside each compiled device
-  segment with ``with_sharding_constraint`` — the pjit lowering (the
-  jax 0.4.37 pin has no ``jax.shard_map``; see parallel/_compat.py).
+  segment with ``with_sharding_constraint`` — the pjit lowering.
 - the functional trainers (pipeline/data_parallel/models): pytrees map
   through the same spec by tree path (``tree_specs``/``tree_shardings``).
 - the checkpoint layer: ``checkpoint_axes`` derives ``save(axes=)``

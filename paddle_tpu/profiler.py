@@ -295,8 +295,7 @@ def summary(sorted_key="total", profile_path=None):
         lines.append(
             f"MFU estimate: {mfu * 100:.2f}% "
             f"(flops/step={_cost.flops_per_step():.3e}, "
-            f"ms/step={ms:.3f}, peak={_cost.peak_flops():.3e} FLOP/s "
-            f"-- see docs/OBSERVABILITY.md for CPU-host caveats)")
+            f"ms/step={ms:.3f}, peak={_cost.peak_flops():.3e} FLOP/s)")
     from paddle_tpu.monitor import memory as _memory
     mem_line = _memory.summary_line()
     if mem_line is not None:

@@ -5,8 +5,8 @@ frozen program's params, and one ahead-of-time compiled XLA executable
 per bucket of the ladder — compiled at **warm boot** (pool
 construction), before the server accepts traffic, so the first real
 request never pays a trace or an XLA compile. When the PR-2 persistent
-compilation cache is armed (``PADDLE_TPU_CACHE_DIR``, wired at
-``paddle_tpu.core`` import), warm boot itself is a disk read on every
+compilation cache is armed (``JAX_COMPILATION_CACHE_DIR``; see
+``core/compile_cache.py``), warm boot itself is a disk read on every
 boot after the first.
 
 Replicas are fed from ONE shared batch queue (the scheduler's dispatch

@@ -284,7 +284,8 @@ class TestBenchModes:
             row = by.get(f"shard_{topo}_step_ms")
             assert row is not None, by.keys()
             assert row["value"] > 0 and row["unit"] == "ms"
-            assert row["mfu"] > 0
+            # the CPU has no peak on record (monitor/cost.PEAK_FLOPS): no MFU
+            assert row["mfu"] is None
             assert "comm_bytes_per_step" in row
             assert row["layout"]["n_devices"] == 1
             assert len(row["windows_ms_per_step"]) >= 2
@@ -426,7 +427,7 @@ class TestBenchModes:
         by = {ln["metric"]: ln for ln in lines}
         expected = [
             "kernel_matmul_ratio", "kernel_matmul_int8_ratio",
-            "kernel_embedding_ratio", "kernel_scatter_add_ratio",
+            "kernel_scatter_add_ratio",
             "kernel_optimizer_ratio", "kernel_attention_ratio",
             "kernel_layer_norm_ratio", "kernel_xent_ratio",
         ]

@@ -284,14 +284,22 @@ class TestDPResidency:
 
 class TestPersistentCache:
     def test_aot_prepare_then_run_hits_disk_cache(
-            self, static_mode, data, fresh_programs, tmp_path):
-        """prepare() lowers+compiles eagerly, writing the cache entry;
-        the first real step's compile is then a disk HIT, and a second
-        executor (fresh jit objects, same program) also compiles purely
-        from disk — the in-process proof of the warm-restart path."""
+            self, static_mode, data, fresh_programs, tmp_path,
+            monkeypatch):
+        """prepare() lowers+compiles eagerly, writing the cache entry
+        (the same executor's first real step then reuses that
+        executable in memory, with no compile request at all); a second
+        executor (fresh jit objects, same program) compiles purely from
+        disk — the in-process proof of the warm-restart path."""
         from paddle_tpu.core import compile_cache
         xb, yb = data
-        compile_cache.enable(str(tmp_path / "xla_cache"))
+        # a cold cache of the test's own: stand in for the fixed
+        # in-checkout path (JAX_COMPILATION_CACHE_DIR is read by jax at
+        # import, too early for a test to place the cache with it)
+        monkeypatch.delenv(compile_cache.ENV_VAR, raising=False)
+        monkeypatch.setattr(compile_cache, "DEFAULT_DIR",
+                            str(tmp_path / "cache"))
+        assert compile_cache.enable() == str(tmp_path / "cache")
         compile_cache.reset_stats()
         try:
             main, startup, loss = _build()
@@ -301,9 +309,7 @@ class TestPersistentCache:
                                fetch_list=[loss])
             assert full                     # single device segment
             assert compile_cache.stats()["misses"] > 0
-            before = compile_cache.stats()["hits"]
             exe.run(main, feed={"x": xb, "y": yb}, fetch_list=[loss])
-            assert compile_cache.stats()["hits"] > before
             # a fresh executor = fresh jit functions = the restarted-
             # process shape, minus the process boundary
             exe2 = pt.static.Executor()
@@ -334,12 +340,15 @@ class TestPersistentCache:
         # first real step neither retraces nor re-lowers
         assert exe.trace_count == t0
 
-    def test_profiler_surfaces_counters(self, tmp_path):
+    def test_profiler_surfaces_counters(self, tmp_path, monkeypatch):
         from paddle_tpu import profiler
         from paddle_tpu.core import compile_cache
         s = profiler.compilation_cache_stats()
         assert set(s) >= {"hits", "misses", "requests"}
-        compile_cache.enable(str(tmp_path / "c"))
+        monkeypatch.delenv(compile_cache.ENV_VAR, raising=False)
+        monkeypatch.setattr(compile_cache, "DEFAULT_DIR",
+                            str(tmp_path / "c"))
+        compile_cache.enable()
         try:
             assert "compilation cache:" in profiler.summary()
         finally:
@@ -365,6 +374,8 @@ class TestWarmRestartEndToEnd:
                 "PYTHONPATH", ""),
             "PT_FAULT_CRASH_AT_STEP": "2",
             "PT_FAULT_ONCE_DIR": str(tmp_path / "once"),
+            # the cache is placed from outside, by jax's own variable
+            "JAX_COMPILATION_CACHE_DIR": str(tmp_path / "cache"),
         }
         rc = launch_collective(
             [WORKER, str(out), "4"], nproc=1, log_dir=str(log_dir),
@@ -376,10 +387,12 @@ class TestWarmRestartEndToEnd:
             pytest.fail(f"launch rc={rc}{logs}")
         cold = json.loads((tmp_path / "wr.inc0.json").read_text())
         warm = json.loads((tmp_path / "wr.inc1.json").read_text())
-        # the launcher defaulted the cache dir under log_dir and both
-        # incarnations shared it
-        assert cold["cache_dir"] == str(log_dir / "xla_cache")
+        # both incarnations kept their cache where the variable said,
+        # and nowhere else
+        assert cold["cache_dir"] == str(tmp_path / "cache")
         assert warm["cache_dir"] == cold["cache_dir"]
+        assert os.listdir(tmp_path / "cache")
+        assert not (log_dir / "xla_cache").exists()
         # cold start compiled for real; warm restart compiled from disk
         assert cold["misses"] > 0
         assert warm["hits"] > 0

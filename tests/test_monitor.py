@@ -247,8 +247,14 @@ class TestExporter:
                 h.observe(4.0)
             r.gauge("segment_flops", labels=("segment",)).set(
                 2e6, segment="0")
+            if rank == 1:       # one rank on a device with a known peak
+                r.gauge("device_peak_flops").set(197e12)
             exporter.write_snapshot(
                 health.metrics_path(str(tmp_path), rank), r)
+            if rank == 0:
+                # no rank has published a peak yet: no mfu field
+                assert "mfu=" not in exporter.job_status_line(
+                    str(tmp_path))
         snaps = exporter.read_rank_snapshots(str(tmp_path))
         assert sorted(snaps) == [0, 1]
         line = exporter.job_status_line(str(tmp_path), restarts=3)
@@ -480,7 +486,7 @@ class TestCost:
         a = cost.analyze_lowered(f.lower(jnp.zeros((32, 32))))
         assert a is not None and a["flops"] > 0
 
-    def test_record_and_mfu_math(self):
+    def test_record_and_mfu_math(self, monkeypatch):
         cost.reset()
         try:
             assert cost.estimate_mfu(ms_per_step=10.0) is None
@@ -491,8 +497,12 @@ class TestCost:
             # latest group supersedes, never accumulates
             cost.record_segment("g2", 0, {"flops": 5e8, "bytes": 1e6})
             assert cost.flops_per_step() == 5e8
+            # the CPU has no entry in the peaks table: no peak, no MFU
+            assert cost.estimate_mfu(ms_per_step=10.0) is None
+            monkeypatch.setattr(cost, "_device_kind",
+                                lambda: "TPU v5 lite")
             mfu = cost.estimate_mfu(ms_per_step=10.0)
-            assert mfu == pytest.approx(5e8 / 0.01 / cost.peak_flops())
+            assert mfu == pytest.approx(5e8 / 0.01 / 197e12)
         finally:
             cost.reset()
 
@@ -520,11 +530,12 @@ class TestCost:
         with pytest.raises(ValueError):
             r.counter("t_nan_total").inc(float("nan"))
 
-    def test_peak_flops_env_override(self, monkeypatch):
-        monkeypatch.setenv("PADDLE_TPU_PEAK_FLOPS", "1e12")
-        assert cost.peak_flops() == 1e12
-        monkeypatch.setenv("PADDLE_TPU_PEAK_FLOPS", "junk")
-        assert cost.peak_flops() == cost.DEFAULT_PEAK_FLOPS
+    def test_peak_flops_is_one_table_keyed_by_device_kind(self):
+        assert cost.peak_flops("TPU v5 lite") == 197e12
+        with pytest.raises(cost.UnknownDevicePeak, match="TPU v9"):
+            cost.peak_flops("TPU v9")
+        with pytest.raises(cost.UnknownDevicePeak):
+            cost.peak_flops()       # tests run on the CPU: no entry
 
 
 # ---------------------------------------------------------------------------
@@ -553,7 +564,7 @@ def _build_and_run(steps=3):
 
 
 class TestExecutorInstrumentation:
-    def test_run_moves_step_metrics_and_cost(self):
+    def test_run_moves_step_metrics_and_cost(self, monkeypatch):
         steps0 = REGISTRY.get("executor_steps_total").value()
         h = REGISTRY.get("executor_step_ms")
         hc0 = h.count()
@@ -567,6 +578,9 @@ class TestExecutorInstrumentation:
         assert cost.flops_per_step() > 0
         flops = REGISTRY.get("segment_flops")
         assert any(v > 0 for v in flops.samples().values())
+        # a device with no entry in the peaks table gets no MFU line
+        assert "MFU estimate" not in profiler.summary()
+        monkeypatch.setattr(cost, "_device_kind", lambda: "TPU v5 lite")
         assert profiler.summary().count("MFU estimate") == 1
 
     def test_startup_run_not_counted_as_step(self):
@@ -972,5 +986,6 @@ class TestTelemetryEndToEnd:
             rep = json.loads(
                 (tmp_path / f"mon.out.rank{rank}.json").read_text())
             assert rep["steps"] == self.TOTAL
-            assert "MFU estimate" in rep["summary"]
+            # CPU workers: no entry in the peaks table, so no MFU line
+            assert "MFU estimate" not in rep["summary"]
             assert rep["restart_count"] == 1

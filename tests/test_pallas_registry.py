@@ -46,7 +46,7 @@ class TestRegistry:
     def test_all_kernels_registered(self):
         names = plk.list_kernels()
         for want in ("fused_matmul", "fused_matmul_int8",
-                     "embedding_gather", "embedding_scatter_add",
+                     "embedding_scatter_add",
                      "fused_sgd", "fused_momentum", "fused_adam",
                      "flash_attention", "fused_layer_norm",
                      "softmax_cross_entropy"):
@@ -113,6 +113,34 @@ class TestRegistry:
             or plk.platform() == plk.platform.__wrapped__()
         info = plk.platform.cache_info()
         assert info.hits >= 1
+
+    def test_platform_probe_does_not_hide_a_dead_backend(self, monkeypatch):
+        def boom():
+            raise RuntimeError("no backend")
+        monkeypatch.setattr(jax, "devices", boom)
+        with pytest.raises(RuntimeError, match="no backend"):
+            plk.platform.__wrapped__()
+
+    def test_mesh_scope_takes_reference_on_a_multi_device_mesh(
+            self, monkeypatch):
+        """On a chip, `auto` picks the Pallas body — except inside a
+        mesh_scope of more than one device, where GSPMD would have to
+        partition a Mosaic call and the lowering refuses."""
+        from types import SimpleNamespace as Mesh
+        from paddle_tpu.ops.pallas import registry
+        monkeypatch.setattr(registry, "platform", lambda: "tpu")
+        assert plk.selected_body("fused_adam") == "pallas"
+        with plk.mesh_scope(Mesh(size=4)):
+            assert plk.selected_body("fused_adam") == "reference"
+            assert not plk.use_pallas("fused_layer_norm")
+            with plk.mesh_scope(None):          # e.g. a shard_map body
+                assert plk.selected_body("fused_adam") == "pallas"
+            with plk.override("on"):            # forced on stays forced
+                assert plk.selected_body("fused_adam") == "pallas"
+            assert plk.selected_body("fused_adam") == "reference"
+        with plk.mesh_scope(Mesh(size=1)):
+            assert plk.selected_body("fused_adam") == "pallas"
+        assert plk.selected_body("fused_adam") == "pallas"
 
 
 # ---------------------------------------------------------------------------
@@ -205,44 +233,9 @@ class TestFusedMatmul:
 
 
 # ---------------------------------------------------------------------------
-# embedding gather / scatter-add parity
+# embedding scatter-add parity
 # ---------------------------------------------------------------------------
 class TestEmbedding:
-    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-    @pytest.mark.parametrize("hd", [(11, 5), (64, 128), (130, 200)])
-    def test_gather_forward_backward(self, dtype, hd):
-        h, d = hd
-        tbl = _f((h, d), dtype)
-        ids = jnp.asarray(RNG.randint(0, h, 17), jnp.int32)
-
-        def loss(t):
-            out = plk.dispatch("embedding_gather", t, ids)
-            return jnp.sum(out.astype(jnp.float32) ** 2)
-
-        with plk.override("off"):
-            lr, gr = jax.value_and_grad(loss)(tbl)
-        with plk.override("on"):
-            lp, gp = jax.value_and_grad(loss)(tbl)
-        _close(lr, lp, dtype, rtol=1e-3)
-        _close(gr, gp, dtype)
-        assert gp.dtype == gr.dtype
-
-    def test_gather_zero_rows(self):
-        tbl = _f((8, 16))
-        with plk.override("on"):
-            out = plk.dispatch("embedding_gather", tbl,
-                               jnp.zeros((0,), jnp.int32))
-        assert out.shape == (0, 16)
-
-    def test_gather_2d_ids_and_oob_clip(self):
-        tbl = _f((10, 12))
-        ids = jnp.asarray([[0, 9], [15, 3]], jnp.int32)  # 15 clips to 9
-        ref = jnp.take(tbl, ids, axis=0)
-        with plk.override("on"):
-            pal = plk.dispatch("embedding_gather", tbl, ids)
-        _close(ref, pal)
-        assert pal.shape == (2, 2, 12)
-
     @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
     def test_scatter_add_duplicates_deterministic(self, dtype):
         dst = _f((33, 130), dtype)

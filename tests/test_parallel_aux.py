@@ -13,8 +13,7 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from paddle_tpu.parallel._compat import CHECK_DISABLED as _CHECK_KW
-from paddle_tpu.parallel._compat import shard_map
+from jax import shard_map
 
 from paddle_tpu.ops.nn import batch_norm, sync_batch_norm
 from paddle_tpu.parallel import dgc
@@ -47,7 +46,7 @@ class TestSyncBatchNorm:
             mesh=mesh4,
             in_specs=(P("data"), P(), P(), P(), P()),
             out_specs=(P("data"), P(), P(), P(), P()),
-            **_CHECK_KW)
+            check_vma=False)
         got = fn(jnp.asarray(x), jnp.asarray(scale), jnp.asarray(bias),
                  jnp.asarray(mean), jnp.asarray(var))
         for g, w in zip(got, want):
@@ -111,7 +110,7 @@ class TestDGC:
         fn = shard_map(inner, mesh=mesh4,
                        in_specs=(jax.tree.map(lambda _: P("data"), grads),),
                        out_specs=jax.tree.map(lambda _: P(), params),
-                       **_CHECK_KW)
+                       check_vma=False)
         out = fn(grads)
         assert out["w"].shape == (16,)
         # sparsity 0.999 with 16 elems → keep 1 per replica minimum;
@@ -134,7 +133,7 @@ class TestDGC:
 
         fn = shard_map(inner, mesh=mesh4,
                        in_specs=(P("data"),), out_specs=P(),
-                       **_CHECK_KW)
+                       check_vma=False)
         out = fn(grads["w"][:, None])
         want = grads["w"].mean(0)[None]
         np.testing.assert_allclose(np.asarray(out).reshape(-1),
@@ -217,7 +216,7 @@ class TestDygraphDataParallel:
         pspecs = jax.tree.map(lambda _: P(), params)
         g_dp = jax.jit(lambda p, xs: shard_map(
             local, mesh=mesh, in_specs=(pspecs, P(DATA_AXIS)),
-            out_specs=pspecs, **_CHECK_KW)(p, xs))(params, x)
+            out_specs=pspecs, check_vma=False)(p, xs))(params, x)
 
         def global_loss(p):
             out, _ = model.apply(p, state, jax.random.PRNGKey(0), x)

@@ -1,7 +1,7 @@
 """Unified mesh partitioner (parallel/spec.py + executor integration):
 one ShardingSpec from program-level annotations down to pjit
 in/out shardings and with_sharding_constraint on the compiled device
-segments — plus the _compat shard_map-fallback pin, the sharded-leaf
+segments — plus the sharded-leaf
 residency fast path, comm-bytes cost analytics, and checkpoint
 save(axes=) derivation. Runs on the 8-device virtual CPU mesh."""
 
@@ -18,9 +18,8 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 import paddle_tpu as pt
-from paddle_tpu.core.enforce import EnforceNotMet, warn_once
+from paddle_tpu.core.enforce import EnforceNotMet
 from paddle_tpu.framework import unique_name
-from paddle_tpu.parallel import _compat
 from paddle_tpu.parallel.mesh import (
     DATA_AXIS, MODEL_AXIS, PIPE_AXIS, MeshConfig, make_mesh,
 )
@@ -104,49 +103,6 @@ class TestSpecLookup:
         assert t is not None and t.spec == P(None, MODEL_AXIS)
         assert spec.constraint_for("unspecced") is None
         assert spec.constraint_for("unspecced@GRAD") is None
-
-
-# ---------------------------------------------------------------------------
-# _compat: the jax-0.4.37 pin (satellite: fallback must not be silent,
-# and the spec lowering must run through pjit, not shard_map)
-# ---------------------------------------------------------------------------
-class TestCompatPin:
-    def test_fallback_flag_matches_interpreter(self):
-        assert _compat.HAS_NATIVE_SHARD_MAP == hasattr(jax, "shard_map")
-
-    @pytest.mark.skipif(_compat.HAS_NATIVE_SHARD_MAP,
-                        reason="this jax has a native jax.shard_map")
-    def test_fallback_engagement_warns_once(self):
-        warn_once.reset_for_tests("shard_map_fallback")
-        mesh = _mesh(data=1, model=1)
-        with pytest.warns(UserWarning, match="jax.experimental.shard_map"):
-            _compat.shard_map(lambda x: x, mesh=mesh, in_specs=P(),
-                              out_specs=P())(jnp.ones((2,)))
-        # once per process: a second engagement stays quiet
-        import warnings
-        with warnings.catch_warnings(record=True) as rec:
-            warnings.simplefilter("always")
-            _compat.shard_map(lambda x: x, mesh=mesh, in_specs=P(),
-                              out_specs=P())(jnp.ones((2,)))
-        assert not [w for w in rec
-                    if "shard_map" in str(w.message)]
-
-    def test_spec_lowering_is_pjit_not_shard_map(self):
-        """The partitioner's lowering primitive is with_sharding_
-        constraint under plain jit (= pjit on this pin) — no shard_map
-        primitive anywhere in the jaxpr, on a 1x1 mesh."""
-        mesh = _mesh(data=1, model=1)
-        spec = ShardingSpec(mesh, params={"w": P(None, MODEL_AXIS)})
-
-        def f(w):
-            w = _compat.sharding_constraint(w, mesh,
-                                            spec.param_spec("w"))
-            return (w * 2).sum()
-
-        jaxpr = jax.make_jaxpr(f)(jnp.ones((2, 2)))
-        prims = {str(e.primitive) for e in jaxpr.jaxpr.eqns}
-        assert "sharding_constraint" in prims, prims
-        assert not any("shard_map" in p for p in prims), prims
 
 
 # ---------------------------------------------------------------------------
@@ -597,7 +553,8 @@ def test_multichip_shard_topology(topo, min_comm):
     by = {ln["metric"]: ln for ln in lines}
     row = by[f"shard_{topo}_step_ms"]
     assert row["value"] > 0 and row["unit"] == "ms"
-    assert row["mfu"] > 0
+    # the CPU has no peak on record (monitor/cost.PEAK_FLOPS): no MFU
+    assert row["mfu"] is None
     assert row["comm_bytes_per_step"] >= min_comm, row
     assert row["layout"]["n_devices"] == 8
     assert len(row["windows_ms_per_step"]) >= 2

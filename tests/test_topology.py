@@ -14,7 +14,7 @@ import pytest
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from paddle_tpu.parallel import collective as C
@@ -107,7 +107,7 @@ class TestBucketedAllReduce:
                 f, mesh=mesh,
                 in_specs=(jax.tree.map(lambda _: P(), tree),),
                 out_specs=jax.tree.map(lambda _: P(), tree),
-                check_rep=False)(t)
+                check_vma=False)(t)
 
         @jax.jit
         def per_leaf(t):
@@ -117,7 +117,7 @@ class TestBucketedAllReduce:
                 f, mesh=mesh,
                 in_specs=(jax.tree.map(lambda _: P(), tree),),
                 out_specs=jax.tree.map(lambda _: P(), tree),
-                check_rep=False)(t)
+                check_vma=False)(t)
 
         got = bucketed(tree)
         want = per_leaf(tree)
@@ -166,7 +166,7 @@ class TestBucketedAllReduce:
             return shard_map(
                 f, mesh=mesh,
                 in_specs=({"w": P()},), out_specs={"w": P()},
-                check_rep=False)(t)
+                check_vma=False)(t)
 
         out = run(tree)
         np.testing.assert_allclose(np.asarray(out["w"]), 4.0)
@@ -204,7 +204,7 @@ class TestFleetKnobs:
                 local, mesh=mesh,
                 in_specs=(specs, jax.tree.map(lambda _: P(), opt_state),
                           specs),
-                out_specs=specs, check_rep=False)(p, s, g))(
+                out_specs=specs, check_vma=False)(p, s, g))(
                     params, opt_state, grads)
         # avg over replicas of identical grads == plain sgd step
         np.testing.assert_allclose(np.asarray(new_p["w"]),
@@ -240,7 +240,7 @@ class TestFleetKnobs:
                 in_specs=({"w": P()}, jax.tree.map(lambda _: P(),
                                                    opt_state),
                           {"w": P()}),
-                out_specs={"w": P()}, check_rep=False)(p, s, g))(
+                out_specs={"w": P()}, check_vma=False)(p, s, g))(
                     params, opt_state, grads)
         np.testing.assert_allclose(np.asarray(new_p["w"]), 0.5)
 

@@ -1,0 +1,266 @@
+"""Build the program the chip runs — without a chip.
+
+The tests force the CPU, and on the CPU the Pallas registry's ``auto``
+mode selects the stock-jnp reference bodies. On a TPU the same ``auto``
+selects the Pallas bodies, so the program that runs there is one no CPU
+test executes. The sandbox's libtpu can still COMPILE for a TPU:
+``jax.experimental.topologies`` describes a ``v5e:2x2`` host, and
+lowering + compiling against its devices reproduces, in seconds, every
+refusal a chip run would meet at start-up —
+
+- "Mosaic kernels cannot be automatically partitioned" (a pallas_call
+  traced for a multi-device mesh under GSPMD),
+- a block that breaks Mosaic's (8, 128) tiling rule,
+- a kernel past the scoped-VMEM limit (RESOURCE_EXHAUSTED ... vmem).
+
+Nothing here runs on a device, so nothing here is a measurement.
+"""
+
+import functools
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+import paddle_tpu as pt
+from paddle_tpu.models import bert
+from paddle_tpu.ops import pallas as plk
+from paddle_tpu.ops.pallas import registry
+from paddle_tpu.parallel.data_parallel import DataParallelTrainer
+from paddle_tpu.parallel.mesh import MODEL_AXIS, MeshConfig, make_mesh
+
+try:
+    from jax.experimental import topologies
+    TOPOLOGY = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    _WHY_NOT = ""
+except Exception as e:  # noqa: BLE001 - any libtpu refusal is the reason
+    TOPOLOGY = None
+    _WHY_NOT = f"libtpu cannot describe a v5e:2x2 topology here: {e!r}"
+    print(_WHY_NOT)
+
+pytestmark = pytest.mark.skipif(TOPOLOGY is None, reason=_WHY_NOT)
+
+
+@pytest.fixture(autouse=True)
+def on_chip_selection(monkeypatch):
+    """What registry.platform() answers on the chip."""
+    monkeypatch.setattr(registry, "platform", lambda: "tpu")
+
+
+def _one_device():
+    return SingleDeviceSharding(TOPOLOGY.devices[0])
+
+
+def _abstract(shape, dtype=jnp.float32, sharding=None):
+    return jax.ShapeDtypeStruct(shape, dtype,
+                                sharding=sharding or _one_device())
+
+
+def _compile(fn, *args, **jit_kw):
+    return jax.jit(fn, **jit_kw).lower(*args).compile()
+
+
+def _mosaic_calls(compiled):
+    return compiled.as_text().count("tpu_custom_call")
+
+
+# ---------------------------------------------------------------------------
+# (a) every registered kernel's Pallas body, at one production shape
+# ---------------------------------------------------------------------------
+F32, BF16, I32, I8 = jnp.float32, jnp.bfloat16, jnp.int32, jnp.int8
+VOCAB, H, FFN = 30522, 768, 3072
+#: name -> (argument (shape, dtype)s, kwargs, differentiate?)
+KERNEL_SHAPES = {
+    "fused_matmul": ([((32768, H), F32), ((H, FFN), F32), ((FFN,), F32)],
+                     {"act": "gelu"}, True),
+    "fused_matmul_int8": ([((4096, H), F32), ((H, FFN), I8),
+                           ((FFN,), F32), ((FFN,), F32)],
+                          {"act": "relu"}, False),
+    # DeepFM's table; n_pad * dp at DEFAULT_VMEM_BUDGET's edge
+    "embedding_scatter_add": ([((100000, 16), F32), ((16384,), I32),
+                               ((16384, 16), F32)], {}, False),
+    "fused_sgd": ([((H, FFN), F32)] * 2 + [((), F32)], {}, False),
+    "fused_momentum": ([((2048, 1000), F32)] * 3 + [((), F32)], {}, False),
+    "fused_adam": ([((VOCAB, H), F32)] * 4 + [((), F32), ((), I32)], {},
+                   False),
+    "flash_attention": ([((8, 12, 2048, 64), BF16)] * 3, {}, True),
+    "fused_layer_norm": ([((64, 512, H), BF16), ((H,), F32), ((H,), F32)],
+                         {}, True),
+    "softmax_cross_entropy": ([((5120, VOCAB), F32), ((5120,), I32)], {},
+                              True),
+}
+
+
+def test_every_registered_kernel_has_a_shape_here():
+    assert sorted(KERNEL_SHAPES) == plk.list_kernels()
+
+
+@pytest.mark.parametrize("name", sorted(KERNEL_SHAPES))
+def test_pallas_body_compiles_for_the_chip(name):
+    shapes, kw, differentiate = KERNEL_SHAPES[name]
+    body = functools.partial(plk.get_body(name, "pallas"), interpret=False,
+                             **kw)
+    args = [_abstract(s, d) for s, d in shapes]
+    fn = body
+    if differentiate:
+        floats = tuple(i for i, (_, d) in enumerate(shapes)
+                       if jnp.issubdtype(d, jnp.floating))
+
+        def fn(*a):
+            return jax.value_and_grad(
+                lambda *b: jnp.sum(body(*b).astype(F32)), floats)(*a)
+    assert _mosaic_calls(_compile(fn, *args)) >= 1
+
+
+def test_scatter_add_budget_is_one_the_compiler_accepts():
+    """At DEFAULT_VMEM_BUDGET the body compiles (the case above); one
+    step past it the registry hands over to the reference and counts —
+    and the old budget of 4 Mi elements is a shape Mosaic refuses."""
+    from paddle_tpu.monitor.registry import REGISTRY
+    from paddle_tpu.ops.pallas import embedding
+    assert 16384 * 128 == plk.DEFAULT_VMEM_BUDGET
+    dst, n = _abstract((100000, 16)), 16384 + 128
+    c = _compile(embedding.embedding_scatter_add_pallas, dst,
+                 _abstract((n,), I32), _abstract((n, 16)))
+    assert _mosaic_calls(c) == 0
+    rejected = REGISTRY.get("pallas_vmem_budget_rejections_total")
+    assert rejected.value(kernel="embedding_scatter_add") >= 1
+    with pytest.raises(Exception, match="(?i)vmem"):
+        _compile(lambda *a: embedding._scatter_add(*a, False), dst,
+                 _abstract((32768,), I32), _abstract((32768, 16)))
+
+
+# ---------------------------------------------------------------------------
+# (b) the BERT train step, published width, small depth
+# ---------------------------------------------------------------------------
+def _bert_step(mesh_cfg, n_devices, batch_size, seq=512, max_preds=80,
+               **cfg_kw):
+    """(compiled step, mesh) through bert.make_train_step itself."""
+    mesh = make_mesh(mesh_cfg, devices=TOPOLOGY.devices[:n_devices])
+    cfg = bert.bert_base(vocab_size=VOCAB, max_seq=seq, remat=False,
+                         **cfg_kw)
+    opt = pt.optimizer.Adam(1e-4)
+    _, step_fn = bert.make_train_step(cfg, opt, mesh)
+    specs = bert.param_specs(cfg)
+    if mesh.shape[MODEL_AXIS] == 1:
+        specs = jax.tree.map(lambda _: P(), specs,
+                             is_leaf=lambda s: isinstance(s, P))
+    pshard = jax.tree.map(lambda s: NamedSharding(mesh, s), specs,
+                          is_leaf=lambda s: isinstance(s, P))
+    pshape = jax.eval_shape(functools.partial(bert.init_params, cfg=cfg),
+                            jax.random.PRNGKey(0))
+    oshape = jax.eval_shape(opt.init, pshape)
+
+    def place(tree, shardings):
+        return jax.tree.map(
+            lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+            tree, shardings)
+    batch = {
+        k: jax.ShapeDtypeStruct(
+            v.shape, v.dtype, sharding=NamedSharding(mesh, P("data", "seq")))
+        for k, v in bert.synthetic_batch(cfg, batch_size, seq,
+                                         max_preds=max_preds).items()}
+    compiled = step_fn.jitted.lower(
+        place(pshape, pshard),
+        place(oshape, opt.state_shardings(oshape, pshard, mesh)),
+        batch).compile()
+    return compiled, mesh
+
+
+def test_bert_step_one_device_runs_the_pallas_bodies():
+    compiled, _ = _bert_step(MeshConfig(data=1), 1, 64, num_layers=2)
+    # 2 layers: 6 layer norms forward + one Adam call per parameter leaf
+    assert _mosaic_calls(compiled) >= 6 + 2 * 12
+
+
+@pytest.mark.parametrize("mesh_cfg,batch", [
+    (MeshConfig(data=4), 256), (MeshConfig(data=2, model=2), 128)],
+    ids=["data4", "data2_model2"])
+def test_bert_step_lowers_and_compiles_on_four_chips(mesh_cfg, batch):
+    """GSPMD refuses to partition a Mosaic call; under a mesh of more
+    than one device `auto` takes the reference bodies, so the step
+    lowers, and the partitioner inserts the gradient all-reduces."""
+    compiled, mesh = _bert_step(mesh_cfg, 4, batch, num_layers=2)
+    assert mesh.size == 4
+    text = compiled.as_text()
+    assert _mosaic_calls(compiled) == 0
+    assert "all-reduce" in text
+    out_sh = jax.tree.leaves(compiled.output_shardings)
+    assert all(len(s.device_set) == 4 for s in out_sh)
+
+
+def test_forcing_pallas_on_under_a_mesh_fails_loudly():
+    with plk.override("on"):
+        with pytest.raises(NotImplementedError,
+                           match="cannot be automatically partitioned"):
+            _bert_step(MeshConfig(data=4), 4, 256, num_layers=1)
+
+
+def test_flash_backward_at_4096_fits_vmem_inside_the_step():
+    """The context in which the backward's dK/dV kernel passed Mosaic's
+    16 MiB default (16.4 MiB): the whole train step at S=4096, full-
+    sequence labels. The flash calls raise their limit explicitly."""
+    compiled, _ = _bert_step(MeshConfig(data=1), 1, 4, seq=4096,
+                             max_preds=None, num_layers=2,
+                             attention_impl="flash")
+    # per layer: flash forward, dK/dV and dQ
+    assert _mosaic_calls(compiled) >= 2 * 3
+
+
+def test_zero_trainer_keeps_pallas_inside_shard_map():
+    """DataParallelTrainer(param_sharding="zero") updates inside a
+    shard_map body: a Mosaic call per shard is legal there, so `auto`
+    keeps the Pallas Adam — and the replicated strategy, which GSPMD
+    partitions, does not."""
+    mesh = make_mesh(MeshConfig(data=4), devices=TOPOLOGY.devices)
+    d = 512
+
+    def loss_fn(params, state, rng, batch):
+        return jnp.mean((jnp.tanh(batch["x"] @ params["w"])
+                         - batch["y"]) ** 2), state
+
+    def abstract_args(trainer, spec):
+        w_sh = NamedSharding(mesh, spec)
+        rep = NamedSharding(mesh, P())
+        params = {"w": _abstract((d, d), sharding=w_sh)}
+        opt_state = {"step": _abstract((), I32, rep),
+                     "slots": {"w": {"moment1": params["w"],
+                                     "moment2": params["w"]}}}
+        data = NamedSharding(mesh, P("data"))
+        batch = {"x": _abstract((64, d), sharding=data),
+                 "y": _abstract((64, d), sharding=data)}
+        rng = _abstract((2,), jnp.uint32, rep)
+        return params, opt_state, {}, rng, batch
+
+    zero = DataParallelTrainer(loss_fn, pt.optimizer.Adam(1e-3), mesh=mesh,
+                               param_sharding="zero", donate=False)
+    zero._param_specs = {"w": P("data", None)}
+    c = zero._step.lower(*abstract_args(zero, P("data", None))).compile()
+    assert _mosaic_calls(c) == 1
+
+    plain = DataParallelTrainer(loss_fn, pt.optimizer.Adam(1e-3),
+                                mesh=mesh, donate=False)
+    c = plain._step.lower(*abstract_args(plain, P())).compile()
+    assert _mosaic_calls(c) == 0
+
+
+# ---------------------------------------------------------------------------
+# the bench.py cell at full depth: ~25 s of compile, and its memory
+# ---------------------------------------------------------------------------
+@pytest.mark.slow
+def test_bert_base_full_depth_bs64_fits_a_v5e():
+    compiled, _ = _bert_step(MeshConfig(data=1), 1, 64,
+                             softmax_dtype="bf16")
+    ma = compiled.memory_analysis()
+    need = (ma.argument_size_in_bytes + ma.temp_size_in_bytes
+            + ma.output_size_in_bytes - ma.alias_size_in_bytes)
+    print(f"BERT-base bs=64 s=512, compiler's account: arguments "
+          f"{ma.argument_size_in_bytes / 2**30:.2f} GiB, temp "
+          f"{ma.temp_size_in_bytes / 2**30:.2f} GiB, total "
+          f"{need / 2**30:.2f} GiB of a v5e's 16 GiB")
+    assert need < 16 * 2**30
+    assert np.isfinite(need)

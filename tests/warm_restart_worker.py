@@ -1,8 +1,9 @@
 """Worker for the executor warm-restart end-to-end test.
 
 Trains a small static-graph program under the elastic launcher. The
-launcher exports PADDLE_TPU_CACHE_DIR (default: <log_dir>/xla_cache),
-so ``import paddle_tpu`` enables the persistent compilation cache;
+workers run with JAX_COMPILATION_CACHE_DIR set (by the test, or by the
+launcher to the fixed in-checkout path), so jax keeps its persistent
+compilation cache there and ``import paddle_tpu`` starts the counters;
 ``Executor.prepare`` then AOT-compiles the step eagerly. The first
 incarnation populates the on-disk cache (misses), crashes via
 ``testing.faults``; the restarted incarnation compiles the identical
