@@ -16,9 +16,12 @@ a cache that moves never hits. Hence one rule for where it lives:
 ``import paddle_tpu`` calls :func:`enable` when the variable is set (the
 launcher exports it to its workers), so a restarted rank's compiles hit
 the previous incarnation's entries. :func:`enable` also zeroes jax's
-"only cache slow/large compiles" thresholds and registers the listener
-behind :func:`stats`; ``paddle_tpu.profiler`` prints those counters, so
-a warm start is verifiable (hits > 0).
+"only cache slow/large compiles" thresholds, makes the operations' names
+and source lines part of the key (so a cached executable never brings
+another program's names into a profile; the price is a recompile when
+only a line or the call path moved) and registers the listener behind :func:`stats`;
+``paddle_tpu.profiler`` prints those counters, so a warm start is
+verifiable (hits > 0).
 """
 
 import os
@@ -106,6 +109,17 @@ def enable():
     # the warm-restart tests (and fast iteration loops) rely on
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    # names are part of the key. jax strips debug info (op names, the
+    # named scopes among them, source lines) from a module before it
+    # hashes it, so a program that differs from a cached one only in its
+    # scopes is a hit, and the executable it gets carries the OLD names
+    # into every profile (tests/test_step_scopes.py shows it). What this
+    # costs: the same program compiles again once a line of a traced
+    # function moved, or when it is traced from another call site (the
+    # locations hold the call stack). A restarted process takes the same
+    # path through the same lines and still hits. What it buys: a
+    # profile whose names are the running code's.
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     _reset_jax_cache_state()
     _ensure_listener()
     with _lock:

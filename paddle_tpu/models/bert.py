@@ -29,6 +29,7 @@ from paddle_tpu.ops.pallas.registry import mesh_scope
 from paddle_tpu.parallel.mesh import (
     DATA_AXIS, MODEL_AXIS, SEQ_AXIS, get_mesh,
 )
+from paddle_tpu.profiler import RecordEvent
 
 __all__ = ["BertConfig", "bert_base", "init_params", "forward", "mlm_loss",
            "make_train_step", "param_specs"]
@@ -174,6 +175,10 @@ def param_specs(cfg):
 # ---------------------------------------------------------------------------
 # forward
 # ---------------------------------------------------------------------------
+# The named scopes (embed, attention, attention_core, ffn, layer_norm, loss;
+# optimizer is in optimizer.py) are how a profile of the step is read:
+# chipbench's per-layer metrics key on them. They cost nothing at run time.
+@jax.named_scope("layer_norm")
 def _layer_norm(x, g, b, mesh=None, eps=1e-12):
     # registry-selected body (ops/pallas/registry.py): the stock-jnp
     # reference is bit-identical to the historical inline math here, the
@@ -184,6 +189,7 @@ def _layer_norm(x, g, b, mesh=None, eps=1e-12):
         return _pk.fused_layer_norm(x, g, b, eps=eps)
 
 
+@jax.named_scope("attention")
 def _attention(lp, x, mask_bias, cfg, mesh=None, key_padding_mask=None):
     """MHA. "dense": GSPMD gathers K/V over "seq". "ring": blockwise
     ring attention via shard_map + ppermute (never materialises full
@@ -215,8 +221,9 @@ def _attention(lp, x, mask_bias, cfg, mesh=None, key_padding_mask=None):
         # qkv stay in cfg.dtype (bf16 MXU matmuls); ring_attention keeps
         # its softmax stats + output accumulator in fp32 internally.
         # key_padding_mask=None takes the maskless path (no mask permute).
-        ctx = _ra.ring_attention(mesh, bshd(q), bshd(k), bshd(v),
-                                 key_padding_mask=key_padding_mask)
+        with jax.named_scope("attention_core"):
+            ctx = _ra.ring_attention(mesh, bshd(q), bshd(k), bshd(v),
+                                     key_padding_mask=key_padding_mask)
         ctx = ctx.reshape(B, S, H).astype(x.dtype)
         return ctx @ lp["out_w"].astype(x.dtype) \
             + lp["out_b"].astype(x.dtype)
@@ -231,7 +238,7 @@ def _attention(lp, x, mask_bias, cfg, mesh=None, key_padding_mask=None):
             return t.reshape(B, S, nh, hd).transpose(0, 2, 1, 3)
 
         bias = mask_bias.reshape(B, S).astype(jnp.float32)
-        with mesh_scope(mesh):
+        with mesh_scope(mesh), jax.named_scope("attention_core"):
             ctx = _pk.flash_attention(heads(q), heads(k), heads(v),
                                       bias=bias)
         ctx = ctx.transpose(0, 2, 1, 3).reshape(B, S, H).astype(x.dtype)
@@ -246,16 +253,17 @@ def _attention(lp, x, mask_bias, cfg, mesh=None, key_padding_mask=None):
         return t.reshape(B, S, nh, hd)
 
     q, k, v = heads(q), heads(k), heads(v)
-    scores = jnp.einsum("bqnd,bknd->bnqk", q, k) / math.sqrt(hd)
-    if cfg.softmax_dtype == "bf16":
-        # skip the fp32 round-trip over [B,N,S,S] (see BertConfig)
-        scores = scores + mask_bias.astype(x.dtype)
-        probs = jax.nn.softmax(scores, axis=-1)
-    else:
-        scores = scores + mask_bias  # [B,1,1,S] additive
-        probs = jax.nn.softmax(scores.astype(jnp.float32),
-                               axis=-1).astype(x.dtype)
-    ctx = jnp.einsum("bnqk,bknd->bqnd", probs, v)
+    with jax.named_scope("attention_core"):
+        scores = jnp.einsum("bqnd,bknd->bnqk", q, k) / math.sqrt(hd)
+        if cfg.softmax_dtype == "bf16":
+            # skip the fp32 round-trip over [B,N,S,S] (see BertConfig)
+            scores = scores + mask_bias.astype(x.dtype)
+            probs = jax.nn.softmax(scores, axis=-1)
+        else:
+            scores = scores + mask_bias  # [B,1,1,S] additive
+            probs = jax.nn.softmax(scores.astype(jnp.float32),
+                                   axis=-1).astype(x.dtype)
+        ctx = jnp.einsum("bnqk,bknd->bqnd", probs, v)
     ctx = ctx.reshape(B, S, H)
     return ctx @ lp["out_w"].astype(x.dtype) + lp["out_b"].astype(x.dtype)
 
@@ -264,9 +272,10 @@ def _block(lp, x, mask_bias, cfg, mesh=None, key_padding_mask=None):
     a = _attention(lp, x, mask_bias, cfg, mesh=mesh,
                    key_padding_mask=key_padding_mask)
     x = _layer_norm(x + a, lp["ln1_g"], lp["ln1_b"], mesh)
-    hme = jax.nn.gelu(x @ lp["fc1_w"].astype(x.dtype)
-                      + lp["fc1_b"].astype(x.dtype), approximate=True)
-    m = hme @ lp["fc2_w"].astype(x.dtype) + lp["fc2_b"].astype(x.dtype)
+    with jax.named_scope("ffn"):
+        hme = jax.nn.gelu(x @ lp["fc1_w"].astype(x.dtype)
+                          + lp["fc1_b"].astype(x.dtype), approximate=True)
+        m = hme @ lp["fc2_w"].astype(x.dtype) + lp["fc2_b"].astype(x.dtype)
     return _layer_norm(x + m, lp["ln2_g"], lp["ln2_b"], mesh)
 
 
@@ -277,11 +286,12 @@ def forward(params, cfg, input_ids, token_type_ids=None, attention_mask=None,
     one the computation is unconstrained (single device / auto-sharded)."""
     B, S = input_ids.shape
     emb = params["embed"]
-    x = (jnp.take(emb["word"], input_ids, axis=0)
-         + emb["pos"][None, :S, :]
-         + (jnp.take(emb["type"], token_type_ids, axis=0)
-            if token_type_ids is not None else 0.0))
-    x = _layer_norm(x.astype(cfg.dtype), emb["ln_g"], emb["ln_b"], mesh)
+    with jax.named_scope("embed"):
+        x = (jnp.take(emb["word"], input_ids, axis=0)
+             + emb["pos"][None, :S, :]
+             + (jnp.take(emb["type"], token_type_ids, axis=0)
+                if token_type_ids is not None else 0.0))
+        x = _layer_norm(x.astype(cfg.dtype), emb["ln_g"], emb["ln_b"], mesh)
     x = _shard_act(x, mesh)
     if attention_mask is None:
         mask_bias = jnp.zeros((B, 1, 1, S), cfg.dtype)
@@ -330,31 +340,32 @@ def mlm_loss(params, cfg, batch, mesh=None):
     hidden = forward(params, cfg, batch["input_ids"],
                      batch.get("token_type_ids"),
                      batch.get("attention_mask"), mesh=mesh)
-    if "masked_positions" in batch:
-        pos = batch["masked_positions"]
-        hidden = jnp.take_along_axis(
-            hidden, pos[..., None].astype(jnp.int32), axis=1)  # [B,P,H]
-        lab = batch["masked_labels"]
-        w = batch["masked_weights"]
-    else:
-        lab = batch["labels"]
-        w = batch["weights"]
-    m = params["mlm"]
-    h = hidden @ m["dense_w"].astype(hidden.dtype) \
-        + m["dense_b"].astype(hidden.dtype)
-    h = jax.nn.gelu(h, approximate=True)
-    h = _layer_norm(h, m["ln_g"], m["ln_b"], mesh)
-    # tied output embedding (fp32 logits for a stable softmax; measured
-    # faster than bf16-in/f32-accum dot_general on this chip — XLA's
-    # fp32 path wins for this [BS,768]x[768,30522] shape)
-    logits = (h.astype(jnp.float32)
-              @ params["embed"]["word"].T.astype(jnp.float32)
-              + m["bias"])
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    picked = jnp.take_along_axis(logp, lab[..., None], axis=-1)[..., 0]
-    w = w.astype(jnp.float32)
-    denom = jnp.maximum(jnp.sum(w), 1.0)
-    return -jnp.sum(picked * w) / denom
+    with jax.named_scope("loss"):
+        if "masked_positions" in batch:
+            pos = batch["masked_positions"]
+            hidden = jnp.take_along_axis(                    # [B,P,H]
+                hidden, pos[..., None].astype(jnp.int32), axis=1)
+            lab = batch["masked_labels"]
+            w = batch["masked_weights"]
+        else:
+            lab = batch["labels"]
+            w = batch["weights"]
+        m = params["mlm"]
+        h = hidden @ m["dense_w"].astype(hidden.dtype) \
+            + m["dense_b"].astype(hidden.dtype)
+        h = jax.nn.gelu(h, approximate=True)
+        h = _layer_norm(h, m["ln_g"], m["ln_b"], mesh)
+        # tied output embedding (fp32 logits for a stable softmax; measured
+        # faster than bf16-in/f32-accum dot_general on this chip — XLA's
+        # fp32 path wins for this [BS,768]x[768,30522] shape)
+        logits = (h.astype(jnp.float32)
+                  @ params["embed"]["word"].T.astype(jnp.float32)
+                  + m["bias"])
+        logp = jax.nn.log_softmax(logits, axis=-1)
+        picked = jnp.take_along_axis(logp, lab[..., None], axis=-1)[..., 0]
+        w = w.astype(jnp.float32)
+        denom = jnp.maximum(jnp.sum(w), 1.0)
+        return -jnp.sum(picked * w) / denom
 
 
 # ---------------------------------------------------------------------------
@@ -438,10 +449,14 @@ def make_train_step(cfg, optimizer, mesh=None, steps_per_call=1):
                 for name, v in batch.items()}, stacked
 
     def step_fn(params, opt_state, batch):
-        batch, stacked = place(batch)
-        if steps_per_call == 1:
-            return jit_step(params, opt_state, batch)
-        return jit_step(params, opt_state, batch, stacked)
+        # host spans (they land on a profile's host plane): the two halves
+        # of what the caller waits for before the step is in flight
+        with RecordEvent("trainer/place"):
+            batch, stacked = place(batch)
+        with RecordEvent("trainer/enqueue"):
+            if steps_per_call == 1:
+                return jit_step(params, opt_state, batch)
+            return jit_step(params, opt_state, batch, stacked)
 
     # for inspection (chip_smoke.py's shard check) and ahead-of-time
     # lowering against a topology (tests/test_tpu_aot_compile.py): the

@@ -32,6 +32,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from paddle_tpu.ops.pallas.registry import mesh_scope
 from paddle_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS, get_mesh
+from paddle_tpu.profiler import RecordEvent
 
 __all__ = ["TransformerConfig", "transformer_base", "transformer_big",
            "transformer_tiny", "init_params", "forward", "nmt_loss",
@@ -158,6 +159,9 @@ def param_specs(cfg):
 # ---------------------------------------------------------------------------
 # forward
 # ---------------------------------------------------------------------------
+# The named scopes are the vocabulary of models/bert.py (embed, attention,
+# attention_core, ffn, layer_norm, loss): how a profile of the step is read.
+@jax.named_scope("layer_norm")
 def _layer_norm(x, ln, eps=1e-6):
     x32 = x.astype(jnp.float32)
     mu = jnp.mean(x32, axis=-1, keepdims=True)
@@ -179,6 +183,7 @@ def _heads(t, nh, hd):
     return t.reshape(B, S, nh, hd).transpose(0, 2, 1, 3)
 
 
+@jax.named_scope("attention")
 def _mha(ap, q_in, kv_in, bias, cfg, kv=None):
     """bias: additive [B,1,q,k] fp32-safe. kv: optional precomputed (k, v)
     (cached cross-attention / incremental decode)."""
@@ -192,29 +197,39 @@ def _mha(ap, q_in, kv_in, bias, cfg, kv=None):
                    nh, hd)
     else:
         k, v = kv
-    scores = jnp.einsum("bnqd,bnkd->bnqk", q, k) / math.sqrt(hd)
-    scores = scores.astype(jnp.float32) + bias
-    probs = jax.nn.softmax(scores, axis=-1).astype(dt)
-    ctx = jnp.einsum("bnqk,bnkd->bnqd", probs, v)
+    with jax.named_scope("attention_core"):
+        scores = jnp.einsum("bnqd,bnkd->bnqk", q, k) / math.sqrt(hd)
+        scores = scores.astype(jnp.float32) + bias
+        probs = jax.nn.softmax(scores, axis=-1).astype(dt)
+        ctx = jnp.einsum("bnqk,bnkd->bnqd", probs, v)
     B, _, S, _ = ctx.shape
     ctx = ctx.transpose(0, 2, 1, 3).reshape(B, S, nh * hd)
     return ctx @ ap["o_w"].astype(dt) + ap["o_b"].astype(dt), (k, v)
 
 
+@jax.named_scope("ffn")
+def _ffn(fp, x):
+    dt = x.dtype
+    f = jax.nn.relu(x @ fp["w1"].astype(dt) + fp["b1"].astype(dt))
+    return f @ fp["w2"].astype(dt) + fp["b2"].astype(dt)
+
+
+@jax.named_scope("embed")
+def _embed(table, ids, cfg):
+    """Token embedding scaled by sqrt(hidden), plus the sinusoid positions."""
+    x = jnp.take(table, ids, axis=0) * math.sqrt(cfg.hidden)
+    pos = _sinusoid(cfg.max_seq, cfg.hidden)[None, :ids.shape[1]]
+    return (x + pos).astype(cfg.dtype)
+
+
 def _enc_layer(lp, x, bias, cfg):
     a, _ = _mha(lp["attn"], x, x, bias, cfg)
     x = _layer_norm(x + a, lp["ln1"])
-    dt = x.dtype
-    f = jax.nn.relu(x @ lp["ffn"]["w1"].astype(dt)
-                    + lp["ffn"]["b1"].astype(dt))
-    f = f @ lp["ffn"]["w2"].astype(dt) + lp["ffn"]["b2"].astype(dt)
-    return _layer_norm(x + f, lp["ln2"])
+    return _layer_norm(x + _ffn(lp["ffn"], x), lp["ln2"])
 
 
 def encode(params, cfg, src_ids, src_mask):
-    B, S = src_ids.shape
-    x = jnp.take(params["src_embed"], src_ids, axis=0) * math.sqrt(cfg.hidden)
-    x = (x + _sinusoid(cfg.max_seq, cfg.hidden)[None, :S]).astype(cfg.dtype)
+    x = _embed(params["src_embed"], src_ids, cfg)
     bias = jnp.where(src_mask[:, None, None, :] > 0, 0.0, -1e9)
     layer = _enc_layer
     if cfg.remat:
@@ -245,18 +260,13 @@ def _dec_layer(lp, x, self_bias, memory, mem_bias, cfg, cache=None, pos=None,
     x = _layer_norm(x + a, lp["ln1"])
     c, _ = _mha(lp["cross_attn"], x, memory, mem_bias, cfg, kv=cross_kv)
     x = _layer_norm(x + c, lp["ln2"])
-    dt = x.dtype
-    f = jax.nn.relu(x @ lp["ffn"]["w1"].astype(dt)
-                    + lp["ffn"]["b1"].astype(dt))
-    f = f @ lp["ffn"]["w2"].astype(dt) + lp["ffn"]["b2"].astype(dt)
-    return _layer_norm(x + f, lp["ln3"]), new_self
+    return _layer_norm(x + _ffn(lp["ffn"], x), lp["ln3"]), new_self
 
 
 def decode_train(params, cfg, tgt_ids, memory, src_mask, tgt_mask):
     """Teacher-forced decoder over the whole target (causal mask)."""
-    B, T = tgt_ids.shape
-    x = jnp.take(params["tgt_embed"], tgt_ids, axis=0) * math.sqrt(cfg.hidden)
-    x = (x + _sinusoid(cfg.max_seq, cfg.hidden)[None, :T]).astype(cfg.dtype)
+    T = tgt_ids.shape[1]
+    x = _embed(params["tgt_embed"], tgt_ids, cfg)
     causal = jnp.tril(jnp.ones((T, T), jnp.float32))
     self_bias = jnp.where(
         (causal[None, None] * tgt_mask[:, None, None, :]) > 0, 0.0, -1e9)
@@ -265,7 +275,8 @@ def decode_train(params, cfg, tgt_ids, memory, src_mask, tgt_mask):
         x, _ = _dec_layer(lp, x, self_bias, memory, mem_bias, cfg)
     x = _layer_norm(x, params["dec_ln"])
     # tied output projection, fp32 logits
-    return x.astype(jnp.float32) @ params["tgt_embed"].T
+    with jax.named_scope("loss"):
+        return x.astype(jnp.float32) @ params["tgt_embed"].T
 
 
 def forward(params, cfg, src_ids, tgt_ids, src_mask=None, tgt_mask=None):
@@ -286,15 +297,16 @@ def nmt_loss(params, cfg, batch):
     """
     logits = forward(params, cfg, batch["src_ids"], batch["tgt_in"],
                      batch.get("src_mask"), batch.get("tgt_mask"))
-    logp = jax.nn.log_softmax(logits, axis=-1)
     eps, n = cfg.label_smoothing, cfg.tgt_vocab
-    picked = jnp.take_along_axis(
-        logp, batch["tgt_out"][..., None].astype(jnp.int32),
-        axis=-1)[..., 0]
-    ll = (1.0 - eps) * picked + (eps / n) * jnp.sum(logp, axis=-1)
-    w = batch["tgt_mask"].astype(jnp.float32) \
-        if "tgt_mask" in batch else jnp.ones_like(ll)
-    return -jnp.sum(ll * w) / jnp.maximum(jnp.sum(w), 1.0)
+    with jax.named_scope("loss"):
+        logp = jax.nn.log_softmax(logits, axis=-1)
+        picked = jnp.take_along_axis(
+            logp, batch["tgt_out"][..., None].astype(jnp.int32),
+            axis=-1)[..., 0]
+        ll = (1.0 - eps) * picked + (eps / n) * jnp.sum(logp, axis=-1)
+        w = batch["tgt_mask"].astype(jnp.float32) \
+            if "tgt_mask" in batch else jnp.ones_like(ll)
+        return -jnp.sum(ll * w) / jnp.maximum(jnp.sum(w), 1.0)
 
 
 # ---------------------------------------------------------------------------
@@ -328,16 +340,25 @@ def make_train_step(cfg, optimizer, mesh=None):
 
     jit_step = jax.jit(step, donate_argnums=(0, 1))
 
-    def step_fn(params, opt_state, batch):
+    def place(batch):
+        """Put a host batch on the mesh, rows over "data"."""
         # device-resident feeds pass through (np.asarray on a jax array
         # would round-trip it to host); device_put no-ops on committed
         # arrays with matching sharding
-        batch = {k: jax.device_put(
-                     v if isinstance(v, jnp.ndarray) else np.asarray(v),
-                     dsh)
-                 for k, v in batch.items()}
-        return jit_step(params, opt_state, batch)
+        return {k: jax.device_put(
+                    v if isinstance(v, jnp.ndarray) else np.asarray(v), dsh)
+                for k, v in batch.items()}
 
+    def step_fn(params, opt_state, batch):
+        # host spans, as bert.make_train_step's
+        with RecordEvent("trainer/place"):
+            batch = place(batch)
+        with RecordEvent("trainer/enqueue"):
+            return jit_step(params, opt_state, batch)
+
+    # for inspection and ahead-of-time lowering, as bert.make_train_step
+    step_fn.place = place
+    step_fn.jitted = jit_step
     return init_fn, step_fn
 
 

@@ -74,6 +74,7 @@ def _scatter_call(dst, ids, updates, interpret):
         out_specs=pl.BlockSpec((bh, dp), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((hp, dp), dst.dtype),
         interpret=interpret,
+        name="embedding_scatter_add",
     )(dst, ids32.reshape(1, -1), updates)
     if hp != h or dp != d:
         out = out[:h, :d]

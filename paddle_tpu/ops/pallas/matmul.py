@@ -133,6 +133,7 @@ def _fmm_call(x2, w, scale, bias, act, interpret):
         out_specs=_vmem_spec((bm, bn), lambda im, in_, ik: (im, in_)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), jnp.float32),
         interpret=interpret,
+        name="fused_matmul" if scale is None else "fused_matmul_int8",
     )(*args)
     if mp != m or np_ != n:
         out = out[:m, :n]

@@ -35,10 +35,11 @@ def _round_up(v, m):
     return -(-v // m) * m
 
 
-def _ew_call(kernel, arrays, scalars, n_out, interpret):
+def _ew_call(name, kernel, arrays, scalars, n_out, interpret):
     """Run an elementwise kernel over same-size tensors: flatten to
     [rows, 128] f32 blocks, ride the scalars in as one (1, ns) block,
-    return n_out f32 arrays of the original flat size."""
+    return n_out f32 arrays of the original flat size. ``name`` is the
+    kernel's name in the compiled program and in a profile."""
     size = arrays[0].size
     rows = -(-size // _LANES)
     br = min(256, _round_up(rows, 8))
@@ -61,6 +62,7 @@ def _ew_call(kernel, arrays, scalars, n_out, interpret):
         out_shape=[jax.ShapeDtypeStruct((rows_p, _LANES), jnp.float32)]
         * n_out,
         interpret=interpret,
+        name=name,
     )(*padded, sc)
     if n_out == 1:
         outs = [outs] if not isinstance(outs, (list, tuple)) else outs
@@ -79,7 +81,8 @@ def _sgd_kernel(p_ref, g_ref, sc_ref, o_ref):
 
 def fused_sgd_pallas(p, g, lr, interpret=False):
     shape = jnp.shape(p)
-    (out,) = _ew_call(_sgd_kernel, [p, g], [lr], 1, bool(interpret))
+    (out,) = _ew_call("fused_sgd", _sgd_kernel, [p, g], [lr], 1,
+                      bool(interpret))
     return out.reshape(shape)
 
 
@@ -112,7 +115,8 @@ def fused_momentum_pallas(p, g, v, lr, momentum=0.9, use_nesterov=False,
     shape = jnp.shape(p)
     kernel = functools.partial(_momentum_kernel, momentum=float(momentum),
                                nesterov=bool(use_nesterov))
-    new_p, new_v = _ew_call(kernel, [p, g, v], [lr], 2, bool(interpret))
+    new_p, new_v = _ew_call("fused_momentum", kernel, [p, g, v], [lr], 2,
+                             bool(interpret))
     return new_p.reshape(shape), new_v.reshape(shape)
 
 
@@ -147,8 +151,8 @@ def fused_adam_pallas(p, g, m1, m2, lr, t, beta1=0.9, beta2=0.999,
     bc = jnp.sqrt(1 - beta2 ** t32) / (1 - beta1 ** t32)
     kernel = functools.partial(_adam_kernel, beta1=float(beta1),
                                beta2=float(beta2), epsilon=float(epsilon))
-    new_p, m1n, m2n = _ew_call(kernel, [p, g, m1, m2], [lr * bc], 3,
-                               bool(interpret))
+    new_p, m1n, m2n = _ew_call("fused_adam", kernel, [p, g, m1, m2],
+                               [lr * bc], 3, bool(interpret))
     return new_p.reshape(shape), m1n.reshape(shape), m2n.reshape(shape)
 
 

@@ -153,6 +153,7 @@ def _flash_fwd(q, k, v, bias, sm_scale, causal, block_q, block_k,
         ],
         compiler_params=_FLASH_COMPILER_PARAMS,
         interpret=interpret,
+        name="flash_fwd",
     )(q, k, v, bias[:, None, :])
     return o, lse4[..., 0]
 
@@ -302,6 +303,7 @@ def _flash_attention_bwd(sm_scale, causal, block_q, block_k, interpret,
         ],
         compiler_params=_FLASH_COMPILER_PARAMS,
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(q, do, lse4, delta, k, v, bias3)
     kernel_q = functools.partial(
         _flash_bwd_dq_kernel, sm_scale=sm_scale, block_q=block_q,
@@ -329,6 +331,7 @@ def _flash_attention_bwd(sm_scale, causal, block_q, block_k, interpret,
         out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype)],
         compiler_params=_FLASH_COMPILER_PARAMS,
         interpret=interpret,
+        name="flash_bwd_dq",
     )(q, do, lse4, delta, k, v, bias3)[0]
     dbias = jnp.sum(dbh[..., 0], axis=1)                   # [B,S]
     return dq, dk, dv, dbias.astype(bias.dtype)
@@ -473,6 +476,7 @@ def _ln_fwd(x2, g, b, eps, block_n, interpret):
             jax.ShapeDtypeStruct((n, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="layer_norm_fwd",
     )(x2, g, b)
     return y, mu[:, 0], rstd[:, 0]
 
@@ -592,6 +596,7 @@ def _xent_fwd_call(logits2, labels1, block_n, interpret):
             jax.ShapeDtypeStruct((n, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="softmax_xent_fwd",
     )(logits2, labels1[:, None])
     return loss[:, 0], lse[:, 0]
 

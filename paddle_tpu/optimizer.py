@@ -93,6 +93,7 @@ class Optimizer:
              for sh, sd in zip(flat_sh, flat_slots)])
         return {"step": rep, "slots": slots_sh}
 
+    @jax.named_scope("optimizer")   # the update's name in a profile
     def apply_gradients(self, params, grads, state, param_meta=None):
         """Returns (new_params, new_state). params/grads are matching
         pytrees; slots is a tree-of-dicts aligned with params."""

@@ -114,13 +114,23 @@ class RecordEvent:
     profiler was never started. ``args`` rides into the recorded event
     (and the Chrome export); the executor passes ``{"flow": id}`` so
     ``export_chrome_trace`` can pair each dispatch with the fetch that
-    materialized it BY ID instead of FIFO order."""
+    materialized it BY ID instead of FIFO order.
+
+    It is also a ``jax.profiler.TraceAnnotation``: while a jax profile
+    is being taken (``start_profiler(trace_dir=...)``, or anybody's
+    ``jax.profiler.start_trace``) the span lands on that trace's
+    ``/host:CPU`` plane, on the clock the device's operations are on,
+    so a gap on the device can be put down to the span open on the host.
+    With no profile running the annotation costs about a microsecond
+    (PERF.md, PR 23)."""
 
     def __init__(self, name, args=None):
         self.name = name
         self.args = args
 
     def __enter__(self):
+        self._annotation = jax.profiler.TraceAnnotation(self.name)
+        self._annotation.__enter__()
         self.t0 = time.perf_counter()
         if _flight._enabled:
             _flight.RECORDER.span_push(self.name)
@@ -128,6 +138,7 @@ class RecordEvent:
 
     def __exit__(self, *exc):
         dur = time.perf_counter() - self.t0
+        self._annotation.__exit__(*exc)
         if _active["on"]:
             _events.append((self.name, self.t0, dur,
                             threading.get_ident(), self.args))

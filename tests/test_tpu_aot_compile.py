@@ -17,6 +17,7 @@ Nothing here runs on a device, so nothing here is a measurement.
 """
 
 import functools
+import re
 
 import numpy as np
 import pytest
@@ -32,17 +33,24 @@ from paddle_tpu.ops.pallas import registry
 from paddle_tpu.parallel.data_parallel import DataParallelTrainer
 from paddle_tpu.parallel.mesh import MODEL_AXIS, MeshConfig, make_mesh
 
-try:
-    from jax.experimental import topologies
-    TOPOLOGY = topologies.get_topology_desc(platform="tpu",
-                                            topology_name="v5e:2x2")
-    _WHY_NOT = ""
-except Exception as e:  # noqa: BLE001 - any libtpu refusal is the reason
-    TOPOLOGY = None
-    _WHY_NOT = f"libtpu cannot describe a v5e:2x2 topology here: {e!r}"
-    print(_WHY_NOT)
 
-pytestmark = pytest.mark.skipif(TOPOLOGY is None, reason=_WHY_NOT)
+@pytest.fixture(scope="module")
+def topo():
+    """The described v5e:2x2 host. Described here, inside a fixture, and not
+    while the module is imported: only one process may load libtpu, every
+    xdist worker imports every test file, and only the worker that runs this
+    file may take the library."""
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any libtpu refusal is the reason
+        pytest.skip(f"libtpu cannot describe a v5e:2x2 topology here: {e!r}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
 
 
 @pytest.fixture(autouse=True)
@@ -51,13 +59,8 @@ def on_chip_selection(monkeypatch):
     monkeypatch.setattr(registry, "platform", lambda: "tpu")
 
 
-def _one_device():
-    return SingleDeviceSharding(TOPOLOGY.devices[0])
-
-
-def _abstract(shape, dtype=jnp.float32, sharding=None):
-    return jax.ShapeDtypeStruct(shape, dtype,
-                                sharding=sharding or _one_device())
+def _abstract(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
 def _compile(fn, *args, **jit_kw):
@@ -100,11 +103,11 @@ def test_every_registered_kernel_has_a_shape_here():
 
 
 @pytest.mark.parametrize("name", sorted(KERNEL_SHAPES))
-def test_pallas_body_compiles_for_the_chip(name):
+def test_pallas_body_compiles_for_the_chip(name, one_chip):
     shapes, kw, differentiate = KERNEL_SHAPES[name]
     body = functools.partial(plk.get_body(name, "pallas"), interpret=False,
                              **kw)
-    args = [_abstract(s, d) for s, d in shapes]
+    args = [_abstract(s, d, one_chip) for s, d in shapes]
     fn = body
     if differentiate:
         floats = tuple(i for i, (_, d) in enumerate(shapes)
@@ -116,31 +119,34 @@ def test_pallas_body_compiles_for_the_chip(name):
     assert _mosaic_calls(_compile(fn, *args)) >= 1
 
 
-def test_scatter_add_budget_is_one_the_compiler_accepts():
+def test_scatter_add_budget_is_one_the_compiler_accepts(one_chip):
     """At DEFAULT_VMEM_BUDGET the body compiles (the case above); one
     step past it the registry hands over to the reference and counts —
     and the old budget of 4 Mi elements is a shape Mosaic refuses."""
     from paddle_tpu.monitor.registry import REGISTRY
     from paddle_tpu.ops.pallas import embedding
     assert 16384 * 128 == plk.DEFAULT_VMEM_BUDGET
-    dst, n = _abstract((100000, 16)), 16384 + 128
+    def abstract(shape, dtype=F32):
+        return _abstract(shape, dtype, one_chip)
+
+    dst, n = abstract((100000, 16)), 16384 + 128
     c = _compile(embedding.embedding_scatter_add_pallas, dst,
-                 _abstract((n,), I32), _abstract((n, 16)))
+                 abstract((n,), I32), abstract((n, 16)))
     assert _mosaic_calls(c) == 0
     rejected = REGISTRY.get("pallas_vmem_budget_rejections_total")
     assert rejected.value(kernel="embedding_scatter_add") >= 1
     with pytest.raises(Exception, match="(?i)vmem"):
         _compile(lambda *a: embedding._scatter_add(*a, False), dst,
-                 _abstract((32768,), I32), _abstract((32768, 16)))
+                 abstract((32768,), I32), abstract((32768, 16)))
 
 
 # ---------------------------------------------------------------------------
 # (b) the BERT train step, published width, small depth
 # ---------------------------------------------------------------------------
-def _bert_step(mesh_cfg, n_devices, batch_size, seq=512, max_preds=80,
+def _bert_step(topo, mesh_cfg, n_devices, batch_size, seq=512, max_preds=80,
                **cfg_kw):
     """(compiled step, mesh) through bert.make_train_step itself."""
-    mesh = make_mesh(mesh_cfg, devices=TOPOLOGY.devices[:n_devices])
+    mesh = make_mesh(mesh_cfg, devices=topo.devices[:n_devices])
     cfg = bert.bert_base(vocab_size=VOCAB, max_seq=seq, remat=False,
                          **cfg_kw)
     opt = pt.optimizer.Adam(1e-4)
@@ -171,8 +177,8 @@ def _bert_step(mesh_cfg, n_devices, batch_size, seq=512, max_preds=80,
     return compiled, mesh
 
 
-def test_bert_step_one_device_runs_the_pallas_bodies():
-    compiled, _ = _bert_step(MeshConfig(data=1), 1, 64, num_layers=2)
+def test_bert_step_one_device_runs_the_pallas_bodies(topo):
+    compiled, _ = _bert_step(topo, MeshConfig(data=1), 1, 64, num_layers=2)
     # 2 layers: 6 layer norms forward + one Adam call per parameter leaf
     assert _mosaic_calls(compiled) >= 6 + 2 * 12
 
@@ -180,11 +186,12 @@ def test_bert_step_one_device_runs_the_pallas_bodies():
 @pytest.mark.parametrize("mesh_cfg,batch", [
     (MeshConfig(data=4), 256), (MeshConfig(data=2, model=2), 128)],
     ids=["data4", "data2_model2"])
-def test_bert_step_lowers_and_compiles_on_four_chips(mesh_cfg, batch):
+def test_bert_step_lowers_and_compiles_on_four_chips(topo, mesh_cfg,
+                                                      batch):
     """GSPMD refuses to partition a Mosaic call; under a mesh of more
     than one device `auto` takes the reference bodies, so the step
     lowers, and the partitioner inserts the gradient all-reduces."""
-    compiled, mesh = _bert_step(mesh_cfg, 4, batch, num_layers=2)
+    compiled, mesh = _bert_step(topo, mesh_cfg, 4, batch, num_layers=2)
     assert mesh.size == 4
     text = compiled.as_text()
     assert _mosaic_calls(compiled) == 0
@@ -193,30 +200,53 @@ def test_bert_step_lowers_and_compiles_on_four_chips(mesh_cfg, batch):
     assert all(len(s.device_set) == 4 for s in out_sh)
 
 
-def test_forcing_pallas_on_under_a_mesh_fails_loudly():
+def test_the_step_names_its_mosaic_calls(topo):
+    """Every ``pallas_call`` has a ``name=``: it is the stem of the compiled
+    instruction, which a profile shows and chipbench's breakdown prints, and
+    the named scope around the call is on its ``op_name``. At S=4096 the
+    step holds all five kernels of the BERT cells."""
+    compiled, _ = _bert_step(topo, MeshConfig(data=1), 1, 4, seq=4096,
+                             max_preds=640, num_layers=1)
+    calls = [line for line in compiled.as_text().splitlines()
+             if "tpu_custom_call" in line]
+    stems = {re.sub(r"\.\d+$", "",
+                    line.split(" = ")[0].split("%")[-1]) for line in calls}
+    assert stems == {"flash_fwd", "flash_bwd_dkv", "flash_bwd_dq",
+                     "layer_norm_fwd", "fused_adam"}
+    op_names = set(re.findall(r'op_name="([^"]*)"', "\n".join(calls)))
+    assert ("jit(step)/jvp(attention)/attention_core/flash_fwd/pallas_call"
+            in op_names)
+    assert ("jit(step)/transpose(jvp(attention))/attention_core/"
+            "flash_bwd_dkv/pallas_call" in op_names)
+    assert "jit(step)/optimizer/fused_adam/pallas_call" in op_names
+    assert any(n.endswith("layer_norm/layer_norm_fwd/pallas_call")
+               for n in op_names)
+
+
+def test_forcing_pallas_on_under_a_mesh_fails_loudly(topo):
     with plk.override("on"):
         with pytest.raises(NotImplementedError,
                            match="cannot be automatically partitioned"):
-            _bert_step(MeshConfig(data=4), 4, 256, num_layers=1)
+            _bert_step(topo, MeshConfig(data=4), 4, 256, num_layers=1)
 
 
-def test_flash_backward_at_4096_fits_vmem_inside_the_step():
+def test_flash_backward_at_4096_fits_vmem_inside_the_step(topo):
     """The context in which the backward's dK/dV kernel passed Mosaic's
     16 MiB default (16.4 MiB): the whole train step at S=4096, full-
     sequence labels. The flash calls raise their limit explicitly."""
-    compiled, _ = _bert_step(MeshConfig(data=1), 1, 4, seq=4096,
+    compiled, _ = _bert_step(topo, MeshConfig(data=1), 1, 4, seq=4096,
                              max_preds=None, num_layers=2,
                              attention_impl="flash")
     # per layer: flash forward, dK/dV and dQ
     assert _mosaic_calls(compiled) >= 2 * 3
 
 
-def test_zero_trainer_keeps_pallas_inside_shard_map():
+def test_zero_trainer_keeps_pallas_inside_shard_map(topo):
     """DataParallelTrainer(param_sharding="zero") updates inside a
     shard_map body: a Mosaic call per shard is legal there, so `auto`
     keeps the Pallas Adam — and the replicated strategy, which GSPMD
     partitions, does not."""
-    mesh = make_mesh(MeshConfig(data=4), devices=TOPOLOGY.devices)
+    mesh = make_mesh(MeshConfig(data=4), devices=topo.devices)
     d = 512
 
     def loss_fn(params, state, rng, batch):
@@ -226,13 +256,13 @@ def test_zero_trainer_keeps_pallas_inside_shard_map():
     def abstract_args(trainer, spec):
         w_sh = NamedSharding(mesh, spec)
         rep = NamedSharding(mesh, P())
-        params = {"w": _abstract((d, d), sharding=w_sh)}
+        params = {"w": _abstract((d, d), F32, w_sh)}
         opt_state = {"step": _abstract((), I32, rep),
                      "slots": {"w": {"moment1": params["w"],
                                      "moment2": params["w"]}}}
         data = NamedSharding(mesh, P("data"))
-        batch = {"x": _abstract((64, d), sharding=data),
-                 "y": _abstract((64, d), sharding=data)}
+        batch = {"x": _abstract((64, d), F32, data),
+                 "y": _abstract((64, d), F32, data)}
         rng = _abstract((2,), jnp.uint32, rep)
         return params, opt_state, {}, rng, batch
 
@@ -252,8 +282,8 @@ def test_zero_trainer_keeps_pallas_inside_shard_map():
 # the bench.py cell at full depth: ~25 s of compile, and its memory
 # ---------------------------------------------------------------------------
 @pytest.mark.slow
-def test_bert_base_full_depth_bs64_fits_a_v5e():
-    compiled, _ = _bert_step(MeshConfig(data=1), 1, 64,
+def test_bert_base_full_depth_bs64_fits_a_v5e(topo):
+    compiled, _ = _bert_step(topo, MeshConfig(data=1), 1, 64,
                              softmax_dtype="bf16")
     ma = compiled.memory_analysis()
     need = (ma.argument_size_in_bytes + ma.temp_size_in_bytes

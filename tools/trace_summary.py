@@ -1,73 +1,73 @@
 #!/usr/bin/env python3
-"""Summarize a jax.profiler trace directory by device-time.
+"""Summarize a jax.profiler trace by device time: per step, by the program's
+named scopes, by the compiler's HLO category, by Pallas kernel.
 
 The tools/timeline.py analog (ref: tools/timeline.py:131 converts the
-reference's profiler proto to chrome tracing): jax already emits
-chrome-trace JSON; this tool aggregates the device lanes into the
-per-HLO-category / per-op table used for the roofline and residue
-analyses in BASELINE.md (r2 ResNet roofline, r3 Transformer-big bound,
-r3 residue attribution).
+reference's profiler proto to chrome tracing). jax writes an ``.xplane.pb``
+under ``<trace_dir>/plugins/profile/<time>/``; ``chipbench/xplane.py`` reads
+it with the stats of the events' metadata (``tf_op``: jax's name stack, the
+``jax.named_scope``s among it; ``hlo_category``) and
+``chipbench/scope_profile.py`` sums it, over the whole executions of the
+program with most device time (the train step). This tool prints that
+reduction; the chip benchmark's per-layer metrics read the same one.
 
 Usage:
-    python tools/trace_summary.py TRACE_DIR [--steps N] [--top K]
+    python tools/trace_summary.py TRACE_DIR_OR_FILE [--top K]
 
-where TRACE_DIR is the directory passed to jax.profiler.trace(...).
---steps divides totals to per-step figures.
+where TRACE_DIR is what ``profiler.profiler(trace_dir=...)`` or
+``jax.profiler.start_trace`` was given (the newest trace under it is read),
+or the ``.xplane.pb[.gz]`` itself.
 """
 
 import argparse
-import glob
-import gzip
-import json
+import os
+import pathlib
 import sys
 
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if REPO not in sys.path:                       # CLI use from anywhere
+    sys.path.insert(0, REPO)
 
-def summarize(trace_dir, steps=1, top=15):
-    paths = glob.glob(f"{trace_dir}/**/*.trace.json.gz", recursive=True)
-    if not paths:
-        raise SystemExit(f"no *.trace.json.gz under {trace_dir}")
-    by_cat, by_name = {}, {}
-    total = 0.0
-    for p in paths:
-        with gzip.open(p, "rt") as f:
-            doc = json.load(f)
-        for e in doc.get("traceEvents", []):
-            if e.get("ph") != "X":
-                continue
-            args = e.get("args") or {}
-            cat = args.get("hlo_category")
-            if cat is None:      # host lanes have no hlo_category
-                continue
-            dur = e.get("dur", 0)
-            by_cat[cat] = by_cat.get(cat, 0.0) + dur
-            key = e.get("name", "").split(".")[0][:48]
-            by_name[key] = by_name.get(key, 0.0) + dur
-            total += dur
-    if not total:
-        raise SystemExit("no device events with hlo_category found")
+from chipbench import scope_profile, xplane    # noqa: E402
 
-    def table(d, title, k):
-        print(f"== {title} ==")
-        for name, us in sorted(d.items(), key=lambda kv: -kv[1])[:k]:
-            print(f"{name:48s} {us / steps / 1000:9.2f} ms/step "
-                  f"{us / total * 100:5.1f}%")
 
-    table(by_cat, "device time by HLO category", top)
-    table(by_name, "device time by op name", top)
-    print(f"device busy total: {total / steps / 1000:.2f} ms/step "
-          f"({len(paths)} trace file(s), steps={steps})")
+def newest_trace(where):
+    where = pathlib.Path(where)
+    if where.is_file():
+        return where
+    files = sorted(where.rglob("*.xplane.pb*"), key=lambda f: f.stat().st_mtime)
+    if not files:
+        raise SystemExit(f"no .xplane.pb under {where}")
+    return files[-1]
+
+
+def summarize(where, top=15):
+    """The lines to print for the newest trace under ``where``."""
+    path = newest_trace(where)
+    reduced = scope_profile.reduce_planes(xplane.load(path))
+    if reduced is None:
+        raise SystemExit(f"{path}: no device plane with a program on it (a "
+                         f"CPU trace has none)")
+    lines = [f"{path}"] + scope_profile.table(reduced)
+    if not reduced["scoped_events"]:
+        lines.append(scope_profile.NO_SCOPE)
+    busy = reduced["busy_ns"]
+    lines.append("== device time a step by HLO category ==")
+    for name, ns in sorted(reduced["category_ns"].items(),
+                           key=lambda kv: -kv[1])[:top]:
+        lines.append(f"{name:40}{ns / 1e6:10.3f} ms{100 * ns / busy:8.2f}%")
+    lines.append("== operations under no scope, by label ==")
+    for name, ns in reduced["unscoped_ops"][:top]:
+        lines.append(f"{name:40}{ns / 1e6:10.3f} ms{100 * ns / busy:8.2f}%")
+    return lines
 
 
 def main(argv=None):
-    ap = argparse.ArgumentParser(description=__doc__)
-    ap.add_argument("trace_dir")
-    ap.add_argument("--steps", type=int, default=1,
-                    help="profiled step count (divides totals)")
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("trace", help="a trace directory or an .xplane.pb[.gz]")
     ap.add_argument("--top", type=int, default=15)
     a = ap.parse_args(argv)
-    if a.steps <= 0:
-        ap.error("--steps must be positive")
-    summarize(a.trace_dir, a.steps, a.top)
+    print("\n".join(summarize(a.trace, a.top)))
 
 
 if __name__ == "__main__":
