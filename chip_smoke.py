@@ -283,8 +283,9 @@ def phase_kernels():
         out[f"flash_in_bert_s{seq}"] = err
         del params, got, want
     # ... and one whole train step at the longest length, bs=4: the
-    # context in which the backward's dK/dV kernel passed Mosaic's
-    # default scoped-VMEM limit (16.4 of 16 MiB) before it raised its own
+    # context in which the flash backward (one kernel: dQ, dK and dV
+    # from it) passed Mosaic's default scoped-VMEM limit (16.4 of 16 MiB)
+    # before the flash calls raised their own
     seq = FLASH_IN_BERT[-1][0]
     cfg = bert_cfg(max_seq=seq, num_layers=2, attention_impl="flash")
     init_fn, step_fn = bert.make_train_step(

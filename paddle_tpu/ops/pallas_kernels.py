@@ -8,8 +8,9 @@ what fusion can't express:
 
 - flash_attention — blockwise online-softmax attention; the [S, S] score
   matrix never exists in HBM (the reference materialises scores in
-  operators/math/ softmax + matmul calls). Forward is a Pallas kernel;
-  backward is the standard blockwise recompute formulated for XLA.
+  operators/math/ softmax + matmul calls). Forward is one Pallas kernel
+  and backward is one: it rebuilds each score tile once, from the saved
+  logsumexp, and takes dQ, dK, dV and the key-bias gradient from it.
 - fused_layer_norm — one VMEM pass for mean/var/normalise/affine.
 - softmax_cross_entropy — fused max/logsumexp/pick in one pass over the
   vocab axis (the reference's softmax_with_cross_entropy fused op,
@@ -25,6 +26,7 @@ registry and forces the Pallas body with that interpreter setting
 registry's platform-based selection.
 """
 
+import dataclasses
 import functools
 import math
 
@@ -40,12 +42,20 @@ __all__ = ["flash_attention", "fused_layer_norm", "softmax_cross_entropy"]
 
 _NEG_INF = -1e30
 
-#: scoped-VMEM limit for the three flash kernels. Each holds one head's
-#: whole-sequence operands resident (K/V forward and dQ; Q/dO and the
-#: lane-padded [S, 1] lse/delta columns in dK/dV), which passes Mosaic's
-#: 16 MiB default at S=4096 (16.16 MiB inside the BERT step). 64 MiB is
-#: half of a v5e core's 128 MiB of VMEM.
+#: scoped-VMEM limit for the flash kernels. Each holds one head's
+#: whole-sequence operands resident (K/V forward; Q, dO, dQ and its
+#: float32 accumulator backward), which passes Mosaic's 16 MiB default at
+#: S=4096. 64 MiB is half of a v5e core's 128 MiB of VMEM.
 _FLASH_COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=64 << 20)
+#: the backward accumulates dQ across its key-block axis, so that axis
+#: runs in order
+_FLASH_BWD_COMPILER_PARAMS = dataclasses.replace(
+    _FLASH_COMPILER_PARAMS,
+    dimension_semantics=("parallel", "parallel", "arbitrary"))
+#: the backward lays up to this many query tiles of a key block out as
+#: straight-line code, so that one tile's matmuls run under the next one's
+#: elementwise work (v5e, S=4096: 8 tiles 12.0 ms a call, 4 12.6, 1 13.8)
+_FLASH_BWD_UNROLL = 8
 
 
 def _vmem_spec(*args, **kwargs):
@@ -57,20 +67,22 @@ def _vmem_spec(*args, **kwargs):
 # flash attention
 # ---------------------------------------------------------------------------
 
-def _masked_scores(qs, k_blk, b_blk, q0, k0, causal):
+def _masked_scores(qs, k_blk, b_blk, q0, k0, causal, transposed=False):
     """Scaled scores for one (q-block, k-block) tile: qs is pre-scaled
     [bq, d], k_blk [bk, d], b_blk [bk] additive key bias; q0/k0 are the
     tile's absolute row/col offsets for the causal mask. Shared by the
-    forward and both backward kernels so masking/bias can never drift
-    between them."""
+    forward and the backward kernel so masking/bias can never drift
+    between them. ``transposed`` gives the tile as [bk, bq] (K Q^T, what
+    the backward wants); b_blk is then a [bk, 1] column."""
+    rows, cols = (k_blk, qs) if transposed else (qs, k_blk)
     s = jax.lax.dot_general(
-        qs, k_blk, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)            # [bq, bk]
-    s = s + b_blk[None, :]
+        rows, cols, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)            # [bq, bk] | [bk, bq]
+    s = s + (b_blk if transposed else b_blk[None, :])
     if causal:
-        bq, bk = s.shape
-        qi = q0 + lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-        ki = k0 + lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+        q_axis = 1 if transposed else 0
+        qi = q0 + lax.broadcasted_iota(jnp.int32, s.shape, q_axis)
+        ki = k0 + lax.broadcasted_iota(jnp.int32, s.shape, 1 - q_axis)
         s = jnp.where(ki <= qi, s, _NEG_INF)
     return s
 
@@ -173,166 +185,137 @@ def _flash_attention_fwd(q, k, v, bias, sm_scale, causal, block_q, block_k,
     return o, (q, k, v, bias, o, lse)
 
 
-def _flash_bwd_dkdv_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
-                           bias_ref, dk_ref, dv_ref, dbh_ref, *,
-                           sm_scale, block_q, block_k, causal, seq_len):
-    """One (batch, head, k-block) cell: stream Q/dO blocks, recompute the
-    probabilities from the saved logsumexp, accumulate dK/dV (and the
-    per-head key-bias grad) in VMEM — scores never touch HBM."""
-    k_blk = k_ref[0, 0].astype(jnp.float32)                # [bk, d]
-    v_blk = v_ref[0, 0].astype(jnp.float32)
-    b_blk = bias_ref[0, 0].astype(jnp.float32)             # [bk]
-    bk, d = k_blk.shape
+def _flash_bwd_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
+                      bias_ref, dq_ref, dk_ref, dv_ref, dbh_ref, dqt_acc, *,
+                      sm_scale, block_q, block_k, causal, seq_len):
+    """One (batch, head, k-block) cell: stream Q/dO blocks, rebuild each
+    tile's probabilities once from the saved logsumexp, and take all four
+    gradients from it. The tile is built transposed, [bk, bq] = K Q^T, so
+    that no product contracts over a tile's rows: dV += p^T dO and
+    dK += dz^T Q are plain, and dQ is accumulated transposed,
+    dQ^T += K^T dz^T, in a float32 [d, S] scratch that lives across the
+    head's key blocks (lse/delta ride as lane-dense [1, bq] rows). Scores
+    never touch HBM, nor do partial dQs."""
     ik = pl.program_id(2)
     nq = seq_len // block_q
 
-    def body(jq, carry):
+    @pl.when(ik == 0)
+    def _():
+        dqt_acc[...] = jnp.zeros_like(dqt_acc)
+
+    k_blk = k_ref[0, 0].astype(jnp.float32)                # [bk, d]
+    v_blk = v_ref[0, 0].astype(jnp.float32)
+    b_col = bias_ref[0].astype(jnp.float32)                # [bk, 1]
+    kt_blk = k_blk.T                                       # [d, bk]
+    bk, d = k_blk.shape
+
+    def tile(jq, carry):
         dk_acc, dv_acc, db_acc = carry
-        qs = q_ref[0, 0, pl.ds(jq * block_q, block_q), :] \
+        q0 = pl.multiple_of(jq * block_q, block_q)
+        qs = q_ref[0, 0, pl.ds(q0, block_q), :] \
             .astype(jnp.float32) * sm_scale                # [bq, d]
-        do_blk = do_ref[0, 0, pl.ds(jq * block_q, block_q), :] \
+        do_blk = do_ref[0, 0, pl.ds(q0, block_q), :] \
             .astype(jnp.float32)
-        lse_blk = lse_ref[0, 0, pl.ds(jq * block_q, block_q), 0]
-        d_blk = delta_ref[0, 0, pl.ds(jq * block_q, block_q), 0]
-        s = _masked_scores(qs, k_blk, b_blk, jq * block_q, ik * block_k,
-                           causal)
-        p = jnp.exp(s - lse_blk[:, None])                  # [bq, bk]
+        lse_row = lse_ref[0, 0, pl.ds(jq, 1), :]           # [1, bq]
+        d_row = delta_ref[0, 0, pl.ds(jq, 1), :]
+        st = _masked_scores(qs, k_blk, b_col, q0, ik * block_k, causal,
+                            transposed=True)
+        pt = jnp.exp(st - lse_row)                         # [bk, bq]
         dv_acc = dv_acc + jax.lax.dot_general(
-            p, do_blk, (((0,), (0,)), ((), ())),
+            pt, do_blk, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)            # [bk, d]
-        dp = jax.lax.dot_general(
-            do_blk, v_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)            # [bq, bk]
-        dz = p * (dp - d_blk[:, None])
+        dpt = jax.lax.dot_general(
+            v_blk, do_blk, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)            # [bk, bq]
+        dzt = pt * (dpt - d_row)
         dk_acc = dk_acc + jax.lax.dot_general(
-            dz, qs, (((0,), (0,)), ((), ())),
+            dzt, qs, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)            # [bk, d]
-        db_acc = db_acc + jnp.sum(dz, axis=0)              # [bk]
+        db_acc = db_acc + jnp.sum(dzt, axis=1, keepdims=True)
+        dqt_acc[:, pl.ds(q0, block_q)] += jax.lax.dot_general(
+            kt_blk, dzt, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)            # [d, bq]
         return dk_acc, dv_acc, db_acc
 
-    # causal: q-blocks strictly above the diagonal see only masked scores
-    jq0 = (ik * block_k) // block_q if causal else 0
-    dk, dv, db = lax.fori_loop(
-        jq0, nq, body,
-        (jnp.zeros((bk, d), jnp.float32), jnp.zeros((bk, d), jnp.float32),
-         jnp.zeros((bk,), jnp.float32)))
+    # causal: q-blocks strictly above the diagonal see only masked scores,
+    # so the loop starts at the diagonal and its length varies; otherwise
+    # the tiles go in groups of straight-line code
+    unroll = 1 if causal else max(
+        u for u in range(1, min(nq, _FLASH_BWD_UNROLL) + 1) if nq % u == 0)
+
+    def group(g, carry):
+        for i in range(unroll):
+            carry = tile(g * unroll + i, carry)
+        return carry
+
+    init = (jnp.zeros((bk, d), jnp.float32), jnp.zeros((bk, d), jnp.float32),
+            jnp.zeros((bk, 1), jnp.float32))
+    if unroll == nq:
+        dk, dv, db = group(0, init)
+    else:
+        jq0 = (ik * block_k) // block_q if causal else 0
+        dk, dv, db = lax.fori_loop(jq0, nq // unroll, group, init)
     dk_ref[0, 0] = dk.astype(dk_ref.dtype)
     dv_ref[0, 0] = dv.astype(dv_ref.dtype)
-    dbh_ref[0, 0, :, 0] = db
+    dbh_ref[0, 0] = db
 
-
-def _flash_bwd_dq_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
-                         bias_ref, dq_ref, *,
-                         sm_scale, block_q, block_k, causal, seq_len):
-    """One (batch, head, q-block) cell: stream K/V blocks, accumulate dQ."""
-    qs = q_ref[0, 0].astype(jnp.float32) * sm_scale        # [bq, d]
-    do_blk = do_ref[0, 0].astype(jnp.float32)
-    lse_blk = lse_ref[0, 0, :, 0]                          # [bq]
-    d_blk = delta_ref[0, 0, :, 0]
-    bq, d = qs.shape
-    iq = pl.program_id(2)
-    nk = seq_len // block_k
-
-    def body(jk, dq_acc):
-        k_blk = k_ref[0, 0, pl.ds(jk * block_k, block_k), :] \
-            .astype(jnp.float32)                           # [bk, d]
-        v_blk = v_ref[0, 0, pl.ds(jk * block_k, block_k), :] \
-            .astype(jnp.float32)
-        b_blk = bias_ref[0, 0, pl.ds(jk * block_k, block_k)] \
-            .astype(jnp.float32)
-        s = _masked_scores(qs, k_blk, b_blk, iq * block_q, jk * block_k,
-                           causal)
-        p = jnp.exp(s - lse_blk[:, None])
-        dp = jax.lax.dot_general(
-            do_blk, v_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dz = p * (dp - d_blk[:, None])
-        return dq_acc + jax.lax.dot_general(
-            dz, k_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-
-    if causal:
-        nk_eff = jnp.minimum(
-            nk, ((iq + 1) * block_q + block_k - 1) // block_k)
-    else:
-        nk_eff = nk
-    dq = lax.fori_loop(0, nk_eff, body, jnp.zeros((bq, d), jnp.float32))
-    dq_ref[0, 0] = (dq * sm_scale).astype(dq_ref.dtype)
+    @pl.when(ik == pl.num_programs(2) - 1)
+    def _():
+        dq_ref[0, 0] = (dqt_acc[...].T * sm_scale).astype(dq_ref.dtype)
 
 
 def _flash_attention_bwd(sm_scale, causal, block_q, block_k, interpret,
                          res, do):
-    """Blockwise recompute backward as two Pallas kernels (the standard
-    flash split): dK/dV gridded over key blocks, dQ over query blocks.
-    Live memory stays O(block · S); the [S, S] score matrix never exists."""
+    """Blockwise recompute backward as one Pallas kernel, gridded over key
+    blocks (hence its name: it is the call that yields dK and dV, and dQ
+    with them). Live memory stays O(block · S); the [S, S] score matrix
+    never exists."""
     q, k, v, bias, o, lse = res
     b, h, s, d = q.shape
-    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), -1,
-                    keepdims=True)                         # [B,H,S,1]
-    lse4 = lse[..., None]                                  # [B,H,S,1]
-    bias3 = bias[:, None, :]                               # [B,1,S]
-    kernel_kv = functools.partial(
-        _flash_bwd_dkdv_kernel, sm_scale=sm_scale, block_q=block_q,
+    nq = s // block_q
+    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), -1)
+    kernel = functools.partial(
+        _flash_bwd_kernel, sm_scale=sm_scale, block_q=block_q,
         block_k=block_k, causal=causal, seq_len=s)
-    dk, dv, dbh = pl.pallas_call(
-        kernel_kv,
+
+    def whole(ib, ih, ik):
+        return (ib, ih, 0, 0)
+
+    def k_block(ib, ih, ik):
+        return (ib, ih, ik, 0)
+
+    # lse/delta as one lane-dense row a query block ([B,H,nq,bq]); the bias
+    # as a column ([B,S,1]), since it runs down the transposed tile's rows
+    dq, dk, dv, dbh = pl.pallas_call(
+        kernel,
         grid=(b, h, s // block_k),
         in_specs=[
-            _vmem_spec((1, 1, s, d), lambda ib, ih, ik: (ib, ih, 0, 0)),
-            _vmem_spec((1, 1, s, d), lambda ib, ih, ik: (ib, ih, 0, 0)),
-            _vmem_spec((1, 1, s, 1), lambda ib, ih, ik: (ib, ih, 0, 0)),
-            _vmem_spec((1, 1, s, 1), lambda ib, ih, ik: (ib, ih, 0, 0)),
-            _vmem_spec((1, 1, block_k, d),
-                       lambda ib, ih, ik: (ib, ih, ik, 0)),
-            _vmem_spec((1, 1, block_k, d),
-                       lambda ib, ih, ik: (ib, ih, ik, 0)),
-            _vmem_spec((1, 1, block_k), lambda ib, ih, ik: (ib, 0, ik)),
+            _vmem_spec((1, 1, s, d), whole),
+            _vmem_spec((1, 1, s, d), whole),
+            _vmem_spec((1, 1, nq, block_q), whole),
+            _vmem_spec((1, 1, nq, block_q), whole),
+            _vmem_spec((1, 1, block_k, d), k_block),
+            _vmem_spec((1, 1, block_k, d), k_block),
+            _vmem_spec((1, block_k, 1), lambda ib, ih, ik: (ib, ik, 0)),
         ],
         out_specs=[
-            _vmem_spec((1, 1, block_k, d),
-                       lambda ib, ih, ik: (ib, ih, ik, 0)),
-            _vmem_spec((1, 1, block_k, d),
-                       lambda ib, ih, ik: (ib, ih, ik, 0)),
-            _vmem_spec((1, 1, block_k, 1),
-                       lambda ib, ih, ik: (ib, ih, ik, 0)),
+            _vmem_spec((1, 1, s, d), whole),
+            _vmem_spec((1, 1, block_k, d), k_block),
+            _vmem_spec((1, 1, block_k, d), k_block),
+            _vmem_spec((1, 1, block_k, 1), k_block),
         ],
         out_shape=[
+            jax.ShapeDtypeStruct(q.shape, q.dtype),
             jax.ShapeDtypeStruct(k.shape, k.dtype),
             jax.ShapeDtypeStruct(v.shape, v.dtype),
             jax.ShapeDtypeStruct((b, h, s, 1), jnp.float32),
         ],
-        compiler_params=_FLASH_COMPILER_PARAMS,
+        scratch_shapes=[pltpu.VMEM((d, s), jnp.float32)],
+        compiler_params=_FLASH_BWD_COMPILER_PARAMS,
         interpret=interpret,
         name="flash_bwd_dkv",
-    )(q, do, lse4, delta, k, v, bias3)
-    kernel_q = functools.partial(
-        _flash_bwd_dq_kernel, sm_scale=sm_scale, block_q=block_q,
-        block_k=block_k, causal=causal, seq_len=s)
-    dq = pl.pallas_call(
-        kernel_q,
-        grid=(b, h, s // block_q),
-        in_specs=[
-            _vmem_spec((1, 1, block_q, d),
-                       lambda ib, ih, iq: (ib, ih, iq, 0)),
-            _vmem_spec((1, 1, block_q, d),
-                       lambda ib, ih, iq: (ib, ih, iq, 0)),
-            _vmem_spec((1, 1, block_q, 1),
-                       lambda ib, ih, iq: (ib, ih, iq, 0)),
-            _vmem_spec((1, 1, block_q, 1),
-                       lambda ib, ih, iq: (ib, ih, iq, 0)),
-            _vmem_spec((1, 1, s, d), lambda ib, ih, iq: (ib, ih, 0, 0)),
-            _vmem_spec((1, 1, s, d), lambda ib, ih, iq: (ib, ih, 0, 0)),
-            _vmem_spec((1, 1, s), lambda ib, ih, iq: (ib, 0, 0)),
-        ],
-        out_specs=[
-            _vmem_spec((1, 1, block_q, d),
-                       lambda ib, ih, iq: (ib, ih, iq, 0)),
-        ],
-        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype)],
-        compiler_params=_FLASH_COMPILER_PARAMS,
-        interpret=interpret,
-        name="flash_bwd_dq",
-    )(q, do, lse4, delta, k, v, bias3)[0]
+    )(q, do, lse.reshape(b, h, nq, block_q),
+      delta.reshape(b, h, nq, block_q), k, v, bias[:, :, None])
     dbias = jnp.sum(dbh[..., 0], axis=1)                   # [B,S]
     return dq, dk, dv, dbias.astype(bias.dtype)
 

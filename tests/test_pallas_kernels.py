@@ -111,6 +111,57 @@ class TestFlashAttention:
             np.asarray(jax.grad(f_flash)(q)),
             np.asarray(jax.grad(f_dense)(q)), atol=3e-4)
 
+    #: the one backward kernel, every branch of it: (sequence, block_q,
+    #: block_k, causal, key bias, input dtype, atol). Each case has at
+    #: least two blocks on both axes, so dQ accumulates across key blocks
+    #: and dK/dV across query blocks.
+    BACKWARD_CASES = {
+        "plain": (256, 128, 128, False, False, jnp.float32, 3e-4),
+        "causal": (256, 128, 128, True, False, jnp.float32, 3e-4),
+        "key_padding_bias": (256, 128, 128, False, True, jnp.float32, 3e-4),
+        "causal_bias": (256, 128, 128, True, True, jnp.float32, 3e-4),
+        "s300_padded": (300, 128, 128, False, True, jnp.float32, 3e-4),
+        "block_q_gt_block_k": (512, 256, 128, False, True, jnp.float32,
+                               3e-4),
+        "block_q_lt_block_k": (512, 128, 256, True, False, jnp.float32,
+                               3e-4),
+        # 1152 / 128 = 9 query tiles: groups of three inside a loop
+        "nine_query_tiles": (1152, 128, 128, False, False, jnp.float32,
+                             3e-4),
+        "bf16": (256, 128, 128, False, True, jnp.bfloat16, 4e-2),
+    }
+
+    @pytest.mark.parametrize("case", sorted(BACKWARD_CASES))
+    def test_one_backward_kernel_matches_dense(self, case):
+        s, bq, bk, causal, with_bias, dtype, atol = self.BACKWARD_CASES[case]
+        q, k, v = (t.astype(dtype)
+                   for t in self._rand(b=2, h=2, s=s, d=32, seed=3))
+        rng = np.random.RandomState(4)
+        w = jnp.asarray(rng.randn(2, 2, s, 32).astype(np.float32))
+        bias = np.zeros((2, s), np.float32)
+        if with_bias:
+            # a soft bias on the kept keys and a padding mask on the tail
+            bias[:] = rng.randn(2, s) * 0.3
+            bias[:, s - s // 5:] = -1e30
+        bias = jnp.asarray(bias)
+
+        def f_flash(q, k, v, bias):
+            out = K.flash_attention(q, k, v, bias=bias, causal=causal,
+                                    block_q=bq, block_k=bk, interpret=True)
+            return jnp.sum(out.astype(jnp.float32) * w)
+
+        def f_dense(q, k, v, bias):
+            return jnp.sum(_dense_attention(q, k, v, bias=bias,
+                                            causal=causal) * w)
+
+        got = jax.grad(f_flash, argnums=(0, 1, 2, 3))(q, k, v, bias)
+        want = jax.grad(f_dense, argnums=(0, 1, 2, 3))(q, k, v, bias)
+        for name, a, b_ in zip(("dq", "dk", "dv", "dbias"), got, want):
+            assert a.dtype == b_.dtype, name
+            np.testing.assert_allclose(
+                np.asarray(a, np.float32), np.asarray(b_, np.float32),
+                atol=atol, err_msg=f"{case}: {name}")
+
     def test_bfloat16(self):
         q, k, v = self._rand(s=64, d=32)
         qb, kb, vb = (t.astype(jnp.bfloat16) for t in (q, k, v))
