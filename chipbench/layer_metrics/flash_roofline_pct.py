@@ -1,12 +1,16 @@
 """Layer: kernels. The flash attention kernels' share of their roofline: the
 least time the chip could take for the matmuls they must do
-(``flops/flash.py``: bound by FLOP/s) over the device time of the Mosaic calls
-``flash_fwd``, ``flash_bwd_dkv`` and ``flash_bwd_dq`` in a step. Only where
-the step runs them (``bert_base.mlm_s4096``)."""
+(``flops/flash.py``: bound by FLOP/s) over the device time of the Mosaic
+calls ``flash_fwd`` and the one backward call in a step. The backward call is
+``flash_bwd_dkv`` in the program today; ``flash_bwd`` is the name it should
+take (it yields dQ too since PR 24), so the PR that renames it edits nothing
+here. None where the step runs no flash call: a cell whose step does lists
+itself under this entry's ``workloads``, or brings an entry of its own whose
+reader calls this one."""
 
 from chipbench import scope_profile
 
-KERNELS = ("flash_fwd", "flash_bwd_dkv", "flash_bwd_dq")
+KERNELS = ("flash_fwd", "flash_bwd_dkv", "flash_bwd")
 
 
 def metric(facts):

@@ -1,15 +1,10 @@
 """Layer: functional trainers. Median host time of the program's span
-``trainer/place`` (the ``device_put`` of a step's batch onto the mesh) in the
-traced steps: one half of ``host_step_call_ms``, ``trainer/enqueue`` being the
+``trainer/place`` (a step's batch put on the mesh, host to device) in the
+traced steps: one half of a ``step_fn`` call, ``host_enqueue_ms`` being the
 other. None where the program writes no such span."""
-
-import statistics
 
 from chipbench import scope_profile
 
 
 def metric(facts):
-    reduced = scope_profile.profile(facts)
-    if reduced is None or not reduced["host_span_ms"]["trainer/place"]:
-        return None
-    return statistics.median(reduced["host_span_ms"]["trainer/place"])
+    return scope_profile.span_ms(facts, "trainer/place")

@@ -26,7 +26,7 @@ import jax                     # noqa: E402
 import jax.numpy as jnp        # noqa: E402
 import numpy as np             # noqa: E402
 
-from chipbench import trace_reduce                      # noqa: E402
+from chipbench import trace_reduce, xplane              # noqa: E402
 from chipbench.aot import step_bytes                    # noqa: E402
 from chipbench.catalog import ROOT, Catalog             # noqa: E402
 
@@ -199,30 +199,51 @@ def check_reference(catalog, config, job, params, seed):
     return ok
 
 
-def take_trace(job, state, pool, workload, device_planes, keep):
-    """TRACED_STEPS steady steps under the profiler; the reduced trace."""
-    trace_dir = OUT_DIR / "trace" / workload
+def take_trace(job, state, pool, trace_dir, device_planes):
+    """TRACED_STEPS steady steps under the profiler, the one trace of a
+    traced run: its planes, parsed once (every reader reads these, none
+    takes a trace of its own), and ``trace_reduce``'s reduction of them. The
+    caller deletes ``trace_dir`` once the last reader has returned."""
     shutil.rmtree(trace_dir, ignore_errors=True)
     options = jax.profiler.ProfileOptions()
     options.python_tracer_level = 0       # host spans, not every Python call
     jax.profiler.start_trace(str(trace_dir), profiler_options=options)
     try:
-        state, *_ = drive(job, state, pool, Spans(), steps=TRACED_STEPS)
+        drive(job, state, pool, Spans(), steps=TRACED_STEPS)
     finally:
         jax.profiler.stop_trace()
     files = sorted(trace_dir.rglob("*.xplane.pb"))
     if not files:
         raise RuntimeError(f"the profiler wrote no .xplane.pb under "
                            f"{trace_dir}")
-    reduced = trace_reduce.reduce_file(files[-1], device_planes)
-    (OUT_DIR / f"{workload}.reduced.json").write_text(
-        json.dumps(reduced, indent=1))
+    t0 = time.perf_counter()
+    planes = xplane.load(files[-1])
+    reduced = trace_reduce.reduce_planes(planes, device_planes)
     log(f"trace: {files[-1].stat().st_size} bytes, "
         f"{len(reduced['devices'])} device plane(s), "
-        f"{reduced['host_spans']} host spans")
-    if not keep:
-        shutil.rmtree(trace_dir, ignore_errors=True)
-    return state, reduced
+        f"{reduced['host_spans']} host spans; read and reduced in "
+        f"{time.perf_counter() - t0:.2f} s")
+    return planes, reduced
+
+
+def read_metrics(catalog, workload, facts, compiles):
+    """{name: value or None} of the cell's per-layer metrics, each from its
+    own reader file. A reader only reads: what the window and the one trace
+    left in ``facts``, and what the program can be asked without running
+    it. One that compiles a program (``compiles`` counts the requests) is
+    refused here, so that a traced run never needs more of the device than
+    the step itself."""
+    before, t0 = compiles.count, time.perf_counter()
+    values = {m["name"]: catalog.module("layer_metrics",
+                                        m["name"]).metric(facts)
+              for m in catalog.metrics("per_layer", workload)}
+    log(f"readers: {len(values)} metrics read in "
+        f"{time.perf_counter() - t0:.2f} s, {compiles.count - before} "
+        f"compile requests")
+    if compiles.count != before:
+        raise SystemExit("a per-layer reader compiled a program: a reader "
+                         "only reads (chipbench/README.md): no result")
+    return values
 
 
 def run_cell(workload, seed, seconds, trace, catalog=None, peaks=None,
@@ -320,9 +341,11 @@ def run_cell(workload, seed, seconds, trace, catalog=None, peaks=None,
     group = "end_to_end"
     if trace:
         group = "per_layer"
-        state, reduced = take_trace(job, state, pool, workload,
-                                    peak.get("device_planes", "/device:"),
-                                    keep_trace)
+        trace_dir = OUT_DIR / "trace" / workload
+        planes, reduced = take_trace(
+            job, state, pool, trace_dir, peak.get("device_planes", "/device:"))
+        (OUT_DIR / f"{workload}.reduced.json").write_text(
+            json.dumps(reduced, indent=1))
         if reduced["devices"]:
             device["busy_s"] = reduced["busy_ns"] / 1e9
             device["window_s"] = reduced["window_ns"] / 1e9
@@ -334,17 +357,15 @@ def run_cell(workload, seed, seconds, trace, catalog=None, peaks=None,
         elif peak.get("device_planes"):
             raise SystemExit("no operation ran on a device in the traced "
                              "steps: no result")
-        facts = {
-            "trace": reduced, "spans": spans, "job": job, "state": state,
+        values = read_metrics(catalog, workload, {
+            "trace": reduced, "planes": planes, "spans": spans, "job": job,
             "config": config, "traffic": traffic, "cell": cell,
             "peak": peak, "catalog": catalog, "step_bytes": need,
             "tokens_per_s": tokens_per_s, "window_tokens_per_s": window_rate,
             "intervals_s": intervals,
-        }
-        values = {}
-        for m in catalog.metrics("per_layer", workload):
-            values[m["name"]] = catalog.module(
-                "layer_metrics", m["name"]).metric(facts)
+        }, compiles)
+        if not keep_trace:
+            shutil.rmtree(trace_dir, ignore_errors=True)
     for m in catalog.metrics(group, workload):
         if values.get(m["name"]) is not None:
             result["metrics"][m["name"]] = {

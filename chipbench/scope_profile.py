@@ -5,8 +5,8 @@
 The program (``paddle_tpu``) wraps its parts in ``jax.named_scope``: ``embed``,
 ``attention``, ``attention_core``, ``ffn``, ``layer_norm``, ``loss`` in the
 models, ``optimizer`` in ``Optimizer.apply_gradients``. It names its Pallas
-kernels (``flash_fwd``, ``flash_bwd_dkv``, ``flash_bwd_dq``,
-``layer_norm_fwd``, ``fused_adam``, ...) and writes two host spans a step,
+kernels (``flash_fwd``, ``flash_bwd_dkv``, ``layer_norm_fwd``,
+``fused_adam``, ...) and writes two host spans a step,
 ``trainer/place`` and ``trainer/enqueue``. In a profile every device operation
 then carries jax's name stack as the stat ``tf_op`` of its event's metadata,
 
@@ -35,25 +35,30 @@ gap of the device put down to the innermost span open on the host when it
 began, the harness's (``next_batch``, ``step_call``, ``fetch_loss``) or the
 program's. All averaged over the device planes.
 
-``profile(facts)`` is what the per-layer metrics call: it takes a trace of its
-own (the harness deletes its ``.xplane.pb`` before a metric runs and keeps only
-``trace_reduce``'s reduction, which has no scopes), reduces it, and keeps the
-result in ``facts``. Where not one device operation carries a scope of the
-vocabulary (a program from before the scopes, or an executable loaded from a
-compile cache that an unscoped program filled) it returns ``None`` and says so
-in the log, so that no scoped metric reads 0 in silence.
+The vocabulary is ``SCOPES`` plus what the cell's configuration file lists
+under ``"scopes"``: names its program nests inside the base scopes (a router,
+its experts, a kernel's name: whatever is on the stacks). A listed name takes
+the time of the operations it is innermost on out of the base scope around it,
+so the scopes still sum with ``unscoped_ns`` to the busy time.
+
+``profile(facts)`` is what the per-layer metrics call: it reduces the one
+trace of the traced run, which the harness parsed once and keeps in
+``facts["planes"]`` until the last reader has returned, and keeps the result
+in ``facts``. No reader takes a trace of its own. Where not one device
+operation carries a scope of the vocabulary (a program from before the
+scopes, or an executable loaded from a compile cache that an unscoped program
+filled) it returns ``None`` and says so in the log, so that no scoped metric
+reads 0 in silence.
 """
 
 import collections
 import functools
 import json
 import re
-import shutil
 import statistics
 import time
 
 from chipbench import trace_reduce as tr
-from chipbench import xplane
 
 SCOPES = ("embed", "attention", "attention_core", "ffn", "layer_norm",
           "loss", "optimizer")
@@ -79,13 +84,18 @@ def elements(tf_op):
     return out
 
 
+def vocabulary(scopes):
+    """``SCOPES`` and then a configuration's own names, each once."""
+    return tuple(dict.fromkeys((*SCOPES, *scopes)))
+
+
 @functools.lru_cache(maxsize=None)     # a step's operations repeat
-def classify(tf_op):
-    """(direction, innermost scope of the vocabulary or None)."""
+def classify(tf_op, scopes=SCOPES):
+    """(direction, innermost of ``scopes`` on the stack or None)."""
     if not tf_op:
         return "other", None
     names = elements(tf_op)
-    scope = next((n for n in reversed(names) if n in SCOPES), None)
+    scope = next((n for n in reversed(names) if n in scopes), None)
     if "transpose(" in tf_op:
         return "backward", scope
     if "jvp(" in tf_op:
@@ -102,7 +112,7 @@ def host_spans(planes):
     return sorted(spans, key=lambda s: s[1])
 
 
-def reduce_device(lines, spans):
+def reduce_device(lines, spans, scopes):
     """The sums of one device plane, a step; None where no step ran on it."""
     modules = lines.get(tr.MODULES_LINE, [])
     name = tr.step_module(modules)
@@ -113,13 +123,14 @@ def reduce_device(lines, spans):
     ops = tr.clip(lines[tr.OPS_LINE], lo, hi)
     own = tr.self_times(ops)
     direction = dict.fromkeys(("forward", "backward", "optimizer", "other"), 0)
-    scope_ns = {s: {"forward": 0, "backward": 0, "total": 0} for s in SCOPES}
+    scope_ns = {s: {"forward": 0, "backward": 0, "total": 0}
+                for s in scopes}
     kernels, categories = collections.Counter(), collections.Counter()
     unscoped_ops = collections.Counter()
     unscoped = scoped_events = 0
     for i, e in enumerate(ops):
         ns = own[i]
-        way, scope = classify(e.stats.get("tf_op"))
+        way, scope = classify(e.stats.get("tf_op"), scopes)
         direction[way] += ns
         if scope is None:
             unscoped += ns
@@ -160,13 +171,15 @@ def mean_of(dicts):
     return {k: sum(d.get(k, 0) for d in dicts) / len(dicts) for k in keys}
 
 
-def reduce_planes(planes, device_planes=tr.DEVICE_PLANES):
+def reduce_planes(planes, device_planes=tr.DEVICE_PLANES, scopes=()):
     """The reduction of a trace read by ``xplane.load``, averaged over its
     device planes; None where it has no device plane with a step on it.
+    ``scopes`` are a configuration's names beside ``SCOPES``.
     ``scoped_events`` counts the device operations that carry a scope of
     the vocabulary: where it is 0 the by-scope numbers say nothing."""
     spans = host_spans(planes)
-    devices = [d for d in (reduce_device(planes[name], spans)
+    scopes = vocabulary(scopes)
+    devices = [d for d in (reduce_device(planes[name], spans, scopes)
                            for name in sorted(planes)
                            if name.startswith(device_planes))
                if d is not None]
@@ -224,51 +237,20 @@ def table(reduced):
     return lines
 
 
-def take(facts, trace_dir):
-    """``run.TRACED_STEPS`` steps through the trainer's own step_fn under the
-    profiler, driven as the measured window is; the path of the trace. The
-    step donates its state, so the state it ends with goes back to facts."""
-    import jax
-    import numpy as np
-    from chipbench import run
-
-    job = facts["job"]
-    rs = np.random.RandomState(0)
-    pool = [job.draw_batch(rs, job.batch) for _ in range(2)]
-    shutil.rmtree(trace_dir, ignore_errors=True)
-    options = jax.profiler.ProfileOptions()
-    options.python_tracer_level = 0       # host spans, not every Python call
-    jax.profiler.start_trace(str(trace_dir), profiler_options=options)
-    try:
-        facts["state"], *_ = run.drive(job, facts["state"], pool,
-                                       run.Spans(), steps=run.TRACED_STEPS)
-    finally:
-        jax.profiler.stop_trace()
-    files = sorted(trace_dir.rglob("*.xplane.pb"))
-    if not files:
-        raise RuntimeError(f"the profiler wrote no .xplane.pb under "
-                           f"{trace_dir}")
-    return files[-1]
-
-
 def profile(facts):
-    """The reduction of this cell's own short trace, or None (see the module
-    docstring). Taken once and kept in ``facts``."""
+    """The reduction of the traced run's one trace (``facts["planes"]``), by
+    the vocabulary of the cell's configuration, or None (see the module
+    docstring). Made once and kept in ``facts``."""
     if "scope_profile" in facts:
         return facts["scope_profile"]
     from chipbench import run
 
-    name = facts["cell"]["name"]
-    trace_dir = run.OUT_DIR / "scope_trace" / name
     t0 = time.perf_counter()
-    path = take(facts, trace_dir)
-    t1 = time.perf_counter()
-    reduced = reduce_planes(xplane.load(path),
-                            facts["peak"].get("device_planes", "/device:"))
-    print(f"[scopes] second trace taken in {t1 - t0:.2f} s "
-          f"({path.stat().st_size} bytes), read and reduced in "
-          f"{time.perf_counter() - t1:.2f} s", flush=True)
-    shutil.rmtree(trace_dir, ignore_errors=True)
+    reduced = reduce_planes(facts["planes"],
+                            facts["peak"].get("device_planes", "/device:"),
+                            facts["config"].get("scopes", ()))
+    print(f"[scopes] the run's one trace reduced by scope in "
+          f"{time.perf_counter() - t0:.2f} s", flush=True)
     if reduced is None:
         print("[scopes] no device plane in the trace", flush=True)
     elif not reduced["scoped_events"]:
@@ -276,7 +258,7 @@ def profile(facts):
         reduced = None
     else:
         run.OUT_DIR.mkdir(parents=True, exist_ok=True)
-        (run.OUT_DIR / f"{name}.scopes.json").write_text(
+        (run.OUT_DIR / f"{facts['cell']['name']}.scopes.json").write_text(
             json.dumps(reduced, indent=1))
         for line in table(reduced):
             print(f"[scopes] {line}", flush=True)
@@ -292,4 +274,13 @@ def ms(facts, *keys):
             return None
         value = value.get(key)
     return None if value is None else value / 1e6
+
+
+def span_ms(facts, name):
+    """Median host milliseconds of the program's span ``name`` in the traced
+    steps; None where the program writes no such span."""
+    reduced = profile(facts)
+    if reduced is None or not reduced["host_span_ms"].get(name):
+        return None
+    return statistics.median(reduced["host_span_ms"][name])
 

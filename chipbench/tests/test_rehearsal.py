@@ -58,21 +58,20 @@ def test_traced_line_reports_the_per_layer_metrics(catalog, capsys):
                     catalog=catalog, peaks=CPU_PEAKS)
     assert set(out) == KEYS            # + breakdown where a device traced
     assert set(out["metrics"]) == {
-        "host_step_call_ms", "mfu_pct", "window_stall_pct",
-        "optimizer_update_ms", "pallas_bodies_selected", "step_hbm_gib",
-        "steps_completed"}
+        "mfu_pct", "window_stall_pct", "pallas_bodies_selected",
+        "step_hbm_gib", "steps_completed"}
     assert out["metrics"]["steps_completed"]["value"] == out["attempted"] - 1
 
 
 def test_traced_line_with_a_device_trace_adds_breakdown_and_busy(
         catalog, capsys, monkeypatch):
-    """The CPU writes no device plane, so the reduction of the trace recorded
-    on the v5e stands in for this run's: the line then has ``breakdown``,
+    """The CPU writes no device plane, so the planes of the trace recorded on
+    the v5e stand in for this run's: the line then has ``breakdown``,
     ``busy_s`` and ``window_s``, and every per-layer metric."""
-    from chipbench import trace_reduce
-    recorded = trace_reduce.reduce_file(
+    from chipbench import xplane
+    recorded = xplane.load(
         FIXTURES / "traces" / "bert_toy.mlm_toy_dp4.xplane.pb.gz")
-    monkeypatch.setattr(trace_reduce, "reduce_file", lambda *a: recorded)
+    monkeypatch.setattr(run.xplane, "load", lambda path: recorded)
     peaks = {"cpu": dict(CPU_PEAKS["cpu"], device_planes="/device:TPU:")}
     out = last_line(capsys, ["--workload", "bert_toy.mlm_toy_dp4", "--seed",
                              "0", "--seconds", "0.5", "--trace", "1"],
@@ -88,6 +87,75 @@ def test_traced_line_with_a_device_trace_adds_breakdown_and_busy(
                                            "bert_toy.mlm_toy_dp4")}
     assert out["metrics"]["collective_exposed_ms"]["value"] == \
         pytest.approx(81195.25 / 2 / 1e6)
+
+
+def test_a_traced_run_takes_one_trace_and_its_readers_compile_nothing(
+        catalog, capsys, monkeypatch):
+    """The profiler is started once in a traced run, no reader asks jax for
+    a compilation, and the trace's directory is gone when the run returns."""
+    import jax
+    started, counts = [], []
+    start_trace, read_metrics = jax.profiler.start_trace, run.read_metrics
+
+    def counting_start(*a, **kw):
+        started.append(a[0])
+        return start_trace(*a, **kw)
+
+    def counting_read(catalog, workload, facts, compiles):
+        counts.append(compiles.count)
+        values = read_metrics(catalog, workload, facts, compiles)
+        counts.append(compiles.count)
+        return values
+
+    monkeypatch.setattr(jax.profiler, "start_trace", counting_start)
+    monkeypatch.setattr(run, "read_metrics", counting_read)
+    out = last_line(capsys, ["--workload", "transformer_toy.wmt_toy",
+                             "--seed", "7", "--seconds", "0.5",
+                             "--trace", "1"],
+                    catalog=catalog, peaks=CPU_PEAKS)
+    assert out["correct"] is True
+    assert len(started) == 1
+    assert counts[0] > 0 and counts[0] == counts[1]
+    assert not (run.OUT_DIR / "trace" / "transformer_toy.wmt_toy").exists()
+
+
+def test_a_reader_that_compiles_is_refused(capsys):
+    """``read_metrics`` gives no result where a reader made jax compile."""
+    import types
+    import jax
+
+    class OneReader:
+        def __init__(self, metric):
+            self.metric = metric
+
+        def metrics(self, group, workload):
+            return [{"name": "one"}]
+
+        def module(self, kind, name):
+            return types.SimpleNamespace(metric=self.metric)
+
+    compiles = run.CompileCounter()
+    assert run.read_metrics(OneReader(lambda facts: 1.5), "cell", {},
+                            compiles) == {"one": 1.5}
+    with pytest.raises(SystemExit):
+        run.read_metrics(
+            OneReader(lambda facts: float(jax.jit(lambda x: x * 3 + 1)(2.0))),
+            "cell", {}, compiles)
+    assert "1 compile requests" in capsys.readouterr().out
+
+
+def test_no_reader_file_runs_the_device():
+    """The rule of chipbench/README.md, as far as a text can show it: no
+    file under a ``layer_metrics`` directory jits, waits for the device or
+    puts an array on it."""
+    for spec in (None, FIXTURES / "benchmark.json",
+                 FIXTURES / "benchmark_scopes.json"):
+        for d in (Catalog(spec) if spec else Catalog()).dirs:
+            for f in sorted((d / "layer_metrics").glob("*.py")):
+                text = f.read_text()
+                for call in ("jax.jit", "block_until_ready", "device_put",
+                             "start_trace(", "step_fn("):
+                    assert call not in text, (f, call)
 
 
 def window_stall(catalog, rate, window_rate):

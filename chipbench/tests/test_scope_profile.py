@@ -3,9 +3,9 @@ plane built by hand, where every number can be worked out on paper; on a small
 trace recorded on a v5e from the tree that brought the scopes
 (``bert_toy.mlm_toy.scopes``: ``record_fixture`` as it is, the file copied
 under this name); on the two older traces, which have no scope; and through
-the harness on the CPU, where the eight metrics find nothing to read."""
+the harness on the CPU, where the nine metrics find nothing to read, and with
+the recorded trace in the place of the CPU's, where they all do."""
 
-import gzip
 import json
 
 import pytest
@@ -15,9 +15,9 @@ from chipbench.catalog import ROOT, Catalog
 
 FIXTURES = ROOT / "chipbench" / "tests" / "fixtures"
 TRACES = FIXTURES / "traces"
-EIGHT = ["fwd_ms", "bwd_ms", "optimizer_step_ms", "attention_core_ms",
-         "layer_norm_ms", "flash_roofline_pct", "scope_unattributed_pct",
-         "host_place_ms"]
+NINE = ["fwd_ms", "bwd_ms", "optimizer_step_ms", "attention_core_ms",
+        "layer_norm_ms", "flash_roofline_pct", "scope_unattributed_pct",
+        "host_place_ms", "host_enqueue_ms"]
 
 
 def test_a_name_stack_comes_apart_into_direction_and_scope():
@@ -167,14 +167,71 @@ def test_reduction_of_the_trace_recorded_with_scopes():
     assert "fused_adam (mosaic)" in labels       # one kernel a Mosaic row
 
 
+def test_a_configuration_names_further_scopes():
+    """``bert_toy_scoped`` lists two names that are on the recorded trace's
+    stacks inside ``optimizer`` and ``layer_norm``. Each takes the time of the
+    operations it is innermost on out of the base scope around it, the other
+    base scopes and the unscoped time stay to the nanosecond, and the whole
+    vocabulary still sums with the unscoped time to the busy time."""
+    _, config, _ = Catalog(FIXTURES / "benchmark_scopes.json").cell(
+        "bert_toy_scoped.mlm_toy")
+    assert config["scopes"] == ["fused_adam", "layer_norm_fwd"]
+    assert sp.vocabulary(config["scopes"]) == sp.SCOPES + ("fused_adam",
+                                                           "layer_norm_fwd")
+    assert sp.vocabulary(["ffn", "router", "router"]) == sp.SCOPES + (
+        "router",)
+    planes = xplane.load(TRACES / "bert_toy.mlm_toy.scopes.xplane.pb.gz")
+    base = sp.reduce_planes(planes)
+    got = sp.reduce_planes(planes, scopes=config["scopes"])
+    assert list(base["scope_ns"]) == list(sp.SCOPES)
+    assert list(got["scope_ns"]) == list(sp.SCOPES) + config["scopes"]
+
+    def total(reduced, scope):
+        return reduced["scope_ns"][scope]["total"]
+
+    # every operation under fused_adam is the kernel or the reshapes beside it
+    assert total(got, "fused_adam") >= got["kernel_ns"]["fused_adam"] > 0
+    assert total(got, "layer_norm_fwd") >= got["kernel_ns"]["layer_norm_fwd"]
+    assert total(got, "optimizer") + total(got, "fused_adam") == \
+        pytest.approx(total(base, "optimizer"))
+    assert total(got, "layer_norm") + total(got, "layer_norm_fwd") == \
+        pytest.approx(total(base, "layer_norm"))
+    assert got["scope_ns"]["layer_norm_fwd"]["forward"] == \
+        total(got, "layer_norm_fwd")              # the backward is plain jnp
+    for scope in ("embed", "attention", "attention_core", "ffn", "loss"):
+        assert got["scope_ns"][scope] == base["scope_ns"][scope]
+    assert got["unscoped_ns"] == base["unscoped_ns"]
+    assert got["direction_ns"] == base["direction_ns"]
+    for reduced in (base, got):
+        assert sum(d["total"] for d in reduced["scope_ns"].values()) \
+            + reduced["unscoped_ns"] == pytest.approx(reduced["busy_ns"],
+                                                      rel=0.02)
+    # a name no stack carries reads 0, and takes nothing
+    none = sp.reduce_planes(planes, scopes=["router"])
+    assert total(none, "router") == 0
+    assert total(none, "optimizer") == total(base, "optimizer")
+
+
 def test_flash_operations_from_shapes():
     """BERT-base at batch 8 x 4096: 12 layers x 6 matmuls x 2 x 8 x 12 x
-    4096^2 x 64 = 1.484e13 operations a step, 75.3 ms at 197 TFLOP/s."""
+    4096^2 x 64 = 1.484e13 operations a step, 75.3 ms at 197 TFLOP/s, as
+    before the function knew ``head_dim`` and ``attention``. A configuration
+    that states its head size is counted by it, and a causal one by half."""
     catalog = Catalog()
     _, config, traffic = catalog.cell("bert_base.mlm_s4096")
-    flops = catalog.module("flops", "flash").flops_per_step(config, traffic)
-    assert flops == 12 * 6 * 2 * 8 * 12 * 4096 ** 2 * 64
+    flops_per_step = catalog.module("flops", "flash").flops_per_step
+    flops = flops_per_step(config, traffic)
+    assert "head_dim" not in config and "attention" not in config
+    assert flops == 12 * 6 * 2 * 8 * 12 * 4096 ** 2 * 64 == 14843406974976
     assert flops / 197e12 == pytest.approx(0.0753, rel=1e-2)
+    assert flops_per_step(dict(config, attention="causal"), traffic) \
+        == flops // 2
+    assert flops_per_step(dict(config, head_dim=128), traffic) == 2 * flops
+    # one layer of 16 heads of 128, causal, batch 2 x 4096
+    decoder = {"num_hidden_layers": 1, "num_attention_heads": 16,
+               "hidden_size": 2048, "head_dim": 128, "attention": "causal"}
+    assert flops_per_step(decoder, {"batch": 2, "seq_len": 4096}) \
+        == 6 * 2 * 2 * 16 * (4096 ** 2 // 2) * 128
 
 
 @pytest.fixture(scope="module")
@@ -189,67 +246,109 @@ def facts_of(catalog, reduced, **more):
                  "peak": {"bf16_flops_per_s": 197e12}}, **more)
 
 
-def test_the_eight_metrics_read_a_reduction(scopes_catalog):
+def test_the_nine_metrics_read_a_reduction(scopes_catalog):
     reduced = sp.reduce_planes(hand_made())
     facts = facts_of(scopes_catalog, reduced)
     got = {name: scopes_catalog.module("layer_metrics", name).metric(facts)
-           for name in EIGHT}
+           for name in NINE}
     assert got["fwd_ms"] == 30e-6 and got["bwd_ms"] == 40e-6
     assert got["optimizer_step_ms"] == 10e-6
     assert got["attention_core_ms"] == 50e-6
     assert got["layer_norm_ms"] == 0
     assert got["scope_unattributed_pct"] == pytest.approx(100 * 20 / 90)
     assert got["host_place_ms"] == 13e-6
+    assert got["host_enqueue_ms"] == 3e-6
     flops = scopes_catalog.module("flops", "flash").flops_per_step(
         facts["config"], facts["traffic"])
     assert got["flash_roofline_pct"] == pytest.approx(
         100 * (flops / 197e12) / 10e-9)
-    # a step without flash calls, a program without the span: nothing to read
+    # the backward call counts under either of its names, old and coming
+    for name in ("flash_bwd_dkv", "flash_bwd"):
+        reduced["kernel_ns"][name] = 30
+        assert scopes_catalog.module(
+            "layer_metrics", "flash_roofline_pct").metric(facts) == \
+            pytest.approx(100 * (flops / 197e12) / 40e-9)
+        del reduced["kernel_ns"][name]
+    # a step without flash calls, a program without the spans: nothing to read
     reduced["kernel_ns"].pop("flash_fwd")
-    reduced["host_span_ms"]["trainer/place"] = []
-    for name in ("flash_roofline_pct", "host_place_ms"):
+    reduced["host_span_ms"] = {"trainer/place": []}
+    for name in ("flash_roofline_pct", "host_place_ms", "host_enqueue_ms"):
         assert scopes_catalog.module("layer_metrics", name).metric(
             facts) is None
 
 
 def test_no_scope_means_none_for_every_metric_and_a_line_in_the_log(
-        scopes_catalog, capsys, monkeypatch, tmp_path):
+        scopes_catalog, capsys):
     """An executable without scopes (the older recorded trace stands in for
-    its profile): every metric returns None, never 0, and the log says why."""
-    old = TRACES / "bert_toy.mlm_toy.xplane.pb.gz"
-    unpacked = tmp_path / "old.xplane.pb"
-    with gzip.open(old, "rb") as f:
-        unpacked.write_bytes(f.read())
-    monkeypatch.setattr(sp, "take", lambda facts, trace_dir: unpacked)
-    facts = facts_of(scopes_catalog, None)
+    the run's): every metric returns None, never 0, and the log says why."""
+    facts = facts_of(scopes_catalog, None, planes=xplane.load(
+        TRACES / "bert_toy.mlm_toy.xplane.pb.gz"))
     del facts["scope_profile"]
     facts["peak"]["device_planes"] = "/device:TPU:"
-    for name in EIGHT:
+    for name in NINE:
         assert scopes_catalog.module("layer_metrics", name).metric(
             facts) is None
     out = capsys.readouterr().out
-    assert out.count(sp.NO_SCOPE) == 1           # taken once, kept in facts
+    assert out.count(sp.NO_SCOPE) == 1           # reduced once, kept in facts
     assert facts["scope_profile"] is None
 
 
-def test_rehearsal_of_the_traced_run_with_the_eight_metrics(scopes_catalog,
-                                                            capsys):
-    """The whole traced run on the CPU, whose trace has no device plane: each
-    of the eight returns None and is left out, the last line keeps its keys,
-    and the state the donating second trace left in ``facts`` still runs a
-    step (the fixture metric ``state_still_steps`` is listed after them)."""
-    run.main(["--workload", "bert_toy.mlm_toy", "--seed", "2147483900",
-              "--seconds", "0.5", "--trace", "1"], catalog=scopes_catalog,
-             peaks={"cpu": {"bf16_flops_per_s": 1e12}})
+def traced_line(capsys, catalog, workload, **peak):
+    run.main(["--workload", workload, "--seed", "2147483900", "--seconds",
+              "0.5", "--trace", "1"], catalog=catalog,
+             peaks={"cpu": dict({"bf16_flops_per_s": 1e12}, **peak)})
     out = capsys.readouterr().out
-    line = json.loads(out.strip().splitlines()[-1])
+    return json.loads(out.strip().splitlines()[-1]), out
+
+
+def test_rehearsal_of_the_traced_run_with_the_nine_metrics(scopes_catalog,
+                                                           capsys):
+    """The whole traced run on the CPU, whose trace has no device plane: each
+    of the nine returns None and is left out, and the last line keeps its
+    keys."""
+    line, out = traced_line(capsys, scopes_catalog, "bert_toy.mlm_toy")
     assert set(line) == {"correct", "attempted", "failed", "metrics", "device"}
     assert line["correct"] is True
-    assert not set(line["metrics"]) & set(EIGHT)
-    assert {"host_step_call_ms", "optimizer_update_ms",
-            "step_hbm_gib"} <= set(line["metrics"])
-    assert line["metrics"]["state_still_steps"]["value"] == 1.0
+    assert not set(line["metrics"]) & set(NINE)
+    assert {"mfu_pct", "step_hbm_gib"} <= set(line["metrics"])
     assert "no device plane in the trace" in out
+    assert "second trace" not in out
     listed = [m["name"] for m in scopes_catalog.metrics(
         "per_layer", "bert_toy.mlm_toy")]
-    assert listed[-9:-1] == EIGHT
+    assert listed[-9:] == NINE
+
+
+def test_rehearsal_with_a_device_trace_reads_a_configuration_s_scopes(
+        scopes_catalog, capsys, monkeypatch):
+    """The planes of the trace recorded with scopes stand in for the CPU
+    run's: every per-layer metric of the cell is on the line, all from the
+    one parse, and the fixture's reader finds the time of ``fused_adam``,
+    which only this cell's configuration names, out of ``optimizer``."""
+    recorded = xplane.load(TRACES / "bert_toy.mlm_toy.scopes.xplane.pb.gz")
+    loads = []
+    monkeypatch.setattr(run.xplane, "load",
+                        lambda path: loads.append(path) or recorded)
+    line, out = traced_line(capsys, scopes_catalog, "bert_toy_scoped.mlm_toy",
+                            device_planes="/device:TPU:")
+    assert len(loads) == 1
+    want = sp.reduce_planes(recorded, scopes=["fused_adam", "layer_norm_fwd"])
+    metrics = {k: v["value"] for k, v in line["metrics"].items()}
+    assert set(metrics) == {m["name"] for m in scopes_catalog.metrics(
+        "per_layer", "bert_toy_scoped.mlm_toy")}
+    assert metrics["fused_adam_ms"] == \
+        want["scope_ns"]["fused_adam"]["total"] / 1e6 > 0
+    assert metrics["optimizer_step_ms"] == \
+        want["scope_ns"]["optimizer"]["total"] / 1e6
+    assert metrics["optimizer_step_ms"] + metrics["fused_adam_ms"] == \
+        pytest.approx(sp.reduce_planes(recorded)["scope_ns"]["optimizer"]
+                      ["total"] / 1e6)
+    assert metrics["host_place_ms"] > 0 and metrics["host_enqueue_ms"] > 0
+    assert metrics["pallas_time_pct"] == pytest.approx(
+        100 * sum(want["kernel_ns"].values()) / want["busy_ns"])
+    assert "[scopes] kernel fused_adam" in out
+    # the base cell reads the same trace by the base vocabulary
+    line, _ = traced_line(capsys, scopes_catalog, "bert_toy.mlm_toy",
+                          device_planes="/device:TPU:")
+    assert "fused_adam_ms" not in line["metrics"]
+    assert line["metrics"]["optimizer_step_ms"]["value"] == pytest.approx(
+        metrics["optimizer_step_ms"] + metrics["fused_adam_ms"])
