@@ -9,8 +9,8 @@ python/paddle/fluid/tests/book/). BERT/transformer is the flagship
 ERNIE/transformer tests (dist_transformer.py) set the shape.
 """
 
-from paddle_tpu.models import (bert, deepfm, resnet, se_resnext,
-                               transformer, vgg)
+from paddle_tpu.models import (bert, blocks, deepfm, olmoe, resnet,
+                               se_resnext, transformer, vgg)
 
-__all__ = ["bert", "deepfm", "resnet", "se_resnext",
+__all__ = ["bert", "blocks", "deepfm", "olmoe", "resnet", "se_resnext",
            "transformer", "vgg"]

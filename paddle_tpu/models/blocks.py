@@ -1,0 +1,96 @@
+"""Pieces of a decoder block that more than one model can use: RMSNorm,
+rotary positions, causal attention, the gated feed-forward.
+
+``models/olmoe.py`` is built from them. ``models/bert.py`` and
+``models/transformer.py`` carry their own layer norm and attention and are
+not moved here yet (ROADMAP C10: their cells repeat to 0.004%, so a change
+to their HLO is a PR judged on its own). Every piece enters the named scope
+a profile of the step is read by (``layer_norm``, ``rope``,
+``attention_core``; the callers enter ``attention`` and ``ffn``).
+"""
+
+import math
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from paddle_tpu.ops.pallas.registry import mesh_scope
+
+__all__ = ["rms_norm", "rope_angles", "apply_rope", "causal_attention",
+           "gated_ffn"]
+
+#: beyond this many positions ``causal_attention``'s ``auto`` takes the flash
+#: kernels: the crossover ``bert._attention`` measured on the v5e
+FLASH_FROM = 1024
+
+
+@jax.named_scope("layer_norm")
+def rms_norm(x, gain, eps=1e-5):
+    """``x / sqrt(mean(x^2) + eps) * gain`` over the last axis: statistics
+    in float32, the result in ``x.dtype``."""
+    x32 = x.astype(jnp.float32)
+    scale = lax.rsqrt(jnp.mean(jnp.square(x32), axis=-1, keepdims=True) + eps)
+    return (x32 * scale * gain.astype(jnp.float32)).astype(x.dtype)
+
+
+def rope_angles(positions, head_dim, theta=10000.0):
+    """(cos, sin), each [positions, head_dim / 2] float32, of the angles
+    ``p * theta^(-2i / head_dim)``."""
+    inv_freq = theta ** (-jnp.arange(0, head_dim, 2, dtype=jnp.float32)
+                         / head_dim)
+    angles = jnp.arange(positions, dtype=jnp.float32)[:, None] * inv_freq
+    return jnp.cos(angles), jnp.sin(angles)
+
+
+@jax.named_scope("rope")
+def apply_rope(x, cos, sin):
+    """Rotary positions in the rotate-half convention on x [B, S, N, D]:
+    the pair (x_i, x_{i + D/2}) is turned by the angle of position s and
+    frequency i. Float32 inside, ``x.dtype`` out."""
+    half = x.shape[-1] // 2
+    x32 = x.astype(jnp.float32)
+    a, b = x32[..., :half], x32[..., half:]
+    cos, sin = cos[None, :, None, :], sin[None, :, None, :]
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
+                           axis=-1).astype(x.dtype)
+
+
+def causal_attention(q, k, v, impl="auto", mesh=None):
+    """Softmax of ``q k^T / sqrt(D)`` over the keys up to each query's own,
+    times v. q, k, v and the result are [B, S, N, D]. ``impl`` is "dense"
+    (XLA, scores in float32), "flash" (the Pallas kernels through the
+    registry, which hands out the dense reference on the CPU and under a
+    mesh of more than one device) or "auto": flash past ``FLASH_FROM``
+    positions, as ``bert._attention`` chooses."""
+    b, s, n, d = q.shape
+    if impl == "auto":
+        impl = "flash" if s > FLASH_FROM else "dense"
+    with jax.named_scope("attention_core"):
+        if impl == "flash":
+            from paddle_tpu.ops import pallas_kernels as _pk
+
+            def heads(t):
+                return t.transpose(0, 2, 1, 3)
+
+            with mesh_scope(mesh):
+                ctx = _pk.flash_attention(heads(q), heads(k), heads(v),
+                                          causal=True)
+            return ctx.transpose(0, 2, 1, 3).astype(q.dtype)
+        scores = jnp.einsum("bqnd,bknd->bnqk", q, k,
+                            preferred_element_type=jnp.float32) \
+            / math.sqrt(d)
+        keep = jnp.tril(jnp.ones((s, s), bool))
+        probs = jax.nn.softmax(jnp.where(keep, scores, -1e30), axis=-1)
+        return jnp.einsum("bnqk,bknd->bqnd", probs.astype(q.dtype), v)
+
+
+def gated_ffn(x, w_gate, w_up, w_down, matmul=jnp.matmul):
+    """``(silu(x w_gate) * (x w_up)) w_down``, no biases: the feed-forward
+    of the Llama family. ``matmul(rows, weights)`` is the plain product, or
+    the grouped one where the three weights are stacks of experts' matrices
+    and the rows are sorted by expert (``parallel/moe.dropless_moe_ffn``)."""
+    dt = x.dtype
+    h = jax.nn.silu(matmul(x, w_gate.astype(dt))) \
+        * matmul(x, w_up.astype(dt))
+    return matmul(h, w_down.astype(dt))

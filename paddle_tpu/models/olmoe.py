@@ -1,0 +1,270 @@
+"""OLMoE: a decoder-only language model with sparse experts in every block
+(Muennighoff et al. 2024, arXiv:2409.02060; the released
+``allenai/OLMoE-1B-7B-0125-Instruct`` config and ``modeling_olmoe.py``).
+
+Pre-norm blocks, ``h = x + Attn(RMSNorm(x))``, ``y = h + MoE(RMSNorm(h))``:
+
+- attention: no biases; RMSNorm of the whole query and key projections
+  before they are split into heads; rotary positions (rotate-half); causal
+  softmax, the flash kernels past 1024 positions (``blocks.py``);
+- experts: a float32 softmax router, the ``experts_per_token`` largest
+  probabilities not renormalised, SiLU-gated experts, every assignment
+  computed whatever the load (``parallel/moe.dropless_moe_ffn``);
+- a final RMSNorm and an untied head on every position; the loss is the
+  mean next-token cross-entropy plus the load-balancing loss and the router
+  z-loss, each the mean over the layers, times their weights.
+
+Built like ``models/bert.py``: float32 master parameters, ``cfg.dtype``
+(bfloat16) activations and matmul operands, one jitted step = forward +
+backward + update, the mesh's ``data`` axis splits the batch and its
+``model`` axis the attention projections and the vocabulary; the experts are
+replicated (sharding them is ROADMAP B4).
+"""
+
+import dataclasses
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
+
+from paddle_tpu.models import blocks
+from paddle_tpu.ops.pallas.registry import mesh_scope
+from paddle_tpu.parallel import moe
+from paddle_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS, get_mesh
+from paddle_tpu.profiler import RecordEvent
+
+__all__ = ["OlmoeConfig", "olmoe_1b_7b", "olmoe_tiny", "init_params",
+           "param_specs", "forward", "lm_loss", "routing_stats",
+           "make_train_step", "synthetic_batch"]
+
+
+@dataclasses.dataclass(frozen=True)  # hashable: used as a jit-static arg
+class OlmoeConfig:
+    vocab_size: int = 50304
+    hidden: int = 2048
+    num_layers: int = 16
+    num_heads: int = 16
+    head_dim: int = 128
+    expert_width: int = 1024
+    num_experts: int = 64
+    experts_per_token: int = 8
+    max_seq: int = 4096
+    rope_theta: float = 10000.0
+    rms_eps: float = 1e-5
+    balance_weight: float = 0.01     # load-balancing loss (the paper's)
+    z_weight: float = 0.001          # router z-loss (the paper's)
+    dtype: object = jnp.bfloat16     # activation/compute dtype
+
+
+def olmoe_1b_7b(**kw):
+    """The published sizes: 1.3 B parameters a token, 6.9 B in all."""
+    return OlmoeConfig(**kw)
+
+
+def olmoe_tiny(**kw):
+    """Small config for tests / dry runs."""
+    for k, v in dict(vocab_size=512, hidden=64, num_layers=2, num_heads=4,
+                     head_dim=16, expert_width=32, num_experts=8,
+                     experts_per_token=2, max_seq=64).items():
+        kw.setdefault(k, v)
+    return OlmoeConfig(**kw)
+
+
+# ---------------------------------------------------------------------------
+# params
+# ---------------------------------------------------------------------------
+def init_params(rng, cfg):
+    """fp32 master params as a nested dict pytree: matrices N(0, 0.02),
+    gains 1."""
+    h, f, e = cfg.hidden, cfg.expert_width, cfg.num_experts
+    qkv = cfg.num_heads * cfg.head_dim
+    keys = iter(jax.random.split(rng, 2 + 8 * cfg.num_layers))
+
+    def normal(shape):
+        return (0.02 * jax.random.normal(next(keys), shape)) \
+            .astype(jnp.float32)
+
+    def ones(n):
+        return jnp.ones((n,), jnp.float32)
+
+    p = {"embed": normal((cfg.vocab_size, h)), "layers": [],
+         "final_norm_g": ones(h), "head_w": normal((h, cfg.vocab_size))}
+    for _ in range(cfg.num_layers):
+        p["layers"].append({
+            "ln1_g": ones(h),
+            "q_w": normal((h, qkv)), "k_w": normal((h, qkv)),
+            "v_w": normal((h, qkv)), "o_w": normal((qkv, h)),
+            "q_norm_g": ones(qkv), "k_norm_g": ones(qkv),
+            "ln2_g": ones(h),
+            "router_w": normal((h, e)),
+            "w_gate": normal((e, h, f)), "w_up": normal((e, h, f)),
+            "w_down": normal((e, f, h)),
+        })
+    return p
+
+
+def param_specs(cfg):
+    """PartitionSpecs over ("model",): the attention projections split
+    their heads' dim, the embedding its rows and the head its columns; the
+    experts, the router and the gains are replicated."""
+    layer = {
+        "ln1_g": P(), "q_w": P(None, MODEL_AXIS), "k_w": P(None, MODEL_AXIS),
+        "v_w": P(None, MODEL_AXIS), "o_w": P(MODEL_AXIS, None),
+        "q_norm_g": P(MODEL_AXIS), "k_norm_g": P(MODEL_AXIS), "ln2_g": P(),
+        "router_w": P(), "w_gate": P(), "w_up": P(), "w_down": P(),
+    }
+    return {"embed": P(MODEL_AXIS, None),
+            "layers": [dict(layer) for _ in range(cfg.num_layers)],
+            "final_norm_g": P(), "head_w": P(None, MODEL_AXIS)}
+
+
+# ---------------------------------------------------------------------------
+# forward
+# ---------------------------------------------------------------------------
+# Named scopes as models/bert.py (embed, attention, attention_core, ffn,
+# layer_norm, loss; optimizer is in optimizer.py) plus rope, moe_router,
+# moe_dispatch, moe_experts: chipbench's per-layer metrics key on them.
+@jax.named_scope("attention")
+def _attention(lp, x, rope, cfg, mesh=None):
+    b, s, _ = x.shape
+    dt = x.dtype
+    q = blocks.rms_norm(x @ lp["q_w"].astype(dt), lp["q_norm_g"],
+                        cfg.rms_eps)
+    k = blocks.rms_norm(x @ lp["k_w"].astype(dt), lp["k_norm_g"],
+                        cfg.rms_eps)
+    v = x @ lp["v_w"].astype(dt)
+
+    def heads(t):
+        return t.reshape(b, s, cfg.num_heads, cfg.head_dim)
+
+    q, k = (blocks.apply_rope(heads(t), *rope) for t in (q, k))
+    ctx = blocks.causal_attention(q, k, heads(v), mesh=mesh)
+    return ctx.reshape(b, s, -1) @ lp["o_w"].astype(dt)
+
+
+def _block(lp, x, rope, cfg, mesh=None):
+    h = x + _attention(lp, blocks.rms_norm(x, lp["ln1_g"], cfg.rms_eps),
+                       rope, cfg, mesh)
+    normed = blocks.rms_norm(h, lp["ln2_g"], cfg.rms_eps)
+    with jax.named_scope("ffn"):
+        m, aux = moe.dropless_moe_ffn(lp, normed, cfg.experts_per_token,
+                                      mesh=mesh)
+    return h + m, aux
+
+
+def _shard_act(x, mesh):
+    if mesh is None or mesh.shape.get(DATA_AXIS, 1) == 1:
+        return x
+    return jax.lax.with_sharding_constraint(
+        x, NamedSharding(mesh, P(DATA_AXIS, None, None)))
+
+
+def _hidden_and_aux(params, cfg, input_ids, mesh=None):
+    """(final normed hidden states [B, S, H], the experts' aux terms
+    stacked over the layers)."""
+    with jax.named_scope("embed"):
+        x = jnp.take(params["embed"], input_ids, axis=0).astype(cfg.dtype)
+    x = _shard_act(x, mesh)
+    rope = blocks.rope_angles(input_ids.shape[1], cfg.head_dim,
+                              cfg.rope_theta)
+
+    auxes = []
+    for lp in params["layers"]:
+        x, aux = _block(lp, x, rope, cfg, mesh)
+        x = _shard_act(x, mesh)
+        auxes.append(aux)
+    hidden = blocks.rms_norm(x, params["final_norm_g"], cfg.rms_eps)
+    return hidden, jax.tree.map(lambda *a: jnp.stack(a), *auxes)
+
+
+def forward(params, cfg, input_ids, mesh=None):
+    """Decoder forward; returns the final normed hidden states [B, S, H]
+    in cfg.dtype (the head is applied in ``lm_loss``)."""
+    return _hidden_and_aux(params, cfg, input_ids, mesh)[0]
+
+
+def lm_loss(params, cfg, batch, mesh=None):
+    """Mean next-token cross-entropy over every position of
+    dict(input_ids, labels) [B, S], plus ``balance_weight`` times the
+    load-balancing loss and ``z_weight`` times the router z-loss (each the
+    mean over the layers). Logits and loss in float32."""
+    from paddle_tpu.ops import pallas_kernels as _pk
+    hidden, aux = _hidden_and_aux(params, cfg, batch["input_ids"], mesh)
+    with jax.named_scope("loss"), mesh_scope(mesh):
+        logits = jnp.dot(hidden, params["head_w"].astype(hidden.dtype),
+                         preferred_element_type=jnp.float32)
+        nll = _pk.softmax_cross_entropy(logits, batch["labels"])
+        return (jnp.mean(nll) + cfg.balance_weight * jnp.mean(aux["balance"])
+                + cfg.z_weight * jnp.mean(aux["z"]))
+
+
+def routing_stats(params, cfg, batch, mesh=None, choices=False):
+    """Assignments per expert of a batch, [layers, experts] on the host:
+    each row sums to ``experts_per_token`` times the batch's tokens. The
+    counter a reader takes the experts' load from. With ``choices`` also
+    the experts of each token, [layers, tokens, experts_per_token]."""
+    aux = jax.jit(lambda p, ids: _hidden_and_aux(p, cfg, ids, mesh)[1])(
+        params, batch["input_ids"])
+    counts = np.asarray(aux["counts"])
+    return (counts, np.asarray(aux["choice"])) if choices else counts
+
+
+# ---------------------------------------------------------------------------
+# train step
+# ---------------------------------------------------------------------------
+def make_train_step(cfg, optimizer, mesh=None):
+    """Returns (init_fn, step_fn) jitted over the mesh with dp/tp shardings
+    pinned. step(params, opt_state, batch) -> (loss, params, opt_state);
+    params and opt_state are donated. ``step_fn.jitted`` and
+    ``step_fn.place`` as ``bert.make_train_step`` hands them out."""
+    mesh = mesh or get_mesh()
+    pspecs = param_specs(cfg)
+    if mesh.shape.get(MODEL_AXIS, 1) == 1:
+        pspecs = jax.tree.map(lambda s: P(), pspecs,
+                              is_leaf=lambda s: isinstance(s, P))
+    pshard = jax.tree.map(lambda s: NamedSharding(mesh, s), pspecs,
+                          is_leaf=lambda s: isinstance(s, P))
+
+    def init_fn(rng):
+        params = jax.jit(functools.partial(init_params, cfg=cfg),
+                         out_shardings=pshard)(rng)
+        opt_state = optimizer.init(params)
+        opt_state = jax.device_put(
+            opt_state, optimizer.state_shardings(opt_state, pshard, mesh))
+        return params, opt_state
+
+    def step(params, opt_state, batch):
+        loss, grads = jax.value_and_grad(
+            lambda p: lm_loss(p, cfg, batch, mesh=mesh))(params)
+        with mesh_scope(mesh):
+            new_params, new_opt = optimizer.apply_gradients(
+                params, grads, opt_state)
+        return loss, new_params, new_opt
+
+    jit_step = jax.jit(step, donate_argnums=(0, 1))
+    dshard = NamedSharding(mesh, P(DATA_AXIS))
+
+    def place(batch):
+        """Put a host batch on the mesh: rows over "data"."""
+        return {name: jax.device_put(v, dshard) for name, v in batch.items()}
+
+    def step_fn(params, opt_state, batch):
+        with RecordEvent("trainer/place"):
+            batch = place(batch)
+        with RecordEvent("trainer/enqueue"):
+            return jit_step(params, opt_state, batch)
+
+    step_fn.place = place
+    step_fn.jitted = jit_step
+    return init_fn, step_fn
+
+
+def synthetic_batch(cfg, batch_size, seq_len=None, seed=0):
+    """Random next-token batch: ``seq_len + 1`` uniform ids a row, inputs
+    the first ``seq_len``, labels the last."""
+    seq_len = seq_len or cfg.max_seq
+    ids = np.random.RandomState(seed).randint(
+        0, cfg.vocab_size, (batch_size, seq_len + 1), dtype=np.int32)
+    return {"input_ids": ids[:, :-1], "labels": ids[:, 1:]}

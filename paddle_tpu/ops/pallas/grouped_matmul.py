@@ -1,0 +1,273 @@
+"""Grouped matmul: the product a dropless mixture-of-experts layer is made of.
+
+``grouped_matmul(lhs [M, K], rhs [G, K, N], group_sizes [G]) -> [M, N]``:
+the rows of ``lhs`` are sorted by group, group ``g`` owns the
+``group_sizes[g]`` rows after those of the groups before it, and each row
+is multiplied by its own group's matrix. Rows past ``sum(group_sizes)``
+come out zero. The work is ``2 M K N`` whatever the sizes are: no capacity,
+no padding to the fullest group, no product over every group.
+
+Two bodies behind the kernel registry (``ops/pallas/registry.py``):
+
+- reference: ``jax.lax.ragged_dot``, jax's own primitive, with jax's own
+  gradient. What the CPU tests run, and what ``auto`` takes under a mesh of
+  more than one device.
+- Pallas: the megablox scheme (Gale et al. 2022; jax's
+  ``pallas.ops.tpu.megablox``). The work list is the (group, row tile)
+  pairs that intersect, in order, made outside the kernel from
+  ``group_sizes`` and handed in by scalar prefetch; the index maps pick the
+  row tile and the group's matrix for each entry, so a matrix is fetched
+  once for all the consecutive tiles of its group. A tile that straddles two
+  groups is visited once for each and the store is masked to the group's
+  rows. Its gradient is the same call on the transposed matrices
+  (``grouped_matmul``) and the per-group ``lhs^T dout``
+  (``grouped_matmul_dw``), which sums the tiles of one group in a float32
+  scratch. Every group is on the work list, empty ones too, so an empty
+  group's gradient is written, as zeros.
+
+XLA:TPU lowers ``ragged_dot`` to a Mosaic kernel of its own with the same
+scheme, but that call carries no jax name stack (its ``op_name`` is
+``ragged-dot-none``), so a profile cannot put its time under the scope that
+issued it, forward or backward; that, and the tile shapes, are why one chip
+takes the Pallas body (PERF.md, PR 26).
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from paddle_tpu.ops.pallas import registry as _registry
+
+__all__ = ["grouped_matmul"]
+
+#: row tile, and the widest contraction and output blocks. At the widths of
+#: a 2048 x 1024 expert the contraction is one block, so a group's matrix
+#: stays in VMEM while its row tiles stream past it.
+_TILE_M, _TILE_K, _TILE_N = 256, 2048, 1024
+_COMPILER_PARAMS = pltpu.CompilerParams(
+    vmem_limit_bytes=64 << 20,
+    dimension_semantics=("parallel", "arbitrary", "arbitrary"))
+
+
+def _tile(size, most):
+    """The largest block not over ``most`` that divides ``size`` into whole
+    lane-aligned blocks; the whole of ``size`` where there is none."""
+    if size <= most:
+        return size
+    for t in range(most, 127, -128):
+        if size % t == 0:
+            return t
+    return size
+
+
+def _work_list(group_sizes, m, tm):
+    """The (group, row tile) pairs to visit, in order, as fixed-size arrays.
+
+    Returns (group_offsets [G+1], group_ids [W], tile_ids [W], n_work [1])
+    with W = m // tm + G. A group visits the tiles its rows touch; an empty
+    group visits one tile and writes nothing to it; the last group also
+    visits the tiles past ``sum(group_sizes)``, whose rows it zeroes. Entries
+    from ``n_work`` on repeat the last one and are skipped."""
+    g = group_sizes.shape[0]
+    tiles_m = m // tm
+    sizes = group_sizes.astype(jnp.int32)
+    ends = jnp.cumsum(sizes)
+    starts = ends - sizes
+    offsets = jnp.concatenate([jnp.zeros((1,), jnp.int32), ends])
+    visit_ends = ends.at[g - 1].set(m)
+    first = jnp.minimum(starts // tm, tiles_m - 1)
+    last = jnp.maximum((visit_ends - 1) // tm, first)
+    visits = last - first + 1
+    w = tiles_m + g
+    group_ids = jnp.repeat(jnp.arange(g, dtype=jnp.int32), visits,
+                           total_repeat_length=w)
+    visit_starts = jnp.cumsum(visits) - visits
+    tile_ids = first[group_ids] + jnp.arange(w, dtype=jnp.int32) \
+        - visit_starts[group_ids]
+    n_work = jnp.sum(visits)
+    tile_ids = jnp.where(jnp.arange(w) < n_work, tile_ids, tiles_m - 1)
+    return offsets, group_ids, tile_ids, n_work.reshape(1)
+
+
+def _row_mask(offsets_ref, group, tile, tm, shape):
+    rows = tile * tm + lax.broadcasted_iota(jnp.int32, shape, 0)
+    return (rows >= offsets_ref[group]) & (rows < offsets_ref[group + 1])
+
+
+def _gmm_kernel(offsets_ref, groups_ref, tiles_ref, n_work_ref,
+                lhs_ref, rhs_ref, out_ref, acc_ref, *, tm, transpose_rhs):
+    """One (output column block, work entry, contraction block) cell."""
+    i, k = pl.program_id(1), pl.program_id(2)
+
+    @pl.when(i < n_work_ref[0])
+    def _():
+        @pl.when(k == 0)
+        def _():
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+
+        contract = (((1,), (1 if transpose_rhs else 0,)), ((), ()))
+        acc_ref[...] += lax.dot_general(
+            lhs_ref[...], rhs_ref[0], contract,
+            preferred_element_type=jnp.float32)
+
+        @pl.when(k == pl.num_programs(2) - 1)
+        def _():
+            group, tile = groups_ref[i], tiles_ref[i]
+            mask = _row_mask(offsets_ref, group, tile, tm, acc_ref.shape)
+            # the first entry of a tile finds nothing of its own in the
+            # output block: rows of no group are written as zeros
+            fresh = (i == 0) | (tiles_ref[jnp.maximum(i - 1, 0)] != tile)
+            kept = jnp.where(fresh, jnp.zeros_like(out_ref), out_ref[...])
+            out_ref[...] = jnp.where(mask, acc_ref[...].astype(out_ref.dtype),
+                                     kept)
+
+
+def _gmm(lhs, rhs, group_sizes, transpose_rhs, interpret):
+    """[M, K] x [G, K, N] (or [G, N, K] transposed) -> [M, N]."""
+    m, kdim = lhs.shape
+    n = rhs.shape[1] if transpose_rhs else rhs.shape[2]
+    tm = min(_TILE_M, m)
+    pad = (-m) % tm
+    if pad:
+        lhs = jnp.pad(lhs, ((0, pad), (0, 0)))
+    mp = m + pad
+    tk, tn = _tile(kdim, _TILE_K), _tile(n, _TILE_N)
+    offsets, group_ids, tile_ids, n_work = _work_list(group_sizes, mp, tm)
+
+    def lhs_map(j, i, k, offsets, groups, tiles, n_work):
+        return tiles[i], k
+
+    def rhs_map(j, i, k, offsets, groups, tiles, n_work):
+        return (groups[i], j, k) if transpose_rhs else (groups[i], k, j)
+
+    def out_map(j, i, k, offsets, groups, tiles, n_work):
+        return tiles[i], j
+
+    out = pl.pallas_call(
+        functools.partial(_gmm_kernel, tm=tm, transpose_rhs=transpose_rhs),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(n // tn, group_ids.shape[0], kdim // tk),
+            in_specs=[
+                pl.BlockSpec((tm, tk), lhs_map),
+                pl.BlockSpec((1, tn, tk) if transpose_rhs else (1, tk, tn),
+                             rhs_map),
+            ],
+            out_specs=pl.BlockSpec((tm, tn), out_map),
+            scratch_shapes=[pltpu.VMEM((tm, tn), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((mp, n), lhs.dtype),
+        compiler_params=_COMPILER_PARAMS,
+        interpret=interpret,
+        name="grouped_matmul",
+    )(offsets, group_ids, tile_ids, n_work, lhs, rhs)
+    return out[:m] if pad else out
+
+
+def _tgmm_kernel(offsets_ref, groups_ref, tiles_ref, n_work_ref,
+                 lhs_ref, dout_ref, dw_ref, acc_ref, *, tm):
+    """One (column block, contraction block, work entry) cell of the
+    weights' gradient: the tiles of one group are consecutive entries."""
+    i = pl.program_id(2)
+    last = n_work_ref[0] - 1
+
+    @pl.when(i <= last)
+    def _():
+        group, tile = groups_ref[i], tiles_ref[i]
+
+        @pl.when((i == 0) | (groups_ref[jnp.maximum(i - 1, 0)] != group))
+        def _():
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+
+        mask = _row_mask(offsets_ref, group, tile, tm, lhs_ref.shape)
+        rows = jnp.where(mask, lhs_ref[...], jnp.zeros_like(lhs_ref))
+        acc_ref[...] += lax.dot_general(
+            rows, dout_ref[...], (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+        @pl.when((i == last) | (groups_ref[jnp.minimum(i + 1, last)] != group))
+        def _():
+            dw_ref[0] = acc_ref[...].astype(dw_ref.dtype)
+
+
+def _tgmm(lhs, dout, group_sizes, interpret):
+    """Per group, ``lhs[rows]^T dout[rows]``: [M, K], [M, N] -> [G, K, N]."""
+    m, kdim = lhs.shape
+    n = dout.shape[1]
+    tm = min(_TILE_M, m)
+    pad = (-m) % tm
+    if pad:
+        lhs = jnp.pad(lhs, ((0, pad), (0, 0)))
+        dout = jnp.pad(dout, ((0, pad), (0, 0)))
+    tk, tn = _tile(kdim, _TILE_K), _tile(n, _TILE_N // 2)
+    offsets, group_ids, tile_ids, n_work = _work_list(group_sizes, m + pad,
+                                                      tm)
+
+    return pl.pallas_call(
+        functools.partial(_tgmm_kernel, tm=tm),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(n // tn, kdim // tk, group_ids.shape[0]),
+            in_specs=[
+                pl.BlockSpec((tm, tk), lambda j, k, i, o, g, t, w: (t[i], k)),
+                pl.BlockSpec((tm, tn), lambda j, k, i, o, g, t, w: (t[i], j)),
+            ],
+            out_specs=pl.BlockSpec((1, tk, tn),
+                                   lambda j, k, i, o, g, t, w: (g[i], k, j)),
+            scratch_shapes=[pltpu.VMEM((tk, tn), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((group_sizes.shape[0], kdim, n),
+                                       lhs.dtype),
+        compiler_params=_COMPILER_PARAMS,
+        interpret=interpret,
+        name="grouped_matmul_dw",
+    )(offsets, group_ids, tile_ids, n_work, lhs, dout)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _grouped_matmul(lhs, rhs, group_sizes, interpret):
+    return _gmm(lhs, rhs, group_sizes, False, interpret)
+
+
+def _grouped_matmul_fwd(lhs, rhs, group_sizes, interpret):
+    return (_gmm(lhs, rhs, group_sizes, False, interpret),
+            (lhs, rhs, group_sizes))
+
+
+def _grouped_matmul_bwd(interpret, res, dout):
+    lhs, rhs, group_sizes = res
+    dout = dout.astype(lhs.dtype)
+    return (_gmm(dout, rhs, group_sizes, True, interpret),
+            _tgmm(lhs, dout, group_sizes, interpret).astype(rhs.dtype), None)
+
+
+_grouped_matmul.defvjp(_grouped_matmul_fwd, _grouped_matmul_bwd)
+
+
+def grouped_matmul_reference(lhs, rhs, group_sizes, interpret=None):
+    return lax.ragged_dot(lhs, rhs, group_sizes.astype(jnp.int32),
+                          preferred_element_type=lhs.dtype)
+
+
+def grouped_matmul_pallas(lhs, rhs, group_sizes, interpret=False):
+    return _grouped_matmul(lhs, rhs.astype(lhs.dtype), group_sizes,
+                           bool(interpret))
+
+
+def grouped_matmul(lhs, rhs, group_sizes, interpret=None):
+    """Each row of ``lhs`` [M, K] times the matrix of its group in ``rhs``
+    [G, K, N]; rows sorted by group, ``group_sizes`` [G] int. Returns
+    [M, N] in ``lhs.dtype`` (float32 accumulation). Differentiable in
+    ``lhs`` and ``rhs``. Body selection is the registry's; an explicit
+    ``interpret=`` forces the Pallas body."""
+    if interpret is not None:
+        return grouped_matmul_pallas(lhs, rhs, group_sizes,
+                                     interpret=bool(interpret))
+    return _registry.dispatch("grouped_matmul", lhs, rhs, group_sizes)
+
+
+_registry.register_kernel(
+    "grouped_matmul", grouped_matmul_reference, grouped_matmul_pallas,
+    doc="[M,K] x [G,K,N] by sorted row groups: dropless expert matmul")

@@ -266,10 +266,9 @@ def _flash_bwd_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
 
 def _flash_attention_bwd(sm_scale, causal, block_q, block_k, interpret,
                          res, do):
-    """Blockwise recompute backward as one Pallas kernel, gridded over key
-    blocks (hence its name: it is the call that yields dK and dV, and dQ
-    with them). Live memory stays O(block · S); the [S, S] score matrix
-    never exists."""
+    """Blockwise recompute backward as one Pallas kernel, ``flash_bwd``,
+    gridded over key blocks: it yields dK and dV, and dQ with them. Live
+    memory stays O(block · S); the [S, S] score matrix never exists."""
     q, k, v, bias, o, lse = res
     b, h, s, d = q.shape
     nq = s // block_q
@@ -313,7 +312,7 @@ def _flash_attention_bwd(sm_scale, causal, block_q, block_k, interpret,
         scratch_shapes=[pltpu.VMEM((d, s), jnp.float32)],
         compiler_params=_FLASH_BWD_COMPILER_PARAMS,
         interpret=interpret,
-        name="flash_bwd_dkv",
+        name="flash_bwd",
     )(q, do, lse.reshape(b, h, nq, block_q),
       delta.reshape(b, h, nq, block_q), k, v, bias[:, :, None])
     dbias = jnp.sum(dbh[..., 0], axis=1)                   # [B,S]

@@ -162,6 +162,31 @@ class TestFlashAttention:
                 np.asarray(a, np.float32), np.asarray(b_, np.float32),
                 atol=atol, err_msg=f"{case}: {name}")
 
+    def test_causal_at_head_size_128_matches_dense(self):
+        """OLMoE's shape of attention (16 heads of 128, causal) through the
+        Pallas bodies in interpret mode, two blocks each way: the forward
+        and all three gradients."""
+        q, k, v = self._rand(b=1, h=1, s=512, d=128, seed=5)
+        w = jnp.asarray(np.random.RandomState(6).randn(1, 1, 512, 128)
+                        .astype(np.float32))
+        blocks = dict(block_q=256, block_k=256, interpret=True)
+
+        def f_flash(q, k, v):
+            return jnp.sum(K.flash_attention(q, k, v, causal=True, **blocks)
+                           * w)
+
+        def f_dense(q, k, v):
+            return jnp.sum(_dense_attention(q, k, v, causal=True) * w)
+
+        np.testing.assert_allclose(
+            np.asarray(K.flash_attention(q, k, v, causal=True, **blocks)),
+            np.asarray(_dense_attention(q, k, v, causal=True)), atol=2e-5)
+        for name, a, b_ in zip(("dq", "dk", "dv"),
+                               jax.grad(f_flash, (0, 1, 2))(q, k, v),
+                               jax.grad(f_dense, (0, 1, 2))(q, k, v)):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
+                                       atol=5e-4, err_msg=name)
+
     def test_bfloat16(self):
         q, k, v = self._rand(s=64, d=32)
         qb, kb, vb = (t.astype(jnp.bfloat16) for t in (q, k, v))

@@ -88,7 +88,9 @@ class TestRegistry:
                 assert plk.selected_body("_test_ref_only") == "reference"
                 assert plk.dispatch("_test_ref_only", 1) == 2
         finally:
-            plk.register_kernel("_test_ref_only", lambda x: x + 1)
+            # leave the registry as it was: test_tpu_aot_compile.py holds
+            # every registered kernel to a shape, on whichever worker it runs
+            plk.registry._REGISTRY.pop("_test_ref_only")
 
     def test_selection_gauge_published(self):
         from paddle_tpu.monitor.registry import gauge

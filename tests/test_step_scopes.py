@@ -1,7 +1,8 @@
 """The names the train step gives its own parts, as a profile reads them.
 
-``models/bert.py``, ``models/transformer.py`` and ``optimizer.py`` wrap their
-parts in ``jax.named_scope`` (one vocabulary for both models), the step
+``models/bert.py``, ``models/transformer.py``, ``models/olmoe.py`` and
+``optimizer.py`` wrap their parts in ``jax.named_scope`` (one vocabulary for
+the three models; OLMoE nests four names of its own inside it), the step
 functions write two host spans through ``profiler.RecordEvent``, and
 ``RecordEvent`` is also a ``jax.profiler.TraceAnnotation``. chipbench's
 per-layer metrics key on all three; these tests hold them in place on the
@@ -20,12 +21,17 @@ import pytest
 import paddle_tpu as pt
 from paddle_tpu import profiler
 from paddle_tpu.core import compile_cache
-from paddle_tpu.models import bert, transformer
+from paddle_tpu.models import bert, olmoe, transformer
 from paddle_tpu.monitor import flight_recorder
 from paddle_tpu.parallel.mesh import MeshConfig, make_mesh
 
 MODEL_SCOPES = ("embed", "attention", "attention_core", "ffn", "layer_norm",
                 "loss")
+#: what a family's program nests inside those (its chipbench configuration
+#: lists them under "scopes")
+FAMILY_SCOPES = {"bert": (), "transformer": (),
+                 "olmoe": ("rope", "moe_router", "moe_dispatch",
+                           "moe_experts")}
 
 
 def _tiny(family):
@@ -37,6 +43,10 @@ def _tiny(family):
         cfg = bert.bert_tiny()
         init_fn, step_fn = bert.make_train_step(cfg, opt, mesh)
         batch = bert.synthetic_batch(cfg, 4, 16, max_preds=4)
+    elif family == "olmoe":
+        cfg = olmoe.olmoe_tiny()
+        init_fn, step_fn = olmoe.make_train_step(cfg, opt, mesh)
+        batch = olmoe.synthetic_batch(cfg, 4, 16)
     else:
         cfg = transformer.transformer_tiny()
         init_fn, step_fn = transformer.make_train_step(cfg, opt, mesh)
@@ -45,7 +55,7 @@ def _tiny(family):
     return step_fn, params, opt_state, batch
 
 
-@pytest.mark.parametrize("family", ["bert", "transformer"])
+@pytest.mark.parametrize("family", ["bert", "transformer", "olmoe"])
 def test_lowered_step_names_every_scope_forward_and_backward(family):
     """Each model scope is on a name stack under ``jvp(`` (forward) and on one
     under ``transpose(jvp(`` (backward); ``optimizer`` is under neither. jax
@@ -61,7 +71,7 @@ def test_lowered_step_names_every_scope_forward_and_backward(family):
         return any(s.startswith(f"jit(step)/{under}")
                    and re.search(rf"[/(]{scope}[/)]", s) for s in stacks)
 
-    for scope in MODEL_SCOPES:
+    for scope in MODEL_SCOPES + FAMILY_SCOPES[family]:
         assert on_a_stack(scope, "jvp("), (scope, "forward")
         assert on_a_stack(scope, "transpose(jvp("), (scope, "backward")
     assert any(s.startswith("jit(step)/optimizer/") for s in stacks)
@@ -95,7 +105,7 @@ def _host_events(trace_dir):
             for line in plane.lines for e in line.events]
 
 
-@pytest.mark.parametrize("family", ["bert", "transformer"])
+@pytest.mark.parametrize("family", ["bert", "transformer", "olmoe"])
 def test_step_fn_writes_its_two_spans_into_a_jax_profile(family, tmp_path):
     """Two steps under ``jax.profiler``: ``trainer/place`` and
     ``trainer/enqueue`` are on the host plane, twice each, one after the

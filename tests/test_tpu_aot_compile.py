@@ -27,7 +27,7 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
 import paddle_tpu as pt
-from paddle_tpu.models import bert
+from paddle_tpu.models import bert, olmoe
 from paddle_tpu.ops import pallas as plk
 from paddle_tpu.ops.pallas import registry
 from paddle_tpu.parallel.data_parallel import DataParallelTrainer
@@ -98,6 +98,9 @@ KERNEL_SHAPES = {
     "fused_adam": ([((VOCAB, H), F32)] * 4 + [((), F32), ((), I32)], {},
                    False),
     "flash_attention": ([((8, 12, 2048, 64), BF16)] * 3, {}, True),
+    # OLMoE's gate/up product: 8 x 8192 assignments, 64 experts of 2048x1024
+    "grouped_matmul": ([((65536, 2048), BF16), ((64, 2048, 1024), BF16),
+                        ((64,), I32)], {}, True),
     "fused_layer_norm": ([((64, 512, H), BF16), ((H,), F32), ((H,), F32)],
                          {}, True),
     "softmax_cross_entropy": ([((5120, VOCAB), F32), ((5120,), I32)], {},
@@ -212,18 +215,18 @@ def test_the_step_names_its_mosaic_calls(topo):
     instruction, which a profile shows and chipbench's breakdown prints, and
     the named scope around the call is on its ``op_name``. At S=4096 the
     step holds all four kernels of the BERT cells: the flash backward is
-    one call, ``flash_bwd_dkv``, which yields dQ too."""
+    one call, ``flash_bwd``, which yields dQ too."""
     compiled, _ = _bert_step(topo, MeshConfig(data=1), 1, 4, seq=4096,
                              max_preds=640, num_layers=1)
     calls = [line for line in compiled.as_text().splitlines()
              if "tpu_custom_call" in line]
     assert set(_mosaic_call_stems(compiled)) == {
-        "flash_fwd", "flash_bwd_dkv", "layer_norm_fwd", "fused_adam"}
+        "flash_fwd", "flash_bwd", "layer_norm_fwd", "fused_adam"}
     op_names = set(re.findall(r'op_name="([^"]*)"', "\n".join(calls)))
     assert ("jit(step)/jvp(attention)/attention_core/flash_fwd/pallas_call"
             in op_names)
     assert ("jit(step)/transpose(jvp(attention))/attention_core/"
-            "flash_bwd_dkv/pallas_call" in op_names)
+            "flash_bwd/pallas_call" in op_names)
     assert "jit(step)/optimizer/fused_adam/pallas_call" in op_names
     assert any(n.endswith("layer_norm/layer_norm_fwd/pallas_call")
                for n in op_names)
@@ -248,8 +251,68 @@ def test_flash_backward_at_4096_fits_vmem_inside_the_step(topo):
     stems = _mosaic_call_stems(compiled)
     # per layer: the flash forward and the one backward
     assert stems.count("flash_fwd") == 2
-    assert stems.count("flash_bwd_dkv") == 2
+    assert stems.count("flash_bwd") == 2
     assert "flash_bwd_dq" not in compiled.as_text()
+
+
+# ---------------------------------------------------------------------------
+# (c) the OLMoE train step of the cell olmoe_1b_7b.lm_s4096, at full size
+# ---------------------------------------------------------------------------
+@pytest.mark.timeout(900)
+def test_olmoe_step_at_published_widths_fits_a_v5e_and_names_its_calls(topo):
+    """One layer of OLMoE-1B-7B with embedding and head (625.6 M parameters)
+    at batch 2 x 4096 through ``olmoe.make_train_step``: it lowers and
+    compiles for one v5e chip, needs less than the 15.75 GiB the chip gives
+    a program, and every Mosaic call in it has a name and sits under the
+    scope that issued it, causal flash at head size 128 and the three
+    grouped matmuls of the expert layer, forward and backward, among them."""
+    mesh = make_mesh(MeshConfig(data=1), devices=topo.devices[:1])
+    cfg = olmoe.olmoe_1b_7b(num_layers=1)
+    opt = pt.optimizer.Adam(1e-4)
+    _, step_fn = olmoe.make_train_step(cfg, opt, mesh)
+    replicated = NamedSharding(mesh, P())
+
+    def on_chip(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=replicated), tree)
+    pshape = jax.eval_shape(functools.partial(olmoe.init_params, cfg=cfg),
+                            jax.random.PRNGKey(0))
+    assert sum(int(np.prod(a.shape)) for a in jax.tree.leaves(pshape)) \
+        == 625_616_896
+    batch = {k: jax.ShapeDtypeStruct(
+        v.shape, v.dtype, sharding=NamedSharding(mesh, P("data")))
+        for k, v in olmoe.synthetic_batch(cfg, 2, 4096).items()}
+    compiled = step_fn.jitted.lower(
+        on_chip(pshape), on_chip(jax.eval_shape(opt.init, pshape)),
+        batch).compile()
+    ma = compiled.memory_analysis()
+    need = (ma.argument_size_in_bytes + ma.temp_size_in_bytes
+            + ma.output_size_in_bytes - ma.alias_size_in_bytes)
+    assert need < 15.75 * 2**30, need / 2**30
+    stems = _mosaic_call_stems(compiled)
+    assert set(stems) == {"flash_fwd", "flash_bwd", "softmax_xent_fwd",
+                          "fused_adam", "grouped_matmul",
+                          "grouped_matmul_dw"}
+    # gate, up, down: forward, the rows' gradient, the weights' gradient
+    assert stems.count("grouped_matmul") == 6
+    assert stems.count("grouped_matmul_dw") == 3
+    assert stems.count("fused_adam") == len(jax.tree.leaves(pshape))
+    calls = [line for line in compiled.as_text().splitlines()
+             if "tpu_custom_call" in line]
+    op_names = set(re.findall(r'op_name="([^"]*)"', "\n".join(calls)))
+    for name in (
+            "jit(step)/jvp(attention)/attention_core/flash_fwd/pallas_call",
+            "jit(step)/transpose(jvp(attention))/attention_core/flash_bwd/"
+            "pallas_call",
+            "jit(step)/jvp(ffn)/moe_experts/grouped_matmul/pallas_call",
+            "jit(step)/transpose(jvp(ffn))/moe_experts/grouped_matmul/"
+            "pallas_call",
+            "jit(step)/transpose(jvp(ffn))/moe_experts/grouped_matmul_dw/"
+            "pallas_call",
+            "jit(step)/jvp(loss)/softmax_xent_fwd/pallas_call",
+            "jit(step)/optimizer/fused_adam/pallas_call"):
+        assert name in op_names, (name, sorted(op_names))
 
 
 def test_zero_trainer_keeps_pallas_inside_shard_map(topo):

@@ -12,7 +12,11 @@ program with most device time (the train step). This tool prints that
 reduction; the chip benchmark's per-layer metrics read the same one.
 
 Usage:
-    python tools/trace_summary.py TRACE_DIR_OR_FILE [--top K]
+    python tools/trace_summary.py TRACE_DIR_OR_FILE [--top K] [--scopes A,B]
+
+``--scopes`` adds names to the base vocabulary, as a chipbench configuration
+file's ``"scopes"`` list does: ``rope,moe_router,moe_dispatch,moe_experts``
+for ``models/olmoe.py``'s step.
 
 where TRACE_DIR is what ``profiler.profiler(trace_dir=...)`` or
 ``jax.profiler.start_trace`` was given (the newest trace under it is read),
@@ -41,10 +45,10 @@ def newest_trace(where):
     return files[-1]
 
 
-def summarize(where, top=15):
+def summarize(where, top=15, scopes=()):
     """The lines to print for the newest trace under ``where``."""
     path = newest_trace(where)
-    reduced = scope_profile.reduce_planes(xplane.load(path))
+    reduced = scope_profile.reduce_planes(xplane.load(path), scopes=scopes)
     if reduced is None:
         raise SystemExit(f"{path}: no device plane with a program on it (a "
                          f"CPU trace has none)")
@@ -66,8 +70,11 @@ def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("trace", help="a trace directory or an .xplane.pb[.gz]")
     ap.add_argument("--top", type=int, default=15)
+    ap.add_argument("--scopes", default="",
+                    help="comma-separated scopes beside the base vocabulary")
     a = ap.parse_args(argv)
-    print("\n".join(summarize(a.trace, a.top)))
+    print("\n".join(summarize(a.trace, a.top,
+                              [s for s in a.scopes.split(",") if s])))
 
 
 if __name__ == "__main__":
