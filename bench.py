@@ -2539,14 +2539,12 @@ def bench_kernels():
     if on_tpu:
         M, K, N = 512, 1024, 4096
         H, D, NI = 65536, 256, 4096
-        PSZ = 1 << 20
         B, HH, S, DH = 4, 8, 512, 64
         LN_N, LN_H = 4096, 1024
         XE_N, XE_V = 512, 32000
     else:
         M, K, N = 16, 32, 32
         H, D, NI = 64, 128, 32
-        PSZ = 2048
         B, HH, S, DH = 1, 1, 128, 16
         LN_N, LN_H = 16, 64
         XE_N, XE_V = 8, 64
@@ -2557,9 +2555,6 @@ def bench_kernels():
     tbl = f32(H, D)
     ids = jnp.asarray(rng.randint(0, H, NI), jnp.int32)
     upd = f32(NI, D)
-    p, g = f32(PSZ), f32(PSZ)
-    m1, m2 = jnp.abs(f32(PSZ)), jnp.abs(f32(PSZ))
-    lr, t = jnp.float32(1e-3), jnp.int32(10)
     q, kk, vv = f32(B, HH, S, DH), f32(B, HH, S, DH), f32(B, HH, S, DH)
     gam, bet, xln = f32(LN_H), f32(LN_H), f32(LN_N, LN_H)
     logits = f32(XE_N, XE_V)
@@ -2572,8 +2567,6 @@ def bench_kernels():
          {"bias": bias}),
         ("kernel_scatter_add_ratio", "embedding_scatter_add",
          (tbl, ids, upd), {}),
-        ("kernel_optimizer_ratio", "fused_adam", (p, g, m1, m2, lr, t),
-         {}),
         ("kernel_attention_ratio", "flash_attention", (q, kk, vv),
          {"causal": True}),
         ("kernel_layer_norm_ratio", "fused_layer_norm", (xln, gam, bet),

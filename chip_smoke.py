@@ -191,16 +191,6 @@ def phase_kernels():
         # the DeepFM table; 8192 updates fit the VMEM budget
         "embedding_scatter_add": ((f32(rows, dim), ids8k, f32(8192, dim)),
                                   {}, None, 2e-2),
-        "fused_sgd": ((f32(h, ffn), f32(h, ffn), jnp.float32(0.1)), {},
-                      None, 1e-5),
-        "fused_momentum": ((f32(2048, 1000), f32(2048, 1000),
-                            f32(2048, 1000), jnp.float32(0.1)),
-                           {"momentum": 0.9}, None, 1e-5),
-        # BERT's word embedding, the largest leaf Adam updates
-        "fused_adam": ((f32(n_vocab, h), f32(n_vocab, h, scale=1e-2),
-                        f32(n_vocab, h, scale=1e-2),
-                        jnp.abs(f32(n_vocab, h, scale=1e-4)),
-                        jnp.float32(1e-4), jnp.int32(3)), {}, None, 1e-5),
         "flash_attention": (tuple(bf16(8, 12, 4 * SEQ, 64)
                                   for _ in range(3)),
                             {"bias": jnp.zeros((8, 4 * SEQ), jnp.float32)},
@@ -403,10 +393,10 @@ def phase_four_chips(one_chip_loss):
 
 
 def four_chip_trainers(devices):
-    """The two other trainers that reach the fused optimizer calls:
+    """The two other trainers that reach the optimizer's update:
     DataParallelTrainer(param_sharding="zero") — a shard_map body, where
-    the Pallas Adam runs per shard — and the static executor under
-    CompiledProgram.with_data_parallel (GSPMD, reference bodies)."""
+    Adam updates each chip's shard — and the static executor under
+    CompiledProgram.with_data_parallel (GSPMD)."""
     mesh = mesh_mod.make_mesh(mesh_mod.MeshConfig(data=4), devices=devices)
     d = 512
 

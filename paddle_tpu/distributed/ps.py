@@ -665,10 +665,7 @@ class _DenseVar:
         if reg is not None:
             g = reg(p, g)
         lr = opt._lr_value(t.astype(jnp.float32)) * self.param_lr
-        from paddle_tpu.optimizer import _pallas_fused_update
-        fused = _pallas_fused_update(opt, p, g, self.slots, lr, t)
-        new_p, self.slots = fused if fused is not None \
-            else opt._update(p, g, self.slots, lr, t)
+        new_p, self.slots = opt._update(p, g, self.slots, lr, t)
         self.value = np.asarray(new_p)
 
     def _accumulate(self, grad):

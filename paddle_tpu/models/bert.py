@@ -400,9 +400,8 @@ def make_train_step(cfg, optimizer, mesh=None, steps_per_call=1):
     def step(params, opt_state, batch):
         loss, grads = jax.value_and_grad(
             lambda p: mlm_loss(p, cfg, batch, mesh=mesh))(params)
-        with mesh_scope(mesh):
-            new_params, new_opt = optimizer.apply_gradients(
-                params, grads, opt_state)
+        new_params, new_opt = optimizer.apply_gradients(
+            params, grads, opt_state)
         return loss, new_params, new_opt
 
     def multi(params, opt_state, batch, stacked):

@@ -238,9 +238,8 @@ def make_train_step(cfg, optimizer, mesh=None):
     def step(params, opt_state, batch):
         loss, grads = jax.value_and_grad(
             lambda p: lm_loss(p, cfg, batch, mesh=mesh))(params)
-        with mesh_scope(mesh):
-            new_params, new_opt = optimizer.apply_gradients(
-                params, grads, opt_state)
+        new_params, new_opt = optimizer.apply_gradients(
+            params, grads, opt_state)
         return loss, new_params, new_opt
 
     jit_step = jax.jit(step, donate_argnums=(0, 1))

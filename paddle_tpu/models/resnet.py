@@ -30,7 +30,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from paddle_tpu.ops.pallas.registry import mesh_scope
 from paddle_tpu.parallel.mesh import DATA_AXIS, get_mesh
 
 __all__ = ["ResNetConfig", "resnet18", "resnet34", "resnet50", "resnet101",
@@ -352,9 +351,8 @@ def make_train_step(cfg, optimizer, mesh=None, steps_per_call=1):
     def step(params, opt_state, images, labels):
         (loss, (bn_params, logits)), grads = jax.value_and_grad(
             loss_fn, has_aux=True)(params, cfg, images, labels)
-        with mesh_scope(mesh):
-            new_params, new_opt = optimizer.apply_gradients(
-                params, grads, opt_state)
+        new_params, new_opt = optimizer.apply_gradients(
+            params, grads, opt_state)
         # splice updated BN running stats (they are not optimizer targets)
         new_params = _merge_bn_stats(new_params, bn_params)
         acc = jnp.mean((jnp.argmax(logits, -1) == labels).astype(jnp.float32))

@@ -18,7 +18,6 @@ import jax.numpy as jnp
 from paddle_tpu.models.resnet import (_bn, _bn_init, _conv,
                                       _conv_init, _maxpool,
                                       _merge_bn_stats)
-from paddle_tpu.ops.pallas.registry import mesh_scope
 
 __all__ = ["SEResNeXtConfig", "se_resnext50", "se_resnext_tiny",
            "init_params", "forward", "loss_fn", "make_train_step",
@@ -203,9 +202,8 @@ def make_train_step(cfg, optimizer, mesh=None):
         (loss, (acc, new)), grads = jax.value_and_grad(
             lambda p: loss_fn(p, cfg, images, labels), has_aux=True)(
                 params)
-        with mesh_scope(mesh):
-            new_params, new_opt = optimizer.apply_gradients(
-                params, grads, opt_state)
+        new_params, new_opt = optimizer.apply_gradients(
+            params, grads, opt_state)
         new_params = _merge_bn_stats(new_params, new)
         return loss, acc, new_params, new_opt
 

@@ -30,7 +30,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from paddle_tpu.ops.pallas.registry import mesh_scope
 from paddle_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS, get_mesh
 from paddle_tpu.profiler import RecordEvent
 
@@ -333,9 +332,8 @@ def make_train_step(cfg, optimizer, mesh=None):
     def step(params, opt_state, batch):
         loss, grads = jax.value_and_grad(
             lambda p: nmt_loss(p, cfg, batch))(params)
-        with mesh_scope(mesh):
-            new_params, new_opt = optimizer.apply_gradients(
-                params, grads, opt_state)
+        new_params, new_opt = optimizer.apply_gradients(
+            params, grads, opt_state)
         return loss, new_params, new_opt
 
     jit_step = jax.jit(step, donate_argnums=(0, 1))

@@ -15,7 +15,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from paddle_tpu.models.resnet import _bn, _bn_init, _conv, _conv_init, \
     _maxpool, _merge_bn_stats, synthetic_batch as _resnet_synthetic_batch
-from paddle_tpu.ops.pallas.registry import mesh_scope
 from paddle_tpu.parallel.mesh import DATA_AXIS, get_mesh
 
 __all__ = ["VGGConfig", "vgg11", "vgg13", "vgg16", "vgg19", "init_params",
@@ -149,9 +148,8 @@ def make_train_step(cfg, optimizer, mesh=None, steps_per_call=1):
     def step(params, opt_state, images, labels, rng):
         (loss, (bn_params, logits)), grads = jax.value_and_grad(
             loss_fn, has_aux=True)(params, cfg, images, labels, True, rng)
-        with mesh_scope(mesh):
-            new_params, new_opt = optimizer.apply_gradients(
-                params, grads, opt_state)
+        new_params, new_opt = optimizer.apply_gradients(
+            params, grads, opt_state)
         new_params = _merge_bn_stats(new_params, bn_params)
         acc = jnp.mean((jnp.argmax(logits, -1) == labels).astype(jnp.float32))
         return loss, acc, new_params, new_opt

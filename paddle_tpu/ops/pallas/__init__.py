@@ -3,8 +3,7 @@
 
 Importing this package registers every built-in kernel:
 fused_matmul / fused_matmul_int8 (matmul.py), embedding_scatter_add
-(embedding.py), fused_sgd / fused_momentum /
-fused_adam (optimizer.py), grouped_matmul (grouped_matmul.py), and — via
+(embedding.py), grouped_matmul (grouped_matmul.py), and — via
 ops/pallas_kernels.py —
 flash_attention / fused_layer_norm / softmax_cross_entropy."""
 
@@ -15,7 +14,6 @@ from paddle_tpu.ops.pallas.registry import (  # noqa: F401
 )
 from paddle_tpu.ops.pallas import matmul as _matmul  # noqa: F401
 from paddle_tpu.ops.pallas import embedding as _embedding  # noqa: F401
-from paddle_tpu.ops.pallas import optimizer as _optimizer  # noqa: F401
 from paddle_tpu.ops.pallas import grouped_matmul as _grouped_matmul  # noqa: F401,E501
 from paddle_tpu.ops.pallas.matmul import try_fused_matmul  # noqa: F401
 

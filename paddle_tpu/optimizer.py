@@ -110,9 +110,7 @@ class Optimizer:
             if self._slot_defaults else [dict() for _ in flat_p]
         new_p, new_s = [], []
         for p, g, s in zip(flat_p, flat_g, flat_s):
-            fused = _pallas_fused_update(self, p, g, s, lr, step)
-            np_, ns_ = fused if fused is not None \
-                else self._update(p, g, s, lr, step)
+            np_, ns_ = self._update(p, g, s, lr, step)
             new_p.append(np_)
             new_s.append(ns_)
         return (jax.tree.unflatten(treedef, new_p),
@@ -222,43 +220,6 @@ class Optimizer:
         return ops, p_g
 
 
-def _pallas_fused_update(opt, p, g, slots, lr, t):
-    """One-VMEM-pass optimizer update via the Pallas kernel registry
-    (ops/pallas/optimizer.py) for the three high-traffic rules. Returns
-    ``(new_p, new_slots)`` or None when the registry selects the stock
-    body / the rule has no fused kernel — the caller then runs
-    ``opt._update`` unchanged, so the flag-off path is bit-identical.
-    Output dtypes are pinned to the stock rule's promotion behavior via
-    ``jax.eval_shape`` over the registered reference body."""
-    from paddle_tpu.ops import pallas as _plk
-    cls = type(opt)
-    if cls is SGDOptimizer:
-        name, args, kw = "fused_sgd", (p, g, lr), {}
-        slot_names = ()
-    elif cls is MomentumOptimizer or cls is DGCMomentumOptimizer:
-        name = "fused_momentum"
-        args = (p, g, slots["velocity"], lr)
-        kw = {"momentum": opt.momentum, "use_nesterov": opt.use_nesterov}
-        slot_names = ("velocity",)
-    elif cls is AdamOptimizer:
-        name = "fused_adam"
-        args = (p, g, slots["moment1"], slots["moment2"], lr, t)
-        kw = {"beta1": opt.beta1, "beta2": opt.beta2,
-              "epsilon": opt.epsilon}
-        slot_names = ("moment1", "moment2")
-    else:
-        return None
-    if not _plk.use_pallas(name) or jnp.size(p) == 0:
-        return None
-    ref = _plk.get_body(name, "reference")
-    want = jax.eval_shape(lambda *a: ref(*a, **kw), *args)
-    out = _plk.dispatch(name, *args, **kw)
-    if not slot_names:
-        return out.astype(want.dtype), slots
-    outs = [o.astype(w.dtype) for o, w in zip(out, want)]
-    return outs[0], dict(zip(slot_names, outs[1:]))
-
-
 def _apply_optimizer_compute(ins, attrs):
     opt = attrs["opt"]
     p, g = ins["Param"][0], ins["Grad"][0]
@@ -268,9 +229,7 @@ def _apply_optimizer_compute(ins, attrs):
     if reg is not None:
         g = reg(p, g)
     lr = opt._lr_value(step.astype(jnp.float32)) * attrs.get("param_lr", 1.0)
-    fused = _pallas_fused_update(opt, p, g, slots, lr, step)
-    new_p, new_slots = fused if fused is not None \
-        else opt._update(p, g, slots, lr, step)
+    new_p, new_slots = opt._update(p, g, slots, lr, step)
     return {"ParamOut": [new_p],
             "SlotOuts": [new_slots[k] for k in attrs["slot_names"]]}
 

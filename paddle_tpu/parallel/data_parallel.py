@@ -186,8 +186,8 @@ class DataParallelTrainer:
             with mesh_scope(self.mesh):
                 loss, grads, new_state = fwd_bwd(params, state, rng,
                                                  batch)
-                new_params, new_opt = self.opt.apply_gradients(
-                    params, grads, opt_state)
+            new_params, new_opt = self.opt.apply_gradients(
+                params, grads, opt_state)
             return loss, new_params, new_opt, new_state
 
         def zero_step(params, opt_state, state, rng, batch):

@@ -32,7 +32,6 @@ import numpy as np
 from jax import lax, shard_map
 
 from paddle_tpu.core.flags import define_flag, get_flag
-from paddle_tpu.ops.pallas.registry import mesh_scope
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from .mesh import DATA_AXIS, DCN_AXIS, PIPE_AXIS
@@ -277,9 +276,8 @@ class PipelineModule:
         @jax.jit
         def step(params, opt_state, batch_x, batch_y):
             loss, grads = loss_and_grads(params, batch_x, batch_y)
-            with mesh_scope(mesh):
-                new_params, new_opt = optimizer.apply_gradients(
-                    params, grads, opt_state)
+            new_params, new_opt = optimizer.apply_gradients(
+                params, grads, opt_state)
             return loss, new_params, new_opt
 
         def init_fn(params):

@@ -427,8 +427,7 @@ class TestBenchModes:
         by = {ln["metric"]: ln for ln in lines}
         expected = [
             "kernel_matmul_ratio", "kernel_matmul_int8_ratio",
-            "kernel_scatter_add_ratio",
-            "kernel_optimizer_ratio", "kernel_attention_ratio",
+            "kernel_scatter_add_ratio", "kernel_attention_ratio",
             "kernel_layer_norm_ratio", "kernel_xent_ratio",
         ]
         for tag in expected:

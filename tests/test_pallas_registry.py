@@ -46,11 +46,13 @@ class TestRegistry:
     def test_all_kernels_registered(self):
         names = plk.list_kernels()
         for want in ("fused_matmul", "fused_matmul_int8",
-                     "embedding_scatter_add",
-                     "fused_sgd", "fused_momentum", "fused_adam",
+                     "embedding_scatter_add", "grouped_matmul",
                      "flash_attention", "fused_layer_norm",
                      "softmax_cross_entropy"):
             assert want in names
+        # the optimizer's rules are plain jnp on every leaf: no kernel
+        assert not [n for n in names if "adam" in n or "sgd" in n
+                    or "momentum" in n]
 
     def test_selection_policy_cpu(self):
         if plk.platform() != "cpu":
@@ -131,18 +133,18 @@ class TestRegistry:
         from types import SimpleNamespace as Mesh
         from paddle_tpu.ops.pallas import registry
         monkeypatch.setattr(registry, "platform", lambda: "tpu")
-        assert plk.selected_body("fused_adam") == "pallas"
+        assert plk.selected_body("fused_layer_norm") == "pallas"
         with plk.mesh_scope(Mesh(size=4)):
-            assert plk.selected_body("fused_adam") == "reference"
-            assert not plk.use_pallas("fused_layer_norm")
+            assert plk.selected_body("fused_layer_norm") == "reference"
+            assert not plk.use_pallas("fused_matmul")
             with plk.mesh_scope(None):          # e.g. a shard_map body
-                assert plk.selected_body("fused_adam") == "pallas"
+                assert plk.selected_body("fused_layer_norm") == "pallas"
             with plk.override("on"):            # forced on stays forced
-                assert plk.selected_body("fused_adam") == "pallas"
-            assert plk.selected_body("fused_adam") == "reference"
+                assert plk.selected_body("fused_layer_norm") == "pallas"
+            assert plk.selected_body("fused_layer_norm") == "reference"
         with plk.mesh_scope(Mesh(size=1)):
-            assert plk.selected_body("fused_adam") == "pallas"
-        assert plk.selected_body("fused_adam") == "pallas"
+            assert plk.selected_body("fused_layer_norm") == "pallas"
+        assert plk.selected_body("fused_layer_norm") == "pallas"
 
 
 # ---------------------------------------------------------------------------
@@ -295,125 +297,6 @@ class TestEmbedding:
         with plk.override("on"):
             on = nn.embedding(ids, tbl, padding_idx=0)
         _close(off, on)
-
-
-# ---------------------------------------------------------------------------
-# fused optimizer updates
-# ---------------------------------------------------------------------------
-class TestFusedOptimizer:
-    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-    @pytest.mark.parametrize("shape", [(7,), (3, 37), (130, 129)])
-    def test_kernels_match_references(self, dtype, shape):
-        p = _f(shape, dtype)
-        g = _f(shape, dtype)
-        v = _f(shape, dtype)
-        m1 = jnp.abs(_f(shape, dtype))
-        m2 = jnp.abs(_f(shape, dtype))
-        lr = jnp.float32(0.01)
-        t = jnp.int32(7)
-        cases = [
-            ("fused_sgd", (p, g, lr), {}),
-            ("fused_momentum", (p, g, v, lr),
-             {"momentum": 0.9, "use_nesterov": False}),
-            ("fused_momentum", (p, g, v, lr),
-             {"momentum": 0.8, "use_nesterov": True}),
-            ("fused_adam", (p, g, m1, m2, lr, t),
-             {"beta1": 0.9, "beta2": 0.999, "epsilon": 1e-8}),
-        ]
-        for name, args, kw in cases:
-            ref = plk.get_body(name, "reference")(*args, **kw)
-            with plk.override("on"):
-                pal = plk.dispatch(name, *args, **kw)
-            if dtype == jnp.bfloat16:
-                # the fused body computes in f32 and rounds once at the
-                # end; the stock chain rounds to bf16 after every op —
-                # agreement is at bf16 resolution, not better
-                _tree_close(ref, pal, dtype, rtol=5e-2, atol=5e-2)
-            else:
-                _tree_close(ref, pal, dtype, rtol=1e-5, atol=1e-5)
-
-    @pytest.mark.parametrize("opt_name", ["sgd", "momentum", "nesterov",
-                                          "adam"])
-    def test_apply_gradients_forced_on_matches_stock(self, opt_name):
-        from paddle_tpu import optimizer as opt_mod
-
-        mk = {
-            "sgd": lambda: opt_mod.SGDOptimizer(0.1),
-            "momentum": lambda: opt_mod.MomentumOptimizer(0.1, 0.9),
-            "nesterov": lambda: opt_mod.MomentumOptimizer(
-                0.1, 0.9, use_nesterov=True),
-            "adam": lambda: opt_mod.AdamOptimizer(0.01),
-        }[opt_name]
-        params = {"w": _f((9, 130)), "b": _f((17,))}
-        grads = {"w": _f((9, 130)), "b": _f((17,))}
-        opt_a, opt_b = mk(), mk()
-        st_a, st_b = opt_a.init(params), opt_b.init(params)
-        for _ in range(3):
-            with plk.override("off"):
-                params_a, st_a = opt_a.apply_gradients(params, grads,
-                                                       st_a)
-            with plk.override("on"):
-                params_b, st_b = opt_b.apply_gradients(params, grads,
-                                                       st_b)
-        _tree_close(params_a, params_b)
-        _tree_close(st_a["slots"], st_b["slots"])
-        for u, v in zip(jax.tree.leaves(params_a),
-                        jax.tree.leaves(params_b)):
-            assert u.dtype == v.dtype
-
-    def test_bf16_param_dtype_promotion_preserved(self):
-        """Stock momentum on bf16 params promotes new_p to f32 (strong
-        f32 lr) while the velocity slot stays bf16 — the fused path must
-        reproduce that exactly (the eval_shape dtype pin)."""
-        from paddle_tpu import optimizer as opt_mod
-
-        opt = opt_mod.MomentumOptimizer(0.1, 0.9)
-        p = _f((12, 130), jnp.bfloat16)
-        g = _f((12, 130), jnp.bfloat16)
-        slots = {"velocity": jnp.zeros_like(p)}
-        lr = jnp.float32(0.1)
-        t = jnp.int32(1)
-        ref_p, ref_s = opt._update(p, g, slots, lr, t)
-        with plk.override("on"):
-            fused = opt_mod._pallas_fused_update(opt, p, g, slots, lr, t)
-        assert fused is not None
-        fp, fs = fused
-        assert fp.dtype == ref_p.dtype
-        assert fs["velocity"].dtype == ref_s["velocity"].dtype
-        _close(ref_p, fp, jnp.bfloat16)
-        _close(ref_s["velocity"], fs["velocity"], jnp.bfloat16)
-
-    def test_unfused_rules_fall_through(self):
-        from paddle_tpu import optimizer as opt_mod
-
-        opt = opt_mod.AdagradOptimizer(0.1)
-        with plk.override("on"):
-            assert opt_mod._pallas_fused_update(
-                opt, _f((4, 4)), _f((4, 4)), {"moment": jnp.zeros((4, 4))},
-                jnp.float32(0.1), jnp.int32(1)) is None
-
-    def test_ps_dense_step_forced_on(self):
-        """The hosted-param PS apply path must stay bit-identical to its
-        stock result when the registry selects the fused kernel."""
-        from paddle_tpu import optimizer as opt_mod
-        from paddle_tpu.distributed.ps import _DenseVar
-
-        def mk():
-            dv = _DenseVar(np.ones((6, 130), np.float32),
-                           opt_mod.AdamOptimizer(0.01))
-            # the native C fast path (when built) bypasses both jnp
-            # bodies; force the jnp route so the A/B is stock vs fused
-            dv._native = (None, None)
-            return dv
-
-        grad = RNG.randn(6, 130).astype(np.float32)
-        a, b = mk(), mk()
-        with plk.override("off"):
-            a._step(grad)
-        with plk.override("on"):
-            b._step(grad)
-        np.testing.assert_allclose(a.value, b.value, rtol=1e-6,
-                                   atol=1e-6)
 
 
 # ---------------------------------------------------------------------------

@@ -16,6 +16,7 @@ refusal a chip run would meet at start-up —
 Nothing here runs on a device, so nothing here is a measurement.
 """
 
+import contextlib
 import functools
 import re
 
@@ -27,7 +28,7 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
 import paddle_tpu as pt
-from paddle_tpu.models import bert, olmoe
+from paddle_tpu.models import bert, olmoe, transformer
 from paddle_tpu.ops import pallas as plk
 from paddle_tpu.ops.pallas import registry
 from paddle_tpu.parallel.data_parallel import DataParallelTrainer
@@ -53,10 +54,18 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.fixture(autouse=True)
-def on_chip_selection(monkeypatch):
+@contextlib.contextmanager
+def _chip_selection():
     """What registry.platform() answers on the chip."""
-    monkeypatch.setattr(registry, "platform", lambda: "tpu")
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(registry, "platform", lambda: "tpu")
+        yield
+
+
+@pytest.fixture(autouse=True)
+def on_chip_selection():
+    with _chip_selection():
+        yield
 
 
 def _abstract(shape, dtype, sharding):
@@ -93,10 +102,6 @@ KERNEL_SHAPES = {
     # DeepFM's table; n_pad * dp at DEFAULT_VMEM_BUDGET's edge
     "embedding_scatter_add": ([((100000, 16), F32), ((16384,), I32),
                                ((16384, 16), F32)], {}, False),
-    "fused_sgd": ([((H, FFN), F32)] * 2 + [((), F32)], {}, False),
-    "fused_momentum": ([((2048, 1000), F32)] * 3 + [((), F32)], {}, False),
-    "fused_adam": ([((VOCAB, H), F32)] * 4 + [((), F32), ((), I32)], {},
-                   False),
     "flash_attention": ([((8, 12, 2048, 64), BF16)] * 3, {}, True),
     # OLMoE's gate/up product: 8 x 8192 assignments, 64 experts of 2048x1024
     "grouped_matmul": ([((65536, 2048), BF16), ((64, 2048, 1024), BF16),
@@ -187,10 +192,52 @@ def _bert_step(topo, mesh_cfg, n_devices, batch_size, seq=512, max_preds=80,
     return compiled, mesh
 
 
-def test_bert_step_one_device_runs_the_pallas_bodies(topo):
-    compiled, _ = _bert_step(topo, MeshConfig(data=1), 1, 64, num_layers=2)
-    # 2 layers: 6 layer norms forward + one Adam call per parameter leaf
-    assert _mosaic_calls(compiled) >= 6 + 2 * 12
+def _lower_replicated(make_train_step, init_params, cfg, batch, topo):
+    """(compiled, parameter shapes, optimizer-state shapes) of a trainer's
+    step under Adam on one chip of the described host."""
+    mesh = make_mesh(MeshConfig(data=1), devices=topo.devices[:1])
+    opt = pt.optimizer.Adam(1e-4)
+    _, step_fn = make_train_step(cfg, opt, mesh)
+    replicated = NamedSharding(mesh, P())
+
+    def on_chip(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=replicated), tree)
+    pshape = jax.eval_shape(functools.partial(init_params, cfg=cfg),
+                            jax.random.PRNGKey(0))
+    oshape = jax.eval_shape(opt.init, pshape)
+    batch = {k: jax.ShapeDtypeStruct(
+        v.shape, v.dtype, sharding=NamedSharding(mesh, P("data")))
+        for k, v in batch.items()}
+    with _chip_selection():    # a module's fixture is made before a test's
+        compiled = step_fn.jitted.lower(on_chip(pshape), on_chip(oshape),
+                                        batch).compile()
+    return compiled, pshape, oshape
+
+
+def _need_bytes(compiled):
+    ma = compiled.memory_analysis()
+    return (ma.argument_size_in_bytes + ma.temp_size_in_bytes
+            + ma.output_size_in_bytes - ma.alias_size_in_bytes)
+
+
+@pytest.fixture(scope="module")
+def bert_two_layers(topo):
+    """The one-chip step of the cell bert_base.mlm_s512 at two layers,
+    compiled once for the tests that read it."""
+    cfg = bert.bert_base(vocab_size=VOCAB, max_seq=512, remat=False,
+                         num_layers=2)
+    return _lower_replicated(
+        bert.make_train_step, bert.init_params, cfg,
+        bert.synthetic_batch(cfg, 64, 512, max_preds=80), topo)
+
+
+def test_bert_step_one_device_runs_the_pallas_bodies(bert_two_layers):
+    compiled, _, _ = bert_two_layers
+    # 2 layers: two layer norms each, the embedding's and the head's,
+    # forward; Adam is the stock rule and no call
+    assert _mosaic_call_stems(compiled) == ["layer_norm_fwd"] * 6
 
 
 @pytest.mark.parametrize("mesh_cfg,batch", [
@@ -214,20 +261,19 @@ def test_the_step_names_its_mosaic_calls(topo):
     """Every ``pallas_call`` has a ``name=``: it is the stem of the compiled
     instruction, which a profile shows and chipbench's breakdown prints, and
     the named scope around the call is on its ``op_name``. At S=4096 the
-    step holds all four kernels of the BERT cells: the flash backward is
+    step holds all three kernels of the BERT cells: the flash backward is
     one call, ``flash_bwd``, which yields dQ too."""
     compiled, _ = _bert_step(topo, MeshConfig(data=1), 1, 4, seq=4096,
                              max_preds=640, num_layers=1)
     calls = [line for line in compiled.as_text().splitlines()
              if "tpu_custom_call" in line]
     assert set(_mosaic_call_stems(compiled)) == {
-        "flash_fwd", "flash_bwd", "layer_norm_fwd", "fused_adam"}
+        "flash_fwd", "flash_bwd", "layer_norm_fwd"}
     op_names = set(re.findall(r'op_name="([^"]*)"', "\n".join(calls)))
     assert ("jit(step)/jvp(attention)/attention_core/flash_fwd/pallas_call"
             in op_names)
     assert ("jit(step)/transpose(jvp(attention))/attention_core/"
             "flash_bwd/pallas_call" in op_names)
-    assert "jit(step)/optimizer/fused_adam/pallas_call" in op_names
     assert any(n.endswith("layer_norm/layer_norm_fwd/pallas_call")
                for n in op_names)
 
@@ -258,46 +304,45 @@ def test_flash_backward_at_4096_fits_vmem_inside_the_step(topo):
 # ---------------------------------------------------------------------------
 # (c) the OLMoE train step of the cell olmoe_1b_7b.lm_s4096, at full size
 # ---------------------------------------------------------------------------
+@pytest.fixture(scope="module")
+def olmoe_full_size(topo):
+    """The step of the cell olmoe_1b_7b.lm_s4096: one layer at the published
+    widths, batch 2 x 4096. Compiled once for the tests that read it."""
+    cfg = olmoe.olmoe_1b_7b(num_layers=1)
+    return _lower_replicated(olmoe.make_train_step, olmoe.init_params, cfg,
+                             olmoe.synthetic_batch(cfg, 2, 4096), topo)
+
+
+@pytest.fixture(scope="module")
+def transformer_one_plus_one(topo):
+    """Transformer-big at one encoder and one decoder layer, the batch of
+    the cell transformer_big.wmt_s256."""
+    cfg = transformer.transformer_big(enc_layers=1, dec_layers=1)
+    return _lower_replicated(
+        transformer.make_train_step, transformer.init_params, cfg,
+        transformer.synthetic_batch(cfg, 32, 256, 256), topo)
+
+
 @pytest.mark.timeout(900)
-def test_olmoe_step_at_published_widths_fits_a_v5e_and_names_its_calls(topo):
+def test_olmoe_step_at_published_widths_fits_a_v5e_and_names_its_calls(
+        olmoe_full_size):
     """One layer of OLMoE-1B-7B with embedding and head (625.6 M parameters)
     at batch 2 x 4096 through ``olmoe.make_train_step``: it lowers and
     compiles for one v5e chip, needs less than the 15.75 GiB the chip gives
     a program, and every Mosaic call in it has a name and sits under the
     scope that issued it, causal flash at head size 128 and the three
     grouped matmuls of the expert layer, forward and backward, among them."""
-    mesh = make_mesh(MeshConfig(data=1), devices=topo.devices[:1])
-    cfg = olmoe.olmoe_1b_7b(num_layers=1)
-    opt = pt.optimizer.Adam(1e-4)
-    _, step_fn = olmoe.make_train_step(cfg, opt, mesh)
-    replicated = NamedSharding(mesh, P())
-
-    def on_chip(tree):
-        return jax.tree.map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
-                                           sharding=replicated), tree)
-    pshape = jax.eval_shape(functools.partial(olmoe.init_params, cfg=cfg),
-                            jax.random.PRNGKey(0))
+    compiled, pshape, _ = olmoe_full_size
     assert sum(int(np.prod(a.shape)) for a in jax.tree.leaves(pshape)) \
         == 625_616_896
-    batch = {k: jax.ShapeDtypeStruct(
-        v.shape, v.dtype, sharding=NamedSharding(mesh, P("data")))
-        for k, v in olmoe.synthetic_batch(cfg, 2, 4096).items()}
-    compiled = step_fn.jitted.lower(
-        on_chip(pshape), on_chip(jax.eval_shape(opt.init, pshape)),
-        batch).compile()
-    ma = compiled.memory_analysis()
-    need = (ma.argument_size_in_bytes + ma.temp_size_in_bytes
-            + ma.output_size_in_bytes - ma.alias_size_in_bytes)
+    need = _need_bytes(compiled)
     assert need < 15.75 * 2**30, need / 2**30
     stems = _mosaic_call_stems(compiled)
     assert set(stems) == {"flash_fwd", "flash_bwd", "softmax_xent_fwd",
-                          "fused_adam", "grouped_matmul",
-                          "grouped_matmul_dw"}
+                          "grouped_matmul", "grouped_matmul_dw"}
     # gate, up, down: forward, the rows' gradient, the weights' gradient
     assert stems.count("grouped_matmul") == 6
     assert stems.count("grouped_matmul_dw") == 3
-    assert stems.count("fused_adam") == len(jax.tree.leaves(pshape))
     calls = [line for line in compiled.as_text().splitlines()
              if "tpu_custom_call" in line]
     op_names = set(re.findall(r'op_name="([^"]*)"', "\n".join(calls)))
@@ -310,16 +355,66 @@ def test_olmoe_step_at_published_widths_fits_a_v5e_and_names_its_calls(topo):
             "pallas_call",
             "jit(step)/transpose(jvp(ffn))/moe_experts/grouped_matmul_dw/"
             "pallas_call",
-            "jit(step)/jvp(loss)/softmax_xent_fwd/pallas_call",
-            "jit(step)/optimizer/fused_adam/pallas_call"):
+            "jit(step)/jvp(loss)/softmax_xent_fwd/pallas_call"):
         assert name in op_names, (name, sorted(op_names))
 
 
-def test_zero_trainer_keeps_pallas_inside_shard_map(topo):
+# ---------------------------------------------------------------------------
+# (d) the optimizer inside the three trainers' steps: the stock rule, in place
+# ---------------------------------------------------------------------------
+def _under_optimizer(compiled):
+    """(opcode, elements of the first result) of every instruction whose
+    ``op_name`` is under the scope ``optimizer``."""
+    found = []
+    for line in compiled.as_text().splitlines():
+        op_name = re.search(r'op_name="([^"]*)"', line)
+        if " = " not in line or not op_name \
+                or "/optimizer/" not in op_name.group(1):
+            continue
+        rhs = line.split(" = ", 1)[1]
+        dims = re.search(r"\w+\[([\d,]*)\]", rhs).group(1)
+        found.append((re.search(r" ([a-z][a-z\-]*)\(", rhs).group(1),
+                      int(np.prod([int(d) for d in dims.split(",") if d]))))
+    return found
+
+
+@pytest.mark.timeout(900)
+@pytest.mark.parametrize("step", ["bert_two_layers",
+                                  "transformer_one_plus_one",
+                                  "olmoe_full_size"])
+def test_the_optimizer_updates_every_leaf_in_place(step, request):
+    """``Optimizer.apply_gradients`` runs the stock rule on every leaf in the
+    shape it has: the compiled step holds no Mosaic call under the scope
+    ``optimizer``, no ``reshape`` or ``copy`` of a leaf there, and every
+    parameter, both moments of each and the step counter come back in the
+    donated buffers. (XLA leaves the gradient of a bias in the
+    ``[heads, head size]`` shape its reduction gives, scales it for the two
+    moments there and reshapes those: a vector, 4 KiB, not a leaf's relayout;
+    hence the size under which a ``reshape`` is let through.)"""
+    compiled, pshape, oshape = request.getfixturevalue(step)
+    under = _under_optimizer(compiled)
+    assert len(under) > len(jax.tree.leaves(pshape))
+    assert not [u for u in under if u[0] == "custom-call"]
+    assert not [u for u in under
+                if u[0] in ("reshape", "copy", "transpose") and u[1] > 4096]
+    donated = jax.tree.leaves((pshape, oshape))
+    header = compiled.as_text().split("\n", 1)[0]
+    assert len(re.findall(r"(?:may|must)-alias", header)) == len(donated)
+    ma = compiled.memory_analysis()
+    exact = sum(int(np.prod(a.shape)) * a.dtype.itemsize for a in donated)
+    # the compiler's sizes are of tiled buffers: a little over the exact ones
+    assert exact <= ma.alias_size_in_bytes <= 1.001 * exact
+    assert ma.alias_size_in_bytes <= ma.output_size_in_bytes \
+        <= ma.alias_size_in_bytes + 4096          # + the loss
+    if step == "olmoe_full_size":
+        # 10.86 GiB; 14.05 with the Pallas Adam and the copies around it
+        assert _need_bytes(compiled) < 11.5 * 2**30
+
+
+def test_zero_trainer_updates_with_no_mosaic_call(topo):
     """DataParallelTrainer(param_sharding="zero") updates inside a
-    shard_map body: a Mosaic call per shard is legal there, so `auto`
-    keeps the Pallas Adam — and the replicated strategy, which GSPMD
-    partitions, does not."""
+    shard_map body, the replicated strategy under GSPMD: both run the one
+    stock rule, and neither program holds a Mosaic call."""
     mesh = make_mesh(MeshConfig(data=4), devices=topo.devices)
     d = 512
 
@@ -344,7 +439,7 @@ def test_zero_trainer_keeps_pallas_inside_shard_map(topo):
                                param_sharding="zero", donate=False)
     zero._param_specs = {"w": P("data", None)}
     c = zero._step.lower(*abstract_args(zero, P("data", None))).compile()
-    assert _mosaic_calls(c) == 1
+    assert _mosaic_calls(c) == 0
 
     plain = DataParallelTrainer(loss_fn, pt.optimizer.Adam(1e-3),
                                 mesh=mesh, donate=False)

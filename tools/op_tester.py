@@ -10,7 +10,7 @@ optionally fwd+bwd) on the current device, print one JSON line per op.
 Presets scale shapes: "bench" (TPU-sized) and "tiny" (CPU/CI).
 ``--pallas on|off|both`` wraps each run in the Pallas kernel registry's
 override (ops/pallas/registry.py) so any op routed through the registry
-(fused_matmul, embedding_scatter_add, fused_adam, layer_norm, ...) can be
+(fused_matmul, embedding_scatter_add, layer_norm, ...) can be
 A/B'd from the CLI; "both" prints one JSON line per body.
 """
 
@@ -86,13 +86,6 @@ def _ops(preset):
              (r(V, H, dtype=jnp.float32),
               jax.random.randint(key, (B * S,), 0, V),
               r(B * S, H, dtype=jnp.float32)), None),
-        "fused_adam":
-            (lambda p, g, m1, m2: PLK.dispatch(
-                "fused_adam", p, g, m1, m2, 1e-3, 10.0),
-             (r(4 * H * H, dtype=jnp.float32),
-              r(4 * H * H, dtype=jnp.float32),
-              r(4 * H * H, dtype=jnp.float32),
-              jnp.abs(r(4 * H * H, dtype=jnp.float32))), None),
     }
     return reg
 
