@@ -2502,122 +2502,6 @@ def bench_shard():
               "to reduce over (n_devices too small)", file=sys.stderr)
 
 
-def bench_kernels():
-    """`python bench.py kernels` — per-kernel Pallas-vs-stock A/B.
-
-    One JSON line per registered kernel: interleaved on/off windows
-    (the `_abba_overhead` ABBA quadruple idiom — both bodies of each
-    ratio sit in the same slice of host drift), value = trimmed-mean
-    ratio of Pallas time over stock time (< 1.0 means the Pallas body
-    is faster). On CPU the Pallas side runs in interpreter mode at tiny
-    shapes — that ratio is a CI liveness check of the exact TPU kernel
-    code path, NOT a perf claim; the on-chip re-measure recipe lives in
-    docs/PERFORMANCE.md "Pallas kernel layer".
-
-    Env: BENCH_KERNELS_PAIRS (ABBA quadruples per kernel),
-    BENCH_KERNELS_ITERS (applications per window)."""
-    import jax
-    import jax.numpy as jnp
-
-    import paddle_tpu.ops.pallas as plk
-
-    dev = jax.devices()[0]
-    on_tpu = dev.platform != "cpu"
-    cpu = not on_tpu
-    pairs = int(os.environ.get("BENCH_KERNELS_PAIRS",
-                               "3" if on_tpu else "2"))
-    iters = int(os.environ.get("BENCH_KERNELS_ITERS",
-                               "20" if on_tpu else "2"))
-    rng = np.random.RandomState(0)
-
-    def f32(*shape):
-        return jnp.asarray(rng.randn(*shape), jnp.float32)
-
-    # TPU shapes are the hot-path operating points (BERT-base matmuls,
-    # CTR-style embedding traffic, BERT param slabs); CPU shapes are the
-    # smallest the kernels' tiling accepts, sized for interpreter mode
-    if on_tpu:
-        M, K, N = 512, 1024, 4096
-        H, D, NI = 65536, 256, 4096
-        B, HH, S, DH = 4, 8, 512, 64
-        LN_N, LN_H = 4096, 1024
-        XE_N, XE_V = 512, 32000
-    else:
-        M, K, N = 16, 32, 32
-        H, D, NI = 64, 128, 32
-        B, HH, S, DH = 1, 1, 128, 16
-        LN_N, LN_H = 16, 64
-        XE_N, XE_V = 8, 64
-
-    x, w, bias = f32(M, K), f32(K, N), f32(N)
-    w8 = jnp.asarray(rng.randint(-127, 128, (K, N)), jnp.int8)
-    scale = jnp.abs(f32(N)) + 0.01
-    tbl = f32(H, D)
-    ids = jnp.asarray(rng.randint(0, H, NI), jnp.int32)
-    upd = f32(NI, D)
-    q, kk, vv = f32(B, HH, S, DH), f32(B, HH, S, DH), f32(B, HH, S, DH)
-    gam, bet, xln = f32(LN_H), f32(LN_H), f32(LN_N, LN_H)
-    logits = f32(XE_N, XE_V)
-    labels = jnp.asarray(rng.randint(0, XE_V, XE_N), jnp.int32)
-
-    cases = [
-        ("kernel_matmul_ratio", "fused_matmul", (x, w),
-         {"bias": bias, "act": "relu"}),
-        ("kernel_matmul_int8_ratio", "fused_matmul_int8", (x, w8, scale),
-         {"bias": bias}),
-        ("kernel_scatter_add_ratio", "embedding_scatter_add",
-         (tbl, ids, upd), {}),
-        ("kernel_attention_ratio", "flash_attention", (q, kk, vv),
-         {"causal": True}),
-        ("kernel_layer_norm_ratio", "fused_layer_norm", (xln, gam, bet),
-         {}),
-        ("kernel_xent_ratio", "softmax_cross_entropy", (logits, labels),
-         {}),
-    ]
-
-    body_label = "pallas_interpret" if cpu else "pallas"
-    for metric, kname, args, kw in cases:
-        # jit both bodies directly from the registry: `override()` can't
-        # retrace an already-cached jit, so the A/B pins each side to a
-        # dedicated compiled callable
-        def make(fn, force_interpret):
-            kw2 = dict(kw)
-            if force_interpret:
-                kw2["interpret"] = True
-
-            def apply(*a):
-                out = fn(*a, **kw2)
-                return sum(jnp.sum(leaf.astype(jnp.float32))
-                           for leaf in jax.tree.leaves(out))
-
-            return jax.jit(apply)
-
-        f_on = make(plk.get_body(kname, "pallas"), cpu)
-        f_off = make(plk.get_body(kname, "reference"), False)
-        f_on(*args).block_until_ready()       # compile outside windows
-        f_off(*args).block_until_ready()
-
-        def window(on, _fs=(f_on, f_off)):
-            f = _fs[0] if on else _fs[1]
-            t0 = time.perf_counter()
-            r = None
-            for _ in range(iters):
-                r = f(*args)
-            r.block_until_ready()
-            return (time.perf_counter() - t0) / iters
-
-        est, pair_ratios, on_ts, off_ts = _abba_overhead(
-            window, pairs, rounds=0)
-        print(json.dumps({
-            "metric": metric, "value": round(est, 4), "unit": "x",
-            "kernel": kname, "body": body_label,
-            "pallas_ms": round(float(np.min(on_ts)) * 1e3, 4),
-            "stock_ms": round(float(np.min(off_ts)) * 1e3, 4),
-            "pairs": len(pair_ratios), "iters": iters,
-            "platform": dev.platform,
-        }))
-
-
 def _emit_registry_snapshot():
     """End-of-run metrics emission: the registry (bench windows +
     whatever executor/prefetch/checkpoint counters the run touched) as
@@ -2701,8 +2585,6 @@ def _dispatch_mode():
         return bench_data()
     if len(sys.argv) > 1 and sys.argv[1] == "shard":
         return bench_shard()
-    if len(sys.argv) > 1 and sys.argv[1] == "kernels":
-        return bench_kernels()
     import jax
     import jax.numpy as jnp
 
@@ -2719,7 +2601,8 @@ def _dispatch_mode():
             f"accelerator; jax found platform={dev.platform!r} "
             f"({dev.device_kind}). Nothing measured.")
     # BENCH_ATTN=dense|flash selects the attention path (flash = Pallas
-    # blockwise kernel, ops/pallas_kernels.py) for A/B runs on the chip
+    # blockwise kernel, ops/pallas/flash_attention.py) for A/B runs on the
+    # chip
     attn = os.environ.get("BENCH_ATTN", "dense")
     # remat off: BERT-base bs=64 seq=512 activations fit v5e HBM, and
     # skipping the recompute is worth ~+0.06 MFU (measured 0.418 vs 0.362;

@@ -199,6 +199,12 @@ def phase_kernels():
                               f32(h)), {}, (0, 1, 2), 2e-2),
         "softmax_cross_entropy": ((f32(n_pred, n_vocab), labels), {},
                                   (0,), 1e-5),
+        # uneven groups, empty ones first, last and between; float32,
+        # because XLA's own ragged-dot kernel, the reference here, does
+        # not compile at 'highest' precision with bf16 operands
+        "grouped_matmul": ((f32(4096, h), f32(8, h, ffn, scale=0.02),
+                            jnp.asarray([0, 1000, 7, 300, 1500, 0, 1289, 0],
+                                        jnp.int32)), {}, (0, 1), 2e-2),
     }
     missing = set(plk.list_kernels()) ^ set(cases)
     if missing:

@@ -25,6 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from paddle_tpu.models.blocks import FLASH_FROM
 from paddle_tpu.ops.pallas.registry import mesh_scope
 from paddle_tpu.parallel.mesh import (
     DATA_AXIS, MODEL_AXIS, SEQ_AXIS, get_mesh,
@@ -47,21 +48,19 @@ class BertConfig:
     dropout: float = 0.1
     dtype: object = jnp.bfloat16     # activation/compute dtype
     remat: bool = True               # jax.checkpoint per block
-    # "auto": dense for S<=1024, flash beyond (measured crossover).
+    # "auto": dense up to blocks.FLASH_FROM positions, flash beyond.
     # "dense": GSPMD gathers K/V over "seq"; "ring": blockwise ring
     # attention (parallel/ring_attention.py) — K/V never materialised
     # whole, permutes ride ICI neighbor links. Use "ring" for long-context
     # runs where S/n_seq is still large. "flash": Pallas blockwise
-    # online-softmax kernel (ops/pallas_kernels.py) — single-device/dp
-    # fast path; scores never materialise in HBM.
+    # online-softmax kernel (ops/pallas/flash_attention.py) —
+    # single-device/dp fast path; scores never materialise in HBM.
     attention_impl: str = "auto"
     # softmax accumulation dtype on the dense path. "fp32" (default) is
-    # the conservative choice; "bf16" skips the f32 round-trip over the
-    # [B,N,S,S] scores — measured +2k tok/s (+0.006 MFU) on the BERT-base
-    # bs=64 s=512 headline with a loss curve matching fp32 to the 4th
-    # decimal (r4 on-chip A/B; full matrix in BASELINE.md "BERT MFU
-    # experiments"). Safe because softmax subtracts the row max before
-    # exponentiating, keeping magnitudes in bf16's comfortable range.
+    # the conservative choice and what every cell runs; "bf16" skips the
+    # f32 round-trip over the [B,N,S,S] scores (no cell measures it).
+    # Safe because softmax subtracts the row max before exponentiating,
+    # keeping magnitudes in bf16's comfortable range.
     softmax_dtype: str = "fp32"
 
     @property
@@ -182,9 +181,9 @@ def param_specs(cfg):
 def _layer_norm(x, g, b, mesh=None, eps=1e-12):
     # registry-selected body (ops/pallas/registry.py): the stock-jnp
     # reference is bit-identical to the historical inline math here, the
-    # Pallas body is one VMEM pass (ops/pallas_kernels.fused_layer_norm).
+    # Pallas body is one VMEM pass (ops/pallas/layer_norm.py).
     # mesh_scope: under a multi-device mesh GSPMD must partition this.
-    from paddle_tpu.ops import pallas_kernels as _pk
+    from paddle_tpu.ops import pallas as _pk
     with mesh_scope(mesh):
         return _pk.fused_layer_norm(x, g, b, eps=eps)
 
@@ -201,17 +200,18 @@ def _attention(lp, x, mask_bias, cfg, mesh=None, key_padding_mask=None):
 
     impl = cfg.attention_impl
     if impl == "auto":
-        # measured crossover on v5e (BERT-base fwd+bwd): XLA's fused
-        # dense attention wins at S<=1024; the Pallas flash kernel wins
-        # beyond (1.6x at 2048, 1.8x at 4096) and caps live memory at
-        # O(block.S) instead of O(S^2). Seq-sharded meshes take the ring
-        # path — flash is a single-device kernel and would force a
-        # gather of the sharded K/V. On any other multi-device mesh the
-        # registry hands "flash" its dense reference body (mesh_scope).
+        # XLA's fused dense attention up to FLASH_FROM positions (the
+        # cell mlm_s512), the Pallas flash kernel beyond (mlm_s4096),
+        # which caps live memory at O(block.S) instead of O(S^2); where
+        # the two cross between those cells is not measured (ROADMAP
+        # A2). Seq-sharded meshes take the ring path — flash is a
+        # single-device kernel and would force a gather of the sharded
+        # K/V. On any other multi-device mesh the registry hands "flash"
+        # its dense reference body (mesh_scope).
         if mesh is not None and mesh.shape.get(SEQ_AXIS, 1) > 1:
             impl = "ring"
         else:
-            impl = "flash" if S > 1024 else "dense"
+            impl = "flash" if S > FLASH_FROM else "dense"
 
     if (impl == "ring" and mesh is not None
             and mesh.shape.get(SEQ_AXIS, 1) > 1):
@@ -230,9 +230,10 @@ def _attention(lp, x, mask_bias, cfg, mesh=None, key_padding_mask=None):
 
     if impl == "flash":
         # Pallas blockwise kernel: [S, S] scores never hit HBM
-        # (paddle_tpu/ops/pallas_kernels.py); the kernel wants [B,N,S,D].
+        # (paddle_tpu/ops/pallas/flash_attention.py); the kernel wants
+        # [B,N,S,D].
         # mask_bias [B,1,1,S] is a key-padding bias → [B, S].
-        from paddle_tpu.ops import pallas_kernels as _pk
+        from paddle_tpu.ops import pallas as _pk
 
         def heads(t):
             return t.reshape(B, S, nh, hd).transpose(0, 2, 1, 3)

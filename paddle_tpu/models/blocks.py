@@ -20,8 +20,10 @@ from paddle_tpu.ops.pallas.registry import mesh_scope
 __all__ = ["rms_norm", "rope_angles", "apply_rope", "causal_attention",
            "gated_ffn"]
 
-#: beyond this many positions ``causal_attention``'s ``auto`` takes the flash
-#: kernels: the crossover ``bert._attention`` measured on the v5e
+#: beyond this many positions ``causal_attention``'s and ``bert._attention``'s
+#: ``auto`` take the flash kernels. The cells on either side are ``mlm_s512``
+#: (dense) and ``mlm_s4096`` / ``lm_s4096`` (flash); no length between 512
+#: and 4096 is measured, so where the two really cross is open (ROADMAP A2).
 FLASH_FROM = 1024
 
 
@@ -62,13 +64,13 @@ def causal_attention(q, k, v, impl="auto", mesh=None):
     (XLA, scores in float32), "flash" (the Pallas kernels through the
     registry, which hands out the dense reference on the CPU and under a
     mesh of more than one device) or "auto": flash past ``FLASH_FROM``
-    positions, as ``bert._attention`` chooses."""
+    positions."""
     b, s, n, d = q.shape
     if impl == "auto":
         impl = "flash" if s > FLASH_FROM else "dense"
     with jax.named_scope("attention_core"):
         if impl == "flash":
-            from paddle_tpu.ops import pallas_kernels as _pk
+            from paddle_tpu.ops import pallas as _pk
 
             def heads(t):
                 return t.transpose(0, 2, 1, 3)

@@ -190,7 +190,7 @@ def lm_loss(params, cfg, batch, mesh=None):
     dict(input_ids, labels) [B, S], plus ``balance_weight`` times the
     load-balancing loss and ``z_weight`` times the router z-loss (each the
     mean over the layers). Logits and loss in float32."""
-    from paddle_tpu.ops import pallas_kernels as _pk
+    from paddle_tpu.ops import pallas as _pk
     hidden, aux = _hidden_and_aux(params, cfg, batch["input_ids"], mesh)
     with jax.named_scope("loss"), mesh_scope(mesh):
         logits = jnp.dot(hidden, params["head_w"].astype(hidden.dtype),

@@ -30,6 +30,6 @@ from paddle_tpu.ops.misc import *            # noqa: F401,F403
 from paddle_tpu.ops.aliases import *         # noqa: F401,F403
 from paddle_tpu.ops.tensor_array import *    # noqa: F401,F403
 from paddle_tpu.ops.selected_rows import *   # noqa: F401,F403
-from paddle_tpu.ops import pallas_kernels    # noqa: F401  (module: perf
-# primitives — flash_attention, fused_layer_norm, softmax_cross_entropy —
-# not part of the fluid.layers parity surface)
+from paddle_tpu.ops import pallas            # noqa: F401  (package: perf
+# primitives — flash_attention, fused_layer_norm, softmax_cross_entropy,
+# grouped_matmul — not part of the fluid.layers parity surface)

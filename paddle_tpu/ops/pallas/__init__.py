@@ -1,35 +1,32 @@
 """Pallas kernel layer: registry + kernel modules (docs/PERFORMANCE.md
 "Pallas kernel layer").
 
-Importing this package registers every built-in kernel:
+Importing this package registers every built-in kernel, one module each:
 fused_matmul / fused_matmul_int8 (matmul.py), embedding_scatter_add
-(embedding.py), grouped_matmul (grouped_matmul.py), and — via
-ops/pallas_kernels.py —
-flash_attention / fused_layer_norm / softmax_cross_entropy."""
+(embedding.py), grouped_matmul (grouped_matmul.py), flash_attention
+(flash_attention.py), fused_layer_norm (layer_norm.py) and
+softmax_cross_entropy (softmax_xent.py). The entry points the models call
+are names of this package; ``flash_attention`` and ``grouped_matmul`` here
+are therefore the functions, not the modules of the same name (import a
+module's own names with ``from paddle_tpu.ops.pallas.<module> import ...``)."""
 
 from paddle_tpu.ops.pallas.registry import (  # noqa: F401
     DEFAULT_VMEM_BUDGET, register_kernel, get_kernel, list_kernels,
     dispatch, get_body, selected_body, use_pallas, selection_mode,
     override, mesh_scope, platform, within_vmem_budget,
 )
-from paddle_tpu.ops.pallas import matmul as _matmul  # noqa: F401
 from paddle_tpu.ops.pallas import embedding as _embedding  # noqa: F401
-from paddle_tpu.ops.pallas import grouped_matmul as _grouped_matmul  # noqa: F401,E501
-from paddle_tpu.ops.pallas.matmul import try_fused_matmul  # noqa: F401
-
-# the three legacy entry points register themselves when
-# ops/pallas_kernels.py executes; import it so `import paddle_tpu.ops.pallas`
-# alone yields the complete registry. Guarded: pallas_kernels imports this
-# package for the platform probe, so during ops/__init__'s own import of
-# pallas_kernels this is a benign partially-initialized no-op.
-try:
-    from paddle_tpu.ops import pallas_kernels as _legacy  # noqa: F401
-except ImportError:  # pragma: no cover - circular during package init
-    pass
+from paddle_tpu.ops.pallas.flash_attention import flash_attention
+from paddle_tpu.ops.pallas.grouped_matmul import grouped_matmul
+from paddle_tpu.ops.pallas.layer_norm import fused_layer_norm
+from paddle_tpu.ops.pallas.matmul import try_fused_matmul
+from paddle_tpu.ops.pallas.softmax_xent import softmax_cross_entropy
 
 __all__ = [
     "register_kernel", "get_kernel", "list_kernels", "dispatch",
     "get_body", "selected_body", "use_pallas", "selection_mode",
     "override", "mesh_scope", "platform", "try_fused_matmul",
     "within_vmem_budget", "DEFAULT_VMEM_BUDGET",
+    "flash_attention", "fused_layer_norm", "softmax_cross_entropy",
+    "grouped_matmul",
 ]

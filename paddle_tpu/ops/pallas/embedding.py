@@ -31,7 +31,7 @@ def _round_up(v, m):
 
 # -- scatter-add -----------------------------------------------------------
 
-def embedding_scatter_add_reference(dst, ids, updates, interpret=None):
+def embedding_scatter_add_reference(dst, ids, updates):
     """Stock body: .at[].add — drops out-of-range ids (JAX default)."""
     return jnp.asarray(dst).at[jnp.asarray(ids)].add(jnp.asarray(updates))
 

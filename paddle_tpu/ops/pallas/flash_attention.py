@@ -1,29 +1,11 @@
-"""Pallas TPU kernels for the hot ops.
+"""Flash attention: blockwise online-softmax attention, forward and
+backward, with the dense attention it is held against.
 
-The reference hand-writes CUDA for its hot paths (operators/math/,
-operators/jit/ xbyak codegen, fused_* ops — SURVEY §2.4); the TPU-native
-equivalent is Pallas (Mosaic) kernels sitting behind the same functional op
-surface. XLA already fuses the easy elementwise chains; these kernels cover
-what fusion can't express:
-
-- flash_attention — blockwise online-softmax attention; the [S, S] score
-  matrix never exists in HBM (the reference materialises scores in
-  operators/math/ softmax + matmul calls). Forward is one Pallas kernel
-  and backward is one: it rebuilds each score tile once, from the saved
-  logsumexp, and takes dQ, dK, dV and the key-bias gradient from it.
-- fused_layer_norm — one VMEM pass for mean/var/normalise/affine.
-- softmax_cross_entropy — fused max/logsumexp/pick in one pass over the
-  vocab axis (the reference's softmax_with_cross_entropy fused op,
-  operators/softmax_with_cross_entropy_op.cc).
-
-All three are registered in the Pallas kernel registry
-(ops/pallas/registry.py) — selection between the Pallas body and a
-stock-jnp reference is the registry's job (`FLAGS_use_pallas_kernels`,
-`PADDLE_TPU_PALLAS`). The public entry points keep their historical
-`interpret=` escape hatch: passing an explicit bool bypasses the
-registry and forces the Pallas body with that interpreter setting
-(tests pin kernel behavior this way); `interpret=None` defers to the
-registry's platform-based selection.
+The [S, S] score matrix never exists in HBM (the reference materialises
+scores in operators/math/ softmax + matmul calls). Forward is one Pallas
+kernel and backward is one: it rebuilds each score tile once, from the
+saved logsumexp, and takes dQ, dK, dV and the key-bias gradient from it.
+Which body a call runs is the registry's choice (``ops/pallas/registry.py``).
 """
 
 import dataclasses
@@ -37,8 +19,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from paddle_tpu.ops.pallas import registry as _registry
+from paddle_tpu.ops.pallas.registry import vmem_spec as _vmem_spec
 
-__all__ = ["flash_attention", "fused_layer_norm", "softmax_cross_entropy"]
+__all__ = ["flash_attention"]
 
 _NEG_INF = -1e30
 
@@ -57,15 +40,6 @@ _FLASH_BWD_COMPILER_PARAMS = dataclasses.replace(
 #: elementwise work (v5e, S=4096: 8 tiles 12.0 ms a call, 4 12.6, 1 13.8)
 _FLASH_BWD_UNROLL = 8
 
-
-def _vmem_spec(*args, **kwargs):
-    kwargs.setdefault("memory_space", pltpu.VMEM)
-    return pl.BlockSpec(*args, **kwargs)
-
-
-# ---------------------------------------------------------------------------
-# flash attention
-# ---------------------------------------------------------------------------
 
 def _masked_scores(qs, k_blk, b_blk, q0, k0, causal, transposed=False):
     """Scaled scores for one (q-block, k-block) tile: qs is pre-scaled
@@ -323,11 +297,10 @@ _flash_attention.defvjp(_flash_attention_fwd, _flash_attention_bwd)
 
 
 def _dense_attention_reference(q, k, v, bias=None, causal=False,
-                               sm_scale=None, block_q=512, block_k=512,
-                               interpret=None):
+                               sm_scale=None, block_q=512, block_k=512):
     """Stock-jnp attention (scores materialized): the semantic reference
-    the flash kernel is pinned against. block_q/block_k/interpret are
-    accepted (and ignored) so both bodies share one signature."""
+    the flash kernel is pinned against. block_q/block_k are accepted (and
+    ignored) so both bodies share one signature."""
     q = jnp.asarray(q)
     k = jnp.asarray(k)
     v = jnp.asarray(v)
@@ -398,269 +371,18 @@ def _flash_attention_pallas(q, k, v, bias=None, causal=False,
 
 
 def flash_attention(q, k, v, bias=None, causal=False, sm_scale=None,
-                    block_q=512, block_k=512, interpret=None):
+                    block_q=512, block_k=512):
     """Blockwise (flash) attention.
 
     q, k, v: [B, H, S, D]. bias: optional [B, S] additive key bias
     (e.g. key-padding mask as 0 / -inf). Returns [B, H, S, D] in q.dtype.
     Sequence is padded to the block size internally (padded keys masked).
-
-    Body selection is the registry's (`FLAGS_use_pallas_kernels`); an
-    explicit ``interpret=`` bool forces the Pallas body.
     """
-    kw = dict(bias=bias, causal=causal, sm_scale=sm_scale,
-              block_q=block_q, block_k=block_k)
-    if interpret is not None:
-        return _flash_attention_pallas(q, k, v, interpret=bool(interpret),
-                                       **kw)
-    return _registry.dispatch("flash_attention", q, k, v, **kw)
-
-
-# ---------------------------------------------------------------------------
-# fused layer norm
-# ---------------------------------------------------------------------------
-
-def _ln_fwd_kernel(x_ref, g_ref, b_ref, y_ref, mu_ref, rstd_ref, *, eps):
-    x = x_ref[:].astype(jnp.float32)
-    mu = jnp.mean(x, axis=-1, keepdims=True)
-    xc = x - mu
-    var = jnp.mean(xc * xc, axis=-1, keepdims=True)
-    rstd = lax.rsqrt(var + eps)
-    y = xc * rstd * g_ref[:].astype(jnp.float32) + b_ref[:].astype(
-        jnp.float32)
-    y_ref[:] = y.astype(y_ref.dtype)
-    mu_ref[:, 0] = mu[:, 0]
-    rstd_ref[:, 0] = rstd[:, 0]
-
-
-def _ln_fwd(x2, g, b, eps, block_n, interpret):
-    n, hdim = x2.shape
-    block_n = min(block_n, n)
-    grid = (pl.cdiv(n, block_n),)
-    y, mu, rstd = pl.pallas_call(
-        functools.partial(_ln_fwd_kernel, eps=eps),
-        grid=grid,
-        in_specs=[
-            _vmem_spec((block_n, hdim), lambda i: (i, 0)),
-            _vmem_spec((hdim,), lambda i: (0,)),
-            _vmem_spec((hdim,), lambda i: (0,)),
-        ],
-        out_specs=[
-            _vmem_spec((block_n, hdim), lambda i: (i, 0)),
-            # stats ride as [n, 1] (bn, 1) blocks: Mosaic's layout for a
-            # bare f32[n] is lane-tiled T(1024) and rejects (bn,) blocks
-            _vmem_spec((block_n, 1), lambda i: (i, 0)),
-            _vmem_spec((block_n, 1), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct(x2.shape, x2.dtype),
-            jax.ShapeDtypeStruct((n, 1), jnp.float32),
-            jax.ShapeDtypeStruct((n, 1), jnp.float32),
-        ],
-        interpret=interpret,
-        name="layer_norm_fwd",
-    )(x2, g, b)
-    return y, mu[:, 0], rstd[:, 0]
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _fused_layer_norm(x2, g, b, eps, block_n, interpret):
-    y, _, _ = _ln_fwd(x2, g, b, eps, block_n, interpret)
-    return y
-
-
-def _fused_ln_fwd(x2, g, b, eps, block_n, interpret):
-    y, mu, rstd = _ln_fwd(x2, g, b, eps, block_n, interpret)
-    return y, (x2, g, mu, rstd)
-
-
-def _fused_ln_bwd(eps, block_n, interpret, res, dy):
-    x2, g, mu, rstd = res
-    x32 = x2.astype(jnp.float32)
-    dy32 = dy.astype(jnp.float32)
-    xhat = (x32 - mu[:, None]) * rstd[:, None]
-    gf = g.astype(jnp.float32)
-    dg = jnp.sum(dy32 * xhat, axis=0)
-    db = jnp.sum(dy32, axis=0)
-    wdy = dy32 * gf
-    c1 = jnp.mean(wdy, axis=-1, keepdims=True)
-    c2 = jnp.mean(wdy * xhat, axis=-1, keepdims=True)
-    dx = (wdy - c1 - xhat * c2) * rstd[:, None]
-    return dx.astype(x2.dtype), dg.astype(g.dtype), db.astype(g.dtype)
-
-
-_fused_layer_norm.defvjp(_fused_ln_fwd, _fused_ln_bwd)
-
-
-def _layer_norm_reference(x, gamma, beta, eps=1e-12, block_n=256,
-                          interpret=None):
-    """Stock-jnp layer norm, bit-identical to models/bert._layer_norm's
-    historical inline math (fp32 stats, x.dtype out)."""
-    x = jnp.asarray(x)
-    x32 = x.astype(jnp.float32)
-    mu = jnp.mean(x32, axis=-1, keepdims=True)
-    var = jnp.mean(jnp.square(x32 - mu), axis=-1, keepdims=True)
-    y = (x32 - mu) * lax.rsqrt(var + eps) \
-        * jnp.asarray(gamma).astype(jnp.float32) \
-        + jnp.asarray(beta).astype(jnp.float32)
-    return y.astype(x.dtype)
-
-
-def _fused_layer_norm_pallas(x, gamma, beta, eps=1e-12, block_n=256,
-                             interpret=False):
-    x = jnp.asarray(x)
-    shape = x.shape
-    hdim = shape[-1]
-    x2 = x.reshape(-1, hdim)
-    n = x2.shape[0]
-    block_n = min(block_n, n)
-    pad = (-n) % block_n
-    if pad:
-        x2 = jnp.pad(x2, ((0, pad), (0, 0)))
-    y = _fused_layer_norm(x2, jnp.asarray(gamma), jnp.asarray(beta),
-                          float(eps), int(block_n), bool(interpret))
-    if pad:
-        y = y[:n]
-    return y.reshape(shape)
-
-
-def fused_layer_norm(x, gamma, beta, eps=1e-12, block_n=256,
-                     interpret=None):
-    """LayerNorm over the last axis in a single VMEM pass.
-
-    x: [..., H]; gamma/beta: [H]. Stats in fp32, output in x.dtype
-    (parity: operators/layer_norm_op.cc; jit/ layernorm kernel).
-    Body selection is the registry's; explicit ``interpret=`` forces the
-    Pallas body.
-    """
-    if interpret is not None:
-        return _fused_layer_norm_pallas(x, gamma, beta, eps=eps,
-                                        block_n=block_n,
-                                        interpret=bool(interpret))
-    return _registry.dispatch("fused_layer_norm", x, gamma, beta, eps=eps,
-                              block_n=block_n)
-
-
-# ---------------------------------------------------------------------------
-# fused softmax cross-entropy
-# ---------------------------------------------------------------------------
-
-def _xent_kernel(logits_ref, labels_ref, loss_ref, lse_ref):
-    x = logits_ref[:].astype(jnp.float32)                  # [bn, V]
-    lab = labels_ref[:, 0]                                 # [bn]
-    m = jnp.max(x, axis=-1)
-    lse = m + jnp.log(jnp.sum(jnp.exp(x - m[:, None]), axis=-1))
-    cols = lax.broadcasted_iota(jnp.int32, x.shape, 1)
-    picked = jnp.sum(jnp.where(cols == lab[:, None], x, 0.0), axis=-1)
-    loss_ref[:, 0] = lse - picked
-    lse_ref[:, 0] = lse
-
-
-def _xent_fwd_call(logits2, labels1, block_n, interpret):
-    n, v = logits2.shape
-    block_n = min(block_n, n)
-    grid = (pl.cdiv(n, block_n),)
-    # 1-D vectors ride as [n, 1] blocks (bn, 1): Mosaic's layout for a
-    # bare s32/f32[n] is lane-tiled T(1024) and rejects (bn,) blocks
-    loss, lse = pl.pallas_call(
-        _xent_kernel,
-        grid=grid,
-        in_specs=[
-            _vmem_spec((block_n, v), lambda i: (i, 0)),
-            _vmem_spec((block_n, 1), lambda i: (i, 0)),
-        ],
-        out_specs=[
-            _vmem_spec((block_n, 1), lambda i: (i, 0)),
-            _vmem_spec((block_n, 1), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n, 1), jnp.float32),
-            jax.ShapeDtypeStruct((n, 1), jnp.float32),
-        ],
-        interpret=interpret,
-        name="softmax_xent_fwd",
-    )(logits2, labels1[:, None])
-    return loss[:, 0], lse[:, 0]
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
-def _softmax_xent(logits2, labels1, block_n, interpret):
-    loss, _ = _xent_fwd_call(logits2, labels1, block_n, interpret)
-    return loss
-
-
-def _softmax_xent_fwd(logits2, labels1, block_n, interpret):
-    loss, lse = _xent_fwd_call(logits2, labels1, block_n, interpret)
-    return loss, (logits2, labels1, lse)
-
-
-def _softmax_xent_bwd(block_n, interpret, res, dloss):
-    logits2, labels1, lse = res
-    x = logits2.astype(jnp.float32)
-    p = jnp.exp(x - lse[:, None])
-    onehot = jax.nn.one_hot(labels1, x.shape[-1], dtype=jnp.float32)
-    dx = (p - onehot) * dloss[:, None]
-    return dx.astype(logits2.dtype), None
-
-
-_softmax_xent.defvjp(_softmax_xent_fwd, _softmax_xent_bwd)
-
-
-def _xent_reference(logits, labels, block_n=128, interpret=None):
-    """Stock-jnp softmax cross-entropy (fp32 max/logsumexp/pick)."""
-    logits = jnp.asarray(logits)
-    labels = jnp.asarray(labels, jnp.int32)
-    x = logits.astype(jnp.float32)
-    m = jnp.max(x, axis=-1)
-    lse = m + jnp.log(jnp.sum(jnp.exp(x - m[..., None]), axis=-1))
-    picked = jnp.take_along_axis(x, labels[..., None],
-                                 axis=-1)[..., 0]
-    return lse - picked
-
-
-def _softmax_xent_pallas(logits, labels, block_n=128, interpret=False):
-    logits = jnp.asarray(logits)
-    labels = jnp.asarray(labels, jnp.int32)
-    v = logits.shape[-1]
-    lead = logits.shape[:-1]
-    logits2 = logits.reshape(-1, v)
-    labels1 = labels.reshape(-1)
-    n = logits2.shape[0]
-    # cap the row block so one (block_n, V) fp32 tile (double-buffered)
-    # stays well under the ~16MB VMEM budget even at LM vocab sizes
-    vmem_rows = max(8, (4 << 20) // max(4 * v, 1) // 8 * 8)
-    block_n = min(block_n, vmem_rows, n)
-    pad = (-n) % block_n
-    if pad:
-        logits2 = jnp.pad(logits2, ((0, pad), (0, 0)))
-        labels1 = jnp.pad(labels1, (0, pad))
-    loss = _softmax_xent(logits2, labels1, int(block_n), bool(interpret))
-    if pad:
-        loss = loss[:n]
-    return loss.reshape(lead)
-
-
-def softmax_cross_entropy(logits, labels, block_n=128, interpret=None):
-    """Fused per-example softmax cross-entropy.
-
-    logits: [..., V]; labels: [...] int. Returns [...] fp32 losses.
-    One pass computes max, logsumexp, and the label pick (parity:
-    operators/softmax_with_cross_entropy_op.cc fused op). Body selection
-    is the registry's; explicit ``interpret=`` forces the Pallas body.
-    """
-    if interpret is not None:
-        return _softmax_xent_pallas(logits, labels, block_n=block_n,
-                                    interpret=bool(interpret))
-    return _registry.dispatch("softmax_cross_entropy", logits, labels,
-                              block_n=block_n)
+    return _registry.dispatch(
+        "flash_attention", q, k, v, bias=bias, causal=causal,
+        sm_scale=sm_scale, block_q=block_q, block_k=block_k)
 
 
 _registry.register_kernel(
     "flash_attention", _dense_attention_reference, _flash_attention_pallas,
     doc="blockwise online-softmax attention; [S,S] scores never in HBM")
-_registry.register_kernel(
-    "fused_layer_norm", _layer_norm_reference, _fused_layer_norm_pallas,
-    doc="one-VMEM-pass layer norm (fp32 stats)")
-_registry.register_kernel(
-    "softmax_cross_entropy", _xent_reference, _softmax_xent_pallas,
-    doc="fused max/logsumexp/pick over the vocab axis")

@@ -246,7 +246,7 @@ def _grouped_matmul_bwd(interpret, res, dout):
 _grouped_matmul.defvjp(_grouped_matmul_fwd, _grouped_matmul_bwd)
 
 
-def grouped_matmul_reference(lhs, rhs, group_sizes, interpret=None):
+def grouped_matmul_reference(lhs, rhs, group_sizes):
     return lax.ragged_dot(lhs, rhs, group_sizes.astype(jnp.int32),
                           preferred_element_type=lhs.dtype)
 
@@ -256,15 +256,11 @@ def grouped_matmul_pallas(lhs, rhs, group_sizes, interpret=False):
                            bool(interpret))
 
 
-def grouped_matmul(lhs, rhs, group_sizes, interpret=None):
+def grouped_matmul(lhs, rhs, group_sizes):
     """Each row of ``lhs`` [M, K] times the matrix of its group in ``rhs``
     [G, K, N]; rows sorted by group, ``group_sizes`` [G] int. Returns
     [M, N] in ``lhs.dtype`` (float32 accumulation). Differentiable in
-    ``lhs`` and ``rhs``. Body selection is the registry's; an explicit
-    ``interpret=`` forces the Pallas body."""
-    if interpret is not None:
-        return grouped_matmul_pallas(lhs, rhs, group_sizes,
-                                     interpret=bool(interpret))
+    ``lhs`` and ``rhs``."""
     return _registry.dispatch("grouped_matmul", lhs, rhs, group_sizes)
 
 

@@ -22,9 +22,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 from paddle_tpu.ops.pallas import registry as _registry
+from paddle_tpu.ops.pallas.registry import vmem_spec as _vmem_spec
 
 __all__ = ["try_fused_matmul"]
 
@@ -41,11 +41,6 @@ _ACTS = {
     "tanh": jnp.tanh,
     "gelu": lambda v: jax.nn.gelu(v, approximate=False),
 }
-
-
-def _vmem_spec(*args, **kwargs):
-    kwargs.setdefault("memory_space", pltpu.VMEM)
-    return pl.BlockSpec(*args, **kwargs)
 
 
 def _round_up(v, m):
@@ -207,8 +202,7 @@ def fused_matmul_pallas(x, w, bias=None, act=None, out_dtype=None,
     return out.reshape(lead + (w.shape[1],))
 
 
-def fused_matmul_reference(x, w, bias=None, act=None, out_dtype=None,
-                           interpret=None):
+def fused_matmul_reference(x, w, bias=None, act=None, out_dtype=None):
     """Stock composition: exactly what _fused_matmul_compute lowers for
     the eligible operand pattern (2-D weight, trailing-axis bias)."""
     out = jnp.matmul(jnp.asarray(x), jnp.asarray(w))
@@ -239,8 +233,7 @@ def fused_matmul_int8_pallas(x, w, scale, bias=None, act=None,
     return out.astype(out_dtype).reshape(lead + (w.shape[1],))
 
 
-def fused_matmul_int8_reference(x, w, scale, bias=None, act=None,
-                                interpret=None):
+def fused_matmul_int8_reference(x, w, scale, bias=None, act=None):
     """The existing sidecar-dequant composition (opt_passes PTQ path):
     materialize the fp32 weight, then the stock matmul chain."""
     wd = jnp.asarray(w).astype(jnp.float32) \

@@ -1,23 +1,24 @@
-"""Pallas kernel registry: ONE selection/fallback/flag home.
+"""Pallas kernel registry: ONE selection/fallback home.
 
 Mirrors the op-registry pattern (``register_op`` in
 ``static/opt_passes.py``): each registered kernel declares a stock-jnp
 **reference** body and an optional **Pallas** body. Selection happens at
-trace/compile time:
+trace/compile time, from what the code observes:
 
-- ``auto`` (default): Pallas body on an accelerator, stock reference on
-  CPU — tier-1 stays on the exact jnp semantics it always had. Inside a
-  :func:`mesh_scope` of more than one device ``auto`` also selects the
-  reference: Mosaic calls are not partitioned by GSPMD (the lowering
-  refuses them), so a step traced for a multi-device mesh takes the
-  bodies XLA can partition. Which body is faster there is not measured.
-- ``on``: force the Pallas body everywhere; on CPU it runs in Pallas
-  interpreter mode (the same kernel code path the TPU compiles).
-- ``off``: force the stock reference everywhere.
+- the platform: the Pallas body on an accelerator, the stock reference on
+  the CPU — tier-1 stays on the exact jnp semantics it always had;
+- :func:`mesh_scope`: inside a mesh of more than one device the
+  reference, because Mosaic calls are not partitioned by GSPMD (the
+  lowering refuses them), so a step traced for a multi-device mesh takes
+  the bodies XLA can partition. Which body is faster there is not
+  measured.
 
-Override via ``FLAGS_use_pallas_kernels=auto|on|off`` (core/flags.py),
-the short env ``PADDLE_TPU_PALLAS=0|1``, or the :func:`override` context
-manager for in-process A/B (bench.py kernels mode, parity tests).
+Nothing a user sets takes part: no flag, no environment variable. A body
+that loses on the chip is deleted, not switched off. Tests and A/B
+harnesses force a body for their own thread with :func:`override`:
+``on`` is the Pallas body everywhere (on the CPU in Pallas interpreter
+mode, the same kernel code the TPU compiles), ``off`` the reference
+everywhere, ``auto`` the selection above.
 
 Every selection change is published through the
 ``pallas_kernels_selected{kernel,body}`` gauge so a running job's kernel
@@ -27,16 +28,16 @@ selection is inspectable from the metrics snapshot
 
 import contextlib
 import functools
-import os
 import threading
 
-from paddle_tpu.core.flags import define_flag, get_flag
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = [
     "register_kernel", "get_kernel", "list_kernels", "dispatch",
     "get_body", "selected_body", "use_pallas", "selection_mode",
     "override", "mesh_scope", "platform", "within_vmem_budget",
-    "DEFAULT_VMEM_BUDGET",
+    "vmem_spec", "DEFAULT_VMEM_BUDGET",
 ]
 
 #: fp32 elements one operand may hold whole in VMEM: 8 MiB, half of
@@ -51,23 +52,7 @@ _REGISTRY = {}
 _lock = threading.Lock()
 _tls = threading.local()
 
-# PADDLE_TPU_PALLAS=0|1 is the short A/B switch; FLAGS_use_pallas_kernels
-# (read by define_flag from the env) wins when both are set, matching the
-# flag system's precedence for every other flag.
-_env_short = os.environ.get("PADDLE_TPU_PALLAS")
-define_flag(
-    "use_pallas_kernels",
-    {"0": "off", "1": "on"}.get(_env_short, "auto"),
-    "Pallas kernel registry selection: 'auto' = Pallas bodies on an "
-    "accelerator, stock jnp reference on CPU; 'on' = force Pallas "
-    "(interpreter mode on CPU); 'off' = force the stock reference. "
-    "Short env form: PADDLE_TPU_PALLAS=0|1 (ops/pallas/registry.py)")
-
-_MODE_ALIASES = {
-    "auto": "auto", "": "auto", "default": "auto",
-    "on": "on", "1": "on", "true": "on", "yes": "on",
-    "off": "off", "0": "off", "false": "off", "no": "off",
-}
+_MODES = ("auto", "on", "off")
 
 
 class Kernel:
@@ -116,19 +101,18 @@ def platform():
 
 
 def selection_mode():
-    """Effective mode: an :func:`override` beats the flag."""
+    """Effective mode: the innermost :func:`override`, else 'auto'."""
     ov = getattr(_tls, "override", None)
-    if ov:
-        return ov[-1]
-    return _MODE_ALIASES.get(str(get_flag("use_pallas_kernels")).lower(),
-                             "auto")
+    return ov[-1] if ov else "auto"
 
 
 @contextlib.contextmanager
 def override(mode):
     """Force selection for the current thread: 'on' | 'off' | 'auto'.
-    Nestable; used by the bench kernels mode and the parity tests."""
-    mode = _MODE_ALIASES[str(mode).lower()]
+    Nestable; used by the parity tests, ``tools/op_tester.py --pallas``
+    and ``chip_smoke.py``."""
+    if mode not in _MODES:
+        raise ValueError(f"override mode {mode!r}: one of {_MODES}")
     stack = getattr(_tls, "override", None)
     if stack is None:
         stack = _tls.override = []
@@ -208,6 +192,13 @@ def _note_selection(name, body):
         pass
 
 
+def vmem_spec(*args, **kwargs):
+    """A ``pl.BlockSpec`` whose block lives in VMEM unless told otherwise:
+    what the kernel modules' in_specs and out_specs are made of."""
+    kwargs.setdefault("memory_space", pltpu.VMEM)
+    return pl.BlockSpec(*args, **kwargs)
+
+
 def within_vmem_budget(kernel, elements, budget=None):
     """True when a kernel body planning to hold ``elements`` fp32
     elements whole in VMEM fits under ``budget`` (default
@@ -243,12 +234,11 @@ def get_body(name, which):
 
 def dispatch(name, *args, **kwargs):
     """Run the selected body. The Pallas body receives ``interpret=``
-    resolved from the platform probe (unless the caller already forced
-    it)."""
+    resolved from the platform probe; the reference body has no such
+    keyword."""
     k = _REGISTRY[name]
     body = selected_body(name)
     _note_selection(name, body)
     if body == "reference":
         return k.reference(*args, **kwargs)
-    kwargs.setdefault("interpret", body == "pallas_interpret")
-    return k.pallas(*args, **kwargs)
+    return k.pallas(*args, interpret=body == "pallas_interpret", **kwargs)

@@ -412,31 +412,3 @@ class TestBenchModes:
         # per-channel int8 weight-only on a 3-layer MLP: relative
         # output error stays at the percent level
         assert 0 <= acc["value"] < 0.05, acc
-
-    def test_kernels_mode_emits_per_kernel_ab_rows(self):
-        """`bench.py kernels` must A/B every registered Pallas kernel
-        against its stock reference (interleaved ABBA windows) and emit
-        one JSON line per kernel. On CPU the Pallas side runs in
-        interpreter mode, so the ratio is a liveness check of the TPU
-        kernel code path, not a perf claim — the sanity band only
-        rejects rot (a ratio of 0 or thousands means a body stopped
-        doing the work or hung)."""
-        lines = _run_mode("kernels",
-                          extra_env={"BENCH_KERNELS_PAIRS": "1",
-                                     "BENCH_KERNELS_ITERS": "1"})
-        by = {ln["metric"]: ln for ln in lines}
-        expected = [
-            "kernel_matmul_ratio", "kernel_matmul_int8_ratio",
-            "kernel_scatter_add_ratio", "kernel_attention_ratio",
-            "kernel_layer_norm_ratio", "kernel_xent_ratio",
-        ]
-        for tag in expected:
-            row = by.get(tag)
-            assert row is not None, sorted(by)
-            assert row["unit"] == "x"
-            assert row["body"] == "pallas_interpret"
-            assert row["platform"] == "cpu"
-            assert row["pallas_ms"] > 0 and row["stock_ms"] > 0
-            # interpreter-mode sanity band: wide on purpose (shared CI
-            # hosts drift), but catches a dead or wedged body
-            assert 1e-3 < row["value"] < 1e3, row

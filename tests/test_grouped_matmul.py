@@ -1,6 +1,7 @@
 """The grouped matmul of the dropless expert layer
 (``ops/pallas/grouped_matmul.py``): both bodies, and their gradients,
-against a loop over the groups; the Pallas body in interpret mode."""
+against a loop over the groups; the Pallas body in interpret mode, chosen
+the registry's way (``override``)."""
 
 import jax
 import jax.numpy as jnp
@@ -8,7 +9,10 @@ import numpy as np
 import pytest
 
 from paddle_tpu.ops import pallas as plk
-from paddle_tpu.ops.pallas import grouped_matmul as gm
+from paddle_tpu.ops.pallas.grouped_matmul import _work_list
+
+#: the override that makes the registry hand out each body on the CPU
+OVERRIDE = {"reference": "off", "pallas_interpret": "on"}
 
 
 def loop_over_groups(lhs, rhs, sizes):
@@ -44,11 +48,11 @@ def test_product_and_gradients_match_the_loop_over_groups(case, body):
     lhs = jnp.asarray(rs.randn(m, k), jnp.float32)
     rhs = jnp.asarray(rs.randn(len(sizes), k, n), jnp.float32)
     w = jnp.asarray(rs.randn(m, n), jnp.float32)
-    interpret = True if body == "pallas_interpret" else None
 
     def f(lhs, rhs):
-        return gm.grouped_matmul(lhs, rhs, jnp.asarray(sizes),
-                                 interpret=interpret)
+        with plk.override(OVERRIDE[body]):
+            assert plk.selected_body("grouped_matmul") == body
+            return plk.grouped_matmul(lhs, rhs, jnp.asarray(sizes))
 
     want = loop_over_groups(lhs, rhs, sizes)
     np.testing.assert_allclose(np.asarray(f(lhs, rhs)), np.asarray(want),
@@ -65,7 +69,7 @@ def test_product_and_gradients_match_the_loop_over_groups(case, body):
 
 def test_the_work_list_visits_each_tile_of_each_group_once_in_order():
     sizes = jnp.asarray([0, 300, 1, 0, 255, 100, 0], jnp.int32)
-    offsets, groups, tiles, n_work = gm._work_list(sizes, 768, 256)
+    offsets, groups, tiles, n_work = _work_list(sizes, 768, 256)
     n = int(n_work[0])
     assert offsets.tolist() == [0, 0, 300, 301, 301, 556, 656, 656]
     visits = list(zip(groups[:n].tolist(), tiles[:n].tolist()))
@@ -82,19 +86,19 @@ def test_bfloat16_rows_take_float32_master_weights():
     lhs = jax.random.normal(jax.random.PRNGKey(0), (128, 64), jnp.bfloat16)
     rhs = jax.random.normal(jax.random.PRNGKey(1), (3, 64, 128))
 
-    def f(lhs, rhs, interpret):
-        return gm.grouped_matmul(lhs, rhs.astype(lhs.dtype), sizes,
-                                 interpret=interpret)
+    def f(lhs, rhs, mode):
+        with plk.override(mode):
+            return plk.grouped_matmul(lhs, rhs.astype(lhs.dtype), sizes)
 
-    out = f(lhs, rhs, True)
+    out = f(lhs, rhs, "on")
     assert out.dtype == jnp.bfloat16
     np.testing.assert_allclose(
         np.asarray(out, np.float32),
         np.asarray(loop_over_groups(lhs.astype(jnp.float32), rhs,
                                     np.asarray(sizes))), rtol=2e-2, atol=0.1)
-    for interpret in (True, None):
+    for mode in ("on", "off"):
         dl, dr = jax.grad(lambda a, b: jnp.sum(
-            f(a, b, interpret).astype(jnp.float32)), (0, 1))(lhs, rhs)
+            f(a, b, mode).astype(jnp.float32)), (0, 1))(lhs, rhs)
         assert dl.dtype == jnp.bfloat16 and dr.dtype == jnp.float32
 
 
@@ -106,4 +110,4 @@ def test_the_registry_selects_between_the_two_bodies():
         sizes = jnp.asarray([5, 3], jnp.int32)
         lhs, rhs = jnp.ones((8, 128)), jnp.ones((2, 128, 128))
         np.testing.assert_allclose(
-            np.asarray(gm.grouped_matmul(lhs, rhs, sizes)), 128.0)
+            np.asarray(plk.grouped_matmul(lhs, rhs, sizes)), 128.0)

@@ -222,7 +222,7 @@ FUSED_FAMILY = {
     "fused_elemwise_activation":
         "paddle_tpu.contrib.layers.fused_elemwise_activation",
     "conv2d_fusion": "paddle_tpu.layers.conv2d_fusion",
-    "flash_attention": "paddle_tpu.ops.pallas_kernels.flash_attention",
+    "flash_attention": "paddle_tpu.ops.pallas.flash_attention",
 }
 
 

@@ -2,7 +2,9 @@
 
 Pattern: every kernel checked against its dense jnp reference, values and
 gradients (the reference's OpTest numeric-vs-analytic discipline,
-unittests/op_test.py).
+unittests/op_test.py). On the CPU the registry's own choice is the
+reference body, so the file's fixture forces the Pallas body for every
+call here, and ``test_entry_point_runs_the_pallas_body`` holds that it does.
 """
 
 import jax
@@ -10,7 +12,65 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from paddle_tpu.ops import pallas_kernels as K
+from paddle_tpu.ops import pallas as K
+from paddle_tpu.ops.selected_rows import (
+    SelectedRows, get_tensor_from_selected_rows,
+)
+
+
+@pytest.fixture(autouse=True)
+def pallas_bodies():
+    with K.override("on"):
+        yield
+
+
+def _matmul_ins(quant):
+    x = jnp.ones((8, 16), jnp.float32)
+    if quant == "int8":
+        return {"X": [x, jnp.ones((16, 8), jnp.int8), jnp.ones((8,))]}
+    return {"X": [x, jnp.ones((16, 8), jnp.float32)]}
+
+
+#: every registered kernel through the entry point the program calls it by
+ENTRY_POINTS = {
+    "flash_attention": lambda: K.flash_attention(
+        *(jnp.ones((1, 1, 128, 16)),) * 3),
+    "fused_layer_norm": lambda: K.fused_layer_norm(
+        jnp.ones((4, 8)), jnp.ones((8,)), jnp.zeros((8,))),
+    "softmax_cross_entropy": lambda: K.softmax_cross_entropy(
+        jnp.ones((4, 8)), jnp.zeros((4,), jnp.int32)),
+    "grouped_matmul": lambda: K.grouped_matmul(
+        jnp.ones((8, 128)), jnp.ones((2, 128, 128)),
+        jnp.asarray([5, 3], jnp.int32)),
+    "fused_matmul": lambda: K.try_fused_matmul(
+        _matmul_ins(None), {"mm_type": "matmul"}),
+    "fused_matmul_int8": lambda: K.try_fused_matmul(
+        _matmul_ins("int8"), {"mm_type": "matmul", "quant": "int8"}),
+    "embedding_scatter_add": lambda: get_tensor_from_selected_rows(
+        SelectedRows(jnp.asarray([2, 5, 2], jnp.int32), jnp.ones((3, 6)), 9)),
+}
+
+
+def test_every_registered_kernel_has_an_entry_point_here():
+    assert sorted(ENTRY_POINTS) == K.list_kernels()
+
+
+@pytest.mark.parametrize("name", sorted(ENTRY_POINTS))
+def test_entry_point_runs_the_pallas_body(name, monkeypatch):
+    """Under the file's fixture a bare call of a kernel's entry point runs
+    its Pallas body, in interpreter mode on the CPU."""
+    assert K.selected_body(name) == (
+        "pallas_interpret" if K.platform() == "cpu" else "pallas")
+    kernel = K.get_kernel(name)
+    body, calls = kernel.pallas, []
+
+    def spy(*args, **kwargs):
+        calls.append(kwargs["interpret"])
+        return body(*args, **kwargs)
+
+    monkeypatch.setattr(kernel, "pallas", spy)
+    assert ENTRY_POINTS[name]() is not None
+    assert calls == [K.platform() == "cpu"]
 
 
 def _dense_attention(q, k, v, bias=None, causal=False):
@@ -147,7 +207,7 @@ class TestFlashAttention:
 
         def f_flash(q, k, v, bias):
             out = K.flash_attention(q, k, v, bias=bias, causal=causal,
-                                    block_q=bq, block_k=bk, interpret=True)
+                                    block_q=bq, block_k=bk)
             return jnp.sum(out.astype(jnp.float32) * w)
 
         def f_dense(q, k, v, bias):
@@ -164,12 +224,12 @@ class TestFlashAttention:
 
     def test_causal_at_head_size_128_matches_dense(self):
         """OLMoE's shape of attention (16 heads of 128, causal) through the
-        Pallas bodies in interpret mode, two blocks each way: the forward
-        and all three gradients."""
+        Pallas bodies, two blocks each way: the forward and all three
+        gradients."""
         q, k, v = self._rand(b=1, h=1, s=512, d=128, seed=5)
         w = jnp.asarray(np.random.RandomState(6).randn(1, 1, 512, 128)
                         .astype(np.float32))
-        blocks = dict(block_q=256, block_k=256, interpret=True)
+        blocks = dict(block_q=256, block_k=256)
 
         def f_flash(q, k, v):
             return jnp.sum(K.flash_attention(q, k, v, causal=True, **blocks)

@@ -7,6 +7,11 @@ duplicate-index scatter-adds). On CPU the Pallas bodies run in
 interpreter mode: the same kernel code the TPU compiles, so these tests
 pin TPU semantics from the CI host."""
 
+import json
+import os
+import subprocess
+import sys
+
 import numpy as np
 import pytest
 
@@ -14,10 +19,13 @@ import jax
 import jax.numpy as jnp
 
 import paddle_tpu.ops.pallas as plk
-from paddle_tpu.core.flags import set_flags
-from paddle_tpu.ops import pallas_kernels as pk
 
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 RNG = np.random.RandomState(42)
+
+KERNELS = ["embedding_scatter_add", "flash_attention", "fused_layer_norm",
+           "fused_matmul", "fused_matmul_int8", "grouped_matmul",
+           "softmax_cross_entropy"]
 
 
 def _f(shape, dtype=jnp.float32, scale=1.0):
@@ -45,10 +53,7 @@ def _tree_close(a, b, dtype=jnp.float32, **kw):
 class TestRegistry:
     def test_all_kernels_registered(self):
         names = plk.list_kernels()
-        for want in ("fused_matmul", "fused_matmul_int8",
-                     "embedding_scatter_add", "grouped_matmul",
-                     "flash_attention", "fused_layer_norm",
-                     "softmax_cross_entropy"):
+        for want in KERNELS:
             assert want in names
         # the optimizer's rules are plain jnp on every leaf: no kernel
         assert not [n for n in names if "adam" in n or "sgd" in n
@@ -66,22 +71,38 @@ class TestRegistry:
         with plk.override("off"):
             assert plk.selected_body("fused_matmul") == "reference"
 
-    def test_flag_controls_selection(self):
-        if plk.platform() != "cpu":
-            pytest.skip("CPU selection table")
-        old = None
-        from paddle_tpu.core.flags import get_flag
-        old = get_flag("use_pallas_kernels")
-        try:
-            set_flags({"use_pallas_kernels": "on"})
-            assert plk.selected_body("fused_matmul") == "pallas_interpret"
-            set_flags({"use_pallas_kernels": "off"})
-            assert plk.selected_body("fused_matmul") == "reference"
-            # an override context beats the flag
-            with plk.override("on"):
-                assert plk.use_pallas("fused_matmul")
-        finally:
-            set_flags({"use_pallas_kernels": old})
+    #: what a fresh interpreter, told nothing but this, prints as JSON.
+    #: The package alone registers everything and there is one kernel home;
+    #: the selectors a person could once set choose nothing.
+    FRESH = {
+        "the_package_alone_registers_every_kernel": (
+            "import paddle_tpu.ops.pallas as p; print(json.dumps("
+            "p.list_kernels()))", {}, KERNELS),
+        "the_old_module_is_gone": (
+            "import importlib.util as u, paddle_tpu.ops as o; print(json.dumps("
+            "u.find_spec(o.__name__ + '.pallas_kernels') is None))", {}, True),
+        "flag_and_variable_select_nothing": (
+            "import paddle_tpu.ops.pallas as p; print(json.dumps("
+            "[p.selected_body(k) for k in p.list_kernels()]))",
+            {"PADDLE_TPU_PALLAS": "1", "FLAGS_use_pallas_kernels": "on"},
+            ["reference"] * len(KERNELS)),
+    }
+
+    @pytest.mark.parametrize("case", sorted(FRESH))
+    def test_in_a_fresh_interpreter(self, case):
+        code, env, want = self.FRESH[case]
+        env = dict(os.environ, JAX_PLATFORMS="cpu", **env)
+        env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
+        r = subprocess.run([sys.executable, "-c", "import json; " + code],
+                           capture_output=True, text=True, timeout=300,
+                           env=env, cwd=REPO)
+        assert r.returncode == 0, r.stderr[-2000:]
+        assert json.loads(r.stdout.splitlines()[-1]) == want
+
+    def test_override_refuses_what_is_not_a_mode(self):
+        with pytest.raises(ValueError, match="one of"):
+            with plk.override("1"):
+                pass
 
     def test_reference_only_kernel_never_selects_pallas(self):
         plk.register_kernel("_test_ref_only", lambda x: x + 1)
@@ -316,12 +337,12 @@ class TestMigratedKernels:
             out = body(q, k, v, bias=bias, causal=causal)
             return jnp.sum(out.astype(jnp.float32) ** 2)
 
-        ref = pk._dense_attention_reference
+        ref = plk.get_body("flash_attention", "reference")
         lr, gr = jax.value_and_grad(
             lambda *a: loss(ref, *a), (0, 1, 2))(q, k, v)
         with plk.override("on"):
             lp, gp = jax.value_and_grad(
-                lambda *a: loss(pk.flash_attention, *a), (0, 1, 2))(
+                lambda *a: loss(plk.flash_attention, *a), (0, 1, 2))(
                 q, k, v)
         tol = dict(rtol=5e-2, atol=5e-2) if dtype == jnp.bfloat16 \
             else dict(rtol=2e-4, atol=2e-4)
@@ -337,12 +358,12 @@ class TestMigratedKernels:
         def loss(body, x, g, b):
             return jnp.sum(body(x, g, b).astype(jnp.float32) ** 2)
 
-        ref = pk._layer_norm_reference
+        ref = plk.get_body("fused_layer_norm", "reference")
         lr, gr = jax.value_and_grad(
             lambda *a: loss(ref, *a), (0, 1, 2))(x, g, b)
         with plk.override("on"):
             lp, gp = jax.value_and_grad(
-                lambda *a: loss(pk.fused_layer_norm, *a), (0, 1, 2))(
+                lambda *a: loss(plk.fused_layer_norm, *a), (0, 1, 2))(
                 x, g, b)
         tol = dict(rtol=5e-2, atol=5e-1) if dtype == jnp.bfloat16 \
             else dict(rtol=1e-4, atol=1e-3)
@@ -355,8 +376,8 @@ class TestMigratedKernels:
         if plk.platform() != "cpu":
             pytest.skip("CPU selection table")
         x, g, b = _f((7, 64)), _f((64,)), _f((64,))
-        a = pk.fused_layer_norm(x, g, b)
-        r = pk._layer_norm_reference(x, g, b)
+        a = plk.fused_layer_norm(x, g, b)
+        r = plk.get_body("fused_layer_norm", "reference")(x, g, b)
         np.testing.assert_array_equal(np.asarray(a), np.asarray(r))
 
     @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
@@ -367,21 +388,12 @@ class TestMigratedKernels:
         def loss(body, lg):
             return jnp.sum(body(lg, labels))
 
-        ref = pk._xent_reference
+        ref = plk.get_body("softmax_cross_entropy", "reference")
         lr, gr = jax.value_and_grad(lambda lg: loss(ref, lg))(logits)
         with plk.override("on"):
             lp, gp = jax.value_and_grad(
-                lambda lg: loss(pk.softmax_cross_entropy, lg))(logits)
+                lambda lg: loss(plk.softmax_cross_entropy, lg))(logits)
         tol = dict(rtol=5e-2, atol=5e-2) if dtype == jnp.bfloat16 \
             else dict(rtol=1e-4, atol=1e-4)
         _close(lr, lp, dtype, **tol)
         _close(gr, gp, dtype, **tol)
-
-    def test_explicit_interpret_bypasses_registry(self):
-        """interpret= pins the Pallas body regardless of selection mode
-        (the legacy escape hatch tests rely on)."""
-        x, g, b = _f((4, 64)), _f((64,)), _f((64,))
-        with plk.override("off"):
-            y = pk.fused_layer_norm(x, g, b, interpret=True)
-        _close(y, pk._layer_norm_reference(x, g, b), rtol=1e-5,
-               atol=1e-5)

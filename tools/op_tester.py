@@ -27,7 +27,6 @@ def _ops(preset):
 
     import paddle_tpu.layers as L
     from paddle_tpu.ops import pallas as PLK
-    from paddle_tpu.ops import pallas_kernels as PK
 
     big = preset == "bench"
     B = 8 if big else 2
@@ -55,15 +54,15 @@ def _ops(preset):
                             (r(B, S, H), r(B, S, H)), None),
         "reduce_sum": (lambda x: x.sum(axis=-1), (r(B, S, H),), None),
         "softmax": (lambda x: jax.nn.softmax(x, -1), (r(B, S, S),), None),
-        "layer_norm": (lambda x, g, b: PK.fused_layer_norm(x, g, b),
+        "layer_norm": (lambda x, g, b: PLK.fused_layer_norm(x, g, b),
                        (r(B * S, H, dtype=jnp.float32),
                         jnp.ones((H,)), jnp.zeros((H,))), None),
         "softmax_cross_entropy":
-            (lambda x, y: PK.softmax_cross_entropy(x, y).mean(),
+            (lambda x, y: PLK.softmax_cross_entropy(x, y).mean(),
              (r(B * S, V, dtype=jnp.float32),
               jax.random.randint(key, (B * S,), 0, V)), None),
         "flash_attention":
-            (lambda q, k, v: PK.flash_attention(q, k, v),
+            (lambda q, k, v: PLK.flash_attention(q, k, v),
              (r(B, 12, S, 64), r(B, 12, S, 64), r(B, 12, S, 64)),
              4 * B * 12 * S * S * 64),
         "dense_attention":
