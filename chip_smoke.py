@@ -54,7 +54,9 @@ GLOBAL_BATCH, SEQ, MAX_PREDS, STEPS = 64, 512, 80, 6
 #: this file on the CPU at toy sizes in interpreter mode, rebinds them.
 VOCAB, HIDDEN, FFN = 30522, 768, 3072
 TABLE_ROWS, TABLE_DIM, SLOTS = 100000, 16, 26
-FLASH_IN_BERT = ((2048, 2), (4096, 1))      # (sequence, batch)
+#: (sequence, batch): the cell mlm_s512's one-tile programs, several heads
+#: each, and the many-tile programs of 2048 and 4096 positions
+FLASH_IN_BERT = ((512, 64), (2048, 2), (4096, 1))
 INTERPRET = False
 #: |four-chip first-step loss - one-chip first-step loss|. The loss is
 #: ~10.3 (ln 30522) through bf16 activations (eps 2^-8); different bodies

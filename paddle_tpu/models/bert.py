@@ -25,7 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from paddle_tpu.models.blocks import FLASH_FROM
+from paddle_tpu.models.blocks import attention_body
 from paddle_tpu.ops.pallas.registry import mesh_scope
 from paddle_tpu.parallel.mesh import (
     DATA_AXIS, MODEL_AXIS, SEQ_AXIS, get_mesh,
@@ -48,7 +48,8 @@ class BertConfig:
     dropout: float = 0.1
     dtype: object = jnp.bfloat16     # activation/compute dtype
     remat: bool = True               # jax.checkpoint per block
-    # "auto": dense up to blocks.FLASH_FROM positions, flash beyond.
+    # "auto": blocks.attention_body's choice (flash from blocks.FLASH_FROM
+    # positions on where the Pallas body runs, dense below and on a mesh).
     # "dense": GSPMD gathers K/V over "seq"; "ring": blockwise ring
     # attention (parallel/ring_attention.py) — K/V never materialised
     # whole, permutes ride ICI neighbor links. Use "ring" for long-context
@@ -200,18 +201,17 @@ def _attention(lp, x, mask_bias, cfg, mesh=None, key_padding_mask=None):
 
     impl = cfg.attention_impl
     if impl == "auto":
-        # XLA's fused dense attention up to FLASH_FROM positions (the
-        # cell mlm_s512), the Pallas flash kernel beyond (mlm_s4096),
-        # which caps live memory at O(block.S) instead of O(S^2); where
-        # the two cross between those cells is not measured (ROADMAP
-        # A2). Seq-sharded meshes take the ring path — flash is a
+        # Seq-sharded meshes take the ring path — flash is a
         # single-device kernel and would force a gather of the sharded
-        # K/V. On any other multi-device mesh the registry hands "flash"
-        # its dense reference body (mesh_scope).
+        # K/V. Else blocks.attention_body: on one chip the Pallas flash
+        # kernels from blocks.FLASH_FROM positions on (the cells mlm_s512
+        # and mlm_s4096), XLA's dense attention below; on any other
+        # multi-device mesh this function's own dense code up to 1024
+        # positions (mlm_s512_dp4) and the registry's reference beyond.
         if mesh is not None and mesh.shape.get(SEQ_AXIS, 1) > 1:
             impl = "ring"
         else:
-            impl = "flash" if S > FLASH_FROM else "dense"
+            impl = attention_body(S, mesh)
 
     if (impl == "ring" and mesh is not None
             and mesh.shape.get(SEQ_AXIS, 1) > 1):
@@ -334,8 +334,7 @@ def mlm_loss(params, cfg, batch, mesh=None):
       masked_weights [B, P] (P = max predictions, static) — the
       vocab-size head runs only on the ~15% masked positions, the way
       BERT pretraining defines the objective. Cuts head FLOPs by S/P
-      (measured +29% tokens/sec on the v5e single-chip bench config:
-      115.2k -> 149.0k at bs=64, seq=512, P=80).
+      (every BERT cell of BENCHMARK.json runs this layout).
 
     Both are static-shape (no dynamic-count gather), TPU-friendly."""
     hidden = forward(params, cfg, batch["input_ids"],

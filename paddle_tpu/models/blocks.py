@@ -15,16 +15,42 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from paddle_tpu.ops.pallas.registry import mesh_scope
+from paddle_tpu.ops.pallas.registry import mesh_scope, selected_body
 
-__all__ = ["rms_norm", "rope_angles", "apply_rope", "causal_attention",
-           "gated_ffn"]
+__all__ = ["rms_norm", "rope_angles", "apply_rope", "attention_body",
+           "causal_attention", "gated_ffn"]
 
-#: beyond this many positions ``causal_attention``'s and ``bert._attention``'s
-#: ``auto`` take the flash kernels. The cells on either side are ``mlm_s512``
-#: (dense) and ``mlm_s4096`` / ``lm_s4096`` (flash); no length between 512
-#: and 4096 is measured, so where the two really cross is open (ROADMAP A2).
-FLASH_FROM = 1024
+#: from this many positions on, ``auto`` takes the flash kernels where the
+#: Pallas body runs (one chip). Measured on a v5e at equal tokens a step
+#: (PERF.md section 6, PR 29): the whole BERT-base step is 17% shorter with
+#: the flash kernels at 512 positions (the cell ``mlm_s512``), 32% at 1024,
+#: and at 2048 the dense scores no longer fit; ``mlm_s4096`` and
+#: ``lm_s4096`` lie beyond. Below: flash still wins at 256 by 2.5% and
+#: loses at 128 by 15%, but at 384 the step with the flash calls hung at
+#: batch 88 and 96, unexplained, so the line stays at the lowest length
+#: that was seen both to win and to run clean. ``causal_attention`` (16
+#: heads of 128) is 3.4 times faster with the kernels at 512 already and
+#: was not measured below, so it reads the same constant.
+FLASH_FROM = 512
+#: where the registry would hand "flash" its reference body (a mesh of more
+#: than one device, the CPU), ``auto`` keeps the callers' inline dense code
+#: up to this many positions: the reference holds the scores in float32
+#: beside the head transposes, larger and slower than the inline code
+#: (``mlm_s512_dp4``: 14.84 GiB a device against 12.61, compiler, PR 29).
+#: Beyond it the reference, as ever: what ``lm_s4096`` would run on a mesh.
+REFERENCE_FLASH_BEYOND = 1024
+
+
+def attention_body(positions, mesh=None):
+    """"flash" or "dense": what ``auto`` runs at ``positions`` keys a query
+    under ``mesh``. It asks the registry which body a flash call would run
+    here, so only what the code observes takes part: the length, the mesh,
+    the platform."""
+    if positions > REFERENCE_FLASH_BEYOND:
+        return "flash"
+    with mesh_scope(mesh):
+        kernel_runs = selected_body("flash_attention") != "reference"
+    return "flash" if kernel_runs and positions >= FLASH_FROM else "dense"
 
 
 @jax.named_scope("layer_norm")
@@ -63,11 +89,10 @@ def causal_attention(q, k, v, impl="auto", mesh=None):
     times v. q, k, v and the result are [B, S, N, D]. ``impl`` is "dense"
     (XLA, scores in float32), "flash" (the Pallas kernels through the
     registry, which hands out the dense reference on the CPU and under a
-    mesh of more than one device) or "auto": flash past ``FLASH_FROM``
-    positions."""
+    mesh of more than one device) or "auto": ``attention_body``'s choice."""
     b, s, n, d = q.shape
     if impl == "auto":
-        impl = "flash" if s > FLASH_FROM else "dense"
+        impl = attention_body(s, mesh)
     with jax.named_scope("attention_core"):
         if impl == "flash":
             from paddle_tpu.ops import pallas as _pk

@@ -39,6 +39,22 @@ _FLASH_BWD_COMPILER_PARAMS = dataclasses.replace(
 #: straight-line code, so that one tile's matmuls run under the next one's
 #: elementwise work (v5e, S=4096: 8 tiles 12.0 ms a call, 4 12.6, 1 13.8)
 _FLASH_BWD_UNROLL = 8
+#: where a head is one tile (S <= 512: one query block, one key block) a
+#: program takes up to this many heads, their tiles as straight-line code
+#: like the backward's groups: a one-tile program has nothing of its own to
+#: run under its matmuls, and pays a grid step for every tile (v5e, B=64
+#: H=12 S=512 d=64, forward + backward a layer: 1 head 2.29 ms, 2 2.09,
+#: 4 2.03, 6 2.04, 12 1.99; my chip run, PR 29)
+_FLASH_HEADS_PER_PROGRAM = 4
+
+
+def _as_row(col):
+    """A per-row statistic ([n] or [n, 1], one value a sublane) as one
+    lane-dense [1, n] row: what an HBM array can hold without padding every
+    value to 128 lanes. Broadcast over the lanes and transposed, which is
+    exact (the MXU would round)."""
+    n = col.shape[0]
+    return jnp.broadcast_to(col.reshape(n, 1), (n, 128)).T[:1]
 
 
 def _masked_scores(qs, k_blk, b_blk, q0, k0, causal, transposed=False):
@@ -63,85 +79,117 @@ def _masked_scores(qs, k_blk, b_blk, q0, k0, causal, transposed=False):
 
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref, *,
                       sm_scale, block_k, causal, seq_len, block_q):
-    """One (batch, head, q-block) cell: stream K/V blocks, keep running
-    (max, sum, acc) — the online-softmax recurrence."""
-    q = q_ref[0, 0].astype(jnp.float32) * sm_scale        # [bq, d]
-    bq, d = q.shape
+    """One (batch, heads, q-block) cell: stream K/V blocks, keep running
+    (max, sum, acc) — the online-softmax recurrence. The logsumexp goes
+    out as row ``iq`` of the heads' [nq, bq] block, which stays in VMEM
+    across the q-blocks."""
+    heads, bq, d = q_ref.shape[1:]
     nk = seq_len // block_k
     iq = pl.program_id(2)
 
-    def body(jk, carry):
-        m_prev, l_prev, acc = carry
-        k_blk = k_ref[0, 0, pl.ds(jk * block_k, block_k), :] \
-            .astype(jnp.float32)                           # [bk, d]
-        v_blk = v_ref[0, 0, pl.ds(jk * block_k, block_k), :] \
-            .astype(jnp.float32)
-        b_blk = bias_ref[0, 0, pl.ds(jk * block_k, block_k)] \
-            .astype(jnp.float32)                           # [bk]
-        s = _masked_scores(q, k_blk, b_blk, iq * block_q, jk * block_k,
-                           causal)
-        m_cur = jnp.max(s, axis=-1)                        # [bq]
-        m_new = jnp.maximum(m_prev, m_cur)
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new[:, None])                    # [bq, bk]
-        l_new = l_prev * alpha + jnp.sum(p, axis=-1)
-        acc = acc * alpha[:, None] + jax.lax.dot_general(
-            p, v_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        return m_new, l_new, acc
+    def head(ih):
+        q = q_ref[0, ih].astype(jnp.float32) * sm_scale    # [bq, d]
 
-    m0 = jnp.full((bq,), _NEG_INF, jnp.float32)
-    l0 = jnp.zeros((bq,), jnp.float32)
-    acc0 = jnp.zeros((bq, d), jnp.float32)
-    if causal:
-        # stop at the diagonal: K blocks entirely above it are fully
-        # masked — skipping them halves causal attention FLOPs
-        nk_eff = jnp.minimum(
-            nk, ((iq + 1) * block_q + block_k - 1) // block_k)
-    else:
-        nk_eff = nk
-    m, l, acc = lax.fori_loop(0, nk_eff, body, (m0, l0, acc0))
-    l_safe = jnp.maximum(l, 1e-30)
-    o_ref[0, 0] = (acc / l_safe[:, None]).astype(o_ref.dtype)
-    lse_ref[0, 0, :, 0] = (m + jnp.log(l_safe)).astype(jnp.float32)
+        def body(jk, carry):
+            m_prev, l_prev, acc = carry
+            k_blk = k_ref[0, ih, pl.ds(jk * block_k, block_k), :] \
+                .astype(jnp.float32)                       # [bk, d]
+            v_blk = v_ref[0, ih, pl.ds(jk * block_k, block_k), :] \
+                .astype(jnp.float32)
+            b_blk = bias_ref[0, 0, pl.ds(jk * block_k, block_k)] \
+                .astype(jnp.float32)                       # [bk]
+            s = _masked_scores(q, k_blk, b_blk, iq * block_q, jk * block_k,
+                               causal)
+            m_cur = jnp.max(s, axis=-1)                    # [bq]
+            m_new = jnp.maximum(m_prev, m_cur)
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new[:, None])                # [bq, bk]
+            l_new = l_prev * alpha + jnp.sum(p, axis=-1)
+            acc = acc * alpha[:, None] + jax.lax.dot_general(
+                p, v_blk, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            return m_new, l_new, acc
+
+        init = (jnp.full((bq,), _NEG_INF, jnp.float32),
+                jnp.zeros((bq,), jnp.float32),
+                jnp.zeros((bq, d), jnp.float32))
+        if nk == 1:
+            m, l, acc = body(0, init)
+        else:
+            # causal: stop at the diagonal. K blocks entirely above it are
+            # fully masked — skipping them halves causal attention FLOPs
+            nk_eff = jnp.minimum(
+                nk, ((iq + 1) * block_q + block_k - 1) // block_k) \
+                if causal else nk
+            m, l, acc = lax.fori_loop(0, nk_eff, body, init)
+        l_safe = jnp.maximum(l, 1e-30)
+        o_ref[0, ih] = (acc / l_safe[:, None]).astype(o_ref.dtype)
+        lse_ref[0, ih, pl.ds(iq, 1), :] = _as_row(m + jnp.log(l_safe))
+
+    for ih in range(heads):
+        head(ih)
 
 
+def _heads_per_program(h, nq, nk):
+    """How many heads one program takes: several where a head is one tile,
+    the largest divisor of ``h`` up to ``_FLASH_HEADS_PER_PROGRAM``."""
+    if nq > 1 or nk > 1:
+        return 1
+    return max(n for n in range(1, min(h, _FLASH_HEADS_PER_PROGRAM) + 1)
+               if h % n == 0)
+
+
+# The two calls are jitted functions of their own so that a model's layers,
+# which call them with the same shapes, share one trace of the kernel and
+# one lowering to Mosaic: a program holds one function a kernel and calls
+# it a layer (XLA inlines the calls). Traced anew a layer, the 24 calls of
+# BERT-base were 3 s of every start, from the compile cache or not
+# (PERF.md section 6, PR 29).
+@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7, 8))
 def _flash_fwd(q, k, v, bias, sm_scale, causal, block_q, block_k,
                interpret):
+    """(o, lse): lse is [B, H, nq, bq] float32, a lane-dense row a query
+    block, as the backward reads it."""
     b, h, s, d = q.shape
     block_q = min(block_q, s)
     block_k = min(block_k, s)
-    grid = (b, h, s // block_q)
+    nq = s // block_q
+    hb = _heads_per_program(h, nq, s // block_k)
     kernel = functools.partial(
         _flash_fwd_kernel, sm_scale=sm_scale, block_k=block_k,
         causal=causal, seq_len=s, block_q=block_q)
+
+    def q_block(ib, ih, iq):
+        return (ib, ih, iq, 0)
+
+    def whole(ib, ih, iq):
+        return (ib, ih, 0, 0)
+
     # Mosaic tiling constraint: a block's last two dims must be
     # (8k, 128k)-divisible or equal to the array's — so the per-batch
-    # bias rides as [B, 1, S] (block (1, 1, S)) and lse as [B, H, S, 1]
-    # (block (1, 1, bq, 1)), both satisfying the "equal dimension" rule.
-    o, lse4 = pl.pallas_call(
+    # bias rides as [B, 1, S] (block (1, 1, S)), and the heads' lse block
+    # is the whole [nq, bq], written back when the heads change.
+    return pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(b, h // hb, nq),
         in_specs=[
-            _vmem_spec((1, 1, block_q, d), lambda ib, ih, iq: (ib, ih, iq, 0)),
-            _vmem_spec((1, 1, s, d), lambda ib, ih, iq: (ib, ih, 0, 0)),
-            _vmem_spec((1, 1, s, d), lambda ib, ih, iq: (ib, ih, 0, 0)),
+            _vmem_spec((1, hb, block_q, d), q_block),
+            _vmem_spec((1, hb, s, d), whole),
+            _vmem_spec((1, hb, s, d), whole),
             _vmem_spec((1, 1, s), lambda ib, ih, iq: (ib, 0, 0)),
         ],
         out_specs=[
-            _vmem_spec((1, 1, block_q, d), lambda ib, ih, iq: (ib, ih, iq, 0)),
-            _vmem_spec((1, 1, block_q, 1),
-                       lambda ib, ih, iq: (ib, ih, iq, 0)),
+            _vmem_spec((1, hb, block_q, d), q_block),
+            _vmem_spec((1, hb, nq, block_q), whole),
         ],
         out_shape=[
             jax.ShapeDtypeStruct(q.shape, q.dtype),
-            jax.ShapeDtypeStruct((b, h, s, 1), jnp.float32),
+            jax.ShapeDtypeStruct((b, h, nq, block_q), jnp.float32),
         ],
         compiler_params=_FLASH_COMPILER_PARAMS,
         interpret=interpret,
         name="flash_fwd",
     )(q, k, v, bias[:, None, :])
-    return o, lse4[..., 0]
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
@@ -160,84 +208,93 @@ def _flash_attention_fwd(q, k, v, bias, sm_scale, causal, block_q, block_k,
 
 
 def _flash_bwd_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
-                      bias_ref, dq_ref, dk_ref, dv_ref, dbh_ref, dqt_acc, *,
+                      bias_ref, dq_ref, dk_ref, dv_ref, db_ref, dqt_acc, *,
                       sm_scale, block_q, block_k, causal, seq_len):
-    """One (batch, head, k-block) cell: stream Q/dO blocks, rebuild each
+    """One (batch, heads, k-block) cell: stream Q/dO blocks, rebuild each
     tile's probabilities once from the saved logsumexp, and take all four
     gradients from it. The tile is built transposed, [bk, bq] = K Q^T, so
     that no product contracts over a tile's rows: dV += p^T dO and
     dK += dz^T Q are plain, and dQ is accumulated transposed,
-    dQ^T += K^T dz^T, in a float32 [d, S] scratch that lives across the
-    head's key blocks (lse/delta ride as lane-dense [1, bq] rows). Scores
-    never touch HBM, nor do partial dQs."""
+    dQ^T += K^T dz^T, in a float32 [d, S] scratch a head that lives across
+    the key blocks (lse/delta ride as lane-dense [1, bq] rows, and the
+    key-bias gradient goes out as one: row ``ik`` of the heads' [nk, bk]
+    block). Scores never touch HBM, nor do partial dQs."""
     ik = pl.program_id(2)
+    heads = q_ref.shape[1]
     nq = seq_len // block_q
 
     @pl.when(ik == 0)
     def _():
         dqt_acc[...] = jnp.zeros_like(dqt_acc)
 
-    k_blk = k_ref[0, 0].astype(jnp.float32)                # [bk, d]
-    v_blk = v_ref[0, 0].astype(jnp.float32)
     b_col = bias_ref[0].astype(jnp.float32)                # [bk, 1]
-    kt_blk = k_blk.T                                       # [d, bk]
-    bk, d = k_blk.shape
-
-    def tile(jq, carry):
-        dk_acc, dv_acc, db_acc = carry
-        q0 = pl.multiple_of(jq * block_q, block_q)
-        qs = q_ref[0, 0, pl.ds(q0, block_q), :] \
-            .astype(jnp.float32) * sm_scale                # [bq, d]
-        do_blk = do_ref[0, 0, pl.ds(q0, block_q), :] \
-            .astype(jnp.float32)
-        lse_row = lse_ref[0, 0, pl.ds(jq, 1), :]           # [1, bq]
-        d_row = delta_ref[0, 0, pl.ds(jq, 1), :]
-        st = _masked_scores(qs, k_blk, b_col, q0, ik * block_k, causal,
-                            transposed=True)
-        pt = jnp.exp(st - lse_row)                         # [bk, bq]
-        dv_acc = dv_acc + jax.lax.dot_general(
-            pt, do_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)            # [bk, d]
-        dpt = jax.lax.dot_general(
-            v_blk, do_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)            # [bk, bq]
-        dzt = pt * (dpt - d_row)
-        dk_acc = dk_acc + jax.lax.dot_general(
-            dzt, qs, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)            # [bk, d]
-        db_acc = db_acc + jnp.sum(dzt, axis=1, keepdims=True)
-        dqt_acc[:, pl.ds(q0, block_q)] += jax.lax.dot_general(
-            kt_blk, dzt, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)            # [d, bq]
-        return dk_acc, dv_acc, db_acc
-
     # causal: q-blocks strictly above the diagonal see only masked scores,
     # so the loop starts at the diagonal and its length varies; otherwise
     # the tiles go in groups of straight-line code
     unroll = 1 if causal else max(
         u for u in range(1, min(nq, _FLASH_BWD_UNROLL) + 1) if nq % u == 0)
 
-    def group(g, carry):
-        for i in range(unroll):
-            carry = tile(g * unroll + i, carry)
-        return carry
+    def head(ih):
+        k_blk = k_ref[0, ih].astype(jnp.float32)           # [bk, d]
+        v_blk = v_ref[0, ih].astype(jnp.float32)
+        kt_blk = k_blk.T                                   # [d, bk]
+        bk, d = k_blk.shape
 
-    init = (jnp.zeros((bk, d), jnp.float32), jnp.zeros((bk, d), jnp.float32),
-            jnp.zeros((bk, 1), jnp.float32))
-    if unroll == nq:
-        dk, dv, db = group(0, init)
-    else:
-        jq0 = (ik * block_k) // block_q if causal else 0
-        dk, dv, db = lax.fori_loop(jq0, nq // unroll, group, init)
-    dk_ref[0, 0] = dk.astype(dk_ref.dtype)
-    dv_ref[0, 0] = dv.astype(dv_ref.dtype)
-    dbh_ref[0, 0] = db
+        def tile(jq, carry):
+            dk_acc, dv_acc, db_acc = carry
+            q0 = pl.multiple_of(jq * block_q, block_q)
+            qs = q_ref[0, ih, pl.ds(q0, block_q), :] \
+                .astype(jnp.float32) * sm_scale            # [bq, d]
+            do_blk = do_ref[0, ih, pl.ds(q0, block_q), :] \
+                .astype(jnp.float32)
+            lse_row = lse_ref[0, ih, pl.ds(jq, 1), :]      # [1, bq]
+            d_row = delta_ref[0, ih, pl.ds(jq, 1), :]
+            st = _masked_scores(qs, k_blk, b_col, q0, ik * block_k, causal,
+                                transposed=True)
+            pt = jnp.exp(st - lse_row)                     # [bk, bq]
+            dv_acc = dv_acc + jax.lax.dot_general(
+                pt, do_blk, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)        # [bk, d]
+            dpt = jax.lax.dot_general(
+                v_blk, do_blk, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)        # [bk, bq]
+            dzt = pt * (dpt - d_row)
+            dk_acc = dk_acc + jax.lax.dot_general(
+                dzt, qs, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)        # [bk, d]
+            db_acc = db_acc + jnp.sum(dzt, axis=1, keepdims=True)
+            dqt_acc[ih, :, pl.ds(q0, block_q)] += jax.lax.dot_general(
+                kt_blk, dzt, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)        # [d, bq]
+            return dk_acc, dv_acc, db_acc
+
+        def group(g, carry):
+            for i in range(unroll):
+                carry = tile(g * unroll + i, carry)
+            return carry
+
+        init = (jnp.zeros((bk, d), jnp.float32),
+                jnp.zeros((bk, d), jnp.float32),
+                jnp.zeros((bk, 1), jnp.float32))
+        if unroll == nq:
+            dk, dv, db = group(0, init)
+        else:
+            jq0 = (ik * block_k) // block_q if causal else 0
+            dk, dv, db = lax.fori_loop(jq0, nq // unroll, group, init)
+        dk_ref[0, ih] = dk.astype(dk_ref.dtype)
+        dv_ref[0, ih] = dv.astype(dv_ref.dtype)
+        db_ref[0, ih, pl.ds(ik, 1), :] = _as_row(db)
+
+    for ih in range(heads):
+        head(ih)
 
     @pl.when(ik == pl.num_programs(2) - 1)
     def _():
-        dq_ref[0, 0] = (dqt_acc[...].T * sm_scale).astype(dq_ref.dtype)
+        for ih in range(heads):
+            dq_ref[0, ih] = (dqt_acc[ih].T * sm_scale).astype(dq_ref.dtype)
 
 
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 4))
 def _flash_attention_bwd(sm_scale, causal, block_q, block_k, interpret,
                          res, do):
     """Blockwise recompute backward as one Pallas kernel, ``flash_bwd``,
@@ -245,7 +302,8 @@ def _flash_attention_bwd(sm_scale, causal, block_q, block_k, interpret,
     memory stays O(block · S); the [S, S] score matrix never exists."""
     q, k, v, bias, o, lse = res
     b, h, s, d = q.shape
-    nq = s // block_q
+    nq, nk = s // block_q, s // block_k
+    hb = _heads_per_program(h, nq, nk)
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), -1)
     kernel = functools.partial(
         _flash_bwd_kernel, sm_scale=sm_scale, block_q=block_q,
@@ -257,39 +315,39 @@ def _flash_attention_bwd(sm_scale, causal, block_q, block_k, interpret,
     def k_block(ib, ih, ik):
         return (ib, ih, ik, 0)
 
-    # lse/delta as one lane-dense row a query block ([B,H,nq,bq]); the bias
-    # as a column ([B,S,1]), since it runs down the transposed tile's rows
+    # lse/delta as one lane-dense row a query block ([B,H,nq,bq]), the
+    # key-bias gradient as one a key block ([B,H,nk,bk]); the bias as a
+    # column ([B,S,1]), since it runs down the transposed tile's rows
     dq, dk, dv, dbh = pl.pallas_call(
         kernel,
-        grid=(b, h, s // block_k),
+        grid=(b, h // hb, nk),
         in_specs=[
-            _vmem_spec((1, 1, s, d), whole),
-            _vmem_spec((1, 1, s, d), whole),
-            _vmem_spec((1, 1, nq, block_q), whole),
-            _vmem_spec((1, 1, nq, block_q), whole),
-            _vmem_spec((1, 1, block_k, d), k_block),
-            _vmem_spec((1, 1, block_k, d), k_block),
+            _vmem_spec((1, hb, s, d), whole),
+            _vmem_spec((1, hb, s, d), whole),
+            _vmem_spec((1, hb, nq, block_q), whole),
+            _vmem_spec((1, hb, nq, block_q), whole),
+            _vmem_spec((1, hb, block_k, d), k_block),
+            _vmem_spec((1, hb, block_k, d), k_block),
             _vmem_spec((1, block_k, 1), lambda ib, ih, ik: (ib, ik, 0)),
         ],
         out_specs=[
-            _vmem_spec((1, 1, s, d), whole),
-            _vmem_spec((1, 1, block_k, d), k_block),
-            _vmem_spec((1, 1, block_k, d), k_block),
-            _vmem_spec((1, 1, block_k, 1), k_block),
+            _vmem_spec((1, hb, s, d), whole),
+            _vmem_spec((1, hb, block_k, d), k_block),
+            _vmem_spec((1, hb, block_k, d), k_block),
+            _vmem_spec((1, hb, nk, block_k), whole),
         ],
         out_shape=[
             jax.ShapeDtypeStruct(q.shape, q.dtype),
             jax.ShapeDtypeStruct(k.shape, k.dtype),
             jax.ShapeDtypeStruct(v.shape, v.dtype),
-            jax.ShapeDtypeStruct((b, h, s, 1), jnp.float32),
+            jax.ShapeDtypeStruct((b, h, nk, block_k), jnp.float32),
         ],
-        scratch_shapes=[pltpu.VMEM((d, s), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((hb, d, s), jnp.float32)],
         compiler_params=_FLASH_BWD_COMPILER_PARAMS,
         interpret=interpret,
         name="flash_bwd",
-    )(q, do, lse.reshape(b, h, nq, block_q),
-      delta.reshape(b, h, nq, block_q), k, v, bias[:, :, None])
-    dbias = jnp.sum(dbh[..., 0], axis=1)                   # [B,S]
+    )(q, do, lse, delta.reshape(b, h, nq, block_q), k, v, bias[:, :, None])
+    dbias = jnp.sum(dbh.reshape(b, h, s), axis=1)          # [B,S]
     return dq, dk, dv, dbias.astype(bias.dtype)
 
 

@@ -172,9 +172,10 @@ class TestFlashAttention:
             np.asarray(jax.grad(f_dense)(q)), atol=3e-4)
 
     #: the one backward kernel, every branch of it: (sequence, block_q,
-    #: block_k, causal, key bias, input dtype, atol). Each case has at
+    #: block_k, causal, key bias, input dtype, atol). The first nine have at
     #: least two blocks on both axes, so dQ accumulates across key blocks
-    #: and dK/dV across query blocks.
+    #: and dK/dV across query blocks; the ``one_tile`` cases are the geometry
+    #: of the cell mlm_s512, a head one tile and both heads in one program.
     BACKWARD_CASES = {
         "plain": (256, 128, 128, False, False, jnp.float32, 3e-4),
         "causal": (256, 128, 128, True, False, jnp.float32, 3e-4),
@@ -189,6 +190,15 @@ class TestFlashAttention:
         "nine_query_tiles": (1152, 128, 128, False, False, jnp.float32,
                              3e-4),
         "bf16": (256, 128, 128, False, True, jnp.bfloat16, 4e-2),
+        "one_tile_s512": (512, 512, 512, False, True, jnp.float32, 3e-4),
+        "one_tile_s512_bf16": (512, 512, 512, False, True, jnp.bfloat16,
+                               4e-2),
+        "one_tile_s384": (384, 512, 512, False, True, jnp.float32, 3e-4),
+        "one_tile_s380_padded": (380, 512, 512, False, True, jnp.float32,
+                                 3e-4),
+        "one_tile_s380_padded_bf16": (380, 512, 512, False, True,
+                                      jnp.bfloat16, 4e-2),
+        "one_tile_causal": (256, 512, 512, True, False, jnp.float32, 3e-4),
     }
 
     @pytest.mark.parametrize("case", sorted(BACKWARD_CASES))
@@ -221,6 +231,38 @@ class TestFlashAttention:
             np.testing.assert_allclose(
                 np.asarray(a, np.float32), np.asarray(b_, np.float32),
                 atol=atol, err_msg=f"{case}: {name}")
+
+    @pytest.mark.parametrize("heads,programs", [(12, 3), (7, 7), (4, 1)])
+    def test_several_heads_a_program_match_dense(self, heads, programs):
+        """Where a head is one tile a program takes several (the largest
+        divisor of the head count up to four): the forward and all four
+        gradients, heads in one program and in more than one."""
+        import importlib
+        fa = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
+        assert heads // fa._heads_per_program(heads, 1, 1) == programs
+        assert fa._heads_per_program(heads, 2, 2) == 1
+        q, k, v = self._rand(b=2, h=heads, s=128, d=16, seed=7)
+        rng = np.random.RandomState(8)
+        w = jnp.asarray(rng.randn(2, heads, 128, 16).astype(np.float32))
+        bias = rng.randn(2, 128).astype(np.float32) * 0.3
+        bias[:, 100:] = -1e30
+        bias = jnp.asarray(bias)
+
+        def f_flash(q, k, v, bias):
+            return jnp.sum(K.flash_attention(q, k, v, bias=bias) * w)
+
+        def f_dense(q, k, v, bias):
+            return jnp.sum(_dense_attention(q, k, v, bias=bias) * w)
+
+        np.testing.assert_allclose(
+            np.asarray(K.flash_attention(q, k, v, bias=bias)),
+            np.asarray(_dense_attention(q, k, v, bias=bias)), atol=2e-5)
+        for name, a, b_ in zip(
+                ("dq", "dk", "dv", "dbias"),
+                jax.grad(f_flash, (0, 1, 2, 3))(q, k, v, bias),
+                jax.grad(f_dense, (0, 1, 2, 3))(q, k, v, bias)):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
+                                       atol=3e-4, err_msg=name)
 
     def test_causal_at_head_size_128_matches_dense(self):
         """OLMoE's shape of attention (16 heads of 128, causal) through the

@@ -87,6 +87,13 @@ def _mosaic_call_stems(compiled):
             if "tpu_custom_call" in line]
 
 
+def _entry_text(compiled):
+    """The instructions of the program's entry computation: the tensors that
+    exist in HBM. What a fusion computes inside itself is not among them."""
+    text = compiled.as_text()
+    return text[text.index("\nENTRY "):]
+
+
 # ---------------------------------------------------------------------------
 # (a) every registered kernel's Pallas body, at one production shape
 # ---------------------------------------------------------------------------
@@ -236,8 +243,56 @@ def bert_two_layers(topo):
 def test_bert_step_one_device_runs_the_pallas_bodies(bert_two_layers):
     compiled, _, _ = bert_two_layers
     # 2 layers: two layer norms each, the embedding's and the head's,
-    # forward; Adam is the stock rule and no call
-    assert _mosaic_call_stems(compiled) == ["layer_norm_fwd"] * 6
+    # forward; at 512 positions on one chip `auto` takes the flash kernels,
+    # one forward and one backward call a layer; Adam is the stock rule
+    # and no call
+    assert sorted(_mosaic_call_stems(compiled)) == (
+        ["flash_bwd"] * 2 + ["flash_fwd"] * 2 + ["layer_norm_fwd"] * 6)
+
+
+def test_bert_step_on_four_chips_keeps_its_own_dense_attention(topo):
+    """The cell mlm_s512_dp4: under a mesh of more than one device the
+    Pallas flash body cannot run, so `auto` keeps bert._attention's inline
+    bf16 dense code and does not hand the step the registry's reference,
+    which holds the scores in float32 (805 MB a layer and a device)."""
+    def step(**kw):
+        return _bert_step(topo, MeshConfig(data=4), 4, 256, num_layers=2,
+                          softmax_dtype="bf16", **kw)[0]
+
+    compiled = step()
+    assert _mosaic_calls(compiled) == 0
+    assert "bf16[64,12,512,512]" in _entry_text(compiled)
+    assert "f32[64,12,512,512]" not in _entry_text(compiled)
+    # and the reference is what the test says it is
+    assert "f32[64,12,512,512]" in _entry_text(step(attention_impl="flash"))
+
+
+#: (positions, devices of the mesh or 0 for no mesh, platform) -> what `auto`
+#: runs: the flash kernels from blocks.FLASH_FROM positions on where the
+#: Pallas body runs; where it does not, the callers' dense code up to 1024
+#: positions and the registry's reference body ("flash" there) beyond
+AUTO_CASES = {
+    "one_chip_below_the_crossover": (256, 1, "tpu", "dense"),
+    "one_chip_at_the_crossover": (512, 1, "tpu", "flash"),
+    "one_chip_mlm_s4096": (4096, 1, "tpu", "flash"),
+    "no_mesh_at_the_crossover": (512, 0, "tpu", "flash"),
+    "four_chips_mlm_s512_dp4": (512, 4, "tpu", "dense"),
+    "four_chips_s1024": (1024, 4, "tpu", "dense"),
+    "four_chips_beyond_1024": (2048, 4, "tpu", "flash"),
+    "cpu_s512": (512, 1, "cpu", "dense"),
+    "cpu_beyond_1024": (2048, 1, "cpu", "flash"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(AUTO_CASES))
+def test_auto_chooses_the_attention_body_from_what_it_observes(case,
+                                                               monkeypatch):
+    from paddle_tpu.models import blocks
+    positions, devices, platform, body = AUTO_CASES[case]
+    monkeypatch.setattr(registry, "platform", lambda: platform)
+    mesh = devices and make_mesh(MeshConfig(data=devices),
+                                 devices=jax.devices()[:devices])
+    assert blocks.attention_body(positions, mesh or None) == body
 
 
 @pytest.mark.parametrize("mesh_cfg,batch", [
@@ -270,10 +325,11 @@ def test_the_step_names_its_mosaic_calls(topo):
     assert set(_mosaic_call_stems(compiled)) == {
         "flash_fwd", "flash_bwd", "layer_norm_fwd"}
     op_names = set(re.findall(r'op_name="([^"]*)"', "\n".join(calls)))
-    assert ("jit(step)/jvp(attention)/attention_core/flash_fwd/pallas_call"
-            in op_names)
+    # each flash call is a jitted function of its own, shared by the layers
+    assert ("jit(step)/jvp(attention)/attention_core/jit(_flash_fwd)/"
+            "flash_fwd/pallas_call" in op_names)
     assert ("jit(step)/transpose(jvp(attention))/attention_core/"
-            "flash_bwd/pallas_call" in op_names)
+            "jit(_flash_attention_bwd)/flash_bwd/pallas_call" in op_names)
     assert any(n.endswith("layer_norm/layer_norm_fwd/pallas_call")
                for n in op_names)
 
@@ -347,9 +403,10 @@ def test_olmoe_step_at_published_widths_fits_a_v5e_and_names_its_calls(
              if "tpu_custom_call" in line]
     op_names = set(re.findall(r'op_name="([^"]*)"', "\n".join(calls)))
     for name in (
-            "jit(step)/jvp(attention)/attention_core/flash_fwd/pallas_call",
-            "jit(step)/transpose(jvp(attention))/attention_core/flash_bwd/"
-            "pallas_call",
+            "jit(step)/jvp(attention)/attention_core/jit(_flash_fwd)/"
+            "flash_fwd/pallas_call",
+            "jit(step)/transpose(jvp(attention))/attention_core/"
+            "jit(_flash_attention_bwd)/flash_bwd/pallas_call",
             "jit(step)/jvp(ffn)/moe_experts/grouped_matmul/pallas_call",
             "jit(step)/transpose(jvp(ffn))/moe_experts/grouped_matmul/"
             "pallas_call",
