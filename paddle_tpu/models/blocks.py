@@ -1,7 +1,8 @@
 """Pieces of a decoder block that more than one model can use: RMSNorm,
 rotary positions, causal attention, the gated feed-forward.
 
-``models/olmoe.py`` is built from them. ``models/bert.py`` and
+``models/olmoe.py`` and ``models/kimi_linear.py`` are built from them.
+``models/bert.py`` and
 ``models/transformer.py`` carry their own layer norm and attention and are
 not moved here yet (ROADMAP C10: their cells repeat to 0.004%, so a change
 to their HLO is a PR judged on its own). Every piece enters the named scope
@@ -17,7 +18,7 @@ from jax import lax
 
 from paddle_tpu.ops.pallas.registry import mesh_scope, selected_body
 
-__all__ = ["rms_norm", "rope_angles", "apply_rope", "attention_body",
+__all__ = ["rms_norm", "rms_normalize", "rope_angles", "apply_rope", "attention_body",
            "causal_attention", "gated_ffn"]
 
 #: from this many positions on, ``auto`` takes the flash kernels where the
@@ -53,13 +54,19 @@ def attention_body(positions, mesh=None):
     return "flash" if kernel_runs and positions >= FLASH_FROM else "dense"
 
 
-@jax.named_scope("layer_norm")
-def rms_norm(x, gain, eps=1e-5):
+def rms_normalize(x, gain, eps=1e-5):
     """``x / sqrt(mean(x^2) + eps) * gain`` over the last axis: statistics
-    in float32, the result in ``x.dtype``."""
+    in float32, the result in ``x.dtype``. Under no scope of its own: for a
+    norm that belongs to its caller's (a latent's, a head's)."""
     x32 = x.astype(jnp.float32)
     scale = lax.rsqrt(jnp.mean(jnp.square(x32), axis=-1, keepdims=True) + eps)
     return (x32 * scale * gain.astype(jnp.float32)).astype(x.dtype)
+
+
+@jax.named_scope("layer_norm")
+def rms_norm(x, gain, eps=1e-5):
+    """``rms_normalize`` under the scope ``layer_norm``: a block's norms."""
+    return rms_normalize(x, gain, eps)
 
 
 def rope_angles(positions, head_dim, theta=10000.0):
@@ -86,7 +93,10 @@ def apply_rope(x, cos, sin):
 
 def causal_attention(q, k, v, impl="auto", mesh=None):
     """Softmax of ``q k^T / sqrt(D)`` over the keys up to each query's own,
-    times v. q, k, v and the result are [B, S, N, D]. ``impl`` is "dense"
+    times v. q and k are [B, S, N, D], v and the result [B, S, N, Dv]: the
+    value heads may have a size of their own (latent attention: 192 for the
+    scores, 128 for the values), and D need be no multiple of the 128-lane
+    grain. ``impl`` is "dense"
     (XLA, scores in float32), "flash" (the Pallas kernels through the
     registry, which hands out the dense reference on the CPU and under a
     mesh of more than one device) or "auto": ``attention_body``'s choice."""

@@ -1,0 +1,432 @@
+"""Kimi Linear: a decoder-only language model whose layers mix tokens by
+two kinds of attention and feed forward by two kinds of layer (Kimi Linear
+technical report, Moonshot AI 2025, arXiv:2510.26692; the released
+``moonshotai/Kimi-Linear-48B-A3B-Instruct`` config).
+
+Pre-norm blocks, ``h = x + Mix(RMSNorm(x))``, ``y = h + FF(RMSNorm(h))``.
+Which mixer a layer has is read from two lists of the configuration
+(``kda_layers``, ``full_attn_layers``: the published 1-based numbers, three
+KDA layers to one MLA layer); the first ``first_dense`` layers have a dense
+feed-forward, the others the experts.
+
+- **KDA** (Kimi Delta Attention): q, k and v through a causal depthwise
+  convolution of ``conv_size`` taps and SiLU; q and k L2-normalised a head,
+  q scaled by 1 / sqrt(d); a log decay per channel ``-exp(A_log) *
+  softplus(low-rank(x) + dt_bias)`` and a write strength per head
+  ``sigmoid(x w_beta)``; the gated delta rule (``ops/kda.py``, chunked);
+  an RMSNorm a head gated by ``sigmoid(low-rank(x))``; the output
+  projection.
+- **MLA without positions**: queries of ``qk_nope + qk_rope`` channels a
+  head; keys and values expanded per head from an RMS-normalised latent of
+  ``kv_lora_rank``, with ``qk_rope`` more key channels shared by the heads
+  and, under ``mla_use_nope``, not rotated; causal softmax through
+  ``blocks.causal_attention`` (the flash kernels at score size 192 and
+  value size 128). No weight absorption: that is a serving form.
+- **experts**: a float32 sigmoid router over all ``num_experts`` with a
+  selection bias, ``experts_per_token`` a token, renormalised and scaled by
+  ``routed_scale``, plus one shared expert
+  (``parallel/moe.dropless_moe_ffn``). ``experts_held`` = (first, n) makes
+  the layer one chip's share of an expert-parallel job: it holds n experts,
+  routes over all and leaves the other chips' part out. The selection bias
+  is a parameter outside the gradient; the train step moves it by
+  DeepSeek-V3's sign rule on the load the router saw (``moe.bias_step``,
+  ``bias_rate`` a step). On one chip's share it has more to do than in the
+  deployment: only the held experts' outputs reach the loss, so the
+  router's gradient, here without the other chips' parts, draws every token
+  toward them; with the bias at rest they took four times their share
+  within twenty steps (PERF.md section 6, PR 30). ``bias_rate`` = 0.05 is
+  what holds that load level in the benchmark's cell, fitted there, and no
+  property of the model (DeepSeek-V3 trains with 0.001).
+- a final RMSNorm and an untied head on every position; the loss is the
+  mean next-token cross-entropy (the config names no auxiliary loss).
+  ``vocab_size`` may be a slice of the published vocabulary: ids, head and
+  loss are then over the slice.
+
+**Recomputation.** Every KDA mixer is under ``jax.checkpoint``: the backward
+pass keeps its input and forms the projections, convolutions, gates and the
+chunked scan again. It is the least that lets one sequence of 8192 positions
+fit a v5e beside 6.7 GiB of parameters and Adam state: 14.95 GiB by the
+compiler's account, where nothing recomputed is 20.8 and the scan alone
+15.1 with 0.7 to spare (PERF.md section 6, PR 30). The MLA mixer and the
+feed-forwards keep what they computed; the experts' rows are formed again
+by ``moe.dropless_moe_ffn`` itself. No option chooses any of it.
+
+Built like ``models/olmoe.py``: float32 master parameters, ``cfg.dtype``
+(bfloat16) activations and matmul operands, one jitted step
+(``models/lm_trainer.py``).
+"""
+
+import dataclasses
+import math
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
+
+from paddle_tpu.models import blocks, lm_trainer
+from paddle_tpu.ops import kda
+from paddle_tpu.ops.pallas.registry import mesh_scope
+from paddle_tpu.parallel import moe
+from paddle_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
+
+__all__ = ["KimiLinearConfig", "kimi_linear_48b_a3b", "kimi_linear_tiny",
+           "init_params", "param_specs", "forward", "stages", "lm_loss",
+           "routing_stats", "make_train_step", "synthetic_batch"]
+
+_KDA_48B = (1, 2, 3, 5, 6, 7, 9, 10, 11, 13, 14, 15, 17, 18, 19, 21, 22, 23,
+            25, 26)
+_MLA_48B = (4, 8, 12, 16, 20, 24, 27)
+
+
+@dataclasses.dataclass(frozen=True)  # hashable: used as a jit-static arg
+class KimiLinearConfig:
+    vocab_size: int = 163840
+    hidden: int = 2304
+    num_layers: int = 27
+    kda_layers: tuple = _KDA_48B         # 1-based, as published
+    full_attn_layers: tuple = _MLA_48B
+    kda_heads: int = 32
+    kda_head_dim: int = 128
+    conv_size: int = 4
+    num_heads: int = 32                  # MLA
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    dense_width: int = 9216
+    first_dense: int = 1
+    expert_width: int = 1024
+    num_experts: int = 256
+    experts_per_token: int = 8
+    routed_scale: float = 2.446
+    bias_rate: float = 0.05              # the selection bias's step
+    experts_held: tuple = None           # (first, n); None: all of them
+    rms_eps: float = 1e-5
+    dtype: object = jnp.bfloat16         # activation/compute dtype
+
+    def mixer(self, layer):
+        """"kda" or "mla" for the 0-based ``layer``."""
+        if layer + 1 in self.kda_layers:
+            return "kda"
+        if layer + 1 in self.full_attn_layers:
+            return "mla"
+        raise ValueError(f"layer {layer + 1} is in neither list of mixers")
+
+    @property
+    def scoring(self):
+        return moe.Scoring("sigmoid", renormalize=True,
+                           scale=self.routed_scale)
+
+    @property
+    def experts_here(self):
+        return self.experts_held[1] if self.experts_held else self.num_experts
+
+
+def kimi_linear_48b_a3b(**kw):
+    """The published sizes: 48 B parameters, 3 B a token."""
+    return KimiLinearConfig(**kw)
+
+
+def kimi_linear_tiny(**kw):
+    """Small config for tests / dry runs: the first five published layers
+    (KDA, KDA, KDA, MLA, KDA; the first one dense)."""
+    for k, v in dict(vocab_size=512, hidden=64, num_layers=5, kda_heads=4,
+                     kda_head_dim=16, num_heads=4, kv_lora_rank=32,
+                     qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+                     dense_width=128, expert_width=32, num_experts=16,
+                     experts_per_token=4).items():
+        kw.setdefault(k, v)
+    return KimiLinearConfig(**kw)
+
+
+# ---------------------------------------------------------------------------
+# params
+# ---------------------------------------------------------------------------
+def init_params(rng, cfg):
+    """fp32 master params as a nested dict pytree. Matrices N(0, 0.02),
+    gains 1, the convolutions' taps U(-1/2, 1/2) (a depthwise Conv1d of 4
+    taps as PyTorch starts it), ``A_log`` = log U(1, 16) a head and
+    ``dt_bias`` the inverse softplus of a step log-uniform in [0.001, 0.1]
+    (the Mamba-2 / Gated DeltaNet start), the selection bias 0."""
+    h = cfg.hidden
+    keys = iter(jax.random.split(rng, 2 + 24 * cfg.num_layers))
+
+    def normal(*shape):
+        return (0.02 * jax.random.normal(next(keys), shape)) \
+            .astype(jnp.float32)
+
+    def ones(n):
+        return jnp.ones((n,), jnp.float32)
+
+    def kda_mixer():
+        n, d = cfg.kda_heads, cfg.kda_head_dim
+        width = n * d
+        step = jnp.exp(jax.random.uniform(
+            next(keys), (width,), jnp.float32, math.log(1e-3),
+            math.log(1e-1)))
+        lp = {"q_w": normal(h, width), "k_w": normal(h, width),
+              "v_w": normal(h, width), "o_w": normal(width, h),
+              "f_a": normal(h, d), "f_b": normal(d, width),
+              "g_a": normal(h, d), "g_b": normal(d, width),
+              "beta_w": normal(h, n),
+              "A_log": jnp.log(jax.random.uniform(
+                  next(keys), (n,), jnp.float32, 1.0, 16.0)),
+              "dt_bias": step + jnp.log(-jnp.expm1(-step)),
+              "o_norm_g": ones(d)}
+        for name in ("q_conv", "k_conv", "v_conv"):
+            lp[name] = jax.random.uniform(
+                next(keys), (cfg.conv_size, width), jnp.float32, -0.5, 0.5)
+        return lp
+
+    def mla_mixer():
+        n = cfg.num_heads
+        qk = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
+        return {"q_w": normal(h, n * qk),
+                "kva_w": normal(h, cfg.kv_lora_rank + cfg.qk_rope_head_dim),
+                "kv_norm_g": ones(cfg.kv_lora_rank),
+                "kvb_w": normal(cfg.kv_lora_rank,
+                                n * (cfg.qk_nope_head_dim + cfg.v_head_dim)),
+                "o_w": normal(n * cfg.v_head_dim, h)}
+
+    def feed_forward(layer):
+        if layer < cfg.first_dense:
+            f = cfg.dense_width
+            return {"ffn_gate": normal(h, f), "ffn_up": normal(h, f),
+                    "ffn_down": normal(f, h)}
+        e, f = cfg.experts_here, cfg.expert_width
+        return {"router_w": normal(h, cfg.num_experts),
+                "router_bias": jnp.zeros((cfg.num_experts,), jnp.float32),
+                "w_gate": normal(e, h, f), "w_up": normal(e, h, f),
+                "w_down": normal(e, f, h),
+                "shared_gate": normal(h, f), "shared_up": normal(h, f),
+                "shared_down": normal(f, h)}
+
+    p = {"embed": normal(cfg.vocab_size, h), "layers": [],
+         "final_norm_g": ones(h), "head_w": normal(h, cfg.vocab_size)}
+    for layer in range(cfg.num_layers):
+        mixer = kda_mixer() if cfg.mixer(layer) == "kda" else mla_mixer()
+        p["layers"].append({"ln1_g": ones(h), "ln2_g": ones(h), **mixer,
+                            **feed_forward(layer)})
+    return p
+
+
+def param_specs(cfg):
+    """PartitionSpecs over ("model",): the mixers' projections split their
+    heads' dim, the dense feed-forward its width, the embedding its rows
+    and the head its columns; everything small, the experts and the router
+    are replicated."""
+    col, row = P(None, MODEL_AXIS), P(MODEL_AXIS, None)
+    split = {"q_w": col, "k_w": col, "v_w": col, "o_w": row, "f_b": col,
+             "g_b": col, "kvb_w": col, "q_conv": col, "k_conv": col,
+             "v_conv": col, "dt_bias": P(MODEL_AXIS), "ffn_gate": col,
+             "ffn_up": col, "ffn_down": row}
+    shapes = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg))
+    return {"embed": row,
+            "layers": [{name: split.get(name, P()) for name in lp}
+                       for lp in shapes["layers"]],
+            "final_norm_g": P(), "head_w": col}
+
+
+# ---------------------------------------------------------------------------
+# forward
+# ---------------------------------------------------------------------------
+# Named scopes as models/olmoe.py (embed, attention, attention_core, ffn,
+# layer_norm, loss, moe_router, moe_dispatch, moe_experts) plus kda_core,
+# short_conv, kda_gate, mla_expand and moe_shared: chipbench's per-layer
+# metrics key on them.
+@jax.named_scope("short_conv")
+def _short_conv(x, taps):
+    """Causal depthwise convolution over positions, then SiLU: x [B, S, C],
+    taps [K, C]; ``y_t = sum_j taps_j x_{t - K + 1 + j}``, no bias."""
+    k = taps.shape[0]
+    s = x.shape[1]
+    padded = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))
+    y = sum(taps[j] * padded[:, j:j + s].astype(jnp.float32)
+            for j in range(k))
+    return jax.nn.silu(y).astype(x.dtype)
+
+
+def _l2_normalize(x, scale=1.0):
+    x32 = x.astype(jnp.float32)
+    inv = jax.lax.rsqrt(jnp.sum(jnp.square(x32), axis=-1, keepdims=True)
+                        + 1e-6)
+    return (x32 * (inv * scale)).astype(x.dtype)
+
+
+@jax.named_scope("attention")
+def _kda(lp, x, cfg):
+    b, s, _ = x.shape
+    dt = x.dtype
+    n, d = cfg.kda_heads, cfg.kda_head_dim
+
+    def heads(t):
+        return t.reshape(b, s, n, d)
+
+    q, k, v = (heads(_short_conv(x @ lp[f"{name}_w"].astype(dt),
+                                 lp[f"{name}_conv"]))
+               for name in "qkv")
+    decay_in = (x @ lp["f_a"].astype(dt)) @ lp["f_b"].astype(dt)
+    gate_in = (x @ lp["g_a"].astype(dt)) @ lp["g_b"].astype(dt)
+    beta_in = x @ lp["beta_w"].astype(dt)
+    with jax.named_scope("kda_gate"):
+        q = _l2_normalize(q, d ** -0.5)
+        k = _l2_normalize(k)
+        g = -jnp.exp(lp["A_log"])[:, None] * heads(jax.nn.softplus(
+            decay_in.astype(jnp.float32) + lp["dt_bias"]))
+        beta = jax.nn.sigmoid(beta_in.astype(jnp.float32))
+    with jax.named_scope("kda_core"):
+        o = kda.kda_chunked(q, k, v, g, beta)
+    with jax.named_scope("kda_gate"):
+        o = blocks.rms_normalize(o, lp["o_norm_g"], cfg.rms_eps) \
+            * jax.nn.sigmoid(heads(gate_in).astype(jnp.float32)).astype(dt)
+    return o.reshape(b, s, -1) @ lp["o_w"].astype(dt)
+
+
+@jax.named_scope("attention")
+def _mla(lp, x, cfg, mesh=None):
+    b, s, _ = x.shape
+    dt = x.dtype
+    n, nope = cfg.num_heads, cfg.qk_nope_head_dim
+    q = (x @ lp["q_w"].astype(dt)).reshape(b, s, n, -1)
+    latent, k_shared = jnp.split(x @ lp["kva_w"].astype(dt),
+                                 [cfg.kv_lora_rank], axis=-1)
+    with jax.named_scope("mla_expand"):
+        kv = (blocks.rms_normalize(latent, lp["kv_norm_g"], cfg.rms_eps)
+              @ lp["kvb_w"].astype(dt)).reshape(b, s, n, -1)
+        k = jnp.concatenate(
+            [kv[..., :nope],
+             jnp.broadcast_to(k_shared[:, :, None, :],
+                              (b, s, n, k_shared.shape[-1]))], axis=-1)
+    ctx = blocks.causal_attention(q, k, kv[..., nope:], mesh=mesh)
+    return ctx.reshape(b, s, -1) @ lp["o_w"].astype(dt)
+
+
+def _feed_forward(lp, x, cfg, mesh=None):
+    with jax.named_scope("ffn"):
+        if "ffn_gate" in lp:
+            return blocks.gated_ffn(x, lp["ffn_gate"], lp["ffn_up"],
+                                    lp["ffn_down"]), None
+        return moe.dropless_moe_ffn(lp, x, cfg.experts_per_token, mesh=mesh,
+                                    scoring=cfg.scoring,
+                                    held=cfg.experts_held)
+
+
+def _block(lp, x, cfg, kind, mesh=None):
+    """One layer: (the stream after the mixer, after the feed-forward, the
+    expert layer's aux terms or None). A KDA mixer is recomputed in the
+    backward pass from its input (the module docstring says why); the
+    experts recompute their own part (``moe.dropless_moe_ffn``); nothing
+    else is."""
+    def mix(lp, x):
+        normed = blocks.rms_norm(x, lp["ln1_g"], cfg.rms_eps)
+        return x + (_kda(lp, normed, cfg) if kind == "kda"
+                    else _mla(lp, normed, cfg, mesh))
+
+    h = (jax.checkpoint(mix) if kind == "kda" else mix)(lp, x)
+    m, aux = _feed_forward(lp, blocks.rms_norm(h, lp["ln2_g"], cfg.rms_eps),
+                           cfg, mesh)
+    return h, h + m, aux
+
+
+def _shard_act(x, mesh):
+    if mesh is None or mesh.shape.get(DATA_AXIS, 1) == 1:
+        return x
+    return jax.lax.with_sharding_constraint(
+        x, NamedSharding(mesh, P(DATA_AXIS, None, None)))
+
+
+def _hidden_and_aux(params, cfg, input_ids, mesh=None):
+    """(final normed hidden states [B, S, H], the expert layers' aux terms
+    stacked over those layers, the residual stream after the embedding and
+    after every mixer and feed-forward, a list of 2 layers + 1)."""
+    with jax.named_scope("embed"):
+        x = jnp.take(params["embed"], input_ids, axis=0).astype(cfg.dtype)
+    x = _shard_act(x, mesh)
+    auxes, stream = [], [x]
+    for layer, lp in enumerate(params["layers"]):
+        h, x, aux = _block(lp, x, cfg, cfg.mixer(layer), mesh)
+        x = _shard_act(x, mesh)
+        stream += [h, x]
+        if aux is not None:
+            auxes.append(aux)
+    hidden = blocks.rms_norm(x, params["final_norm_g"], cfg.rms_eps)
+    return hidden, jax.tree.map(lambda *a: jnp.stack(a), *auxes), stream
+
+
+def forward(params, cfg, input_ids, mesh=None):
+    """Decoder forward; returns the final normed hidden states [B, S, H]
+    in cfg.dtype (the head is applied in ``lm_loss``)."""
+    return _hidden_and_aux(params, cfg, input_ids, mesh)[0]
+
+
+def stages(params, cfg, input_ids, mesh=None):
+    """(what every part of the forward pass hands on, [2 layers + 2, B, S, H]
+    in cfg.dtype: the embedding, the residual stream after each layer's
+    mixer and after its feed-forward, and last the final normed hidden
+    states (``forward``'s); the expert layers' aux terms of that same pass,
+    stacked over those layers: ``counts`` [layers, E], ``choice`` [layers,
+    T, k]). For a check that holds each part to a reference on that part's
+    own input: the choices are those made on the states returned, which two
+    separately compiled passes do not promise."""
+    hidden, aux, stream = _hidden_and_aux(params, cfg, input_ids, mesh)
+    return jnp.stack(stream + [hidden]), aux
+
+
+def _loss_and_counts(params, cfg, batch, mesh=None):
+    """(``lm_loss``, the assignments each expert took [expert layers, E])."""
+    from paddle_tpu.ops import pallas as _pk
+    hidden, aux, _ = _hidden_and_aux(params, cfg, batch["input_ids"], mesh)
+    with jax.named_scope("loss"), mesh_scope(mesh):
+        logits = jnp.dot(hidden, params["head_w"].astype(hidden.dtype),
+                         preferred_element_type=jnp.float32)
+        return (jnp.mean(_pk.softmax_cross_entropy(logits, batch["labels"])),
+                aux["counts"])
+
+
+def lm_loss(params, cfg, batch, mesh=None):
+    """Mean next-token cross-entropy over every position of
+    dict(input_ids, labels) [B, S], over ``cfg.vocab_size`` ids. Logits and
+    loss in float32."""
+    return _loss_and_counts(params, cfg, batch, mesh)[0]
+
+
+def routing_stats(params, cfg, batch, mesh=None, choices=False):
+    """Assignments per expert of a batch over all ``num_experts``, [expert
+    layers, experts] on the host: each row sums to ``experts_per_token``
+    times the batch's tokens; the columns of ``experts_held`` are the rows
+    this chip computes. The counter a reader takes the experts' load from.
+    With ``choices`` also the experts of each token, [expert layers, tokens,
+    experts_per_token]."""
+    aux = jax.jit(lambda p, ids: _hidden_and_aux(p, cfg, ids, mesh)[1])(
+        params, batch["input_ids"])
+    counts = np.asarray(aux["counts"])
+    return (counts, np.asarray(aux["choice"])) if choices else counts
+
+
+# ---------------------------------------------------------------------------
+# train step
+# ---------------------------------------------------------------------------
+def make_train_step(cfg, optimizer, mesh=None):
+    """(init_fn, step_fn) of ``lm_trainer.make_train_step`` for this
+    model: step(params, opt_state, batch) -> (loss, params, opt_state).
+    After the optimizer's update every router's selection bias takes one
+    step of ``moe.bias_step`` on the load of this batch."""
+    def move_biases(params, counts):
+        routers = iter(counts)
+        layers = [dict(lp, router_bias=moe.bias_step(
+            lp["router_bias"], next(routers), cfg.bias_rate))
+            if "router_bias" in lp else lp for lp in params["layers"]]
+        return dict(params, layers=layers)
+
+    return lm_trainer.make_train_step(cfg, optimizer, mesh, init_params,
+                                      param_specs, _loss_and_counts,
+                                      after_update=move_biases)
+
+
+def synthetic_batch(cfg, batch_size, seq_len, seed=0):
+    """Random next-token batch: ``seq_len + 1`` uniform ids a row, inputs
+    the first ``seq_len``, labels the last."""
+    ids = np.random.RandomState(seed).randint(
+        0, cfg.vocab_size, (batch_size, seq_len + 1), dtype=np.int32)
+    return {"input_ids": ids[:, :-1], "labels": ids[:, 1:]}

@@ -8,8 +8,10 @@ Pre-norm blocks, ``h = x + Attn(RMSNorm(x))``, ``y = h + MoE(RMSNorm(h))``:
   before they are split into heads; rotary positions (rotate-half); causal
   softmax, the flash kernels past 1024 positions (``blocks.py``);
 - experts: a float32 softmax router, the ``experts_per_token`` largest
-  probabilities not renormalised, SiLU-gated experts, every assignment
-  computed whatever the load (``parallel/moe.dropless_moe_ffn``);
+  probabilities not renormalised (``moe.Scoring``'s default; the layer also
+  knows a sigmoid with a bias, renormalised and scaled), SiLU-gated experts,
+  every assignment computed whatever the load, every expert held here
+  (``parallel/moe.dropless_moe_ffn``, which can also hold a range of them);
 - a final RMSNorm and an untied head on every position; the loss is the
   mean next-token cross-entropy plus the load-balancing loss and the router
   z-loss, each the mean over the layers, times their weights.
@@ -22,18 +24,16 @@ replicated (sharding them is ROADMAP B4).
 """
 
 import dataclasses
-import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from paddle_tpu.models import blocks
+from paddle_tpu.models import blocks, lm_trainer
 from paddle_tpu.ops.pallas.registry import mesh_scope
 from paddle_tpu.parallel import moe
-from paddle_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS, get_mesh
-from paddle_tpu.profiler import RecordEvent
+from paddle_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
 
 __all__ = ["OlmoeConfig", "olmoe_1b_7b", "olmoe_tiny", "init_params",
            "param_specs", "forward", "lm_loss", "routing_stats",
@@ -215,49 +215,10 @@ def routing_stats(params, cfg, batch, mesh=None, choices=False):
 # train step
 # ---------------------------------------------------------------------------
 def make_train_step(cfg, optimizer, mesh=None):
-    """Returns (init_fn, step_fn) jitted over the mesh with dp/tp shardings
-    pinned. step(params, opt_state, batch) -> (loss, params, opt_state);
-    params and opt_state are donated. ``step_fn.jitted`` and
-    ``step_fn.place`` as ``bert.make_train_step`` hands them out."""
-    mesh = mesh or get_mesh()
-    pspecs = param_specs(cfg)
-    if mesh.shape.get(MODEL_AXIS, 1) == 1:
-        pspecs = jax.tree.map(lambda s: P(), pspecs,
-                              is_leaf=lambda s: isinstance(s, P))
-    pshard = jax.tree.map(lambda s: NamedSharding(mesh, s), pspecs,
-                          is_leaf=lambda s: isinstance(s, P))
-
-    def init_fn(rng):
-        params = jax.jit(functools.partial(init_params, cfg=cfg),
-                         out_shardings=pshard)(rng)
-        opt_state = optimizer.init(params)
-        opt_state = jax.device_put(
-            opt_state, optimizer.state_shardings(opt_state, pshard, mesh))
-        return params, opt_state
-
-    def step(params, opt_state, batch):
-        loss, grads = jax.value_and_grad(
-            lambda p: lm_loss(p, cfg, batch, mesh=mesh))(params)
-        new_params, new_opt = optimizer.apply_gradients(
-            params, grads, opt_state)
-        return loss, new_params, new_opt
-
-    jit_step = jax.jit(step, donate_argnums=(0, 1))
-    dshard = NamedSharding(mesh, P(DATA_AXIS))
-
-    def place(batch):
-        """Put a host batch on the mesh: rows over "data"."""
-        return {name: jax.device_put(v, dshard) for name, v in batch.items()}
-
-    def step_fn(params, opt_state, batch):
-        with RecordEvent("trainer/place"):
-            batch = place(batch)
-        with RecordEvent("trainer/enqueue"):
-            return jit_step(params, opt_state, batch)
-
-    step_fn.place = place
-    step_fn.jitted = jit_step
-    return init_fn, step_fn
+    """(init_fn, step_fn) of ``lm_trainer.make_train_step`` for this
+    model: step(params, opt_state, batch) -> (loss, params, opt_state)."""
+    return lm_trainer.make_train_step(cfg, optimizer, mesh, init_params,
+                                      param_specs, lm_loss)
 
 
 def synthetic_batch(cfg, batch_size, seq_len=None, seed=0):

@@ -83,7 +83,8 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref, *,
     (max, sum, acc) — the online-softmax recurrence. The logsumexp goes
     out as row ``iq`` of the heads' [nq, bq] block, which stays in VMEM
     across the q-blocks."""
-    heads, bq, d = q_ref.shape[1:]
+    heads, bq = q_ref.shape[1:3]
+    dv = v_ref.shape[-1]
     nk = seq_len // block_k
     iq = pl.program_id(2)
 
@@ -112,7 +113,7 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref, *,
 
         init = (jnp.full((bq,), _NEG_INF, jnp.float32),
                 jnp.zeros((bq,), jnp.float32),
-                jnp.zeros((bq, d), jnp.float32))
+                jnp.zeros((bq, dv), jnp.float32))
         if nk == 1:
             m, l, acc = body(0, init)
         else:
@@ -151,6 +152,7 @@ def _flash_fwd(q, k, v, bias, sm_scale, causal, block_q, block_k,
     """(o, lse): lse is [B, H, nq, bq] float32, a lane-dense row a query
     block, as the backward reads it."""
     b, h, s, d = q.shape
+    dv = v.shape[-1]
     block_q = min(block_q, s)
     block_k = min(block_k, s)
     nq = s // block_q
@@ -175,15 +177,15 @@ def _flash_fwd(q, k, v, bias, sm_scale, causal, block_q, block_k,
         in_specs=[
             _vmem_spec((1, hb, block_q, d), q_block),
             _vmem_spec((1, hb, s, d), whole),
-            _vmem_spec((1, hb, s, d), whole),
+            _vmem_spec((1, hb, s, dv), whole),
             _vmem_spec((1, 1, s), lambda ib, ih, iq: (ib, 0, 0)),
         ],
         out_specs=[
-            _vmem_spec((1, hb, block_q, d), q_block),
+            _vmem_spec((1, hb, block_q, dv), q_block),
             _vmem_spec((1, hb, nq, block_q), whole),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct(q.shape, q.dtype),
+            jax.ShapeDtypeStruct((b, h, s, dv), q.dtype),
             jax.ShapeDtypeStruct((b, h, nq, block_q), jnp.float32),
         ],
         compiler_params=_FLASH_COMPILER_PARAMS,
@@ -239,6 +241,7 @@ def _flash_bwd_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
         v_blk = v_ref[0, ih].astype(jnp.float32)
         kt_blk = k_blk.T                                   # [d, bk]
         bk, d = k_blk.shape
+        dv = v_blk.shape[-1]
 
         def tile(jq, carry):
             dk_acc, dv_acc, db_acc = carry
@@ -274,7 +277,7 @@ def _flash_bwd_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
             return carry
 
         init = (jnp.zeros((bk, d), jnp.float32),
-                jnp.zeros((bk, d), jnp.float32),
+                jnp.zeros((bk, dv), jnp.float32),
                 jnp.zeros((bk, 1), jnp.float32))
         if unroll == nq:
             dk, dv, db = group(0, init)
@@ -302,6 +305,7 @@ def _flash_attention_bwd(sm_scale, causal, block_q, block_k, interpret,
     memory stays O(block · S); the [S, S] score matrix never exists."""
     q, k, v, bias, o, lse = res
     b, h, s, d = q.shape
+    dv = v.shape[-1]
     nq, nk = s // block_q, s // block_k
     hb = _heads_per_program(h, nq, nk)
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), -1)
@@ -323,17 +327,17 @@ def _flash_attention_bwd(sm_scale, causal, block_q, block_k, interpret,
         grid=(b, h // hb, nk),
         in_specs=[
             _vmem_spec((1, hb, s, d), whole),
-            _vmem_spec((1, hb, s, d), whole),
+            _vmem_spec((1, hb, s, dv), whole),
             _vmem_spec((1, hb, nq, block_q), whole),
             _vmem_spec((1, hb, nq, block_q), whole),
             _vmem_spec((1, hb, block_k, d), k_block),
-            _vmem_spec((1, hb, block_k, d), k_block),
+            _vmem_spec((1, hb, block_k, dv), k_block),
             _vmem_spec((1, block_k, 1), lambda ib, ih, ik: (ib, ik, 0)),
         ],
         out_specs=[
             _vmem_spec((1, hb, s, d), whole),
             _vmem_spec((1, hb, block_k, d), k_block),
-            _vmem_spec((1, hb, block_k, d), k_block),
+            _vmem_spec((1, hb, block_k, dv), k_block),
             _vmem_spec((1, hb, nk, block_k), whole),
         ],
         out_shape=[
@@ -432,9 +436,13 @@ def flash_attention(q, k, v, bias=None, causal=False, sm_scale=None,
                     block_q=512, block_k=512):
     """Blockwise (flash) attention.
 
-    q, k, v: [B, H, S, D]. bias: optional [B, S] additive key bias
-    (e.g. key-padding mask as 0 / -inf). Returns [B, H, S, D] in q.dtype.
-    Sequence is padded to the block size internally (padded keys masked).
+    q, k: [B, H, S, D]; v: [B, H, S, Dv], where Dv may differ from D (latent
+    attention scores on 192 channels and carries 128) and D need be no
+    multiple of 128: a block takes the whole head, whatever its size, and
+    Mosaic lays 192 out on two lane tiles. bias: optional [B, S] additive
+    key bias (e.g. key-padding mask as 0 / -inf). Returns [B, H, S, Dv] in
+    q.dtype. The default ``sm_scale`` is 1 / sqrt(D). Sequence is padded to
+    the block size internally (padded keys masked).
     """
     return _registry.dispatch(
         "flash_attention", q, k, v, bias=bias, causal=causal,

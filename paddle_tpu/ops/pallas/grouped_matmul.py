@@ -4,8 +4,12 @@
 the rows of ``lhs`` are sorted by group, group ``g`` owns the
 ``group_sizes[g]`` rows after those of the groups before it, and each row
 is multiplied by its own group's matrix. Rows past ``sum(group_sizes)``
-come out zero. The work is ``2 M K N`` whatever the sizes are: no capacity,
-no padding to the fullest group, no product over every group.
+come out zero. The work is ``2 sum(group_sizes) K N``, to the row tile: no
+capacity, no padding to the fullest group, no product over every group, and
+no product for the rows past the last group, which a layer that holds a
+share of a router's experts has thirty times as many of as rows of its own
+(``parallel/moe.dropless_moe_ffn``, ``held``). Those rows' tiles are written
+as zeros, at the speed of memory, and fetch nothing.
 
 Two bodies behind the kernel registry (``ops/pallas/registry.py``):
 
@@ -19,11 +23,12 @@ Two bodies behind the kernel registry (``ops/pallas/registry.py``):
   row tile and the group's matrix for each entry, so a matrix is fetched
   once for all the consecutive tiles of its group. A tile that straddles two
   groups is visited once for each and the store is masked to the group's
-  rows. Its gradient is the same call on the transposed matrices
-  (``grouped_matmul``) and the per-group ``lhs^T dout``
-  (``grouped_matmul_dw``), which sums the tiles of one group in a float32
-  scratch. Every group is on the work list, empty ones too, so an empty
-  group's gradient is written, as zeros.
+  rows. The tiles wholly past the groups' rows come last on the list, to be
+  zeroed; the weights' gradient does not visit them. Its gradient is the
+  same call on the transposed matrices (``grouped_matmul``) and the
+  per-group ``lhs^T dout`` (``grouped_matmul_dw``), which sums the tiles of
+  one group in a float32 scratch. Every group is on the work list, empty
+  ones too, so an empty group's gradient is written, as zeros.
 
 XLA:TPU lowers ``ragged_dot`` to a Mosaic kernel of its own with the same
 scheme, but that call carries no jax name stack (its ``op_name`` is
@@ -45,9 +50,9 @@ from paddle_tpu.ops.pallas import registry as _registry
 __all__ = ["grouped_matmul"]
 
 #: row tile, and the widest contraction and output blocks. At the widths of
-#: a 2048 x 1024 expert the contraction is one block, so a group's matrix
-#: stays in VMEM while its row tiles stream past it.
-_TILE_M, _TILE_K, _TILE_N = 256, 2048, 1024
+#: a 2048 x 1024 and of a 2304 x 1024 expert the contraction is one block,
+#: so a group's matrix stays in VMEM while its row tiles stream past it.
+_TILE_M, _TILE_K, _TILE_N = 256, 2304, 1024
 _COMPILER_PARAMS = pltpu.CompilerParams(
     vmem_limit_bytes=64 << 20,
     dimension_semantics=("parallel", "arbitrary", "arbitrary"))
@@ -67,30 +72,35 @@ def _tile(size, most):
 def _work_list(group_sizes, m, tm):
     """The (group, row tile) pairs to visit, in order, as fixed-size arrays.
 
-    Returns (group_offsets [G+1], group_ids [W], tile_ids [W], n_work [1])
-    with W = m // tm + G. A group visits the tiles its rows touch; an empty
-    group visits one tile and writes nothing to it; the last group also
-    visits the tiles past ``sum(group_sizes)``, whose rows it zeroes. Entries
-    from ``n_work`` on repeat the last one and are skipped."""
+    Returns (group_offsets [G+1], group_ids [W], tile_ids [W], counts [2])
+    with W = m // tm + G and counts = (n_real, n_work). The first ``n_real``
+    entries are the products: a group visits the tiles its rows touch, an
+    empty group one tile, to which it writes nothing. The entries from
+    ``n_real`` to ``n_work`` are the tiles wholly past ``sum(group_sizes)``,
+    one entry each, under the last group: they hold rows of no group, so
+    they are zeroed with no product and nothing fetched. Entries from
+    ``n_work`` on repeat the last one and are skipped."""
     g = group_sizes.shape[0]
     tiles_m = m // tm
     sizes = group_sizes.astype(jnp.int32)
     ends = jnp.cumsum(sizes)
     starts = ends - sizes
     offsets = jnp.concatenate([jnp.zeros((1,), jnp.int32), ends])
-    visit_ends = ends.at[g - 1].set(m)
     first = jnp.minimum(starts // tm, tiles_m - 1)
-    last = jnp.maximum((visit_ends - 1) // tm, first)
+    last = jnp.maximum((ends - 1) // tm, first)
     visits = last - first + 1
     w = tiles_m + g
     group_ids = jnp.repeat(jnp.arange(g, dtype=jnp.int32), visits,
                            total_repeat_length=w)
     visit_starts = jnp.cumsum(visits) - visits
-    tile_ids = first[group_ids] + jnp.arange(w, dtype=jnp.int32) \
-        - visit_starts[group_ids]
-    n_work = jnp.sum(visits)
-    tile_ids = jnp.where(jnp.arange(w) < n_work, tile_ids, tiles_m - 1)
-    return offsets, group_ids, tile_ids, n_work.reshape(1)
+    at = jnp.arange(w, dtype=jnp.int32)
+    n_real = jnp.sum(visits)
+    first_tail = (ends[g - 1] + tm - 1) // tm
+    n_work = n_real + tiles_m - first_tail
+    tile_ids = jnp.where(
+        at < n_real, first[group_ids] + at - visit_starts[group_ids],
+        jnp.minimum(first_tail + at - n_real, tiles_m - 1))
+    return offsets, group_ids, tile_ids, jnp.stack([n_real, n_work])
 
 
 def _row_mask(offsets_ref, group, tile, tm, shape):
@@ -98,12 +108,12 @@ def _row_mask(offsets_ref, group, tile, tm, shape):
     return (rows >= offsets_ref[group]) & (rows < offsets_ref[group + 1])
 
 
-def _gmm_kernel(offsets_ref, groups_ref, tiles_ref, n_work_ref,
+def _gmm_kernel(offsets_ref, groups_ref, tiles_ref, counts_ref,
                 lhs_ref, rhs_ref, out_ref, acc_ref, *, tm, transpose_rhs):
     """One (output column block, work entry, contraction block) cell."""
     i, k = pl.program_id(1), pl.program_id(2)
 
-    @pl.when(i < n_work_ref[0])
+    @pl.when(i < counts_ref[0])
     def _():
         @pl.when(k == 0)
         def _():
@@ -125,6 +135,19 @@ def _gmm_kernel(offsets_ref, groups_ref, tiles_ref, n_work_ref,
             out_ref[...] = jnp.where(mask, acc_ref[...].astype(out_ref.dtype),
                                      kept)
 
+    # a tile wholly past the groups' rows: zeros, and no product
+    @pl.when((i >= counts_ref[0]) & (i < counts_ref[1])
+             & (k == pl.num_programs(2) - 1))
+    def _():
+        out_ref[...] = jnp.zeros_like(out_ref)
+
+
+def _real(i, counts):
+    """The work entry whose operands entry ``i`` has resident: its own for
+    a product, the last product's for the entries after them, so that a
+    tile that is only zeroed, and a skipped entry, fetch nothing."""
+    return jnp.minimum(i, counts[0] - 1)
+
 
 def _gmm(lhs, rhs, group_sizes, transpose_rhs, interpret):
     """[M, K] x [G, K, N] (or [G, N, K] transposed) -> [M, N]."""
@@ -136,15 +159,17 @@ def _gmm(lhs, rhs, group_sizes, transpose_rhs, interpret):
         lhs = jnp.pad(lhs, ((0, pad), (0, 0)))
     mp = m + pad
     tk, tn = _tile(kdim, _TILE_K), _tile(n, _TILE_N)
-    offsets, group_ids, tile_ids, n_work = _work_list(group_sizes, mp, tm)
+    offsets, group_ids, tile_ids, counts = _work_list(group_sizes, mp, tm)
+    last_k = kdim // tk - 1
 
-    def lhs_map(j, i, k, offsets, groups, tiles, n_work):
-        return tiles[i], k
+    def lhs_map(j, i, k, offsets, groups, tiles, counts):
+        return tiles[_real(i, counts)], jnp.where(i < counts[0], k, last_k)
 
-    def rhs_map(j, i, k, offsets, groups, tiles, n_work):
+    def rhs_map(j, i, k, offsets, groups, tiles, counts):
+        k = jnp.where(i < counts[0], k, last_k)
         return (groups[i], j, k) if transpose_rhs else (groups[i], k, j)
 
-    def out_map(j, i, k, offsets, groups, tiles, n_work):
+    def out_map(j, i, k, offsets, groups, tiles, counts):
         return tiles[i], j
 
     out = pl.pallas_call(
@@ -163,16 +188,17 @@ def _gmm(lhs, rhs, group_sizes, transpose_rhs, interpret):
         compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
         name="grouped_matmul",
-    )(offsets, group_ids, tile_ids, n_work, lhs, rhs)
+    )(offsets, group_ids, tile_ids, counts, lhs, rhs)
     return out[:m] if pad else out
 
 
-def _tgmm_kernel(offsets_ref, groups_ref, tiles_ref, n_work_ref,
+def _tgmm_kernel(offsets_ref, groups_ref, tiles_ref, counts_ref,
                  lhs_ref, dout_ref, dw_ref, acc_ref, *, tm):
     """One (column block, contraction block, work entry) cell of the
-    weights' gradient: the tiles of one group are consecutive entries."""
+    weights' gradient: the tiles of one group are consecutive entries. The
+    tiles past the groups' rows add nothing and are not visited."""
     i = pl.program_id(2)
-    last = n_work_ref[0] - 1
+    last = counts_ref[0] - 1
 
     @pl.when(i <= last)
     def _():
@@ -203,7 +229,7 @@ def _tgmm(lhs, dout, group_sizes, interpret):
         lhs = jnp.pad(lhs, ((0, pad), (0, 0)))
         dout = jnp.pad(dout, ((0, pad), (0, 0)))
     tk, tn = _tile(kdim, _TILE_K), _tile(n, _TILE_N // 2)
-    offsets, group_ids, tile_ids, n_work = _work_list(group_sizes, m + pad,
+    offsets, group_ids, tile_ids, counts = _work_list(group_sizes, m + pad,
                                                       tm)
 
     return pl.pallas_call(
@@ -212,18 +238,20 @@ def _tgmm(lhs, dout, group_sizes, interpret):
             num_scalar_prefetch=4,
             grid=(n // tn, kdim // tk, group_ids.shape[0]),
             in_specs=[
-                pl.BlockSpec((tm, tk), lambda j, k, i, o, g, t, w: (t[i], k)),
-                pl.BlockSpec((tm, tn), lambda j, k, i, o, g, t, w: (t[i], j)),
+                pl.BlockSpec((tm, tk),
+                             lambda j, k, i, o, g, t, c: (t[_real(i, c)], k)),
+                pl.BlockSpec((tm, tn),
+                             lambda j, k, i, o, g, t, c: (t[_real(i, c)], j)),
             ],
             out_specs=pl.BlockSpec((1, tk, tn),
-                                   lambda j, k, i, o, g, t, w: (g[i], k, j)),
+                                   lambda j, k, i, o, g, t, c: (g[i], k, j)),
             scratch_shapes=[pltpu.VMEM((tk, tn), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((group_sizes.shape[0], kdim, n),
                                        lhs.dtype),
         compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
         name="grouped_matmul_dw",
-    )(offsets, group_ids, tile_ids, n_work, lhs, dout)
+    )(offsets, group_ids, tile_ids, counts, lhs, dout)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))

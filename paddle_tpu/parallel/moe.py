@@ -19,13 +19,20 @@ x mean dispatch fraction x num_experts, Switch eq. 4) is returned for
 the caller to add.
 
 ``dropless_moe_ffn`` is the other layer: gated experts, top-k, **no
-capacity and no dropped token**, for one device or experts replicated under
-a ``data`` mesh. The k T (token, expert) assignments are sorted by expert,
-the rows gathered in that order, and the experts are three grouped matmuls
-over ragged groups (``ops/pallas/grouped_matmul.py``). Both layers take
-their gate from ``route`` and their balance term from ``balance_loss``.
-The capacity path goes when the sharded dropless exchange lands (ROADMAP
-B4).
+capacity and no dropped token**. The k T (token, expert) assignments are
+sorted by expert, the rows gathered in that order, and the experts are
+three grouped matmuls over ragged groups (``ops/pallas/grouped_matmul.py``).
+How a token's scores become its experts and their weights is data of the
+call (``Scoring``: a softmax as it is, or a sigmoid with a selection bias,
+renormalised and scaled). The layer holds every expert (one device, or
+experts replicated under a ``data`` mesh), or the range of experts it is
+told it holds, as one chip of an expert-parallel job does: it routes over
+all of them, computes the part of the result its own experts give and
+leaves the rest out (the exchange that would bring the other chips' parts
+is ROADMAP B4; nothing here stands in for it). A shared expert, where the
+parameters have one, is applied to every token. Both layers take their gate
+from ``route`` and their balance term from ``balance_loss``. The capacity
+path goes when the sharded dropless exchange lands (ROADMAP B4).
 """
 
 import dataclasses
@@ -39,7 +46,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from paddle_tpu.parallel.mesh import EXPERT_AXIS
 
 __all__ = ["MoEConfig", "init_moe_params", "moe_ffn",
-           "moe_param_specs", "route", "balance_loss", "dropless_moe_ffn"]
+           "moe_param_specs", "Scoring", "route", "bias_step", "balance_loss",
+           "dropless_moe_ffn"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -95,17 +103,52 @@ def moe_sharding_spec(mesh=None):
     return ShardingSpec(mesh, params=moe_param_specs())
 
 
-def route(x32, gate_w, top_k):
+@dataclasses.dataclass(frozen=True)
+class Scoring:
+    """How router logits become a token's experts and their weights.
+    ``activation``: "softmax" over all experts (Switch, OLMoE) or "sigmoid"
+    of each logit (DeepSeek-V3, Kimi). ``renormalize``: the chosen scores
+    divided by their sum. ``scale``: a constant on the weights. A selection
+    bias is a parameter, not part of the rule: ``route`` takes it apart."""
+    activation: str = "softmax"
+    renormalize: bool = False
+    scale: float = 1.0
+
+
+def route(x32, gate_w, top_k, scoring=Scoring(), bias=None):
     """The gate both layers share: float32 logits ``x gate_w`` [T, E] at
     full precision (on a TPU a float32 product is otherwise rounded to
-    bfloat16, which flips close choices), their softmax, and the ``top_k``
-    largest probabilities of each token with the experts they belong to.
-    Returns (logits, probs, top_p [T, k], top_e [T, k])."""
+    bfloat16, which flips close choices), their scores (``scoring``), and
+    the ``top_k`` experts of each token with their weights. With ``bias``
+    [E] the experts are the largest ``scores + bias`` and the weights are
+    taken from the scores without it; the bias is outside the gradient.
+    Returns (logits, scores, top_p [T, k], top_e [T, k])."""
     logits = jnp.dot(x32, gate_w.astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
-    probs = jax.nn.softmax(logits, axis=-1)
-    top_p, top_e = jax.lax.top_k(probs, top_k)
+    probs = jax.nn.softmax(logits, axis=-1) \
+        if scoring.activation == "softmax" else jax.nn.sigmoid(logits)
+    if bias is None:
+        top_p, top_e = jax.lax.top_k(probs, top_k)
+    else:
+        _, top_e = jax.lax.top_k(
+            probs + jax.lax.stop_gradient(bias.astype(jnp.float32)), top_k)
+        top_p = jnp.take_along_axis(probs, top_e, axis=-1)
+    if scoring.renormalize:
+        top_p = top_p / (jnp.sum(top_p, axis=-1, keepdims=True) + 1e-20)
+    if scoring.scale != 1.0:
+        top_p = top_p * scoring.scale
     return logits, probs, top_p, top_e
+
+
+def bias_step(bias, counts, rate):
+    """The selection bias after one step of the rule that balances a router
+    without a loss term (DeepSeek-V3, arXiv:2412.19437, sec. 2.1.2): an
+    expert that took more than the mean of the assignments loses ``rate``,
+    one that took fewer gains it. ``counts`` [..., E] over all the router's
+    experts, as ``dropless_moe_ffn`` returns them."""
+    counts = counts.astype(jnp.float32)
+    return bias + rate * jnp.sign(
+        jnp.mean(counts, axis=-1, keepdims=True) - counts)
 
 
 def balance_loss(probs, counts):
@@ -212,48 +255,180 @@ _rows_in_token_order.defvjp(
     lambda order, dy: (jnp.take(dy, order, axis=0), None, None))
 
 
-def dropless_moe_ffn(params, x, top_k, mesh=None):
-    """Gated top-k experts with every assignment computed.
-
-    x: [..., d_model], leading dims flattened as T tokens. ``params``:
-    ``router_w`` [D, E], ``w_gate`` and ``w_up`` [E, D, F], ``w_down``
-    [E, F, D]; no biases. Per token ``sum_e p_e * w_down_e (silu(w_gate_e
-    x) * w_up_e x)`` over its ``top_k`` largest softmax probabilities, NOT
-    renormalised. Router in float32, experts in ``x.dtype``.
-
-    Returns (y, aux): ``aux["balance"]`` as ``balance_loss``, ``aux["z"]``
-    the router z-loss ``mean(logsumexp(logits)^2)``, ``aux["counts"]`` [E]
-    the assignments each expert took (they sum to ``top_k * T``),
-    ``aux["choice"]`` [T, top_k] the experts of each token.
-
-    The experts are replicated: under a mesh the grouped matmul takes the
-    body GSPMD can partition (``mesh_scope``). Named scopes, inside the
-    caller's ``ffn``: ``moe_router``, ``moe_dispatch``, ``moe_experts``."""
+def _gated_experts(rows, weights, sizes, mesh):
+    """Rows sorted by expert through their experts' gated feed-forward:
+    three grouped matmuls over the groups ``sizes``."""
     from paddle_tpu.models.blocks import gated_ffn
     from paddle_tpu.ops.pallas.grouped_matmul import grouped_matmul
     from paddle_tpu.ops.pallas.registry import mesh_scope
+
+    with jax.named_scope("moe_experts"), mesh_scope(mesh):
+        return gated_ffn(rows, *weights,
+                         matmul=lambda a, w: grouped_matmul(a, w, sizes))
+
+
+#: Rows of the held experts' assignments one pass of ``_held_experts``
+#: takes. A pass gathers its rows, runs the three grouped matmuls on them
+#: and adds their weighted outputs into the tokens' rows; the number of
+#: passes follows the rows held (``ceil(rows / HELD_ROW_TILE)``), so nothing
+#: is sized for the router's worst case and nothing is dropped. A blocking
+#: size, not a limit: what a pass pays whatever its fill (the gather and the
+#: sum back of a full tile, the gate's elementwise pass, the weights'
+#: gradients added into their sum) is paid once for up to 8192 rows, four
+#: times what a balanced router sends to 8 of 256 experts from 8192 tokens,
+#: and a pass's rows and products stay under 0.2 GiB. With 14% of the
+#: assignments held (9218 rows in the fullest layer: two passes) the Kimi
+#: Linear step is 5.6 ms longer than with 4.4%, 4.2 ms of it the experts'
+#: products on their rows (PERF.md section 6, PR 30).
+HELD_ROW_TILE = 8192
+
+
+def _held_pass(i, order, top_p, sizes, tile):
+    """What pass ``i`` works on: the places [tile] its assignments have in
+    (token, choice) order, their weights (0 past the rows held), and the
+    rows each held expert has inside this pass [n]."""
+    lo = i * tile
+    at = jax.lax.dynamic_slice(order, (lo,), (tile,))
+    ends = jnp.cumsum(sizes)
+    weight = jnp.where(lo + jnp.arange(tile) < ends[-1],
+                       jnp.take(top_p.reshape(-1), at), 0.0)
+    part = jnp.clip(jnp.minimum(ends, lo + tile)
+                    - jnp.maximum(ends - sizes, lo), 0, None)
+    return at, weight, part
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def _held_experts(xt, top_p, weights, order, sizes, top_k, mesh, tile):
+    """The experts' part for a layer that holds a share of them, [T, D]
+    float32. ``order`` [a multiple of ``tile``] lists the assignments with
+    the held ones first, sorted by expert; ``sizes`` [n] how many each held
+    expert took. The rows are worked ``tile`` at a time in a loop whose trip
+    count is ``ceil(sum(sizes) / tile)``, forward and backward, so the time
+    follows the rows held. The backward pass keeps the tokens, the order and
+    the scores and makes each pass's rows and products again."""
+    passes = -(-jnp.sum(sizes) // tile)
+
+    def one(i, y):
+        at, weight, part = _held_pass(i, order, top_p, sizes, tile)
+        with jax.named_scope("moe_dispatch"):
+            token = at // top_k
+            rows = jnp.take(xt, token, axis=0)
+        out = _gated_experts(rows, weights, part, mesh)
+        with jax.named_scope("moe_dispatch"):
+            return y.at[token].add(out.astype(jnp.float32)
+                                   * weight[:, None])
+
+    return jax.lax.fori_loop(0, passes, one,
+                             jnp.zeros(xt.shape, jnp.float32))
+
+
+def _held_fwd(xt, top_p, weights, order, sizes, top_k, mesh, tile):
+    return (_held_experts(xt, top_p, weights, order, sizes, top_k, mesh,
+                          tile), (xt, top_p, weights, order, sizes))
+
+
+def _held_bwd(top_k, mesh, tile, kept, dy):
+    xt, top_p, weights, order, sizes = kept
+    passes = -(-jnp.sum(sizes) // tile)
+    dy = dy.astype(jnp.float32)
+
+    def one(i, grads):
+        dx, dp, dw = grads
+        at, weight, part = _held_pass(i, order, top_p, sizes, tile)
+        with jax.named_scope("moe_dispatch"):
+            token = at // top_k
+            rows = jnp.take(xt, token, axis=0)
+            dy_rows = jnp.take(dy, token, axis=0)
+        out, back = jax.vjp(
+            lambda r, w: _gated_experts(r, w, part, mesh), rows, weights)
+        d_rows, dw_pass = back((dy_rows * weight[:, None]).astype(out.dtype))
+        with jax.named_scope("moe_dispatch"):
+            # a row past the rows held came out zero: its score gets nothing
+            dp = dp.at[at].add(jnp.sum(out.astype(jnp.float32) * dy_rows,
+                                       axis=-1))
+            dx = dx.at[token].add(d_rows.astype(jnp.float32))
+        return dx, dp, jax.tree.map(jnp.add, dw, dw_pass)
+
+    dx, dp, dw = jax.lax.fori_loop(0, passes, one, (
+        jnp.zeros(xt.shape, jnp.float32), jnp.zeros(top_p.size, jnp.float32),
+        jax.tree.map(jnp.zeros_like, weights)))
+    return (dx.astype(xt.dtype), dp.reshape(top_p.shape).astype(top_p.dtype),
+            dw, None, None)
+
+
+_held_experts.defvjp(_held_fwd, _held_bwd)
+
+
+def dropless_moe_ffn(params, x, top_k, mesh=None, scoring=Scoring(),
+                     held=None):
+    """Gated top-k experts with every assignment computed.
+
+    x: [..., d_model], leading dims flattened as T tokens. ``params``:
+    ``router_w`` [D, E], ``w_gate`` and ``w_up`` [n, D, F], ``w_down``
+    [n, F, D]; no biases. Optionally ``router_bias`` [E], the selection
+    bias of ``route``, and ``shared_gate`` / ``shared_up`` / ``shared_down``
+    ([D, F], [D, F], [F, D]), a gated expert every token takes with weight
+    1. Per token ``sum_e w_e * w_down_e (silu(w_gate_e x) * w_up_e x)`` over
+    its ``top_k`` experts, chosen and weighted as ``scoring`` says (default:
+    the largest softmax probabilities, NOT renormalised). Router in float32,
+    experts in ``x.dtype``.
+
+    ``held`` = (first, n): this layer holds the experts ``first`` to
+    ``first + n - 1`` of the router's E, and the stacks have n matrices. It
+    routes over all E, computes the assignments that fall on its own and
+    leaves the others out of ``y`` (another chip's part). ``None``: all E.
+    The assignments held come first in expert order and are worked
+    ``HELD_ROW_TILE`` rows a pass, as many passes as they need
+    (``_held_experts``): one path, whose time follows the rows held, with
+    nothing sized for a router's worst case and nothing dropped. The
+    backward pass makes each pass's rows and products again (the tokens,
+    the order and the scores are kept, not the rows).
+
+    Returns (y, aux): ``aux["balance"]`` as ``balance_loss``, ``aux["z"]``
+    the router z-loss ``mean(logsumexp(logits)^2)``, ``aux["counts"]`` [E]
+    the assignments each expert took, held or not (they sum to ``top_k *
+    T``), ``aux["choice"]`` [T, top_k] the experts of each token.
+
+    Under a mesh the grouped matmul takes the body GSPMD can partition
+    (``mesh_scope``). Named scopes, inside the caller's ``ffn``:
+    ``moe_router``, ``moe_dispatch``, ``moe_experts``, ``moe_shared``."""
+    from paddle_tpu.models.blocks import gated_ffn
 
     shape = x.shape
     xt = x.reshape(-1, shape[-1])
     e = params["router_w"].shape[-1]
     with jax.named_scope("moe_router"):
         logits, probs, top_p, top_e = route(
-            xt.astype(jnp.float32), params["router_w"], top_k)
+            xt.astype(jnp.float32), params["router_w"], top_k, scoring,
+            params.get("router_bias"))
         counts = jnp.bincount(top_e.reshape(-1), length=e)
         aux = {"balance": balance_loss(probs, counts),
                "z": jnp.mean(jnp.square(
                    jax.nn.logsumexp(logits, axis=-1))),
                "counts": counts, "choice": top_e}
-    with jax.named_scope("moe_dispatch"):
-        order = jnp.argsort(top_e.reshape(-1), stable=True)    # [k T]
-        inverse = jnp.argsort(order)
-        rows = _rows_in_expert_order(xt, order, inverse, top_k)
-    with jax.named_scope("moe_experts"), mesh_scope(mesh):
-        out = gated_ffn(
-            rows, params["w_gate"], params["w_up"], params["w_down"],
-            matmul=lambda a, w: grouped_matmul(a, w, counts))
-    with jax.named_scope("moe_dispatch"):
-        out = _rows_in_token_order(out, order, inverse)
-        y = jnp.sum(out.reshape(-1, top_k, shape[-1]).astype(jnp.float32)
-                    * top_p[..., None], axis=1)
+    weights = (params["w_gate"], params["w_up"], params["w_down"])
+    if held is None:
+        with jax.named_scope("moe_dispatch"):
+            order = jnp.argsort(top_e.reshape(-1), stable=True)    # [k T]
+            inverse = jnp.argsort(order)
+            rows = _rows_in_expert_order(xt, order, inverse, top_k)
+        out = _gated_experts(rows, weights, counts, mesh)
+        with jax.named_scope("moe_dispatch"):
+            out = _rows_in_token_order(out, order, inverse)
+            y = jnp.sum(out.reshape(-1, top_k, shape[-1])
+                        .astype(jnp.float32) * top_p[..., None], axis=1)
+    else:
+        first, n = held
+        with jax.named_scope("moe_dispatch"):
+            here = (top_e >= first) & (top_e < first + n)
+            key = jnp.where(here, top_e - first, n)     # the others: last
+            order = jnp.argsort(key.reshape(-1), stable=True)
+            tile = min(HELD_ROW_TILE, order.shape[0])
+            order = jnp.pad(order, (0, -order.shape[0] % tile))
+        y = _held_experts(xt, top_p, weights, order,
+                          counts[first:first + n], top_k, mesh, tile)
+    if "shared_gate" in params:
+        with jax.named_scope("moe_shared"):
+            y = y + gated_ffn(xt, params["shared_gate"], params["shared_up"],
+                              params["shared_down"]).astype(jnp.float32)
     return y.reshape(shape).astype(x.dtype), aux

@@ -1,0 +1,61 @@
+"""Flash attention where the value heads have a size of their own and the
+score heads are no multiple of the 128-lane grain (latent attention: 192 for
+the scores, 128 for the values): the Pallas body in interpret mode against
+the dense body, outputs and the three gradients, through the kernel's entry
+point and through ``blocks.causal_attention``."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from paddle_tpu.models import blocks
+from paddle_tpu.ops import pallas as plk
+
+#: (positions, score head size, value head size): the published 192 / 128 at
+#: one tile a head; several tiles a head; a length and sizes that are
+#: multiples of nothing; equal sizes, as every other model has them
+CASES = [(256, 192, 128), (1024, 48, 32), (300, 24, 16), (512, 64, 64)]
+
+
+def qkv(positions, d, dv, seed=0, heads=2):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    q, k = (jax.random.normal(key, (1, heads, positions, d)) for key in ks[:2])
+    v, w = (jax.random.normal(key, (1, heads, positions, dv))
+            for key in ks[2:])
+    return q, k, v, w
+
+
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+@pytest.mark.parametrize("positions,d,dv", CASES)
+def test_kernel_matches_the_dense_body(positions, d, dv, causal):
+    q, k, v, w = qkv(positions, d, dv)
+
+    def f(q, k, v, mode):
+        with plk.override(mode):
+            return plk.flash_attention(q, k, v, causal=causal)
+
+    out = f(q, k, v, "on")
+    assert out.shape == (1, 2, positions, dv)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(f(q, k, v, "off")),
+                               atol=2e-5)
+    got, want = (jax.grad(lambda q, k, v: jnp.sum(f(q, k, v, mode) * w),
+                          (0, 1, 2))(q, k, v) for mode in ("on", "off"))
+    for name, a, b in zip("qkv", got, want):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-5,
+                                   err_msg=f"d{name}")
+
+
+@pytest.mark.parametrize("impl", ["dense", "flash"])
+def test_causal_attention_takes_a_value_head_size_of_its_own(impl):
+    """[B, S, N, 24] queries and keys, [B, S, N, 16] values, against the
+    softmax written out; the scale is 1 / sqrt(24)."""
+    q, k, v, _ = (t.transpose(0, 2, 1, 3) for t in qkv(96, 24, 16, seed=1))
+    with plk.override("on"):
+        got = blocks.causal_attention(q, k, v, impl=impl)
+    scores = jnp.einsum("bqnd,bknd->bnqk", q, k) / np.sqrt(24)
+    scores = jnp.where(jnp.tril(jnp.ones((96, 96), bool)), scores, -jnp.inf)
+    want = jnp.einsum("bnqk,bknd->bqnd", jax.nn.softmax(scores, axis=-1), v)
+    assert got.shape == (1, 96, 2, 16)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)

@@ -36,6 +36,10 @@ CASES = {
     "many_in_one_tile": (1024, 256, 128, [100, 56, 60, 40, 256, 0, 512]),
     "rows_left_over": (600, 128, 256, [10, 20, 30]),
     "aligned": (768, 128, 128, [256, 256, 256]),
+    # a share of the experts held: most tiles lie past the groups' rows
+    "tiles_past_the_rows": (2048, 128, 128, [40, 0, 300, 17]),
+    "no_rows_at_all": (768, 128, 128, [0, 0, 0]),
+    "two_contraction_blocks": (512, 4608, 128, [200, 0, 100]),
 }
 
 
@@ -77,6 +81,25 @@ def test_the_work_list_visits_each_tile_of_each_group_once_in_order():
                       (5, 2), (6, 2)]
     assert len(groups) == 768 // 256 + 7
     assert tiles[n:].tolist() == [2] * (len(groups) - n)    # skipped entries
+    assert n_work.tolist() == [n, n]          # no tile past the groups' rows
+
+
+def test_the_work_list_zeroes_the_tiles_past_the_rows_without_a_product():
+    """A layer that holds a share of the experts: 357 rows of 2048 are real.
+    The products visit the two tiles that hold them; the six tiles wholly
+    past them come after, one entry each, to be zeroed; the rest is
+    skipped."""
+    sizes = jnp.asarray([40, 0, 300, 17], jnp.int32)
+    _, groups, tiles, counts = _work_list(sizes, 2048, 256)
+    n_real, n_work = counts.tolist()
+    visits = list(zip(groups[:n_real].tolist(), tiles[:n_real].tolist()))
+    assert visits == [(0, 0), (1, 0), (2, 0), (2, 1), (3, 1)]
+    assert tiles[n_real:n_work].tolist() == [2, 3, 4, 5, 6, 7]
+    assert set(groups[n_real:].tolist()) == {3}     # the last group's matrix
+    assert len(groups) == 2048 // 256 + 4
+    # nothing held at all: every group its one empty visit, every tile zeroed
+    _, _, tiles, counts = _work_list(jnp.zeros((3,), jnp.int32), 768, 256)
+    assert counts.tolist() == [3, 6] and tiles.tolist() == [0, 0, 0, 0, 1, 2]
 
 
 def test_bfloat16_rows_take_float32_master_weights():

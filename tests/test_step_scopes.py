@@ -21,7 +21,7 @@ import pytest
 import paddle_tpu as pt
 from paddle_tpu import profiler
 from paddle_tpu.core import compile_cache
-from paddle_tpu.models import bert, olmoe, transformer
+from paddle_tpu.models import bert, kimi_linear, olmoe, transformer
 from paddle_tpu.monitor import flight_recorder
 from paddle_tpu.parallel.mesh import MeshConfig, make_mesh
 
@@ -31,7 +31,10 @@ MODEL_SCOPES = ("embed", "attention", "attention_core", "ffn", "layer_norm",
 #: lists them under "scopes")
 FAMILY_SCOPES = {"bert": (), "transformer": (),
                  "olmoe": ("rope", "moe_router", "moe_dispatch",
-                           "moe_experts")}
+                           "moe_experts"),
+                 "kimi_linear": ("kda_core", "short_conv", "kda_gate",
+                                 "mla_expand", "moe_router", "moe_dispatch",
+                                 "moe_experts", "moe_shared")}
 
 
 def _tiny(family):
@@ -47,6 +50,10 @@ def _tiny(family):
         cfg = olmoe.olmoe_tiny()
         init_fn, step_fn = olmoe.make_train_step(cfg, opt, mesh)
         batch = olmoe.synthetic_batch(cfg, 4, 16)
+    elif family == "kimi_linear":
+        cfg = kimi_linear.kimi_linear_tiny(experts_held=(4, 4))
+        init_fn, step_fn = kimi_linear.make_train_step(cfg, opt, mesh)
+        batch = kimi_linear.synthetic_batch(cfg, 2, 24)
     else:
         cfg = transformer.transformer_tiny()
         init_fn, step_fn = transformer.make_train_step(cfg, opt, mesh)
@@ -55,7 +62,8 @@ def _tiny(family):
     return step_fn, params, opt_state, batch
 
 
-@pytest.mark.parametrize("family", ["bert", "transformer", "olmoe"])
+@pytest.mark.parametrize("family", ["bert", "transformer", "olmoe",
+                                    "kimi_linear"])
 def test_lowered_step_names_every_scope_forward_and_backward(family):
     """Each model scope is on a name stack under ``jvp(`` (forward) and on one
     under ``transpose(jvp(`` (backward); ``optimizer`` is under neither. jax
@@ -105,7 +113,8 @@ def _host_events(trace_dir):
             for line in plane.lines for e in line.events]
 
 
-@pytest.mark.parametrize("family", ["bert", "transformer", "olmoe"])
+@pytest.mark.parametrize("family", ["bert", "transformer", "olmoe",
+                                    "kimi_linear"])
 def test_step_fn_writes_its_two_spans_into_a_jax_profile(family, tmp_path):
     """Two steps under ``jax.profiler``: ``trainer/place`` and
     ``trainer/enqueue`` are on the host plane, twice each, one after the

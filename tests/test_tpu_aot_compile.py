@@ -28,7 +28,7 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
 import paddle_tpu as pt
-from paddle_tpu.models import bert, olmoe, transformer
+from paddle_tpu.models import bert, kimi_linear, olmoe, transformer
 from paddle_tpu.ops import pallas as plk
 from paddle_tpu.ops.pallas import registry
 from paddle_tpu.parallel.data_parallel import DataParallelTrainer
@@ -414,6 +414,88 @@ def test_olmoe_step_at_published_widths_fits_a_v5e_and_names_its_calls(
             "pallas_call",
             "jit(step)/jvp(loss)/softmax_xent_fwd/pallas_call"):
         assert name in op_names, (name, sorted(op_names))
+
+
+# ---------------------------------------------------------------------------
+# (c2) what Kimi Linear brought: flash at 192 / 128, a share of the experts,
+# the chunked delta rule, and the step of kimi_linear_48b_a3b.lm_s8192
+# ---------------------------------------------------------------------------
+def test_flash_compiles_with_score_heads_of_192_beside_value_heads_of_128(
+        one_chip):
+    """Latent attention at 8192 positions: queries and keys of 192 channels
+    (no multiple of the 128-lane grain: Mosaic lays them on two lane tiles),
+    values of 128; forward and the one backward call."""
+    q = _abstract((1, 32, 8192, 192), BF16, one_chip)
+    v = _abstract((1, 32, 8192, 128), BF16, one_chip)
+
+    def fn(q, k, v):
+        return jax.value_and_grad(lambda *a: jnp.sum(plk.flash_attention(
+            *a, causal=True).astype(F32)), (0, 1, 2))(q, k, v)
+    compiled = _compile(fn, q, q, v)
+    assert sorted(_mosaic_call_stems(compiled)) == ["flash_bwd", "flash_fwd"]
+
+
+def test_grouped_matmul_compiles_for_a_share_of_the_experts(one_chip):
+    """8 of 256 experts of 2304 x 1024 over the 8192 rows the expert layer
+    sizes for them: the contraction is one block, the tiles past the rows
+    held are on the work list to be zeroed."""
+    args = [_abstract((8192, 2304), BF16, one_chip),
+            _abstract((8, 2304, 1024), BF16, one_chip),
+            _abstract((8,), I32, one_chip)]
+
+    def fn(lhs, rhs, sizes):
+        return jax.value_and_grad(lambda a, b: jnp.sum(plk.grouped_matmul(
+            a, b, sizes).astype(F32)), (0, 1))(lhs, rhs)
+    compiled = _compile(fn, *args)
+    assert _mosaic_calls(compiled) == 3    # product, rows' and weights' grad
+
+
+@pytest.fixture(scope="module")
+def kimi_linear_full_size(topo):
+    """The step of the cell kimi_linear_48b_a3b.lm_s8192: the published
+    layers 1 to 5 at the published widths, 8 of 256 experts, an eighth of
+    the vocabulary, batch 1 x 8192. The expert layers' loop over the rows
+    held is part of the program whatever a batch routes, so a router
+    collapsed onto the held experts runs what is compiled here."""
+    cfg = kimi_linear.kimi_linear_48b_a3b(num_layers=5, vocab_size=20480,
+                                          experts_held=(0, 8))
+    return _lower_replicated(
+        kimi_linear.make_train_step, kimi_linear.init_params, cfg,
+        kimi_linear.synthetic_batch(cfg, 1, 8192), topo)
+
+
+@pytest.mark.timeout(900)
+def test_kimi_linear_step_at_published_widths_fits_a_v5e(
+        kimi_linear_full_size):
+    """602.4 M parameters with their two Adam moments are 6.7 GiB of the
+    step's arguments; with every KDA mixer recomputed the whole step needs
+    less than the 15.75 GiB a v5e gives a program; its Mosaic calls are the
+    flash kernels (the MLA layer), the grouped matmuls (the four expert
+    layers' one loop over the rows held, forward and backward) and the
+    cross-entropy, each under its scope."""
+    compiled, pshape, _ = kimi_linear_full_size
+    assert sum(int(np.prod(a.shape)) for a in jax.tree.leaves(pshape)) \
+        == 602_434_432
+    ma = compiled.memory_analysis()
+    assert ma.argument_size_in_bytes > 6.7 * 2**30
+    need = _need_bytes(compiled)
+    assert need < 15.75 * 2**30, need / 2**30
+    stems = _mosaic_call_stems(compiled)
+    assert set(stems) == {"flash_fwd", "flash_bwd", "softmax_xent_fwd",
+                          "grouped_matmul", "grouped_matmul_dw"}
+    assert stems.count("flash_fwd") == stems.count("flash_bwd") == 1
+    calls = [line for line in compiled.as_text().splitlines()
+             if "tpu_custom_call" in line]
+    op_names = "\n".join(re.findall(r'op_name="([^"]*)"', "\n".join(calls)))
+    for scope, kernel in (("attention_core", "flash_fwd"),
+                          ("attention_core", "flash_bwd"),
+                          ("moe_experts", "grouped_matmul"),
+                          ("moe_experts", "grouped_matmul_dw"),
+                          ("loss", "softmax_xent_fwd")):
+        assert re.search(rf"{scope}[^\n]*/{kernel}/pallas_call", op_names), \
+            (scope, kernel)
+    # the scan is XLA's: its chunks are a loop of the compiled program
+    assert "kda_core" in compiled.as_text()
 
 
 # ---------------------------------------------------------------------------
