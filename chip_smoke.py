@@ -171,6 +171,11 @@ def phase_kernels():
     def bf16(*shape):
         return f32(*shape).astype(jnp.bfloat16)
 
+    def unit(*shape):
+        t = f32(*shape)
+        return (t / jnp.linalg.norm(t, axis=-1, keepdims=True)) \
+            .astype(jnp.bfloat16)
+
     def sum_f32(tree):
         return sum(jnp.sum(leaf.astype(jnp.float32))
                    for leaf in jax.tree.leaves(tree))
@@ -207,6 +212,14 @@ def phase_kernels():
         "grouped_matmul": ((f32(4096, h), f32(8, h, ffn, scale=0.02),
                             jnp.asarray([0, 1000, 7, 300, 1500, 0, 1289, 0],
                                         jnp.int32)), {}, (0, 1), 2e-2),
+        # two heads of 128, ten grid steps of 128 positions with padding; q, k
+        # of unit norm, decays in (-1, 0], beta in (0, 1), as a KDA mixer
+        # hands them over; both bodies round the same operands to bf16
+        "kda_chunked": ((unit(2, 1200, 2, 128), unit(2, 1200, 2, 128),
+                         bf16(2, 1200, 2, 128),
+                         -jax.nn.sigmoid(f32(2, 1200, 2, 128)),
+                         jax.nn.sigmoid(f32(2, 1200, 2))),
+                        {"chunk": 32}, (0, 1, 2, 3, 4), 3e-2),
     }
     missing = set(plk.list_kernels()) ^ set(cases)
     if missing:

@@ -38,14 +38,43 @@ on the diagonal is summed channel by channel (``_diag_scores``).
 operands are in the dtype of ``q`` (bfloat16 in a model) with float32
 accumulation.
 
-**The backward** is autodiff through the scan over chunks with the chunk
-body recomputed (``jax.checkpoint``), so the scan keeps the state each chunk
-starts from and nothing else of a chunk; ``_diag_scores`` has a backward of
-its own that forms the [sub, sub, d] exponentials again instead of keeping
-them (they would be 2 GiB a layer at 8192 positions and 32 heads). What the
-part before the scan keeps is a dozen arrays of the inputs' size; a caller
-that cannot afford them wraps the call in ``jax.checkpoint`` and keeps the
-inputs alone (``models/kimi_linear.py`` does).
+**Two bodies.** ``kda_chunked`` is a kernel of the Pallas registry
+(``ops/pallas/registry.py``: the platform and ``mesh_scope`` choose, nothing
+a caller sets). The **reference** body is this file's ``jax.numpy`` code:
+what the CPU and a mesh of several devices run, and what the other is held
+against. The **Pallas** body (``ops/pallas/kda.py``: the kernels ``kda_fwd``
+and ``kda_bwd``) runs where a head is a whole lane tile (``d % 128 == 0``)
+and hands any other shape back to this one. Both cut the positions into
+chunks of ``CHUNK`` and round the same operands.
+
+**What each keeps for the backward.** The reference body's backward is
+autodiff through the scan over chunks with the chunk body recomputed
+(``jax.checkpoint``), so the scan keeps the state each chunk starts from and
+nothing else of a chunk; ``_diag_scores`` has a backward of its own that
+forms the [sub, sub, d] exponentials again instead of keeping them (they
+would be 2 GiB a layer at 8192 positions and 32 heads); what the part before
+the scan keeps is a dozen arrays of the inputs' size. The Pallas body keeps,
+for every 128 positions and head, the state they start from and three
+``[128, 128]`` tiles (``a_qk``, ``P_kk`` and the inverse), 402 MB a layer at
+8192 positions and 32 heads of 128, and forms everything else again inside
+``kda_bwd``. A caller that cannot afford either wraps the call in
+``jax.checkpoint`` and keeps the inputs alone (``models/kimi_linear.py``
+does).
+
+**Why the Pallas body inverts.** The reference body solves ``(I + A) X =
+Diag(beta) [V | K * exp(G)]`` with ``jax.scipy.linalg.solve_triangular``, a
+custom call nothing fuses with (2.3 ms a layer). A kernel has matmuls:
+``(I + A)^{-1}`` is formed explicitly in float32 by the block formula
+``[[L11^-1, 0], [-L22^-1 L21 L11^-1, L22^-1]]``, level by level from blocks
+of one row, and multiplied in; ``A`` is strictly lower triangular with
+entries under 1, the same substitution in another order.
+
+**Where the Pallas body rounds:** where this one does (the paragraph
+above), with one freedom: the pairs of positions inside one sub-block of
+``_SUB`` = 16 rows, which this body sums channel by channel in float32, are
+matmuls there too, with float32 operands where the pair lies inside a block
+of 8 rows and operands in the inputs' dtype from 8 up (this body: from 16
+up).
 
 The op is jitted, so a model's layers, which call it with the same shapes,
 share one trace and one lowering (PERF.md section 6, PR 29).
@@ -57,15 +86,21 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from paddle_tpu.ops.pallas import registry as _registry
+
 __all__ = ["CHUNK", "kda_chunked", "kda_recurrent"]
 
-#: positions a chunk. Chosen on the chip (PERF.md section 6, PR 30: the table
-#: of 16 to 128 at 8192 positions and 32 heads of 128; forward + backward
-#: 36.4 ms a layer at 32, 37.2 at 16, 40.6 at 64, 68.3 at 128): a constant
-#: of the op, not an option.
+#: positions a chunk, of whichever body runs. Chosen on the chip for the
+#: reference body (PERF.md section 6, PR 30: the table of 16 to 128 at 8192
+#: positions and 32 heads of 128; forward + backward 36.4 ms a layer at 32,
+#: 37.2 at 16, 40.6 at 64, 68.3 at 128): a constant of the op, not an option.
+#: Read outside this package by the benchmark: ``chipbench/flops/kda_core.py``
+#: counts the op's operations at it (``mfu_pct``, ``kda_core_roofline_pct``)
+#: and ``chipbench/tests/test_kimi_linear_cell.py`` pins it, so a change of
+#: it is a ``benchmark`` PR's.
 CHUNK = 32
-#: positions a sub-block of the score matrices inside a chunk (same table:
-#: 8 and 32 both cost 8% more than 16 at chunk 32)
+#: positions a sub-block of the reference body's score matrices inside a
+#: chunk (same table: 8 and 32 both cost 8% more than 16 at chunk 32)
 _SUB = 16
 
 
@@ -224,7 +259,7 @@ def kda_chunked(q, k, v, g, beta):
     takes them: normalised, q scaled), the log decay g [B, S, H, d] (<= 0)
     and the write strength beta [B, S, H], from a zero state. Returns
     [B, S, H, d] in ``v.dtype``. Differentiable in all five."""
-    return _kda_chunked(q, k, v, g, beta, CHUNK)
+    return _registry.dispatch("kda_chunked", q, k, v, g, beta, CHUNK)
 
 
 def kda_recurrent(q, k, v, g, beta):

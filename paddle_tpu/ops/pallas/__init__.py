@@ -4,8 +4,10 @@
 Importing this package registers every built-in kernel, one module each:
 fused_matmul / fused_matmul_int8 (matmul.py), embedding_scatter_add
 (embedding.py), grouped_matmul (grouped_matmul.py), flash_attention
-(flash_attention.py), fused_layer_norm (layer_norm.py) and
-softmax_cross_entropy (softmax_xent.py). The entry points the models call
+(flash_attention.py), fused_layer_norm (layer_norm.py),
+softmax_cross_entropy (softmax_xent.py) and kda_chunked (kda.py; its entry
+point is ``paddle_tpu.ops.kda.kda_chunked``, beside the reference body and
+the recurrence it stands for). The other entry points the models call
 are names of this package; ``flash_attention`` and ``grouped_matmul`` here
 are therefore the functions, not the modules of the same name (import a
 module's own names with ``from paddle_tpu.ops.pallas.<module> import ...``)."""
@@ -18,6 +20,7 @@ from paddle_tpu.ops.pallas.registry import (  # noqa: F401
 from paddle_tpu.ops.pallas import embedding as _embedding  # noqa: F401
 from paddle_tpu.ops.pallas.flash_attention import flash_attention
 from paddle_tpu.ops.pallas.grouped_matmul import grouped_matmul
+from paddle_tpu.ops.pallas import kda as _kda  # noqa: F401
 from paddle_tpu.ops.pallas.layer_norm import fused_layer_norm
 from paddle_tpu.ops.pallas.matmul import try_fused_matmul
 from paddle_tpu.ops.pallas.softmax_xent import softmax_cross_entropy

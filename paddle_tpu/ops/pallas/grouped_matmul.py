@@ -149,6 +149,11 @@ def _real(i, counts):
     return jnp.minimum(i, counts[0] - 1)
 
 
+# Jitted functions of their own, as the flash and the delta-rule calls are: an
+# expert layer calls each three times, and a model's layers share one trace
+# of a call and one lowering to Mosaic for every shape (PERF.md section 6,
+# PR 32).
+@functools.partial(jax.jit, static_argnums=(3, 4))
 def _gmm(lhs, rhs, group_sizes, transpose_rhs, interpret):
     """[M, K] x [G, K, N] (or [G, N, K] transposed) -> [M, N]."""
     m, kdim = lhs.shape
@@ -219,6 +224,7 @@ def _tgmm_kernel(offsets_ref, groups_ref, tiles_ref, counts_ref,
             dw_ref[0] = acc_ref[...].astype(dw_ref.dtype)
 
 
+@functools.partial(jax.jit, static_argnums=(3,))
 def _tgmm(lhs, dout, group_sizes, interpret):
     """Per group, ``lhs[rows]^T dout[rows]``: [M, K], [M, N] -> [G, K, N]."""
     m, kdim = lhs.shape

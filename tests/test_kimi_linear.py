@@ -12,6 +12,7 @@ counter the benchmark reads.
 """
 
 import dataclasses
+import importlib
 import importlib.util
 import pathlib
 
@@ -393,3 +394,59 @@ def test_the_train_step_moves_the_selection_bias_against_the_load(tiny):
     np.testing.assert_allclose(
         np.asarray(moe.bias_step(jnp.zeros(3), jnp.asarray([5, 1, 3]), 0.5)),
         [-0.5, 0.5, 0.0])
+
+
+# ---------------------------------------------------------------------------
+# the set-up: a kernel is traced for the step, not for every layer
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("kda_layers", [1, 3])
+def test_a_step_traces_a_kernel_body_once_however_many_layers_call_it(
+        kda_layers, monkeypatch):
+    """Every Pallas call is a jitted function of its own that the layers
+    share, so tracing a step enters a kernel's body once for each context jax
+    traces in (the pass; and, for the delta rule's forward, the JVP of the
+    mixer's ``jax.checkpoint``, which jax traces under a mesh context of its
+    own), never once a layer: a body traced anew a layer costs every set-up
+    its trace and its lowering to Mosaic a layer (PERF.md section 6, PR 29
+    and PR 32: 5.2 s in the BERT cells, 6 s in the Kimi Linear cell). Heads
+    of 128 channels, so that the delta rule takes its kernels; one dense
+    layer and ``kda_layers`` expert layers, three grouped matmuls each."""
+    from paddle_tpu.ops import pallas as plk
+    from paddle_tpu.ops.pallas import kda as kda_mod
+    from paddle_tpu.parallel.mesh import MeshConfig, make_mesh
+
+    # the package's name ``grouped_matmul`` is the function, not the module
+    gmm_mod = importlib.import_module("paddle_tpu.ops.pallas.grouped_matmul")
+    entered = {}
+
+    def counted(module, name):
+        body = getattr(module, name)
+
+        def enter(*args, **kw):
+            entered[name] = entered.get(name, 0) + 1
+            return body(*args, **kw)
+
+        monkeypatch.setattr(module, name, enter)
+
+    for module, names in ((kda_mod, ("_fwd_kernel", "_bwd_kernel")),
+                          (gmm_mod, ("_gmm_kernel", "_tgmm_kernel"))):
+        for name in names:
+            counted(module, name)
+    layers = kda_layers + 1
+    cfg = kl.kimi_linear_tiny(
+        num_layers=layers, kda_layers=tuple(range(1, layers + 1)),
+        full_attn_layers=(), kda_heads=2, kda_head_dim=128,
+        experts_held=(4, 4))
+    mesh = make_mesh(MeshConfig(data=1), devices=jax.devices()[:1])
+    init_fn, step_fn = kl.make_train_step(cfg, pt.optimizer.Adam(1e-3), mesh)
+    params, opt_state = jax.eval_shape(init_fn, jax.random.PRNGKey(0))
+    batch = jax.eval_shape(step_fn.place, kl.synthetic_batch(cfg, 1, 256))
+    jax.clear_caches()            # what earlier tests of this process traced
+    with plk.override("on"):
+        step_fn.jitted.trace(params, opt_state, batch)
+    # the grouped product at two shapes (gate and up; down), each in the
+    # pass, in the experts' own backward (``moe._held_bwd`` makes its rows'
+    # products again under ``jax.vjp``) and transposed for the rows'
+    # gradient; the weights' gradient at the two shapes
+    assert entered == {"_fwd_kernel": 2, "_bwd_kernel": 1,
+                       "_gmm_kernel": 6, "_tgmm_kernel": 2}, entered

@@ -12,6 +12,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from paddle_tpu.ops import kda
 from paddle_tpu.ops import pallas as K
 from paddle_tpu.ops.selected_rows import (
     SelectedRows, get_tensor_from_selected_rows,
@@ -48,6 +49,9 @@ ENTRY_POINTS = {
         _matmul_ins("int8"), {"mm_type": "matmul", "quant": "int8"}),
     "embedding_scatter_add": lambda: get_tensor_from_selected_rows(
         SelectedRows(jnp.asarray([2, 5, 2], jnp.int32), jnp.ones((3, 6)), 9)),
+    "kda_chunked": lambda: kda.kda_chunked(
+        *(jnp.ones((1, 128, 1, 128)),) * 3, -jnp.ones((1, 128, 1, 128)),
+        jnp.ones((1, 128, 1))),
 }
 
 

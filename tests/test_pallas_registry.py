@@ -25,7 +25,7 @@ RNG = np.random.RandomState(42)
 
 KERNELS = ["embedding_scatter_add", "flash_attention", "fused_layer_norm",
            "fused_matmul", "fused_matmul_int8", "grouped_matmul",
-           "softmax_cross_entropy"]
+           "kda_chunked", "softmax_cross_entropy"]
 
 
 def _f(shape, dtype=jnp.float32, scale=1.0):

@@ -1010,7 +1010,10 @@ class TestRoundFourHardening:
         from paddle_tpu.serving.resilience import SwapWatchdog
         srv = _server(d1)
         try:
-            orig = SwapWatchdog._latency
+            # the class's own entry: the attribute read would unwrap the
+            # staticmethod, and putting that back leaves a method that is
+            # handed `self` in every later test of the process
+            orig = SwapWatchdog.__dict__["_latency"]
             SwapWatchdog._latency = staticmethod(lambda: (0.0, 0))
             try:
                 rep = srv.swap(d2, watchdog_ms=50,

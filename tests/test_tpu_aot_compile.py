@@ -29,6 +29,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
 import paddle_tpu as pt
 from paddle_tpu.models import bert, kimi_linear, olmoe, transformer
+from paddle_tpu.ops import kda
 from paddle_tpu.ops import pallas as plk
 from paddle_tpu.ops.pallas import registry
 from paddle_tpu.parallel.data_parallel import DataParallelTrainer
@@ -117,6 +118,10 @@ KERNEL_SHAPES = {
                          {}, True),
     "softmax_cross_entropy": ([((5120, VOCAB), F32), ((5120,), I32)], {},
                               True),
+    # a KDA layer of kimi_linear_48b_a3b.lm_s8192: q, k, v, g, beta
+    "kda_chunked": ([((1, 8192, 32, 128), BF16)] * 3
+                    + [((1, 8192, 32, 128), F32), ((1, 8192, 32), F32)],
+                    {"chunk": 32}, True),
 }
 
 
@@ -407,11 +412,12 @@ def test_olmoe_step_at_published_widths_fits_a_v5e_and_names_its_calls(
             "flash_fwd/pallas_call",
             "jit(step)/transpose(jvp(attention))/attention_core/"
             "jit(_flash_attention_bwd)/flash_bwd/pallas_call",
-            "jit(step)/jvp(ffn)/moe_experts/grouped_matmul/pallas_call",
-            "jit(step)/transpose(jvp(ffn))/moe_experts/grouped_matmul/"
+            "jit(step)/jvp(ffn)/moe_experts/jit(_gmm)/grouped_matmul/"
             "pallas_call",
-            "jit(step)/transpose(jvp(ffn))/moe_experts/grouped_matmul_dw/"
-            "pallas_call",
+            "jit(step)/transpose(jvp(ffn))/moe_experts/jit(_gmm)/"
+            "grouped_matmul/pallas_call",
+            "jit(step)/transpose(jvp(ffn))/moe_experts/jit(_tgmm)/"
+            "grouped_matmul_dw/pallas_call",
             "jit(step)/jvp(loss)/softmax_xent_fwd/pallas_call"):
         assert name in op_names, (name, sorted(op_names))
 
@@ -450,6 +456,28 @@ def test_grouped_matmul_compiles_for_a_share_of_the_experts(one_chip):
     assert _mosaic_calls(compiled) == 3    # product, rows' and weights' grad
 
 
+def test_the_delta_rule_is_two_mosaic_calls_where_a_head_is_a_lane_tile(
+        one_chip):
+    """[1, 8192, 32, 128], forward and backward: ``kda_fwd`` and ``kda_bwd``
+    and no other custom call (the reference body's triangular solve is one);
+    at a head of 16 channels (``kimi_linear_tiny``) the Pallas body hands
+    over to the reference and the program holds no Mosaic call."""
+    def compiled(d):
+        wide = _abstract((1, 8192, 32, d), BF16, one_chip)
+        g = _abstract((1, 8192, 32, d), F32, one_chip)
+        beta = _abstract((1, 8192, 32), F32, one_chip)
+        return _compile(lambda *a: jax.value_and_grad(
+            lambda *b: jnp.sum(kda.kda_chunked(*b).astype(F32)),
+            range(5))(*a), wide, wide, wide, g, beta)
+
+    assert plk.selected_body("kda_chunked") == "pallas"
+    full = compiled(128)
+    assert sorted(_mosaic_call_stems(full)) == ["kda_bwd", "kda_fwd"]
+    assert full.as_text().count("custom-call(") \
+        == full.as_text().count("tpu_custom_call")
+    assert _mosaic_calls(compiled(16)) == 0
+
+
 @pytest.fixture(scope="module")
 def kimi_linear_full_size(topo):
     """The step of the cell kimi_linear_48b_a3b.lm_s8192: the published
@@ -470,9 +498,11 @@ def test_kimi_linear_step_at_published_widths_fits_a_v5e(
     """602.4 M parameters with their two Adam moments are 6.7 GiB of the
     step's arguments; with every KDA mixer recomputed the whole step needs
     less than the 15.75 GiB a v5e gives a program; its Mosaic calls are the
-    flash kernels (the MLA layer), the grouped matmuls (the four expert
-    layers' one loop over the rows held, forward and backward) and the
-    cross-entropy, each under its scope."""
+    flash kernels (the MLA layer), the delta rule's two kernels (the four
+    KDA layers: the forward once for the pass and once more where the
+    mixer is recomputed, the backward once), the grouped matmuls (the four
+    expert layers' one loop over the rows held, forward and backward) and
+    the cross-entropy, each under its scope."""
     compiled, pshape, _ = kimi_linear_full_size
     assert sum(int(np.prod(a.shape)) for a in jax.tree.leaves(pshape)) \
         == 602_434_432
@@ -480,10 +510,16 @@ def test_kimi_linear_step_at_published_widths_fits_a_v5e(
     assert ma.argument_size_in_bytes > 6.7 * 2**30
     need = _need_bytes(compiled)
     assert need < 15.75 * 2**30, need / 2**30
+    # 11.07 GiB; 14.59 with the scan as a jax.numpy body, which kept six
+    # prepared arrays of the inputs' size and a state a chunk (PR 30)
+    assert need < 11.5 * 2**30, need / 2**30
     stems = _mosaic_call_stems(compiled)
     assert set(stems) == {"flash_fwd", "flash_bwd", "softmax_xent_fwd",
-                          "grouped_matmul", "grouped_matmul_dw"}
+                          "grouped_matmul", "grouped_matmul_dw",
+                          "kda_fwd", "kda_bwd"}
     assert stems.count("flash_fwd") == stems.count("flash_bwd") == 1
+    # a call a layer: XLA inlines the jitted calls the layers share
+    assert stems.count("kda_fwd") == 8 and stems.count("kda_bwd") == 4
     calls = [line for line in compiled.as_text().splitlines()
              if "tpu_custom_call" in line]
     op_names = "\n".join(re.findall(r'op_name="([^"]*)"', "\n".join(calls)))
@@ -491,11 +527,17 @@ def test_kimi_linear_step_at_published_widths_fits_a_v5e(
                           ("attention_core", "flash_bwd"),
                           ("moe_experts", "grouped_matmul"),
                           ("moe_experts", "grouped_matmul_dw"),
-                          ("loss", "softmax_xent_fwd")):
+                          ("loss", "softmax_xent_fwd"),
+                          ("kda_core", "kda_fwd"),
+                          ("kda_core", "kda_bwd")):
         assert re.search(rf"{scope}[^\n]*/{kernel}/pallas_call", op_names), \
             (scope, kernel)
-    # the scan is XLA's: its chunks are a loop of the compiled program
-    assert "kda_core" in compiled.as_text()
+    # the scan is the kernels': every Mosaic call of the delta rule is under
+    # ``kda_core``, and XLA's triangular solve and its loop over the chunks
+    # are gone from the program
+    assert all("kda_core" in name for name in op_names.splitlines()
+               if "/kda_" in name)
+    assert "triangular" not in compiled.as_text().lower()
 
 
 # ---------------------------------------------------------------------------
