@@ -202,6 +202,12 @@ def phase_kernels():
                                   for _ in range(3)),
                             {"bias": jnp.zeros((8, 4 * SEQ), jnp.float32)},
                             (0, 1, 2), 3e-2),
+        # a window of SEQ keys, groups of 8 query heads on one key/value head
+        "flash_attention/window": ((bf16(1, 16, 4 * SEQ, 128),
+                                    bf16(1, 2, 4 * SEQ, 128),
+                                    bf16(1, 2, 4 * SEQ, 128)),
+                                   {"causal": True, "window": SEQ},
+                                   (0, 1, 2), 3e-2),
         "fused_layer_norm": ((bf16(GLOBAL_BATCH, SEQ, h), f32(h) + 1.0,
                               f32(h)), {}, (0, 1, 2), 2e-2),
         "softmax_cross_entropy": ((f32(n_pred, n_vocab), labels), {},
@@ -221,14 +227,14 @@ def phase_kernels():
                          jax.nn.sigmoid(f32(2, 1200, 2))),
                         {"chunk": 32}, (0, 1, 2, 3, 4), 3e-2),
     }
-    missing = set(plk.list_kernels()) ^ set(cases)
+    missing = set(plk.list_kernels()) ^ {c.split("/")[0] for c in cases}
     if missing:
         raise AssertionError(f"kernels without a smoke case (or cases "
                              f"without a kernel): {sorted(missing)}")
     out = {}
     for name, (args, kw, argnums, tol) in cases.items():
         def run(which, _name=name, _kw=kw, _argnums=argnums):
-            body = plk.get_body(_name, which)
+            body = plk.get_body(_name.split("/")[0], which)
             kw2 = dict(_kw, interpret=INTERPRET) if which == "pallas" \
                 else _kw
 

@@ -1,15 +1,18 @@
 """Pieces of a decoder block that more than one model can use: RMSNorm,
 rotary positions, causal attention, the gated feed-forward.
 
-``models/olmoe.py`` and ``models/kimi_linear.py`` are built from them.
+``models/olmoe.py``, ``models/kimi_linear.py`` and ``models/laguna.py`` are
+built from them.
 ``models/bert.py`` and
 ``models/transformer.py`` carry their own layer norm and attention and are
 not moved here yet (ROADMAP C10: their cells repeat to 0.004%, so a change
 to their HLO is a PR judged on its own). Every piece enters the named scope
 a profile of the step is read by (``layer_norm``, ``rope``,
-``attention_core``; the callers enter ``attention`` and ``ffn``).
+``attention_core`` and, inside it, ``attention_window`` where a call has a
+window; the callers enter ``attention`` and ``ffn``).
 """
 
+import contextlib
 import math
 
 import jax
@@ -18,8 +21,8 @@ from jax import lax
 
 from paddle_tpu.ops.pallas.registry import mesh_scope, selected_body
 
-__all__ = ["rms_norm", "rms_normalize", "rope_angles", "apply_rope", "attention_body",
-           "causal_attention", "gated_ffn"]
+__all__ = ["rms_norm", "rms_normalize", "yarn_inv_freq", "rope_angles",
+           "apply_rope", "attention_body", "causal_attention", "gated_ffn"]
 
 #: from this many positions on, ``auto`` takes the flash kernels where the
 #: Pallas body runs (one chip). Measured on a v5e at equal tokens a step
@@ -44,7 +47,8 @@ REFERENCE_FLASH_BEYOND = 1024
 
 def attention_body(positions, mesh=None):
     """"flash" or "dense": what ``auto`` runs at ``positions`` keys a query
-    under ``mesh``. It asks the registry which body a flash call would run
+    under ``mesh`` (the sequence's length, or the window where that is
+    shorter). It asks the registry which body a flash call would run
     here, so only what the code observes takes part: the length, the mesh,
     the platform."""
     if positions > REFERENCE_FLASH_BEYOND:
@@ -69,41 +73,83 @@ def rms_norm(x, gain, eps=1e-5):
     return rms_normalize(x, gain, eps)
 
 
-def rope_angles(positions, head_dim, theta=10000.0):
+def yarn_inv_freq(rot, theta, factor, original_positions, beta_fast,
+                  beta_slow):
+    """YaRN's inverse frequencies [rot / 2] (Peng et al. 2023,
+    arXiv:2309.00071, "NTK-by-parts"): with ``pos_i = theta^(2i / rot)``, the
+    plain law ``1 / pos_i`` on the channels that turn more than ``beta_fast``
+    times within ``original_positions``, the interpolated one ``1 / (factor
+    pos_i)`` on those that turn fewer than ``beta_slow`` times, and a linear
+    ramp between the two channel numbers (the correction range: floor and
+    ceiling, clipped to the channels there are)."""
+    pos = theta ** (jnp.arange(0, rot, 2, dtype=jnp.float32) / rot)
+
+    def channel_of(turns):
+        return rot * math.log(original_positions / (turns * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    low = max(math.floor(channel_of(beta_fast)), 0)
+    high = min(math.ceil(channel_of(beta_slow)), rot // 2 - 1)
+    ramp = jnp.clip((jnp.arange(rot // 2, dtype=jnp.float32) - low)
+                    / max(high - low, 1e-3), 0.0, 1.0)
+    return (1.0 / (factor * pos)) * ramp + (1.0 / pos) * (1.0 - ramp)
+
+
+def rope_angles(positions, head_dim, theta=10000.0, inv_freq=None,
+                factor=1.0):
     """(cos, sin), each [positions, head_dim / 2] float32, of the angles
-    ``p * theta^(-2i / head_dim)``."""
-    inv_freq = theta ** (-jnp.arange(0, head_dim, 2, dtype=jnp.float32)
-                         / head_dim)
+    ``p * inv_freq_i``. ``head_dim`` is the rotated width (a head's, or the
+    part of it ``apply_rope`` is to turn); the law is the plain one,
+    ``theta^(-2i / head_dim)``, or the ``inv_freq`` [head_dim / 2] given
+    (``yarn_inv_freq``). ``factor`` multiplies cos and sin both (YaRN's
+    attention factor: it scales the scores by its square)."""
+    if inv_freq is None:
+        inv_freq = theta ** (-jnp.arange(0, head_dim, 2, dtype=jnp.float32)
+                             / head_dim)
     angles = jnp.arange(positions, dtype=jnp.float32)[:, None] * inv_freq
-    return jnp.cos(angles), jnp.sin(angles)
+    if factor == 1.0:
+        return jnp.cos(angles), jnp.sin(angles)
+    return jnp.cos(angles) * factor, jnp.sin(angles) * factor
 
 
 @jax.named_scope("rope")
 def apply_rope(x, cos, sin):
-    """Rotary positions in the rotate-half convention on x [B, S, N, D]:
-    the pair (x_i, x_{i + D/2}) is turned by the angle of position s and
-    frequency i. Float32 inside, ``x.dtype`` out."""
-    half = x.shape[-1] // 2
+    """Rotary positions in the rotate-half convention on the first ``rot``
+    channels of x [B, S, N, D], ``rot`` twice the width of ``cos``: the pair
+    (x_i, x_{i + rot/2}) is turned by the angle of position s and frequency
+    i; the channels from ``rot`` on pass through. Float32 inside,
+    ``x.dtype`` out."""
+    half = cos.shape[-1]
     x32 = x.astype(jnp.float32)
-    a, b = x32[..., :half], x32[..., half:]
+    a, b = x32[..., :half], x32[..., half:2 * half]
     cos, sin = cos[None, :, None, :], sin[None, :, None, :]
-    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
-                           axis=-1).astype(x.dtype)
+    turned = [a * cos - b * sin, b * cos + a * sin]
+    if 2 * half < x.shape[-1]:
+        turned.append(x32[..., 2 * half:])
+    return jnp.concatenate(turned, axis=-1).astype(x.dtype)
 
 
-def causal_attention(q, k, v, impl="auto", mesh=None):
+def causal_attention(q, k, v, impl="auto", mesh=None, window=None):
     """Softmax of ``q k^T / sqrt(D)`` over the keys up to each query's own,
-    times v. q and k are [B, S, N, D], v and the result [B, S, N, Dv]: the
-    value heads may have a size of their own (latent attention: 192 for the
-    scores, 128 for the values), and D need be no multiple of the 128-lane
-    grain. ``impl`` is "dense"
+    times v; with ``window`` over the ``window`` keys that end at the
+    query's own. q is [B, S, N, D], k [B, S, Nkv, D], v [B, S, Nkv, Dv] and
+    the result [B, S, N, Dv]: the value heads may have a size of their own
+    (latent attention: 192 for the scores, 128 for the values), D need be no
+    multiple of the 128-lane grain, and N may be a multiple of Nkv: query
+    head i then reads key/value head ``i // (N / Nkv)``. ``impl`` is "dense"
     (XLA, scores in float32), "flash" (the Pallas kernels through the
     registry, which hands out the dense reference on the CPU and under a
-    mesh of more than one device) or "auto": ``attention_body``'s choice."""
+    mesh of more than one device) or "auto": ``attention_body``'s choice at
+    the keys a query sees, ``min(S, window)``. A windowed call's core is
+    under the scope ``attention_window`` inside ``attention_core``."""
     b, s, n, d = q.shape
+    if window is not None and window >= s:
+        window = None
     if impl == "auto":
-        impl = attention_body(s, mesh)
-    with jax.named_scope("attention_core"):
+        impl = attention_body(window or s, mesh)
+    windowed = contextlib.nullcontext() if window is None \
+        else jax.named_scope("attention_window")
+    with jax.named_scope("attention_core"), windowed:
         if impl == "flash":
             from paddle_tpu.ops import pallas as _pk
 
@@ -112,12 +158,16 @@ def causal_attention(q, k, v, impl="auto", mesh=None):
 
             with mesh_scope(mesh):
                 ctx = _pk.flash_attention(heads(q), heads(k), heads(v),
-                                          causal=True)
+                                          causal=True, window=window)
             return ctx.transpose(0, 2, 1, 3).astype(q.dtype)
+        if k.shape[2] != n:
+            k, v = (jnp.repeat(t, n // t.shape[2], axis=2) for t in (k, v))
         scores = jnp.einsum("bqnd,bknd->bnqk", q, k,
                             preferred_element_type=jnp.float32) \
             / math.sqrt(d)
         keep = jnp.tril(jnp.ones((s, s), bool))
+        if window is not None:
+            keep &= ~jnp.tril(jnp.ones((s, s), bool), -window)
         probs = jax.nn.softmax(jnp.where(keep, scores, -1e30), axis=-1)
         return jnp.einsum("bnqk,bknd->bqnd", probs.astype(q.dtype), v)
 

@@ -1,0 +1,378 @@
+"""Laguna: a decoder-only language model whose layers attend in two ways and
+feed forward in two (poolside's ``Laguna-XS.2``, 33.4B-A3B; the released
+``poolside/Laguna-XS.2`` config).
+
+Pre-norm blocks, ``h = x + Attn(RMSNorm(x))``, ``y = h + FF(RMSNorm(h))``.
+What a layer is comes from the configuration's lists, a layer an entry
+(``layer_types``, ``heads``), as the published config gives them:
+
+- **attention**, no biases and no query/key norm. ``heads[l]`` query heads of
+  ``head_dim`` over ``kv_heads`` key/value heads: query head i reads
+  key/value head ``i // (heads[l] / kv_heads)``. A ``full_attention`` layer
+  sees every key up to the query's own, a ``sliding_attention`` layer the
+  ``window`` keys that end there; the published model gives its sliding
+  layers more query heads (64 against 48). Rotary positions (rotate-half) on
+  the first ``rotary_factor`` of each query and key head by the layer type's
+  own law (``RotaryLaw``: plain, or YaRN with its factor on cos and sin).
+  Both through ``blocks.causal_attention``: the flash kernels, which skip the
+  band's outside and read a group's key/value head in place
+  (``ops/pallas/flash_attention.py``).
+- **a gate on the attention output**: one scalar a head, ``sigmoid(h W_g)``
+  from the layer's normed input, on the head's context before the output
+  projection (Qiu et al. 2025, arXiv:2505.06708: position G1, headwise).
+  The config says ``gating: true`` and not which form: this one is assumed
+  (``chipbench/configs/laguna_xs2.json`` has the alternative).
+- **feed-forward**: the layers of ``dense_layers`` a SiLU-gated one of
+  ``dense_width``; the others a float32 sigmoid router over all
+  ``num_experts``, ``experts_per_token`` a token, renormalised and scaled by
+  ``routed_scale``, the weights on the experts' outputs, plus one shared
+  expert (``parallel/moe.dropless_moe_ffn``). ``experts_held`` = (first, n)
+  makes the layer one chip's share of an expert-parallel job, as
+  ``models/kimi_linear.py`` says; the selection bias and its step
+  (``moe.bias_step``, ``bias_rate``) are that file's too, and here as there
+  the rate is fitted to the benchmark's cell and no property of the model.
+- a final RMSNorm and an untied head on every position; the loss is the
+  mean next-token cross-entropy (the config names no auxiliary loss).
+  ``vocab_size`` may be a slice of the published vocabulary.
+
+**Recomputation.** Every mixer and the dense feed-forward are under
+``jax.checkpoint``: the backward pass keeps their inputs and forms the
+projections, rotary positions, attention, gate and the feed-forward's three
+products again. It is the least, of the subsets tried, that lets one
+sequence of 16 384 positions fit a v5e beside 7.73 GiB of parameters and
+Adam state: 12.03 GiB by the compiler's account (11.92 at 8192 rows a pass
+of the held experts, as the subsets were compiled), where nothing recomputed
+is 16.05 and every proper subset (the sliding mixers, the full ones, the dense
+feed-forward, any two of them, all three with the flash calls' outputs kept)
+stays between 15.88 and 16.55 of the 15.75 a program may take (PERF.md
+section 6, PR 33, has the table). The experts' rows are formed again by
+``moe.dropless_moe_ffn`` itself; the router and the shared expert keep what
+they computed. No option chooses any of it.
+
+Built like ``models/kimi_linear.py``: float32 master parameters,
+``cfg.dtype`` (bfloat16) activations and matmul operands, one jitted step
+(``models/lm_trainer.py``). No attention, router or trainer code of its own.
+"""
+
+import dataclasses
+import math
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
+
+from paddle_tpu.models import blocks, lm_trainer
+from paddle_tpu.ops.pallas.registry import mesh_scope
+from paddle_tpu.parallel import moe
+from paddle_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
+
+__all__ = ["RotaryLaw", "LagunaConfig", "laguna_xs2", "laguna_tiny",
+           "init_params", "param_specs", "forward", "stages", "lm_loss",
+           "routing_stats", "make_train_step", "synthetic_batch"]
+
+FULL, SLIDING = "full_attention", "sliding_attention"
+
+
+@dataclasses.dataclass(frozen=True)
+class RotaryLaw:
+    """How one layer type turns its heads: ``rotary_factor`` of a head's
+    channels (the first ones) by the plain law of ``theta``, or by YaRN's
+    where ``yarn`` = (factor, original positions, beta_fast, beta_slow);
+    ``attention_factor`` multiplies cos and sin."""
+    theta: float = 10000.0
+    rotary_factor: float = 1.0
+    yarn: tuple = None
+    attention_factor: float = 1.0
+
+    def angles(self, positions, head_dim):
+        """(cos, sin), each [positions, rotated width / 2] float32."""
+        rot = int(head_dim * self.rotary_factor)
+        inv_freq = None if self.yarn is None else blocks.yarn_inv_freq(
+            rot, self.theta, *self.yarn)
+        return blocks.rope_angles(positions, rot, self.theta, inv_freq,
+                                  self.attention_factor)
+
+
+#: the published laws: YaRN by 64 from 4096 positions on half of a full
+#: layer's head, the plain law on the whole of a sliding layer's
+_YARN_XS2 = RotaryLaw(500000.0, 0.5, (64.0, 4096, 64.0, 1.0),
+                      0.1 * math.log(64.0) + 1.0)
+_PERIOD = (FULL, SLIDING, SLIDING, SLIDING)
+
+
+@dataclasses.dataclass(frozen=True)  # hashable: used as a jit-static arg
+class LagunaConfig:
+    vocab_size: int = 100352
+    hidden: int = 2048
+    num_layers: int = 40
+    layer_types: tuple = _PERIOD * 10    # a layer an entry, as published
+    heads: tuple = (48, 64, 64, 64) * 10
+    kv_heads: int = 8
+    head_dim: int = 128
+    window: int = 512
+    rope_full: RotaryLaw = _YARN_XS2
+    rope_sliding: RotaryLaw = RotaryLaw()
+    dense_width: int = 8192
+    dense_layers: tuple = (0,)           # 0-based: ``mlp_layer_types``
+    expert_width: int = 512
+    shared_width: int = 512
+    num_experts: int = 256
+    experts_per_token: int = 8
+    routed_scale: float = 2.5
+    bias_rate: float = 0.001             # the selection bias's step
+    experts_held: tuple = None           # (first, n); None: all of them
+    rms_eps: float = 1e-6
+    dtype: object = jnp.bfloat16         # activation/compute dtype
+
+    def __post_init__(self):
+        for name in ("layer_types", "heads"):
+            if len(getattr(self, name)) < self.num_layers:
+                raise ValueError(f"{name} has fewer entries than layers")
+        if any(n % self.kv_heads for n in self.heads[:self.num_layers]):
+            raise ValueError("a layer's query heads are a multiple of the "
+                             "key/value heads")
+
+    def rope(self, kind):
+        return self.rope_full if kind == FULL else self.rope_sliding
+
+    @property
+    def scoring(self):
+        return moe.Scoring("sigmoid", renormalize=True,
+                           scale=self.routed_scale)
+
+    @property
+    def experts_here(self):
+        return self.experts_held[1] if self.experts_held else self.num_experts
+
+
+def laguna_xs2(**kw):
+    """The published sizes: 33.4 B parameters, 3 B a token."""
+    return LagunaConfig(**kw)
+
+
+def laguna_tiny(**kw):
+    """Small config for tests / dry runs: the first five published layers
+    (full with the dense feed-forward, three sliding, full), groups of 6 and
+    8 query heads over 2 key/value heads, a window of 24."""
+    for k, v in dict(vocab_size=512, hidden=64, num_layers=5,
+                     heads=(12, 16, 16, 16) * 10, kv_heads=2, head_dim=16,
+                     window=24, dense_width=128, expert_width=32,
+                     shared_width=32, num_experts=16, experts_per_token=4,
+                     rope_full=dataclasses.replace(
+                         _YARN_XS2, yarn=(64.0, 32, 64.0, 1.0))).items():
+        kw.setdefault(k, v)
+    return LagunaConfig(**kw)
+
+
+# ---------------------------------------------------------------------------
+# params
+# ---------------------------------------------------------------------------
+def init_params(rng, cfg):
+    """fp32 master params as a nested dict pytree: matrices N(0, 0.02),
+    gains 1, the selection bias 0."""
+    h, d = cfg.hidden, cfg.head_dim
+    keys = iter(jax.random.split(rng, 2 + 16 * cfg.num_layers))
+
+    def normal(*shape):
+        return (0.02 * jax.random.normal(next(keys), shape)) \
+            .astype(jnp.float32)
+
+    def ones(n):
+        return jnp.ones((n,), jnp.float32)
+
+    def attention(layer):
+        n, kv = cfg.heads[layer], cfg.kv_heads
+        return {"q_w": normal(h, n * d), "k_w": normal(h, kv * d),
+                "v_w": normal(h, kv * d), "g_w": normal(h, n),
+                "o_w": normal(n * d, h)}
+
+    def feed_forward(layer):
+        if layer in cfg.dense_layers:
+            f = cfg.dense_width
+            return {"ffn_gate": normal(h, f), "ffn_up": normal(h, f),
+                    "ffn_down": normal(f, h)}
+        e, f, fs = cfg.experts_here, cfg.expert_width, cfg.shared_width
+        return {"router_w": normal(h, cfg.num_experts),
+                "router_bias": jnp.zeros((cfg.num_experts,), jnp.float32),
+                "w_gate": normal(e, h, f), "w_up": normal(e, h, f),
+                "w_down": normal(e, f, h),
+                "shared_gate": normal(h, fs), "shared_up": normal(h, fs),
+                "shared_down": normal(fs, h)}
+
+    p = {"embed": normal(cfg.vocab_size, h), "layers": [],
+         "final_norm_g": ones(h), "head_w": normal(h, cfg.vocab_size)}
+    for layer in range(cfg.num_layers):
+        p["layers"].append({"ln1_g": ones(h), "ln2_g": ones(h),
+                            **attention(layer), **feed_forward(layer)})
+    return p
+
+
+def param_specs(cfg):
+    """PartitionSpecs over ("model",): the query, gate and output
+    projections split their heads, the dense feed-forward its width, the
+    embedding its rows and the head its columns; the key and value
+    projections (8 heads), everything small, the experts and the router are
+    replicated."""
+    col, row = P(None, MODEL_AXIS), P(MODEL_AXIS, None)
+    split = {"q_w": col, "g_w": col, "o_w": row, "ffn_gate": col,
+             "ffn_up": col, "ffn_down": row}
+    shapes = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg))
+    return {"embed": row,
+            "layers": [{name: split.get(name, P()) for name in lp}
+                       for lp in shapes["layers"]],
+            "final_norm_g": P(), "head_w": col}
+
+
+# ---------------------------------------------------------------------------
+# forward
+# ---------------------------------------------------------------------------
+# Named scopes as models/kimi_linear.py (embed, attention, attention_core,
+# rope, ffn, layer_norm, loss, moe_router, moe_dispatch, moe_experts,
+# moe_shared) plus attention_window (blocks.causal_attention enters it) and
+# attn_gate: chipbench's per-layer metrics key on them.
+@jax.named_scope("attention")
+def _attention(lp, x, cfg, kind, angles, mesh=None):
+    b, s, _ = x.shape
+    dt = x.dtype
+    q, k, v = ((x @ lp[f"{name}_w"].astype(dt)).reshape(b, s, -1,
+                                                        cfg.head_dim)
+               for name in "qkv")
+    gate_in = jnp.dot(x, lp["g_w"].astype(dt),
+                      preferred_element_type=jnp.float32)      # [B, S, N]
+    q, k = blocks.apply_rope(q, *angles), blocks.apply_rope(k, *angles)
+    ctx = blocks.causal_attention(
+        q, k, v, mesh=mesh, window=cfg.window if kind == SLIDING else None)
+    with jax.named_scope("attn_gate"):
+        ctx = (ctx.astype(jnp.float32)
+               * jax.nn.sigmoid(gate_in)[..., None]).astype(dt)
+    return ctx.reshape(b, s, -1) @ lp["o_w"].astype(dt)
+
+
+def _feed_forward(lp, x, cfg, mesh=None):
+    with jax.named_scope("ffn"):
+        if "ffn_gate" in lp:
+            return blocks.gated_ffn(x, lp["ffn_gate"], lp["ffn_up"],
+                                    lp["ffn_down"]), None
+        return moe.dropless_moe_ffn(lp, x, cfg.experts_per_token, mesh=mesh,
+                                    scoring=cfg.scoring,
+                                    held=cfg.experts_held)
+
+
+def _block(lp, x, cfg, kind, angles, mesh=None):
+    """One layer: (the stream after the mixer, after the feed-forward, the
+    expert layer's aux terms or None). The mixer and the dense feed-forward
+    are recomputed in the backward pass from their inputs (the module
+    docstring says why); the experts recompute their own part."""
+    def mix(lp, x):
+        normed = blocks.rms_norm(x, lp["ln1_g"], cfg.rms_eps)
+        return x + _attention(lp, normed, cfg, kind, angles, mesh)
+
+    def feed(lp, h):
+        return _feed_forward(lp, blocks.rms_norm(h, lp["ln2_g"], cfg.rms_eps),
+                             cfg, mesh)
+
+    h = jax.checkpoint(mix)(lp, x)
+    m, aux = (jax.checkpoint(feed) if "ffn_gate" in lp else feed)(lp, h)
+    return h, h + m, aux
+
+
+def _shard_act(x, mesh):
+    if mesh is None or mesh.shape.get(DATA_AXIS, 1) == 1:
+        return x
+    return jax.lax.with_sharding_constraint(
+        x, NamedSharding(mesh, P(DATA_AXIS, None, None)))
+
+
+def _hidden_and_aux(params, cfg, input_ids, mesh=None):
+    """(final normed hidden states [B, S, H], the expert layers' aux terms
+    stacked over those layers, the residual stream after the embedding and
+    after every mixer and feed-forward, a list of 2 layers + 1)."""
+    with jax.named_scope("embed"):
+        x = jnp.take(params["embed"], input_ids, axis=0).astype(cfg.dtype)
+    x = _shard_act(x, mesh)
+    s = input_ids.shape[1]
+    angles = {kind: cfg.rope(kind).angles(s, cfg.head_dim)
+              for kind in dict.fromkeys(cfg.layer_types[:cfg.num_layers])}
+    auxes, stream = [], [x]
+    for layer, lp in enumerate(params["layers"]):
+        kind = cfg.layer_types[layer]
+        h, x, aux = _block(lp, x, cfg, kind, angles[kind], mesh)
+        x = _shard_act(x, mesh)
+        stream += [h, x]
+        if aux is not None:
+            auxes.append(aux)
+    hidden = blocks.rms_norm(x, params["final_norm_g"], cfg.rms_eps)
+    return hidden, jax.tree.map(lambda *a: jnp.stack(a), *auxes), stream
+
+
+def forward(params, cfg, input_ids, mesh=None):
+    """Decoder forward; returns the final normed hidden states [B, S, H]
+    in cfg.dtype (the head is applied in ``lm_loss``)."""
+    return _hidden_and_aux(params, cfg, input_ids, mesh)[0]
+
+
+def stages(params, cfg, input_ids, mesh=None):
+    """(what every part of the forward pass hands on, [2 layers + 2, B, S, H]
+    in cfg.dtype: the embedding, the residual stream after each layer's
+    mixer and after its feed-forward, and last the final normed hidden
+    states; the expert layers' aux terms of that same pass, stacked over
+    those layers: ``counts`` [layers, E], ``choice`` [layers, T, k]). As
+    ``kimi_linear.stages``, and for its reason."""
+    hidden, aux, stream = _hidden_and_aux(params, cfg, input_ids, mesh)
+    return jnp.stack(stream + [hidden]), aux
+
+
+def _loss_and_counts(params, cfg, batch, mesh=None):
+    """(``lm_loss``, the assignments each expert took [expert layers, E])."""
+    from paddle_tpu.ops import pallas as _pk
+    hidden, aux, _ = _hidden_and_aux(params, cfg, batch["input_ids"], mesh)
+    with jax.named_scope("loss"), mesh_scope(mesh):
+        logits = jnp.dot(hidden, params["head_w"].astype(hidden.dtype),
+                         preferred_element_type=jnp.float32)
+        return (jnp.mean(_pk.softmax_cross_entropy(logits, batch["labels"])),
+                aux["counts"])
+
+
+def lm_loss(params, cfg, batch, mesh=None):
+    """Mean next-token cross-entropy over every position of
+    dict(input_ids, labels) [B, S], over ``cfg.vocab_size`` ids. Logits and
+    loss in float32."""
+    return _loss_and_counts(params, cfg, batch, mesh)[0]
+
+
+def routing_stats(params, cfg, batch, mesh=None, choices=False):
+    """Assignments per expert of a batch over all ``num_experts``, [expert
+    layers, experts] on the host, as ``kimi_linear.routing_stats``."""
+    aux = jax.jit(lambda p, ids: _hidden_and_aux(p, cfg, ids, mesh)[1])(
+        params, batch["input_ids"])
+    counts = np.asarray(aux["counts"])
+    return (counts, np.asarray(aux["choice"])) if choices else counts
+
+
+# ---------------------------------------------------------------------------
+# train step
+# ---------------------------------------------------------------------------
+def make_train_step(cfg, optimizer, mesh=None):
+    """(init_fn, step_fn) of ``lm_trainer.make_train_step`` for this
+    model: step(params, opt_state, batch) -> (loss, params, opt_state).
+    After the optimizer's update every router's selection bias takes one
+    step of ``moe.bias_step`` on the load of this batch."""
+    def move_biases(params, counts):
+        routers = iter(counts)
+        layers = [dict(lp, router_bias=moe.bias_step(
+            lp["router_bias"], next(routers), cfg.bias_rate))
+            if "router_bias" in lp else lp for lp in params["layers"]]
+        return dict(params, layers=layers)
+
+    return lm_trainer.make_train_step(cfg, optimizer, mesh, init_params,
+                                      param_specs, _loss_and_counts,
+                                      after_update=move_biases)
+
+
+def synthetic_batch(cfg, batch_size, seq_len, seed=0):
+    """Random next-token batch: ``seq_len + 1`` uniform ids a row, inputs
+    the first ``seq_len``, labels the last."""
+    ids = np.random.RandomState(seed).randint(
+        0, cfg.vocab_size, (batch_size, seq_len + 1), dtype=np.int32)
+    return {"input_ids": ids[:, :-1], "labels": ids[:, 1:]}

@@ -283,6 +283,24 @@ def _gated_experts(rows, weights, sizes, mesh):
 HELD_ROW_TILE = 8192
 
 
+def _held_row_tile(assignments, held, experts):
+    """Rows a pass for a layer that holds ``held`` of ``experts`` and sees
+    ``assignments`` (token, expert) pairs a step: ``HELD_ROW_TILE``, or as
+    many of them as take twice what a balanced router sends here. A router
+    at par must not sit on a pass's edge, nor within reach of one: 32 of 256
+    experts and 8 x 16 384 assignments are 16 384 rows at par, two tiles to
+    the row, and the batch's own noise (120 rows) then decides between two
+    passes and three; a layer's share of the assignments also differs by
+    two or three points from batch to batch and by seed around its 12.5%,
+    and a second pass of 24 576 rows is 14 ms of a 705 ms step in the Laguna
+    cell whatever it holds (PERF.md section 6, PR 33). With 32 768 rows a
+    pass it is one pass up to a share of 25%. 8 of 256 and 8 x 8192 are 2048
+    rows at par: one tile, as ever."""
+    par = assignments * held // experts
+    tiles = max(1, -(-2 * par // HELD_ROW_TILE))
+    return min(HELD_ROW_TILE * tiles, assignments)
+
+
 def _held_pass(i, order, top_p, sizes, tile):
     """What pass ``i`` works on: the places [tile] its assignments have in
     (token, choice) order, their weights (0 past the rows held), and the
@@ -378,7 +396,7 @@ def dropless_moe_ffn(params, x, top_k, mesh=None, scoring=Scoring(),
     routes over all E, computes the assignments that fall on its own and
     leaves the others out of ``y`` (another chip's part). ``None``: all E.
     The assignments held come first in expert order and are worked
-    ``HELD_ROW_TILE`` rows a pass, as many passes as they need
+    ``_held_row_tile`` rows a pass, as many passes as they need
     (``_held_experts``): one path, whose time follows the rows held, with
     nothing sized for a router's worst case and nothing dropped. The
     backward pass makes each pass's rows and products again (the tokens,
@@ -423,7 +441,7 @@ def dropless_moe_ffn(params, x, top_k, mesh=None, scoring=Scoring(),
             here = (top_e >= first) & (top_e < first + n)
             key = jnp.where(here, top_e - first, n)     # the others: last
             order = jnp.argsort(key.reshape(-1), stable=True)
-            tile = min(HELD_ROW_TILE, order.shape[0])
+            tile = _held_row_tile(order.shape[0], n, e)
             order = jnp.pad(order, (0, -order.shape[0] % tile))
         y = _held_experts(xt, top_p, weights, order,
                           counts[first:first + n], top_k, mesh, tile)

@@ -28,7 +28,7 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
 import paddle_tpu as pt
-from paddle_tpu.models import bert, kimi_linear, olmoe, transformer
+from paddle_tpu.models import bert, kimi_linear, laguna, olmoe, transformer
 from paddle_tpu.ops import kda
 from paddle_tpu.ops import pallas as plk
 from paddle_tpu.ops.pallas import registry
@@ -538,6 +538,99 @@ def test_kimi_linear_step_at_published_widths_fits_a_v5e(
     assert all("kda_core" in name for name in op_names.splitlines()
                if "/kda_" in name)
     assert "triangular" not in compiled.as_text().lower()
+
+
+# ---------------------------------------------------------------------------
+# (c3) what Laguna brought: a window and groups in the flash kernels, and the
+# step of laguna_xs2.lm_s16384
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("heads, window, names", [
+    (64, 512, ["flash_bwd_window", "flash_fwd_window"]),
+    (48, None, ["flash_bwd", "flash_fwd"])], ids=["sliding", "full"])
+def test_flash_compiles_at_16384_positions_over_8_key_value_heads(
+        one_chip, heads, window, names):
+    """A layer's attention of the cell: 64 query heads behind a window of
+    512 and 48 causal ones, each over 8 key/value heads of 128, forward and
+    the one backward call. The windowed calls carry names of their own. No
+    repeated K or V exists: the program's arguments are q and the 8 heads
+    of k and v, and no [1, heads, 16384, 128] copy of them is made (the only
+    arrays of the query heads' shape are q, the output, its cotangent, dQ
+    and the dK and dV parts a query head, which one sum folds)."""
+    q = _abstract((1, heads, 16384, 128), BF16, one_chip)
+    kv = _abstract((1, 8, 16384, 128), BF16, one_chip)
+
+    def fn(q, k, v):
+        return jax.value_and_grad(lambda *a: jnp.sum(plk.flash_attention(
+            *a, causal=True, window=window).astype(F32)), (0, 1, 2))(q, k, v)
+    compiled = _compile(fn, q, kv, kv)
+    assert sorted(_mosaic_call_stems(compiled)) == names
+    per_head = 16384 * 128 * 2
+    assert compiled.memory_analysis().argument_size_in_bytes \
+        == (heads + 2 * 8) * per_head
+    entry = _entry_text(compiled)
+    assert "broadcast" not in "".join(
+        line for line in entry.splitlines()
+        if f"bf16[1,{heads},16384,128]" in line.split(" = ")[0])
+    calls = [line for line in entry.splitlines() if "tpu_custom_call" in line]
+    # each call reads the 8 heads as they are
+    assert all(line.count("bf16[1,8,16384,128]") >= 2 for line in calls)
+
+
+@pytest.fixture(scope="module")
+def laguna_full_size(topo):
+    """The step of the cell laguna_xs2.lm_s16384: the published layers 0 to
+    4 at the published widths, 32 of 256 experts, an eighth of the
+    vocabulary, batch 1 x 16384."""
+    cfg = laguna.laguna_xs2(num_layers=5, vocab_size=12544,
+                            experts_held=(0, 32))
+    return _lower_replicated(
+        laguna.make_train_step, laguna.init_params, cfg,
+        laguna.synthetic_batch(cfg, 1, 16384), topo)
+
+
+@pytest.mark.timeout(900)
+def test_laguna_step_at_published_widths_fits_a_v5e(laguna_full_size):
+    """691.6 M parameters with their two Adam moments are 7.73 GiB of the
+    step's arguments; with every mixer and the dense feed-forward recomputed
+    the whole step needs less than the 15.75 GiB a v5e gives a program (no
+    proper subset of them does: PERF.md section 6, PR 33). Its Mosaic calls:
+    the causal flash kernels (two full layers: the forward once for the pass
+    and once where the mixer is recomputed, the backward once), the windowed
+    ones (three sliding layers, the same), the grouped matmuls and the
+    cross-entropy, each under its scope, the windowed ones under
+    ``attention_window`` inside ``attention_core``."""
+    compiled, pshape, _ = laguna_full_size
+    assert sum(int(np.prod(a.shape)) for a in jax.tree.leaves(pshape)) \
+        == 691_624_960
+    ma = compiled.memory_analysis()
+    assert ma.argument_size_in_bytes > 7.7 * 2**30
+    need = _need_bytes(compiled)
+    assert need < 12.5 * 2**30, need / 2**30          # 12.03 GiB
+    stems = _mosaic_call_stems(compiled)
+    assert set(stems) == {"flash_fwd", "flash_bwd", "flash_fwd_window",
+                          "flash_bwd_window", "softmax_xent_fwd",
+                          "grouped_matmul", "grouped_matmul_dw"}
+    # a call a layer: XLA inlines the jitted calls the layers share
+    assert stems.count("flash_fwd") == 4 and stems.count("flash_bwd") == 2
+    assert stems.count("flash_fwd_window") == 6
+    assert stems.count("flash_bwd_window") == 3
+    calls = [line for line in compiled.as_text().splitlines()
+             if "tpu_custom_call" in line]
+    op_names = "\n".join(re.findall(r'op_name="([^"]*)"', "\n".join(calls)))
+    for scope, kernel in (
+            ("attention_core", "flash_fwd"), ("attention_core", "flash_bwd"),
+            ("attention_core/attention_window", "flash_fwd_window"),
+            ("attention_core/attention_window", "flash_bwd_window"),
+            ("moe_experts", "grouped_matmul"),
+            ("moe_experts", "grouped_matmul_dw"),
+            ("loss", "softmax_xent_fwd")):
+        assert re.search(rf"{scope}[^\n]*/{kernel}/pallas_call", op_names), \
+            (scope, kernel)
+    assert all("attention_window" in name for name in op_names.splitlines()
+               if "_window/" in name)
+    assert not any("attention_window" in name
+                   for name in op_names.splitlines()
+                   if "/flash_fwd/" in name or "/flash_bwd/" in name)
 
 
 # ---------------------------------------------------------------------------
