@@ -49,7 +49,9 @@ class BertConfig:
     dtype: object = jnp.bfloat16     # activation/compute dtype
     remat: bool = True               # jax.checkpoint per block
     # "auto": blocks.attention_body's choice (flash from blocks.FLASH_FROM
-    # positions on where the Pallas body runs, dense below and on a mesh).
+    # positions on where the Pallas body runs: one chip, or a shard at a time
+    # on a mesh that splits only the batch; dense below, and on a mesh that
+    # splits more).
     # "dense": GSPMD gathers K/V over "seq"; "ring": blockwise ring
     # attention (parallel/ring_attention.py) — K/V never materialised
     # whole, permutes ride ICI neighbor links. Use "ring" for long-context
@@ -183,7 +185,8 @@ def _layer_norm(x, g, b, mesh=None, eps=1e-12):
     # registry-selected body (ops/pallas/registry.py): the stock-jnp
     # reference is bit-identical to the historical inline math here, the
     # Pallas body is one VMEM pass (ops/pallas/layer_norm.py).
-    # mesh_scope: under a multi-device mesh GSPMD must partition this.
+    # mesh_scope: under a multi-device mesh GSPMD must partition this, and
+    # the layer norm declares no batch split, so it takes the reference.
     from paddle_tpu.ops import pallas as _pk
     with mesh_scope(mesh):
         return _pk.fused_layer_norm(x, g, b, eps=eps)
@@ -203,15 +206,16 @@ def _attention(lp, x, mask_bias, cfg, mesh=None, key_padding_mask=None):
     if impl == "auto":
         # Seq-sharded meshes take the ring path — flash is a
         # single-device kernel and would force a gather of the sharded
-        # K/V. Else blocks.attention_body: on one chip the Pallas flash
-        # kernels from blocks.FLASH_FROM positions on (the cells mlm_s512
-        # and mlm_s4096), XLA's dense attention below; on any other
-        # multi-device mesh this function's own dense code up to 1024
-        # positions (mlm_s512_dp4) and the registry's reference beyond.
+        # K/V. Else blocks.attention_body: the Pallas flash kernels from
+        # blocks.FLASH_FROM positions on, on one chip (the cells mlm_s512
+        # and mlm_s4096) and a shard at a time on a mesh that splits only
+        # the batch (mlm_s512_dp4), XLA's dense attention below; on a mesh
+        # that splits more (model) this function's own dense code up to
+        # 1024 positions and the registry's reference beyond.
         if mesh is not None and mesh.shape.get(SEQ_AXIS, 1) > 1:
             impl = "ring"
         else:
-            impl = attention_body(S, mesh)
+            impl = attention_body(S, mesh, B)
 
     if (impl == "ring" and mesh is not None
             and mesh.shape.get(SEQ_AXIS, 1) > 1):

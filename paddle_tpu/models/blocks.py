@@ -36,25 +36,28 @@ __all__ = ["rms_norm", "rms_normalize", "yarn_inv_freq", "rope_angles",
 #: heads of 128) is 3.4 times faster with the kernels at 512 already and
 #: was not measured below, so it reads the same constant.
 FLASH_FROM = 512
-#: where the registry would hand "flash" its reference body (a mesh of more
-#: than one device, the CPU), ``auto`` keeps the callers' inline dense code
-#: up to this many positions: the reference holds the scores in float32
-#: beside the head transposes, larger and slower than the inline code
-#: (``mlm_s512_dp4``: 14.84 GiB a device against 12.61, compiler, PR 29).
-#: Beyond it the reference, as ever: what ``lm_s4096`` would run on a mesh.
+#: where the registry would hand "flash" its reference body (the CPU; a
+#: mesh that splits more than the batch, or a batch its data axis does not
+#: divide: GSPMD cannot partition a Mosaic call, and only over the batch can
+#: the registry run one a shard at a time), ``auto`` keeps the callers'
+#: inline dense code up to this many positions: the reference holds the
+#: scores in float32 beside the head transposes, larger and slower than the
+#: inline code (``mlm_s512_dp4`` before it ran the kernels a shard at a
+#: time: 14.84 GiB a device against 12.61, compiler, PR 29). Beyond it the
+#: reference, as ever: what ``lm_s4096`` would run on a ``model`` mesh.
 REFERENCE_FLASH_BEYOND = 1024
 
 
-def attention_body(positions, mesh=None):
+def attention_body(positions, mesh=None, batch=None):
     """"flash" or "dense": what ``auto`` runs at ``positions`` keys a query
     under ``mesh`` (the sequence's length, or the window where that is
-    shorter). It asks the registry which body a flash call would run
-    here, so only what the code observes takes part: the length, the mesh,
-    the platform."""
+    shorter) on ``batch`` rows. It asks the registry which body a flash call
+    would run here, so only what the code observes takes part: the length,
+    the mesh and whether it splits only the batch, the platform."""
     if positions > REFERENCE_FLASH_BEYOND:
         return "flash"
     with mesh_scope(mesh):
-        kernel_runs = selected_body("flash_attention") != "reference"
+        kernel_runs = selected_body("flash_attention", batch) != "reference"
     return "flash" if kernel_runs and positions >= FLASH_FROM else "dense"
 
 
@@ -138,15 +141,17 @@ def causal_attention(q, k, v, impl="auto", mesh=None, window=None):
     multiple of the 128-lane grain, and N may be a multiple of Nkv: query
     head i then reads key/value head ``i // (N / Nkv)``. ``impl`` is "dense"
     (XLA, scores in float32), "flash" (the Pallas kernels through the
-    registry, which hands out the dense reference on the CPU and under a
-    mesh of more than one device) or "auto": ``attention_body``'s choice at
-    the keys a query sees, ``min(S, window)``. A windowed call's core is
-    under the scope ``attention_window`` inside ``attention_core``."""
+    registry, which runs them a shard of the batch at a time under a mesh
+    that splits only the batch, and hands out the dense reference on the
+    CPU and under a mesh that splits more) or "auto": ``attention_body``'s
+    choice at the keys a query sees, ``min(S, window)``. A windowed call's
+    core is under the scope ``attention_window`` inside
+    ``attention_core``."""
     b, s, n, d = q.shape
     if window is not None and window >= s:
         window = None
     if impl == "auto":
-        impl = attention_body(window or s, mesh)
+        impl = attention_body(window or s, mesh, b)
     windowed = contextlib.nullcontext() if window is None \
         else jax.named_scope("attention_window")
     with jax.named_scope("attention_core"), windowed:

@@ -583,4 +583,5 @@ def tiles_visited_pct(seq_len, window, block_q=512, block_k=512):
 
 _registry.register_kernel(
     "flash_attention", _dense_attention_reference, _flash_attention_pallas,
-    doc="blockwise online-softmax attention; [S,S] scores never in HBM")
+    doc="blockwise online-softmax attention; [S,S] scores never in HBM",
+    batch_leading=("q", "k", "v", "bias"))

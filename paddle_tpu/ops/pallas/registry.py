@@ -10,8 +10,14 @@ trace/compile time, from what the code observes:
 - :func:`mesh_scope`: inside a mesh of more than one device the
   reference, because Mosaic calls are not partitioned by GSPMD (the
   lowering refuses them), so a step traced for a multi-device mesh takes
-  the bodies XLA can partition. Which body is faster there is not
-  measured.
+  the bodies XLA can partition. One exception, for a kernel that declares
+  which of its operands lead with the batch (``batch_leading``): where
+  the mesh splits only the batch (``parallel.mesh.DATA_AXIS`` is its one
+  axis above 1, and divides the operands' batch) the Pallas body runs a
+  shard at a time inside ``jax.shard_map`` over that axis, under the name
+  ``pallas_per_shard``. ``flash_attention`` declares it, and on ``data=4``
+  at 512 positions the BERT-base step is 14% shorter for it (PERF.md
+  section 6, PR 34).
 
 Nothing a user sets takes part: no flag, no environment variable. A body
 that loses on the chip is deleted, not switched off. Tests and A/B
@@ -28,10 +34,14 @@ selection is inspectable from the metrics snapshot
 
 import contextlib
 import functools
+import inspect
 import threading
 
+import jax
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec
 
 __all__ = [
     "register_kernel", "get_kernel", "list_kernels", "dispatch",
@@ -59,25 +69,32 @@ class Kernel:
     """One registered kernel: a stock-jnp reference body and an optional
     Pallas body. Both bodies share one signature; the Pallas body must
     additionally accept ``interpret=`` (bool) — the registry injects it
-    from the platform probe."""
+    from the platform probe. ``batch_leading`` names the parameters whose
+    leading dimension is the batch, the first of them never ``None``; the
+    result leads with the batch too, and no row of it reads another row's
+    operands. A kernel that says so can run a shard of the batch at a
+    time."""
 
-    __slots__ = ("name", "reference", "pallas", "doc")
+    __slots__ = ("name", "reference", "pallas", "doc", "batch_leading")
 
-    def __init__(self, name, reference, pallas=None, doc=""):
+    def __init__(self, name, reference, pallas=None, doc="",
+                 batch_leading=()):
         self.name = name
         self.reference = reference
         self.pallas = pallas
         self.doc = doc
+        self.batch_leading = tuple(batch_leading)
 
     def __repr__(self):
         bodies = "reference+pallas" if self.pallas else "reference"
         return f"Kernel({self.name!r}, {bodies})"
 
 
-def register_kernel(name, reference, pallas=None, doc=""):
+def register_kernel(name, reference, pallas=None, doc="",
+                    batch_leading=()):
     """Register (or re-register) a kernel. Mirrors ``register_op``:
     last registration wins, so tests can shadow a body."""
-    k = Kernel(name, reference, pallas, doc)
+    k = Kernel(name, reference, pallas, doc, batch_leading)
     with _lock:
         _REGISTRY[name] = k
     return k
@@ -130,36 +147,61 @@ def mesh_scope(mesh):
     trainers and the executor INSIDE the function they jit, so it is
     active while that function is traced. ``None`` and one-device meshes
     change nothing. Not for shard_map bodies: there a Mosaic call runs
-    per shard and stays legal."""
-    stack = getattr(_tls, "mesh_sizes", None)
+    per shard and stays legal, which is how :func:`dispatch` itself runs a
+    ``batch_leading`` kernel under a mesh that splits only the batch."""
+    stack = getattr(_tls, "meshes", None)
     if stack is None:
-        stack = _tls.mesh_sizes = []
-    stack.append(1 if mesh is None else int(mesh.size))
+        stack = _tls.meshes = []
+    stack.append(mesh)
     try:
         yield
     finally:
         stack.pop()
 
 
-def _partitioned():
-    stack = getattr(_tls, "mesh_sizes", None)
-    return bool(stack) and stack[-1] > 1
+def _partitioning_mesh():
+    """The innermost :func:`mesh_scope`'s mesh if GSPMD has something to
+    partition over it (more than one device), else None."""
+    stack = getattr(_tls, "meshes", None)
+    mesh = stack[-1] if stack else None
+    return mesh if mesh is not None and mesh.size > 1 else None
 
 
-def selected_body(name):
-    """Which body a dispatch of ``name`` would run right now:
-    'pallas' (compiled), 'pallas_interpret' (CPU interpreter mode), or
-    'reference'."""
+def _splits_only_batch(mesh, batch):
+    """True where the data axis is the one axis of ``mesh`` above 1 and
+    divides ``batch`` (None: a question asked before the operands are
+    there, which leaves the batch out), and the code traced is not already
+    a ``shard_map`` body over that axis (a trainer's, that passed its mesh
+    on to the model: there is no second split to make)."""
+    from paddle_tpu.parallel.mesh import DATA_AXIS
+    shape = dict(mesh.shape)
+    n = shape.pop(DATA_AXIS, 1)
+    return all(size == 1 for size in shape.values()) \
+        and (batch is None or batch % n == 0) \
+        and DATA_AXIS not in jax.sharding.get_abstract_mesh().manual_axes
+
+
+def selected_body(name, batch=None):
+    """Which body a dispatch of ``name`` would run right now, on operands
+    of ``batch`` rows where that is known: 'pallas' (compiled),
+    'pallas_per_shard' (compiled, a shard of the batch at a time inside
+    ``shard_map`` over the mesh's data axis), either with '_interpret'
+    behind it (CPU interpreter mode), or 'reference'."""
     k = _REGISTRY[name]
-    if k.pallas is None:
-        return "reference"
     mode = selection_mode()
-    if mode == "off":
-        return "reference"
     cpu = platform() == "cpu"
-    if mode == "on":
-        return "pallas_interpret" if cpu else "pallas"
-    return "reference" if cpu or _partitioned() else "pallas"
+    if k.pallas is None or mode == "off" or (mode == "auto" and cpu):
+        return "reference"
+    mesh = _partitioning_mesh()
+    if mesh is None:
+        body = "pallas"
+    elif k.batch_leading and _splits_only_batch(mesh, batch):
+        body = "pallas_per_shard"
+    elif mode == "on":
+        body = "pallas"
+    else:
+        return "reference"
+    return body + "_interpret" if cpu else body
 
 
 def use_pallas(name):
@@ -232,13 +274,48 @@ def get_body(name, which):
     return k.reference if which == "reference" else k.pallas
 
 
+@functools.lru_cache(maxsize=64)
+def _per_shard_call(kernel, mesh, arrays, static):
+    """``kernel``'s Pallas body on the operands named ``arrays``, a shard
+    of the batch at a time over ``mesh``'s data axis; ``static`` holds its
+    other arguments. A jitted function of its own, and one a (kernel, mesh,
+    arguments): a model's layers call it with the same shapes and share one
+    trace of the ``shard_map`` and of what it holds, as they share the
+    kernels' own (``flash_attention._flash_fwd``)."""
+    from paddle_tpu.parallel.mesh import DATA_AXIS
+    rows = PartitionSpec(DATA_AXIS)
+
+    def shard(*operands):
+        return kernel.pallas(**dict(zip(arrays, operands)), **dict(static))
+
+    shard.__name__ = kernel.name + "_per_shard"
+    # check_vma off: a pallas_call says nothing of how its results vary
+    # over the mesh; every operand and result here varies over the data axis
+    return jax.jit(jax.shard_map(shard, mesh=mesh, in_specs=rows,
+                                 out_specs=rows, check_vma=False))
+
+
+def _dispatch_per_shard(kernel, interpret, args, kwargs):
+    bound = inspect.signature(kernel.pallas).bind(
+        *args, interpret=interpret, **kwargs).arguments
+    arrays = {n: bound.pop(n) for n in kernel.batch_leading
+              if bound.get(n) is not None}
+    call = _per_shard_call(kernel, _partitioning_mesh(), tuple(arrays),
+                           tuple(sorted(bound.items())))
+    return call(*arrays.values())
+
+
 def dispatch(name, *args, **kwargs):
     """Run the selected body. The Pallas body receives ``interpret=``
     resolved from the platform probe; the reference body has no such
-    keyword."""
+    keyword. A ``batch_leading`` kernel's first operand tells the batch."""
     k = _REGISTRY[name]
-    body = selected_body(name)
+    body = selected_body(
+        name, np.shape(args[0])[0] if k.batch_leading and args else None)
     _note_selection(name, body)
     if body == "reference":
         return k.reference(*args, **kwargs)
-    return k.pallas(*args, interpret=body == "pallas_interpret", **kwargs)
+    interpret = body.endswith("_interpret")
+    if body.startswith("pallas_per_shard"):
+        return _dispatch_per_shard(k, interpret, args, kwargs)
+    return k.pallas(*args, interpret=interpret, **kwargs)

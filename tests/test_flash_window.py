@@ -189,7 +189,7 @@ def test_causal_attention_takes_a_window_and_groups(impl, window):
 def test_auto_is_asked_with_the_keys_a_query_sees(monkeypatch):
     asked = []
     monkeypatch.setattr(blocks, "attention_body",
-                        lambda positions, mesh=None:
+                        lambda positions, mesh=None, batch=None:
                         asked.append(positions) or "dense")
     q, k, v, _ = arrays(0, 4, 2, 64)
     to_bsnd = lambda t: t.transpose(0, 2, 1, 3)      # noqa: E731

@@ -7,6 +7,7 @@ duplicate-index scatter-adds). On CPU the Pallas bodies run in
 interpreter mode: the same kernel code the TPU compiles, so these tests
 pin TPU semantics from the CI host."""
 
+import functools
 import json
 import os
 import subprocess
@@ -17,6 +18,7 @@ import pytest
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 import paddle_tpu.ops.pallas as plk
 
@@ -30,6 +32,14 @@ KERNELS = ["embedding_scatter_add", "flash_attention", "fused_layer_norm",
 
 def _f(shape, dtype=jnp.float32, scale=1.0):
     return jnp.asarray(RNG.randn(*shape) * scale, dtype)
+
+
+def _selection_gauge():
+    from paddle_tpu.monitor.registry import gauge
+    return gauge("pallas_kernels_selected",
+                 "Which body the Pallas kernel registry selected "
+                 "(1 = active), per kernel",
+                 labels=("kernel", "body"))
 
 
 def _close(a, b, dtype=jnp.float32, **kw):
@@ -116,16 +126,12 @@ class TestRegistry:
             plk.registry._REGISTRY.pop("_test_ref_only")
 
     def test_selection_gauge_published(self):
-        from paddle_tpu.monitor.registry import gauge
         with plk.override("on"):
             plk.dispatch("fused_layer_norm", _f((4, 8)), _f((8,)),
                          _f((8,)))
-        g = gauge("pallas_kernels_selected",
-                  "Which body the Pallas kernel registry selected "
-                  "(1 = active), per kernel",
-                  labels=("kernel", "body"))
         body = "pallas_interpret" if plk.platform() == "cpu" else "pallas"
-        assert g.value(kernel="fused_layer_norm", body=body) == 1.0
+        assert _selection_gauge().value(kernel="fused_layer_norm",
+                                        body=body) == 1.0
 
     def test_override_nests_and_restores(self):
         with plk.override("off"):
@@ -397,3 +403,165 @@ class TestMigratedKernels:
             else dict(rtol=1e-4, atol=1e-4)
         _close(lr, lp, dtype, **tol)
         _close(gr, gp, dtype, **tol)
+
+
+# ---------------------------------------------------------------------------
+# a kernel that leads with the batch, a shard at a time on a data mesh
+# ---------------------------------------------------------------------------
+def _mesh(**axes):
+    from paddle_tpu.parallel.mesh import MeshConfig, make_mesh
+    n = int(np.prod(list(axes.values())))
+    return make_mesh(MeshConfig(**axes), devices=jax.devices()[:n])
+
+
+def _key_bias(b, s):
+    """A key-padding bias: rows 1 and 2 mask their last keys."""
+    keys = np.arange(s)[None, :]
+    kept = np.full((b, 1), s)
+    kept[1], kept[2] = s - 37, s // 2
+    return jnp.asarray(np.where(keys < kept, 0.0, -1e30), jnp.float32)
+
+
+#: (q, k, v shapes, with a key bias, the call's static arguments)
+PER_SHARD_FORMS = {
+    # BERT's: every head its own keys, a padding bias, sized for the
+    # interpreter (the cell is [64, 12, 512, 64] a shard, one tile a head)
+    "bert": ((8, 4, 256, 64), (8, 4, 256, 64), (8, 4, 256, 64), True, {}),
+    # the decoders': causal, a window, 8 query heads over 2 key/value
+    # heads, value heads of their own size
+    "decoder": ((8, 8, 256, 64), (8, 2, 256, 64), (8, 2, 256, 32), False,
+                {"causal": True, "window": 128}),
+}
+
+
+class TestPerShard:
+    @pytest.mark.parametrize("form", sorted(PER_SHARD_FORMS))
+    def test_flash_on_a_data_mesh_is_the_one_device_call(self, form):
+        """Under a mesh that splits only the batch the registry runs the
+        Pallas body a shard at a time inside shard_map: the same values and
+        the same gradients of q, k, v as the call on one device, and a
+        result that stays split by the batch."""
+        qs, ks, vs, biased, static = PER_SHARD_FORMS[form]
+        q, k, v = _f(qs, scale=0.5), _f(ks, scale=0.5), _f(vs)
+        bias = _key_bias(qs[0], qs[2]) if biased else None
+        weight = _f(qs[:3] + vs[3:])
+        mesh = _mesh(data=4)
+
+        def loss(q, k, v, mesh=None):
+            with plk.mesh_scope(mesh):
+                out = plk.flash_attention(q, k, v, bias=bias, block_q=128,
+                                          block_k=128, **static)
+            return jnp.sum(out * weight), out
+
+        grad = jax.value_and_grad(loss, argnums=(0, 1, 2), has_aux=True)
+        rows = NamedSharding(mesh, P("data"))
+        with plk.override("on"):
+            with plk.mesh_scope(mesh):
+                assert plk.selected_body("flash_attention", qs[0]) == \
+                    "pallas_per_shard_interpret"
+            (_, want), want_grads = grad(q, k, v)
+            (_, got), got_grads = jax.jit(
+                lambda *qkv: grad(*qkv, mesh=mesh))(
+                *(jax.device_put(t, rows) for t in (q, k, v)))
+        assert got.sharding.is_equivalent_to(rows, got.ndim)
+        _close(got, want, atol=1e-6)
+        _tree_close(got_grads, want_grads, atol=1e-6)
+
+    #: (kernel, mesh axes, batch) -> the body `auto` selects on a chip
+    SELECTIONS = {
+        "flash_on_data4": ("flash_attention", {"data": 4}, 256,
+                           "pallas_per_shard"),
+        "flash_before_the_operands_are_there": (
+            "flash_attention", {"data": 4}, None, "pallas_per_shard"),
+        "flash_on_a_batch_the_axis_does_not_divide": (
+            "flash_attention", {"data": 4}, 6, "reference"),
+        "flash_on_data2_model2": ("flash_attention",
+                                  {"data": 2, "model": 2}, 256, "reference"),
+        "flash_on_data2_seq2": ("flash_attention", {"data": 2, "seq": 2},
+                                256, "reference"),
+        "flash_on_one_device": ("flash_attention", {"data": 1}, 256,
+                                "pallas"),
+        "layer_norm_declares_nothing": ("fused_layer_norm", {"data": 4}, 256,
+                                        "reference"),
+        "grouped_matmul_declares_nothing": ("grouped_matmul", {"data": 4},
+                                            256, "reference"),
+    }
+
+    @pytest.mark.parametrize("case", sorted(SELECTIONS))
+    def test_selection_from_the_mesh_and_the_batch(self, case, monkeypatch):
+        kernel, axes, batch, body = self.SELECTIONS[case]
+        monkeypatch.setattr(plk.registry, "platform", lambda: "tpu")
+        with plk.mesh_scope(_mesh(**axes)):
+            assert plk.selected_body(kernel, batch) == body
+
+    def test_inside_a_shard_map_body_there_is_no_second_split(
+            self, monkeypatch):
+        """A trainer's shard_map body that passes its mesh on to the model
+        (DataParallelTrainer's ZeRO step with a loss that takes the mesh)
+        keeps the parent's answer under that scope, and does not nest a
+        shard_map over an axis that is manual already."""
+        monkeypatch.setattr(plk.registry, "platform", lambda: "tpu")
+        mesh, seen = _mesh(data=4), []
+
+        def body(q):
+            with plk.mesh_scope(mesh):
+                seen.append(plk.selected_body("flash_attention", q.shape[0]))
+            return q
+
+        jax.eval_shape(jax.shard_map(body, mesh=mesh, in_specs=P("data"),
+                                     out_specs=P("data")), _f((32, 2, 128, 32)))
+        assert seen == ["reference"]
+
+    def test_a_dispatch_reads_the_batch_from_its_operands(self, monkeypatch):
+        """What a caller tells ``selected_body`` a dispatch reads off its
+        first operand: six rows over data=4 take the reference on a chip."""
+        kernel = plk.get_kernel("flash_attention")
+        ran = []
+        monkeypatch.setattr(plk.registry, "platform", lambda: "tpu")
+        monkeypatch.setattr(kernel, "reference",
+                            lambda q, *a, **kw: ran.append(q.shape) or q)
+        q = _f((6, 2, 128, 32))
+        with plk.mesh_scope(_mesh(data=4)):
+            plk.flash_attention(q, q, q)
+        assert ran == [(6, 2, 128, 32)]
+
+    def test_the_layers_share_one_trace_of_the_per_shard_call(
+            self, monkeypatch):
+        """The wrapper is a jitted function a (kernel, mesh, arguments): a
+        second layer with the same shapes traces neither the shard_map nor
+        the kernel inside it again."""
+        kernel = plk.get_kernel("flash_attention")
+        body, traced = kernel.pallas, []
+
+        @functools.wraps(body)     # the registry binds by the body's names
+        def spy(*args, **kwargs):
+            traced.append(kwargs["interpret"])
+            return body(*args, **kwargs)
+
+        monkeypatch.setattr(kernel, "pallas", spy)
+        plk.registry._per_shard_call.cache_clear()
+        mesh = _mesh(data=4)
+        # placed on the mesh as a step's batch is: an operand that knows
+        # no mesh is another type to jit than a layer's result
+        q = jax.device_put(_f((4, 2, 128, 32)), NamedSharding(mesh, P("data")))
+
+        @jax.jit
+        def two_layers(q):
+            with plk.mesh_scope(mesh), plk.override("on"):
+                x = plk.flash_attention(q, q, q, causal=True)
+                return plk.flash_attention(x, x, x, causal=True)
+
+        two_layers(q)
+        assert traced == [True]
+        info = plk.registry._per_shard_call.cache_info()
+        assert (info.misses, info.hits) == (1, 1)
+
+    def test_the_gauge_tells_the_per_shard_body_apart(self):
+        q = _f((4, 2, 128, 32))
+        with plk.mesh_scope(_mesh(data=4)), plk.override("on"):
+            plk.flash_attention(q, q, q)
+        g = _selection_gauge()
+        assert g.value(kernel="flash_attention",
+                       body="pallas_per_shard_interpret") == 1.0
+        assert g.value(kernel="flash_attention",
+                       body="pallas_interpret") in (None, 0.0)

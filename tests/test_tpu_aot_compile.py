@@ -255,37 +255,59 @@ def test_bert_step_one_device_runs_the_pallas_bodies(bert_two_layers):
         ["flash_bwd"] * 2 + ["flash_fwd"] * 2 + ["layer_norm_fwd"] * 6)
 
 
-def test_bert_step_on_four_chips_keeps_its_own_dense_attention(topo):
-    """The cell mlm_s512_dp4: under a mesh of more than one device the
-    Pallas flash body cannot run, so `auto` keeps bert._attention's inline
-    bf16 dense code and does not hand the step the registry's reference,
-    which holds the scores in float32 (805 MB a layer and a device)."""
-    def step(**kw):
-        return _bert_step(topo, MeshConfig(data=4), 4, 256, num_layers=2,
-                          softmax_dtype="bf16", **kw)[0]
+def test_bert_step_on_four_chips_runs_the_flash_kernels_a_shard_at_a_time(
+        topo, capsys):
+    """The cell mlm_s512_dp4: the mesh splits only the batch, so the registry
+    runs the flash kernels inside shard_map over `data`, one forward and one
+    backward call a layer on a chip's 64 rows, and no [64, 12, 512, 512]
+    scores exist in HBM in either type. Nothing is resharded around the
+    calls: the gradients' all-reduces are the program's only collectives."""
+    compiled, _ = _bert_step(topo, MeshConfig(data=4), 4, 256,
+                             num_layers=2, softmax_dtype="bf16")
+    # the layer norm declares no batch split: its reference body on a mesh
+    assert sorted(_mosaic_call_stems(compiled)) == (
+        ["flash_bwd"] * 2 + ["flash_fwd"] * 2)
+    entry = _entry_text(compiled)
+    assert "[64,12,512,512]" not in entry
+    text = compiled.as_text()
+    assert "all-reduce" in text
+    assert not re.search(r"[ )](all-gather|all-to-all|collective-permute)"
+                         r"(-start)?\(", text)
+    # the scope a profile's attention_core_ms reads is still on the calls
+    op_names = re.findall(r'op_name="([^"]*)"', "\n".join(
+        line for line in text.splitlines() if "tpu_custom_call" in line))
+    assert len(op_names) == 4 and all(
+        "/attention_core/jit(flash_attention_per_shard)/" in n
+        for n in op_names), op_names
+    with capsys.disabled():
+        print(f"\nbert 2 layers on data=4, a device: "
+              f"{_need_bytes(compiled) / 2**30:.3f} GiB (compiler)")
 
-    compiled = step()
-    assert _mosaic_calls(compiled) == 0
-    assert "bf16[64,12,512,512]" in _entry_text(compiled)
-    assert "f32[64,12,512,512]" not in _entry_text(compiled)
-    # and the reference is what the test says it is
-    assert "f32[64,12,512,512]" in _entry_text(step(attention_impl="flash"))
 
-
-#: (positions, devices of the mesh or 0 for no mesh, platform) -> what `auto`
-#: runs: the flash kernels from blocks.FLASH_FROM positions on where the
-#: Pallas body runs; where it does not, the callers' dense code up to 1024
-#: positions and the registry's reference body ("flash" there) beyond
+#: (positions, the mesh's axes or None for no mesh, batch, platform) -> what
+#: `auto` runs: the flash kernels from blocks.FLASH_FROM positions on where
+#: the Pallas body runs (one chip, or a mesh that splits only the batch, and
+#: that evenly: there a shard at a time); where it does not, the callers'
+#: dense code up to 1024 positions and the registry's reference body ("flash"
+#: there) beyond
 AUTO_CASES = {
-    "one_chip_below_the_crossover": (256, 1, "tpu", "dense"),
-    "one_chip_at_the_crossover": (512, 1, "tpu", "flash"),
-    "one_chip_mlm_s4096": (4096, 1, "tpu", "flash"),
-    "no_mesh_at_the_crossover": (512, 0, "tpu", "flash"),
-    "four_chips_mlm_s512_dp4": (512, 4, "tpu", "dense"),
-    "four_chips_s1024": (1024, 4, "tpu", "dense"),
-    "four_chips_beyond_1024": (2048, 4, "tpu", "flash"),
-    "cpu_s512": (512, 1, "cpu", "dense"),
-    "cpu_beyond_1024": (2048, 1, "cpu", "flash"),
+    "one_chip_below_the_crossover": (256, {"data": 1}, 64, "tpu", "dense"),
+    "one_chip_at_the_crossover": (512, {"data": 1}, 64, "tpu", "flash"),
+    "one_chip_mlm_s4096": (4096, {"data": 1}, 8, "tpu", "flash"),
+    "no_mesh_at_the_crossover": (512, None, 64, "tpu", "flash"),
+    "four_chips_mlm_s512_dp4": (512, {"data": 4}, 256, "tpu", "flash"),
+    "four_chips_s1024": (1024, {"data": 4}, 128, "tpu", "flash"),
+    "four_chips_below_the_crossover": (256, {"data": 4}, 256, "tpu",
+                                       "dense"),
+    "four_chips_a_batch_the_mesh_does_not_divide": (512, {"data": 4}, 6,
+                                                    "tpu", "dense"),
+    "four_chips_beyond_1024": (2048, {"data": 4}, 32, "tpu", "flash"),
+    "data2_model2_s512": (512, {"data": 2, "model": 2}, 128, "tpu", "dense"),
+    "data2_model2_beyond_1024": (2048, {"data": 2, "model": 2}, 32, "tpu",
+                                 "flash"),
+    "cpu_s512": (512, {"data": 1}, 64, "cpu", "dense"),
+    "cpu_four_devices_s512": (512, {"data": 4}, 256, "cpu", "dense"),
+    "cpu_beyond_1024": (2048, {"data": 1}, 16, "cpu", "flash"),
 }
 
 
@@ -293,25 +315,28 @@ AUTO_CASES = {
 def test_auto_chooses_the_attention_body_from_what_it_observes(case,
                                                                monkeypatch):
     from paddle_tpu.models import blocks
-    positions, devices, platform, body = AUTO_CASES[case]
+    positions, axes, batch, platform, body = AUTO_CASES[case]
     monkeypatch.setattr(registry, "platform", lambda: platform)
-    mesh = devices and make_mesh(MeshConfig(data=devices),
-                                 devices=jax.devices()[:devices])
-    assert blocks.attention_body(positions, mesh or None) == body
+    mesh = axes and make_mesh(
+        MeshConfig(**axes),
+        devices=jax.devices()[:int(np.prod(list(axes.values())))])
+    assert blocks.attention_body(positions, mesh, batch) == body
 
 
-@pytest.mark.parametrize("mesh_cfg,batch", [
-    (MeshConfig(data=4), 256), (MeshConfig(data=2, model=2), 128)],
+@pytest.mark.parametrize("mesh_cfg,batch,mosaic_calls", [
+    (MeshConfig(data=4), 256, 4), (MeshConfig(data=2, model=2), 128, 0)],
     ids=["data4", "data2_model2"])
 def test_bert_step_lowers_and_compiles_on_four_chips(topo, mesh_cfg,
-                                                      batch):
-    """GSPMD refuses to partition a Mosaic call; under a mesh of more
-    than one device `auto` takes the reference bodies, so the step
+                                                      batch, mosaic_calls):
+    """GSPMD refuses to partition a Mosaic call. Under a mesh that splits
+    only the batch the flash kernels run a shard at a time inside shard_map
+    (a forward and a backward call a layer); under any other mesh of more
+    than one device `auto` takes the reference bodies. Either way the step
     lowers, and the partitioner inserts the gradient all-reduces."""
     compiled, mesh = _bert_step(topo, mesh_cfg, 4, batch, num_layers=2)
     assert mesh.size == 4
     text = compiled.as_text()
-    assert _mosaic_calls(compiled) == 0
+    assert _mosaic_calls(compiled) == mosaic_calls
     assert "all-reduce" in text
     out_sh = jax.tree.leaves(compiled.output_shardings)
     assert all(len(s.device_set) == 4 for s in out_sh)
