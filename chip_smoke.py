@@ -40,7 +40,7 @@ import jaxlib
 import numpy as np
 
 import paddle_tpu as pt
-from paddle_tpu import native
+from paddle_tpu import native, profiler
 from paddle_tpu.core import compile_cache
 from paddle_tpu.models import bert
 from paddle_tpu.monitor.registry import REGISTRY
@@ -528,9 +528,13 @@ def main():
     log(f"compile_cache: hits={cc['hits']} misses={cc['misses']} "
         f"requests={cc['requests']}")
     log(f"total: {time.perf_counter() - t_all:.1f} s")
+    # the start-up timeline (process start, import, the compile log); of
+    # the programs compiled, the line names the last three
+    timeline = profiler.startup()
+    timeline["compile"]["compiled"] = timeline["compile"]["compiled"][-3:]
     log("summary: " + json.dumps({
         "phases": {k: "ok" for k in phases}, "four_chips": four,
-        "compile_cache": {"dir": cache_dir, **cc}}))
+        "compile_cache": {"dir": cache_dir, **cc}, "startup": timeline}))
     # the last line carries these two keys and no other
     log(json.dumps({"ok": True, "device": device}))
     return 0

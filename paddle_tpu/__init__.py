@@ -17,6 +17,10 @@ Public surface mirrors the reference's `paddle.fluid` so users can migrate:
 (Program/Executor), eager by default (the reference's dygraph).
 """
 
+import time as _time
+
+_IMPORT_T0 = _time.perf_counter()       # the span startup/import begins
+
 from paddle_tpu.core import dtypes
 from paddle_tpu.core.dtypes import (
     float32, float64, float16, bfloat16, int8, int16, int32, int64, bool_,
@@ -84,3 +88,8 @@ from paddle_tpu import contrib
 from paddle_tpu import inference
 
 from paddle_tpu.version import __version__  # noqa: E402
+
+# the package's own import, first line to last, on the start-up timeline
+# (profiler.startup): what lies before it is the interpreter, jax where the
+# caller imported it first, and the caller's other imports
+profiler.record_span("startup/import", _IMPORT_T0, _time.perf_counter())

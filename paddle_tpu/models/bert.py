@@ -393,12 +393,13 @@ def make_train_step(cfg, optimizer, mesh=None, steps_per_call=1):
     pshard = jax.tree.map(lambda s: NamedSharding(mesh, s), pspecs,
                           is_leaf=lambda s: isinstance(s, P))
     def init_fn(rng):
-        params = jax.jit(
-            functools.partial(init_params, cfg=cfg),
-            out_shardings=pshard)(rng)
-        opt_state = optimizer.init(params)
-        opt_state = jax.device_put(
-            opt_state, optimizer.state_shardings(opt_state, pshard, mesh))
+        with RecordEvent("trainer/init"):
+            params = jax.jit(
+                functools.partial(init_params, cfg=cfg),
+                out_shardings=pshard)(rng)
+            opt_state = optimizer.init(params)
+            opt_state = jax.device_put(
+                opt_state, optimizer.state_shardings(opt_state, pshard, mesh))
         return params, opt_state
 
     def step(params, opt_state, batch):

@@ -42,11 +42,12 @@ def make_train_step(cfg, optimizer, mesh, init_params, param_specs, loss_fn,
                           is_leaf=lambda s: isinstance(s, P))
 
     def init_fn(rng):
-        params = jax.jit(functools.partial(init_params, cfg=cfg),
-                         out_shardings=pshard)(rng)
-        opt_state = optimizer.init(params)
-        opt_state = jax.device_put(
-            opt_state, optimizer.state_shardings(opt_state, pshard, mesh))
+        with RecordEvent("trainer/init"):
+            params = jax.jit(functools.partial(init_params, cfg=cfg),
+                             out_shardings=pshard)(rng)
+            opt_state = optimizer.init(params)
+            opt_state = jax.device_put(
+                opt_state, optimizer.state_shardings(opt_state, pshard, mesh))
         return params, opt_state
 
     def step(params, opt_state, batch):

@@ -15,6 +15,16 @@ long run can no longer grow host memory without bound (cap via
 ``PADDLE_TPU_PROFILER_MAX_EVENTS``). When the flight recorder
 (monitor/flight_recorder.py) is armed, ``RecordEvent`` also feeds it, so
 a postmortem names the span a dying rank was stuck inside.
+
+Start-up is on from the first line (PR 35): the spans named in
+``STARTUP_SPANS`` (``startup/import``, stamped by the package's
+``__init__``, and the trainers' ``trainer/init``) are kept in the same
+ring whether or not a profile is running, and :func:`startup` puts them on
+one timeline with the process's start and with what the compile log
+(``core/compile_cache.py``) holds: seconds tracing, lowering and in the
+backend, the cache's answers, the functions by self seconds.
+``summary()`` prints it. No switch, and nothing a step pays: the step's
+own spans reach the ring only while profiling, as before.
 """
 
 import collections
@@ -33,7 +43,8 @@ from paddle_tpu.monitor.registry import _ThreadShards
 __all__ = [
     "profiler", "start_profiler", "stop_profiler", "reset_profiler",
     "RecordEvent", "record_memory_event", "export_chrome_trace",
-    "compilation_cache_stats", "set_max_events",
+    "compilation_cache_stats", "set_max_events", "record_span",
+    "process_start", "startup", "STARTUP_SPANS",
 ]
 
 _DEFAULT_MAX_EVENTS = int(os.environ.get(
@@ -99,6 +110,11 @@ _mem_events = _ShardedRing(_DEFAULT_MAX_EVENTS)  # (name, ts, bytes, place)
 _active = {"on": False, "jax_dir": None}
 
 
+#: spans with one of these prefixes are kept with no profile running: they
+#: happen once a process (or once a trainer), not once a step
+STARTUP_SPANS = ("startup/", "trainer/init")
+
+
 def set_max_events(n):
     """Cap the profiler's per-thread event rings (oldest events drop
     first). Returns the previous cap."""
@@ -139,11 +155,81 @@ class RecordEvent:
     def __exit__(self, *exc):
         dur = time.perf_counter() - self.t0
         self._annotation.__exit__(*exc)
-        if _active["on"]:
+        if _active["on"] or self.name.startswith(STARTUP_SPANS):
             _events.append((self.name, self.t0, dur,
                             threading.get_ident(), self.args))
         if _flight._enabled:
             _flight.RECORDER.span_pop(self.name, dur)
+
+
+def record_span(name, t0, t1):
+    """A span whose ends the caller stamped with ``time.perf_counter``,
+    into the ring under ``RecordEvent``'s rule: the package's
+    ``startup/import`` begins before this module exists."""
+    if _active["on"] or name.startswith(STARTUP_SPANS):
+        _events.append((name, t0, t1 - t0, threading.get_ident(), None))
+
+
+def process_start():
+    """When this process started, on ``time.perf_counter``'s clock, or None
+    where ``/proc`` does not say. Field 22 of ``/proc/self/stat`` is the
+    start in clock ticks since boot, and on Linux ``perf_counter`` is
+    CLOCK_MONOTONIC, which counts from boot too: the two subtract, to the
+    tick (10 ms)."""
+    try:
+        with open("/proc/self/stat") as f:
+            fields = f.read().rsplit(")", 1)[1].split()
+        return int(fields[19]) / os.sysconf("SC_CLK_TCK")
+    except (OSError, ValueError, IndexError):
+        return None
+
+
+def startup(until=None):
+    """One timeline from the process's start to ``until`` (a
+    ``perf_counter`` reading; default: now): ``process_start``, the kept
+    spans that began before ``until`` as [name, start, end], oldest first,
+    and ``compile``, the compile log's reduction up to ``until``
+    (``compile_cache.reduce``). Every time is on ``perf_counter``."""
+    from paddle_tpu.core import compile_cache
+    if until is None:
+        until = time.perf_counter()
+    spans = sorted(([name, t0, t0 + dur]
+                    for name, t0, dur, _tid, _args in _events.snapshot()
+                    if name.startswith(STARTUP_SPANS) and t0 < until),
+                   key=lambda span: span[1])
+    return {"process_start": process_start(), "until": until,
+            "spans": spans, "compile": compile_cache.reduce(until=until)}
+
+
+def _startup_lines():
+    """The timeline as ``summary`` prints it; times since process start
+    (since the first span where the process's start is not known)."""
+    line = startup()
+    log, spans = line["compile"], line["spans"]
+    origin = line["process_start"]
+    if origin is None:
+        origin = spans[0][1] if spans else line["until"]
+    lines = ["start-up: " + "; ".join(
+        [f"process start {line['until'] - origin:.2f} s ago"]
+        + [f"{name} {t0 - origin:.2f} to {t1 - origin:.2f} s"
+           for name, t0, t1 in spans])]
+    lines.append(
+        f"compile log: tracing {log['trace_s']:.2f} s, lowering "
+        f"{log['lower_s']:.2f} s, backend {log['backend_s']:.2f} s "
+        f"({log['retrieval_s']:.2f} s reading the cache) in "
+        f"{log['programs']} programs; {log['requests']} requests, "
+        f"{log['hits']} hits, {log['misses']} misses; dropped "
+        f"{log['dropped']['records']} records")
+    if log["by_self_s"]:
+        lines.append("  most self seconds of trace + lowering: " + ", ".join(
+            f"{name} {self_s:.2f} s in {calls}"
+            for name, calls, self_s in log["by_self_s"]))
+    if log["compiled"]:
+        lines.append("  last compiled: " + ", ".join(
+            f"{name} at {t0 - origin:.2f} s ({cache or 'cache not asked'}, "
+            f"{seconds:.2f} s)"
+            for name, t0, seconds, cache in log["compiled"][-3:]))
+    return lines
 
 
 def record_memory_event(name, nbytes, place="host"):
@@ -286,6 +372,7 @@ def summary(sorted_key="total", profile_path=None):
         lines.append(f"compilation cache: {cc['hits']} hits / "
                      f"{cc['misses']} misses "
                      f"({compile_cache.cache_dir()})")
+    lines.extend(_startup_lines())
     from paddle_tpu.monitor.registry import REGISTRY as _REG
     trips = _REG.get("anomaly_trips_total")
     trip_samples = trips.samples() if trips is not None else {}

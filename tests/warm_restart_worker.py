@@ -10,7 +10,9 @@ incarnation populates the on-disk cache (misses), crashes via
 program and must hit the cache instead of redoing XLA.
 
 Writes <out_prefix>.inc<restart_count>.json with the incarnation's
-compilation-cache counters, executor trace count, and loss stream.
+compilation-cache counters, the compile log's account of the same process
+(``compile_cache.reduce()``: what ``test_startup_timeline.py`` holds the
+counters to), executor trace count, and loss stream.
 """
 
 import json
@@ -62,6 +64,8 @@ def main():
                 "cache_dir": compile_cache.cache_dir(),
                 "hits": stats["hits"],
                 "misses": stats["misses"],
+                "requests": stats["requests"],
+                "log": compile_cache.reduce(),
                 "trace_count": exe.trace_count,
                 "aot_full": bool(aot_full),
                 "losses": losses,
