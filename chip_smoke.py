@@ -202,6 +202,12 @@ def phase_kernels():
                                   for _ in range(3)),
                             {"bias": jnp.zeros((8, 4 * SEQ), jnp.float32)},
                             (0, 1, 2), 3e-2),
+        # the projections' own layout: q, k and v side by side in one
+        # [B, S, 3 H D] array, two heads of 64 a lane tile (BERT's call)
+        "flash_attention/rows_major": ((bf16(8, 4 * SEQ, 3 * 12 * 64),),
+                                       {"bias": jnp.zeros((8, 4 * SEQ),
+                                                          jnp.float32),
+                                        "num_heads": 12}, (0,), 3e-2),
         # a window of SEQ keys, groups of 8 query heads on one key/value head
         "flash_attention/window": ((bf16(1, 16, 4 * SEQ, 128),
                                     bf16(1, 2, 4 * SEQ, 128),

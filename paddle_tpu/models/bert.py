@@ -200,7 +200,6 @@ def _attention(lp, x, mask_bias, cfg, mesh=None, key_padding_mask=None):
     B, S, H = x.shape
     nh, hd = cfg.num_heads, cfg.head_dim
     qkv = x @ lp["qkv_w"].astype(x.dtype) + lp["qkv_b"].astype(x.dtype)
-    q, k, v = jnp.split(qkv, 3, axis=-1)
 
     impl = cfg.attention_impl
     if impl == "auto":
@@ -217,6 +216,23 @@ def _attention(lp, x, mask_bias, cfg, mesh=None, key_padding_mask=None):
         else:
             impl = attention_body(S, mesh, B)
 
+    if impl == "flash":
+        # Pallas blockwise kernel: [S, S] scores never hit HBM
+        # (paddle_tpu/ops/pallas/flash_attention.py). It reads q, k and v
+        # where the projection left them, side by side in qkv [B, S, 3H],
+        # and writes the context as [B, S, H]: the heads are lane tiles of
+        # its blocks, so nothing is split or transposed to [B,N,S,D] here
+        # (97 such copies were 23.5 ms of the cell mlm_s512's step).
+        # mask_bias [B,1,1,S] is a key-padding bias → [B, S].
+        from paddle_tpu.ops import pallas as _pk
+
+        bias = mask_bias.reshape(B, S).astype(jnp.float32)
+        with mesh_scope(mesh), jax.named_scope("attention_core"):
+            ctx = _pk.flash_attention(qkv, bias=bias, num_heads=nh)
+        return ctx @ lp["out_w"].astype(x.dtype) \
+            + lp["out_b"].astype(x.dtype)
+
+    q, k, v = jnp.split(qkv, 3, axis=-1)
     if (impl == "ring" and mesh is not None
             and mesh.shape.get(SEQ_AXIS, 1) > 1):
         from paddle_tpu.parallel import ring_attention as _ra
@@ -229,24 +245,6 @@ def _attention(lp, x, mask_bias, cfg, mesh=None, key_padding_mask=None):
             ctx = _ra.ring_attention(mesh, bshd(q), bshd(k), bshd(v),
                                      key_padding_mask=key_padding_mask)
         ctx = ctx.reshape(B, S, H).astype(x.dtype)
-        return ctx @ lp["out_w"].astype(x.dtype) \
-            + lp["out_b"].astype(x.dtype)
-
-    if impl == "flash":
-        # Pallas blockwise kernel: [S, S] scores never hit HBM
-        # (paddle_tpu/ops/pallas/flash_attention.py); the kernel wants
-        # [B,N,S,D].
-        # mask_bias [B,1,1,S] is a key-padding bias → [B, S].
-        from paddle_tpu.ops import pallas as _pk
-
-        def heads(t):
-            return t.reshape(B, S, nh, hd).transpose(0, 2, 1, 3)
-
-        bias = mask_bias.reshape(B, S).astype(jnp.float32)
-        with mesh_scope(mesh), jax.named_scope("attention_core"):
-            ctx = _pk.flash_attention(heads(q), heads(k), heads(v),
-                                      bias=bias)
-        ctx = ctx.transpose(0, 2, 1, 3).reshape(B, S, H).astype(x.dtype)
         return ctx @ lp["out_w"].astype(x.dtype) \
             + lp["out_b"].astype(x.dtype)
 

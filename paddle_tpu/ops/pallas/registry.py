@@ -29,7 +29,10 @@ everywhere, ``auto`` the selection above.
 Every selection change is published through the
 ``pallas_kernels_selected{kernel,body}`` gauge so a running job's kernel
 selection is inspectable from the metrics snapshot
-(docs/OBSERVABILITY.md).
+(docs/OBSERVABILITY.md). Where a kernel's Pallas body has blocks for more
+than one layout of its operands (``flash_attention``: heads-major, and
+rows-major, the projections' own), the body's name there says which the
+last call took: ``pallas_rows_major``, ``pallas_per_shard_rows_major``.
 """
 
 import contextlib
@@ -73,17 +76,22 @@ class Kernel:
     leading dimension is the batch, the first of them never ``None``; the
     result leads with the batch too, and no row of it reads another row's
     operands. A kernel that says so can run a shard of the batch at a
-    time."""
+    time. ``layout``, of a kernel whose Pallas body has blocks for more
+    than one layout of its operands, takes a call's arguments and names the
+    layout they run in ("" for the first there was): the gauge's body name
+    carries it, and nothing else reads it."""
 
-    __slots__ = ("name", "reference", "pallas", "doc", "batch_leading")
+    __slots__ = ("name", "reference", "pallas", "doc", "batch_leading",
+                 "layout")
 
     def __init__(self, name, reference, pallas=None, doc="",
-                 batch_leading=()):
+                 batch_leading=(), layout=None):
         self.name = name
         self.reference = reference
         self.pallas = pallas
         self.doc = doc
         self.batch_leading = tuple(batch_leading)
+        self.layout = layout
 
     def __repr__(self):
         bodies = "reference+pallas" if self.pallas else "reference"
@@ -91,10 +99,10 @@ class Kernel:
 
 
 def register_kernel(name, reference, pallas=None, doc="",
-                    batch_leading=()):
+                    batch_leading=(), layout=None):
     """Register (or re-register) a kernel. Mirrors ``register_op``:
     last registration wins, so tests can shadow a body."""
-    k = Kernel(name, reference, pallas, doc, batch_leading)
+    k = Kernel(name, reference, pallas, doc, batch_leading, layout)
     with _lock:
         _REGISTRY[name] = k
     return k
@@ -234,6 +242,19 @@ def _note_selection(name, body):
         pass
 
 
+def _in_layout(body, kernel, args, kwargs):
+    """The gauge's name for ``body`` on these arguments: a Pallas body with
+    the operand layout the kernel says they run in, where it names one,
+    before ``_interpret`` (``pallas_rows_major``,
+    ``pallas_per_shard_rows_major_interpret``)."""
+    layout = kernel.layout(*args, **kwargs) \
+        if kernel.layout and body != "reference" else ""
+    if not layout:
+        return body
+    stem, interpret, _ = body.partition("_interpret")
+    return f"{stem}_{layout}{interpret}"
+
+
 def vmem_spec(*args, **kwargs):
     """A ``pl.BlockSpec`` whose block lives in VMEM unless told otherwise:
     what the kernel modules' in_specs and out_specs are made of."""
@@ -312,7 +333,7 @@ def dispatch(name, *args, **kwargs):
     k = _REGISTRY[name]
     body = selected_body(
         name, np.shape(args[0])[0] if k.batch_leading and args else None)
-    _note_selection(name, body)
+    _note_selection(name, _in_layout(body, k, args, kwargs))
     if body == "reference":
         return k.reference(*args, **kwargs)
     interpret = body.endswith("_interpret")

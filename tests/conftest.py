@@ -68,6 +68,20 @@ def pytest_runtest_call(item):
         signal.signal(signal.SIGALRM, prev)
 
 
+@pytest.fixture(autouse=True, scope="module")
+def _static_mode_ends_with_its_file():
+    """``pt.enable_static()`` is ambient state, and a score of test files
+    switch it on and leave it on. Under ``--dist loadfile`` the next file on
+    that worker then builds static Variables in its eager tests (which file
+    that is follows the workers' timing: ``test_book`` after
+    ``test_pallas_registry``, ``test_contrib_r3::TestTrainingDecoder`` in
+    the driver's runs). A file ends in the mode it began in."""
+    from paddle_tpu.static import program
+    was = program.in_static_mode()
+    yield
+    (program.enable_static if was else program.disable_static)()
+
+
 @pytest.fixture(autouse=True)
 def _seed():
     np.random.seed(0)

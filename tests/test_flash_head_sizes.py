@@ -2,7 +2,8 @@
 score heads are no multiple of the 128-lane grain (latent attention: 192 for
 the scores, 128 for the values): the Pallas body in interpret mode against
 the dense body, outputs and the three gradients, through the kernel's entry
-point and through ``blocks.causal_attention``."""
+point (heads-major operands, and rows-major ones of the same sizes) and
+through ``blocks.causal_attention``."""
 
 import jax
 import jax.numpy as jnp
@@ -37,6 +38,37 @@ def test_kernel_matches_the_dense_body(positions, d, dv, causal):
 
     out = f(q, k, v, "on")
     assert out.shape == (1, 2, positions, dv)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(f(q, k, v, "off")),
+                               atol=2e-5)
+    got, want = (jax.grad(lambda q, k, v: jnp.sum(f(q, k, v, mode) * w),
+                          (0, 1, 2))(q, k, v) for mode in ("on", "off"))
+    for name, a, b in zip("qkv", got, want):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-5,
+                                   err_msg=f"d{name}")
+
+
+@pytest.mark.parametrize("positions,d,dv", CASES)
+def test_rows_major_operands_take_any_head_size(positions, d, dv):
+    """[B, S, heads D] queries and keys and [B, S, heads Dv] values, as a
+    projection leaves them: two heads of 64 run the rows-major blocks, every
+    other size here is transposed to heads-major inside the call; either
+    way the dense body's context and gradients."""
+    from paddle_tpu.ops.pallas.flash_attention import operand_layout
+
+    def rows(t):
+        return t.transpose(0, 2, 1, 3).reshape(1, positions, -1)
+
+    q, k, v, w = (rows(t) for t in qkv(positions, d, dv, seed=2))
+    assert operand_layout(q, k, v, num_heads=2) == (
+        "rows_major" if (d, dv) == (64, 64) else "")
+
+    def f(q, k, v, mode):
+        with plk.override(mode):
+            return plk.flash_attention(q, k, v, causal=True, num_heads=2)
+
+    out = f(q, k, v, "on")
+    assert out.shape == (1, positions, 2 * dv)
     np.testing.assert_allclose(np.asarray(out), np.asarray(f(q, k, v, "off")),
                                atol=2e-5)
     got, want = (jax.grad(lambda q, k, v: jnp.sum(f(q, k, v, mode) * w),

@@ -293,6 +293,118 @@ class TestFlashAttention:
             np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
                                        atol=5e-4, err_msg=name)
 
+    #: the rows-major operands ([B, S, heads D]: the projections' layout):
+    #: query heads, key/value heads, positions, head size, blocks, causal,
+    #: window, with a key bias, q k v packed in one array, dtype, tolerance
+    #: and the layout the call must take ("" = transposed to heads-major)
+    ROWS_MAJOR_CASES = {
+        # BERT's: 12 heads of 64, two a lane tile, one tile a head, packed
+        "d64_pairs_one_tile": (12, 12, 128, 64, 512, False, None, True,
+                               True, jnp.float32, 3e-4, "rows_major"),
+        "d64_pairs_many_tiles": (4, 4, 256, 64, 128, False, None, True,
+                                 True, jnp.float32, 3e-4, "rows_major"),
+        "d64_pairs_bf16": (4, 4, 256, 64, 128, False, None, True, True,
+                           jnp.bfloat16, 6e-2, "rows_major"),
+        "d64_three_arrays_causal": (4, 4, 256, 64, 128, True, None, False,
+                                    False, jnp.float32, 3e-4, "rows_major"),
+        "d64_one_tile_causal": (2, 2, 128, 64, 512, True, None, False, True,
+                                jnp.float32, 3e-4, "rows_major"),
+        "d64_window": (2, 2, 384, 64, 128, True, 100, False, True,
+                       jnp.float32, 3e-4, "rows_major"),
+        "d64_padded_s300": (6, 6, 300, 64, 512, False, None, True, True,
+                            jnp.float32, 3e-4, "rows_major"),
+        # a head a lane tile
+        "d128_packed_bias": (4, 4, 128, 128, 512, False, None, True, True,
+                             jnp.float32, 3e-4, "rows_major"),
+        "d128_group_causal": (4, 2, 256, 128, 128, True, None, False, False,
+                              jnp.float32, 5e-4, "rows_major"),
+        "d128_group_window": (4, 2, 384, 128, 128, True, 100, False, False,
+                              jnp.float32, 5e-4, "rows_major"),
+        "d128_group_bias": (4, 2, 256, 128, 128, False, None, True, False,
+                            jnp.float32, 5e-4, "rows_major"),
+        # shapes the rows-major blocks do not take: an odd count of 64-wide
+        # heads; a pair of query heads over one key/value head; D = 192
+        "d64_seven_heads": (7, 7, 128, 64, 512, False, None, True, True,
+                            jnp.float32, 3e-4, ""),
+        "d64_group": (4, 2, 128, 64, 512, True, None, False, False,
+                      jnp.float32, 3e-4, ""),
+        "d192": (2, 2, 128, 192, 512, True, None, False, False,
+                 jnp.float32, 5e-4, ""),
+    }
+
+    @pytest.mark.parametrize("case", sorted(ROWS_MAJOR_CASES))
+    def test_rows_major_matches_dense_and_heads_major(self, case):
+        """q, k and v as the projections leave them against the dense
+        attention and against the heads-major call on the same numbers: the
+        context, dQ, dK, dV and the key-bias gradient."""
+        import importlib
+        fa = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
+        (h, hkv, s, d, block, causal, window, with_bias, packed, dtype, atol,
+         layout) = self.ROWS_MAJOR_CASES[case]
+        b = 2
+        rng = np.random.RandomState(11)
+        q = jnp.asarray(rng.randn(b, s, h * d) * 0.5, dtype)
+        k = jnp.asarray(rng.randn(b, s, hkv * d) * 0.5, dtype)
+        v = jnp.asarray(rng.randn(b, s, hkv * d), dtype)
+        w = jnp.asarray(rng.randn(b, s, h * d).astype(np.float32))
+        bias = np.zeros((b, s), np.float32)
+        if with_bias:
+            bias[:] = rng.randn(b, s) * 0.3
+            bias[:, s - s // 5:] = -1e30
+        bias = jnp.asarray(bias)
+        static = dict(causal=causal, window=window, block_q=block,
+                      block_k=block)
+
+        def heads(t):
+            return t.reshape(b, s, -1, d).transpose(0, 2, 1, 3)
+
+        def rows(t):
+            return t.transpose(0, 2, 1, 3).reshape(b, s, -1)
+
+        def f_rows(q, k, v, bias):
+            if packed:
+                out = K.flash_attention(jnp.concatenate([q, k, v], -1),
+                                        bias=bias, num_heads=h, **static)
+            else:
+                out = K.flash_attention(q, k, v, bias=bias, num_heads=h,
+                                        **static)
+            return jnp.sum(out.astype(jnp.float32) * w), out
+
+        def f_heads(q, k, v, bias):
+            out = rows(K.flash_attention(heads(q), heads(k), heads(v),
+                                         bias=bias, **static))
+            return jnp.sum(out.astype(jnp.float32) * w), out
+
+        def f_dense(q, k, v, bias):
+            with K.override("off"):
+                return f_heads(q, k, v, bias)
+
+        operands = (jnp.concatenate([q, k, v], -1),) if packed else (q, k, v)
+        assert fa.operand_layout(*operands, num_heads=h) == layout
+        (_, out), got = jax.value_and_grad(f_rows, (0, 1, 2, 3),
+                                           has_aux=True)(q, k, v, bias)
+        assert out.shape == (b, s, h * d) and out.dtype == dtype
+        for other in (f_heads, f_dense):
+            (_, want_out), want = jax.value_and_grad(
+                other, (0, 1, 2, 3), has_aux=True)(q, k, v, bias)
+            np.testing.assert_allclose(
+                np.asarray(out, np.float32), np.asarray(want_out, np.float32),
+                atol=atol / 10, err_msg=f"{case}: {other.__name__}")
+            for name, a, b_ in zip(("dq", "dk", "dv", "dbias"), got, want):
+                assert a.dtype == b_.dtype and a.shape == b_.shape, name
+                np.testing.assert_allclose(
+                    np.asarray(a, np.float32), np.asarray(b_, np.float32),
+                    atol=atol, err_msg=f"{case}: {other.__name__}: {name}")
+
+    def test_rows_major_operands_say_their_heads(self):
+        q = jnp.ones((1, 128, 256))
+        with pytest.raises(ValueError, match="num_heads"):
+            K.flash_attention(q, q, q)
+        with pytest.raises(ValueError, match="k and v both"):
+            K.flash_attention(q, q, num_heads=4)
+        with pytest.raises(ValueError, match="4 query heads over 3"):
+            K.flash_attention(q, q[..., :192], q[..., :192], num_heads=4)
+
     def test_bfloat16(self):
         q, k, v = self._rand(s=64, d=32)
         qb, kb, vb = (t.astype(jnp.bfloat16) for t in (q, k, v))

@@ -431,6 +431,13 @@ PER_SHARD_FORMS = {
     # heads, value heads of their own size
     "decoder": ((8, 8, 256, 64), (8, 2, 256, 64), (8, 2, 256, 32), False,
                 {"causal": True, "window": 128}),
+    # the projections' own layout, [B, S, heads D]: BERT's since PR 36, four
+    # heads of 64 as two lane tiles of two; and heads of 128 over a
+    # key/value group, causal
+    "bert_rows_major": ((8, 256, 256), (8, 256, 256), (8, 256, 256), True,
+                        {"num_heads": 4}),
+    "decoder_rows_major": ((8, 256, 512), (8, 256, 256), (8, 256, 256),
+                           False, {"causal": True, "num_heads": 4}),
 }
 
 
@@ -443,8 +450,9 @@ class TestPerShard:
         result that stays split by the batch."""
         qs, ks, vs, biased, static = PER_SHARD_FORMS[form]
         q, k, v = _f(qs, scale=0.5), _f(ks, scale=0.5), _f(vs)
-        bias = _key_bias(qs[0], qs[2]) if biased else None
-        weight = _f(qs[:3] + vs[3:])
+        positions = qs[1] if "num_heads" in static else qs[2]
+        bias = _key_bias(qs[0], positions) if biased else None
+        weight = _f(qs if "num_heads" in static else qs[:3] + vs[3:])
         mesh = _mesh(data=4)
 
         def loss(q, k, v, mesh=None):
@@ -466,6 +474,42 @@ class TestPerShard:
         assert got.sharding.is_equivalent_to(rows, got.ndim)
         _close(got, want, atol=1e-6)
         _tree_close(got_grads, want_grads, atol=1e-6)
+
+    def test_packed_operand_a_shard_at_a_time_and_the_gauge_says_so(self):
+        """BERT's own call since PR 36: x @ qkv_w as it is, one [B, S, 3 H D]
+        array, k and v None. On data=4 it runs a shard at a time like the
+        one-device call, and the gauge's body names the operand layout
+        beside the place the body runs."""
+        qkv, bias = _f((8, 128, 3 * 256), scale=0.5), _key_bias(8, 128)
+        weight = _f((8, 128, 256))
+        mesh = _mesh(data=4)
+
+        def loss(qkv, mesh=None):
+            with plk.mesh_scope(mesh):
+                out = plk.flash_attention(qkv, bias=bias, num_heads=4)
+            return jnp.sum(out * weight), out
+
+        grad = jax.value_and_grad(loss, has_aux=True)
+        rows = NamedSharding(mesh, P("data"))
+        gauge = _selection_gauge()
+        with plk.override("on"):
+            (_, want), want_grad = grad(qkv)
+            assert gauge.value(kernel="flash_attention",
+                               body="pallas_rows_major_interpret") == 1
+            (_, got), got_grad = jax.jit(lambda t: grad(t, mesh=mesh))(
+                jax.device_put(qkv, rows))
+            assert gauge.value(
+                kernel="flash_attention",
+                body="pallas_per_shard_rows_major_interpret") == 1
+            assert gauge.value(kernel="flash_attention",
+                               body="pallas_rows_major_interpret") == 0
+            # heads-major operands keep the names there were
+            plk.flash_attention(*(_f((1, 2, 128, 32)),) * 3)
+            assert gauge.value(kernel="flash_attention",
+                               body="pallas_interpret") == 1
+        assert got.sharding.is_equivalent_to(rows, got.ndim)
+        _close(got, want, atol=1e-6)
+        _close(got_grad, want_grad, atol=1e-6)
 
     #: (kernel, mesh axes, batch) -> the body `auto` selects on a chip
     SELECTIONS = {

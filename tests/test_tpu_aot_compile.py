@@ -255,6 +255,40 @@ def test_bert_step_one_device_runs_the_pallas_bodies(bert_two_layers):
         ["flash_bwd"] * 2 + ["flash_fwd"] * 2 + ["layer_norm_fwd"] * 6)
 
 
+def _relayouts_of(compiled, dims):
+    """The compiled step's copy and transpose instructions (a fusion named
+    for either among them) whose result has ``dims`` in any order."""
+    found = []
+    for line in compiled.as_text().splitlines():
+        m = re.match(r"\s+(?:ROOT )?%?([\w.\-]+) = \w+\[([\d,]+)\]\S* "
+                     r"([\w\-]+)\(", line)
+        if m and re.search(r"copy|transpose", m.group(1) + " " + m.group(3)) \
+                and sorted(map(int, m.group(2).split(","))) == sorted(dims):
+            found.append(m.group(1))
+    return found
+
+
+def test_bert_step_one_device_relayouts_no_operand_of_a_flash_call(
+        bert_two_layers):
+    """The flash kernels read q, k and v where ``x @ qkv_w`` left them and
+    write the context where ``@ out_w`` reads it (PR 36): the optimised step
+    holds no copy or transpose of an array of the heads' shape, [B, N, S, D]
+    in any order, and the calls are still two ``flash_fwd`` and two
+    ``flash_bwd`` under ``attention_core``."""
+    compiled, _, _ = bert_two_layers
+    calls = [line for line in compiled.as_text().splitlines()
+             if "tpu_custom_call" in line and "flash_" in line]
+    assert len(calls) == 4
+    assert all("/attention_core/" in line for line in calls)
+    relayouts = _relayouts_of(compiled, (64, 12, 512, 64))
+    assert not relayouts, (
+        f"{len(relayouts)} copies or transposes of a [64, 12, 512, 64] "
+        f"array around the flash calls ({relayouts[:4]}); the step before "
+        "PR 36 held 17 in two layers (14 copies, 7 a layer, and 3 "
+        "copy-done; 97 in the cell's twelve layers: q, k, v, the context "
+        "and their gradients to and from [B, N, S, D])")
+
+
 def test_bert_step_on_four_chips_runs_the_flash_kernels_a_shard_at_a_time(
         topo, capsys):
     """The cell mlm_s512_dp4: the mesh splits only the batch, so the registry
@@ -563,6 +597,39 @@ def test_kimi_linear_step_at_published_widths_fits_a_v5e(
     assert all("kda_core" in name for name in op_names.splitlines()
                if "/kda_" in name)
     assert "triangular" not in compiled.as_text().lower()
+
+
+# (b2) the flash kernels on rows-major operands ([B, S, heads D]: PR 36)
+#: name -> (q, k, v widths in heads of D; D; positions; the call's statics)
+ROWS_MAJOR_FLASH = {
+    # BERT's at 4096 positions: pairs of 64-wide heads, many tiles, packed
+    "pairs_of_64_packed_s4096": ((12, None, None), 64, 4096, {}),
+    # a head of 128 a lane tile: causal over a key/value group, and a window
+    "heads_of_128_group_causal": ((16, 4, 4), 128, 4096, {"causal": True}),
+    "heads_of_128_group_window": ((16, 4, 4), 128, 4096,
+                                  {"causal": True, "window": 512}),
+}
+
+
+@pytest.mark.parametrize("case", sorted(ROWS_MAJOR_FLASH))
+def test_flash_compiles_on_rows_major_operands(one_chip, case):
+    """Forward and backward on the projections' own layout: the lane-tile
+    index maps, the aligned lane slices and the pair's selects pass Mosaic
+    at shapes no model of the benchmark runs yet (heads of 128) and at the
+    one ``mlm_s4096`` does."""
+    (hq, hk, hv), d, s, static = ROWS_MAJOR_FLASH[case]
+    widths = (3 * hq,) if hk is None else (hq, hk, hv)
+    operands = [_abstract((2, s, w * d), BF16, one_chip) for w in widths]
+
+    def f(*operands):
+        return jax.value_and_grad(lambda *a: jnp.sum(plk.flash_attention(
+            *a, num_heads=hq, **static).astype(F32)),
+            tuple(range(len(operands))))(*operands)
+
+    compiled = _compile(f, *operands)
+    window = "_window" if "window" in static else ""
+    assert sorted(_mosaic_call_stems(compiled)) == [
+        "flash_bwd" + window, "flash_fwd" + window]
 
 
 # ---------------------------------------------------------------------------
