@@ -232,6 +232,19 @@ def phase_kernels():
                          -jax.nn.sigmoid(f32(2, 1200, 2, 128)),
                          jax.nn.sigmoid(f32(2, 1200, 2))),
                         {"chunk": 32}, (0, 1, 2, 3, 4), 3e-2),
+        # a decay a head and 4 key heads under 8 value heads, as a Gated
+        # DeltaNet mixer hands them over: the kernels gdn_fwd / gdn_bwd
+        "kda_chunked/head_decay": ((unit(2, 1200, 4, 128),
+                                    unit(2, 1200, 4, 128),
+                                    bf16(2, 1200, 8, 128),
+                                    -jax.nn.sigmoid(f32(2, 1200, 8)),
+                                    jax.nn.sigmoid(f32(2, 1200, 8))),
+                                   {"chunk": 64}, (0, 1, 2, 3, 4), 3e-2),
+        # heads of 256, two lane tiles: 16 query heads on 2 key/value heads
+        "flash_attention/d256": ((bf16(1, 16, 4 * SEQ, 256),
+                                  bf16(1, 2, 4 * SEQ, 256),
+                                  bf16(1, 2, 4 * SEQ, 256)),
+                                 {"causal": True}, (0, 1, 2), 3e-2),
     }
     missing = set(plk.list_kernels()) ^ {c.split("/")[0] for c in cases}
     if missing:

@@ -233,27 +233,8 @@ def param_specs(cfg):
 # ---------------------------------------------------------------------------
 # Named scopes as models/olmoe.py (embed, attention, attention_core, ffn,
 # layer_norm, loss, moe_router, moe_dispatch, moe_experts) plus kda_core,
-# short_conv, kda_gate, mla_expand and moe_shared: chipbench's per-layer
-# metrics key on them.
-@jax.named_scope("short_conv")
-def _short_conv(x, taps):
-    """Causal depthwise convolution over positions, then SiLU: x [B, S, C],
-    taps [K, C]; ``y_t = sum_j taps_j x_{t - K + 1 + j}``, no bias."""
-    k = taps.shape[0]
-    s = x.shape[1]
-    padded = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))
-    y = sum(taps[j] * padded[:, j:j + s].astype(jnp.float32)
-            for j in range(k))
-    return jax.nn.silu(y).astype(x.dtype)
-
-
-def _l2_normalize(x, scale=1.0):
-    x32 = x.astype(jnp.float32)
-    inv = jax.lax.rsqrt(jnp.sum(jnp.square(x32), axis=-1, keepdims=True)
-                        + 1e-6)
-    return (x32 * (inv * scale)).astype(x.dtype)
-
-
+# short_conv (blocks.short_conv enters it), kda_gate, mla_expand and
+# moe_shared: chipbench's per-layer metrics key on them.
 @jax.named_scope("attention")
 def _kda(lp, x, cfg):
     b, s, _ = x.shape
@@ -263,15 +244,15 @@ def _kda(lp, x, cfg):
     def heads(t):
         return t.reshape(b, s, n, d)
 
-    q, k, v = (heads(_short_conv(x @ lp[f"{name}_w"].astype(dt),
-                                 lp[f"{name}_conv"]))
+    q, k, v = (heads(blocks.short_conv(x @ lp[f"{name}_w"].astype(dt),
+                                       lp[f"{name}_conv"]))
                for name in "qkv")
     decay_in = (x @ lp["f_a"].astype(dt)) @ lp["f_b"].astype(dt)
     gate_in = (x @ lp["g_a"].astype(dt)) @ lp["g_b"].astype(dt)
     beta_in = x @ lp["beta_w"].astype(dt)
     with jax.named_scope("kda_gate"):
-        q = _l2_normalize(q, d ** -0.5)
-        k = _l2_normalize(k)
+        q = blocks.l2_normalize(q, d ** -0.5)
+        k = blocks.l2_normalize(k)
         g = -jnp.exp(lp["A_log"])[:, None] * heads(jax.nn.softplus(
             decay_in.astype(jnp.float32) + lp["dt_bias"]))
         beta = jax.nn.sigmoid(beta_in.astype(jnp.float32))

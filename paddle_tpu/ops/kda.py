@@ -1,12 +1,24 @@
-"""Kimi Delta Attention's core: the gated delta rule with a decay per
-channel, in its chunked form (Kimi Linear technical report, Moonshot AI
-2025, arXiv:2510.26692; the chunking after Yang et al. 2024,
-arXiv:2412.06464).
+"""The gated delta rule in its chunked form: Kimi Delta Attention's core,
+with a decay per channel (Kimi Linear technical report, Moonshot AI 2025,
+arXiv:2510.26692), and Gated DeltaNet's, with a decay per head (Yang et al.
+2024, arXiv:2412.06464, whose chunking both use).
 
 Per head, with a state ``S`` in R^{d x d} that starts at zero::
 
     S_t = (I - beta_t k_t k_t^T) Diag(exp(g_t)) S_{t-1} + beta_t k_t v_t^T
     o_t = S_t^T q_t
+
+**The operands say which rule.** ``g`` of rank 4, ``[B, S, H, d]``, is a
+decay a channel; ``g`` of rank 3, ``[B, S, H]``, one number a head and
+position, the same on every channel (``Diag(exp(g_t))`` = ``exp(g_t) I``).
+``q`` and ``k`` may have fewer heads than ``v``, ``H / n``: value head ``h``
+then reads key head ``h // n`` (Gated DeltaNet: 16 key heads under 32 value
+heads). Nothing else chooses. With a decay a head the chunk's score
+matrices are one product each, masked by ``exp(G_i - G_j)`` on ``[C, C]``
+(``_prepare_head_decay``): the exponent is a scalar a pair, formed as the
+difference, so it is never positive, and the sub-blocks, the reference rows
+and the channel-by-channel diagonal below have nothing to do there. Its
+chunk is ``CHUNK_HEAD``.
 
 ``kda_recurrent`` is that recurrence, one position a step: what the chunked
 form is held against. ``kda_chunked`` is what a training step runs. It cuts
@@ -43,9 +55,10 @@ accumulation.
 a caller sets). The **reference** body is this file's ``jax.numpy`` code:
 what the CPU and a mesh of several devices run, and what the other is held
 against. The **Pallas** body (``ops/pallas/kda.py``: the kernels ``kda_fwd``
-and ``kda_bwd``) runs where a head is a whole lane tile (``d % 128 == 0``)
-and hands any other shape back to this one. Both cut the positions into
-chunks of ``CHUNK`` and round the same operands.
+and ``kda_bwd``, and ``gdn_fwd`` and ``gdn_bwd`` for a decay a head) runs
+where a head is a whole lane tile (``d % 128 == 0``) and hands any other
+shape back to this one. Both cut the positions into chunks of ``CHUNK``
+(``CHUNK_HEAD``) and round the same operands.
 
 **What each keeps for the backward.** The reference body's backward is
 autodiff through the scan over chunks with the chunk body recomputed
@@ -88,7 +101,7 @@ from jax import lax
 
 from paddle_tpu.ops.pallas import registry as _registry
 
-__all__ = ["CHUNK", "kda_chunked", "kda_recurrent"]
+__all__ = ["CHUNK", "CHUNK_HEAD", "kda_chunked", "kda_recurrent"]
 
 #: positions a chunk, of whichever body runs. Chosen on the chip for the
 #: reference body (PERF.md section 6, PR 30: the table of 16 to 128 at 8192
@@ -102,6 +115,14 @@ CHUNK = 32
 #: positions a sub-block of the reference body's score matrices inside a
 #: chunk (same table: 8 and 32 both cost 8% more than 16 at chunk 32)
 _SUB = 16
+#: positions a chunk where the decay is one number a head (``g`` of rank 3):
+#: a chunk's scores are two products whatever its size, so what a larger
+#: chunk costs is the inverse's levels alone and what it saves is the walk
+#: over the state. Chosen on the chip for the Pallas body (PERF.md section 6,
+#: PR 38: [1, 16384, 16 | 32, 128], forward + backward 13.5 ms a layer at 64,
+#: 14.2 at 32, 13.7 at 128): a constant of the op, not an option. Read by
+#: ``chipbench/flops/gdn_core.py``, as ``CHUNK`` is by ``flops/kda_core.py``.
+CHUNK_HEAD = 64
 
 
 # ---------------------------------------------------------------------------
@@ -209,6 +230,47 @@ def _prepare(q, k, v, g, beta):
         decay=jnp.exp(g_end[..., 0, :]))
 
 
+def _prepare_head_decay(q, k, v, g, beta):
+    """``_prepare`` for a decay a head: g [N, B, H, C] as beta, q and k [N,
+    B, H / n, C, d]. A chunk's scores are one product a key head, operands
+    in ``q.dtype``, times ``exp(G_i - G_j)`` a value head."""
+    dt = q.dtype
+    c = q.shape[-2]
+    n = v.shape[2] // q.shape[2]
+    G = jnp.einsum("ij,...j->...i", jnp.tril(jnp.ones((c, c), jnp.float32)),
+                   g, precision=lax.Precision.HIGHEST)
+    lower = jnp.tril(jnp.ones((c, c), bool))
+    # the exponent is clamped at 0 so that the masked half cannot overflow
+    decay = jnp.where(lower, jnp.exp(jnp.minimum(
+        G[..., :, None] - G[..., None, :], 0.0)), 0.0)
+
+    def per_value_head(t):
+        return jnp.repeat(t, n, axis=2) if n > 1 else t
+
+    with jax.named_scope("kda_scores"):
+        products = jnp.einsum(
+            "...id,...jd->...ij", jnp.concatenate([q, k], axis=-2), k,
+            preferred_element_type=jnp.float32)             # [.., 2 C, C]
+        p_qk, p_kk = (per_value_head(p) * decay
+                      for p in jnp.split(products, 2, axis=-2))
+        a_kk = beta[..., None] * jnp.where(jnp.tril(lower, -1), p_kk, 0.0)
+        a_qk = p_qk.astype(dt)
+    q32, k32 = (per_value_head(t.astype(jnp.float32)) for t in (q, k))
+    e_in = jnp.exp(G)[..., None]
+    with jax.named_scope("kda_solve"):
+        rhs = beta[..., None] * jnp.concatenate(
+            [v.astype(jnp.float32), k32 * e_in], axis=-1)
+        solved = jax.scipy.linalg.solve_triangular(
+            a_kk + jnp.eye(c, dtype=jnp.float32), rhs, lower=True,
+            unit_diagonal=True)
+        u0, w = jnp.split(solved, [v.shape[-1]], axis=-1)
+    g_end = G[..., -1:]
+    return dict(
+        u0=u0, w=w.astype(dt), a_qk=a_qk, q_in=(q32 * e_in).astype(dt),
+        k_out=(k32 * jnp.exp(g_end - G)[..., None]).astype(dt),
+        decay=jnp.exp(g_end))
+
+
 @jax.checkpoint
 def _chunk_step(state, x):
     """One chunk given the state it starts from: (the next state, the
@@ -229,14 +291,18 @@ def _chunk_step(state, x):
 
 @functools.partial(jax.jit, static_argnums=(5,))
 def _kda_chunked(q, k, v, g, beta, chunk):
-    b, s, h, d = q.shape
+    b, s, _, d = q.shape
+    h = v.shape[2]
     pad = (-s) % chunk
     if pad:
         # a padded position neither decays (g = 0) nor writes (beta = 0)
-        q, k, v, g = (jnp.pad(t, ((0, 0), (0, pad), (0, 0), (0, 0)))
-                      for t in (q, k, v, g))
-        beta = jnp.pad(beta, ((0, 0), (0, pad), (0, 0)))
+        q, k, v, g, beta = (
+            jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
+            for t in (q, k, v, g, beta))
     n = (s + pad) // chunk
+    if g.ndim == 4 and q.shape[2] != h:
+        # a decay a channel under grouped key heads: a value head's own copy
+        q, k = (jnp.repeat(t, h // t.shape[2], axis=2) for t in (q, k))
 
     def chunks(t):
         """[B, S, H, ...] -> [N, B, H, C, ...]: one transpose, the chunks
@@ -244,9 +310,9 @@ def _kda_chunked(q, k, v, g, beta, chunk):
         t = t.reshape(b, n, chunk, *t.shape[2:])
         return t.transpose(1, 0, 3, 2, *range(4, t.ndim))
 
-    xs = _prepare(chunks(q), chunks(k), chunks(v),
-                  chunks(g.astype(jnp.float32)),
-                  chunks(beta.astype(jnp.float32)))
+    xs = (_prepare if g.ndim == 4 else _prepare_head_decay)(
+        chunks(q), chunks(k), chunks(v), chunks(g.astype(jnp.float32)),
+        chunks(beta.astype(jnp.float32)))
     state = jnp.zeros((b, h, d, v.shape[-1]), jnp.float32)
     with jax.named_scope("kda_scan"):
         _, out = lax.scan(_chunk_step, state, xs)
@@ -255,16 +321,26 @@ def _kda_chunked(q, k, v, g, beta, chunk):
 
 
 def kda_chunked(q, k, v, g, beta):
-    """The gated delta rule over q, k, v [B, S, H, d] (q and k as the rule
-    takes them: normalised, q scaled), the log decay g [B, S, H, d] (<= 0)
-    and the write strength beta [B, S, H], from a zero state. Returns
-    [B, S, H, d] in ``v.dtype``. Differentiable in all five."""
-    return _registry.dispatch("kda_chunked", q, k, v, g, beta, CHUNK)
+    """The gated delta rule over v [B, S, H, d], q and k [B, S, H / n, d]
+    (as the rule takes them: normalised, q scaled; value head h reads key
+    head ``h // n``), the log decay g (<= 0), [B, S, H, d] a channel or [B,
+    S, H] a head, and the write strength beta [B, S, H], from a zero state.
+    Returns [B, S, H, d] in ``v.dtype``. Differentiable in all five."""
+    if v.shape[2] % q.shape[2] or q.shape != k.shape:
+        raise ValueError(f"value heads {v.shape[2]} are no multiple of the "
+                         f"key heads of q {q.shape} and k {k.shape}")
+    return _registry.dispatch("kda_chunked", q, k, v, g, beta,
+                              CHUNK if g.ndim == 4 else CHUNK_HEAD)
 
 
 def kda_recurrent(q, k, v, g, beta):
     """The same, one position a step in float32: the definition."""
     f32 = jnp.float32
+    n = v.shape[2] // q.shape[2]
+    if n > 1:
+        q, k = (jnp.repeat(t, n, axis=2) for t in (q, k))
+    if g.ndim == 3:
+        g = g[..., None]
     q, k, v, g, beta = (jnp.moveaxis(t.astype(f32), 1, 0)
                         for t in (q, k, v, g, beta))
 

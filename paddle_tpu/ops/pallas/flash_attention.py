@@ -88,6 +88,10 @@ _FLASH_COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=64 << 20)
 _FLASH_BWD_COMPILER_PARAMS = dataclasses.replace(
     _FLASH_COMPILER_PARAMS,
     dimension_semantics=("parallel", "parallel", "arbitrary"))
+#: what the backward needs beside its whole-sequence operands (the key
+#: block's tiles, the statistics' rows, Mosaic's own stack: 1.1 MiB counted
+#: by the compiler at S = 16 384, d = 256), generously
+_FLASH_BWD_HEADROOM = 16 << 20
 #: the backward lays up to this many query tiles of a key block out as
 #: straight-line code, so that one tile's matmuls run under the next one's
 #: elementwise work (v5e, S=4096: 8 tiles 12.0 ms a call, 4 12.6, 1 13.8)
@@ -579,6 +583,22 @@ def _head_sums(x, heads, factors):
         preferred_element_type=jnp.float32)
 
 
+def _bwd_compiler_params(resident_bytes):
+    """The backward call's compiler parameters where its whole-sequence
+    operands (Q, dO and dQ in the operands' two-byte dtype, each in the
+    pipeline's two buffers, and dQ's float32 accumulator) take
+    ``resident_bytes`` of VMEM: the 64 MiB every call has had, and where
+    they and ``_FLASH_BWD_HEADROOM`` pass it (16 384 positions of a head of
+    256: 64 MiB resident, 65.12 counted by the compiler) that much, 80 MiB
+    there, of the 128 MiB a v5e core has. A shape that fitted keeps its
+    program."""
+    need = resident_bytes + _FLASH_BWD_HEADROOM
+    if need <= _FLASH_BWD_COMPILER_PARAMS.vmem_limit_bytes:
+        return _FLASH_BWD_COMPILER_PARAMS
+    return dataclasses.replace(_FLASH_BWD_COMPILER_PARAMS,
+                               vmem_limit_bytes=need)
+
+
 @functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 4, 5, 6))
 def _flash_attention_bwd(sm_scale, causal, block_q, block_k, interpret,
                          window, heads, res, do):
@@ -638,7 +658,8 @@ def _flash_attention_bwd(sm_scale, causal, block_q, block_k, interpret,
         scratch_shapes=[pltpu.VMEM(
             (hb // geo.per_tile, _LANES if geo.rows_major else geo.d, s),
             jnp.float32)],
-        compiler_params=_FLASH_BWD_COMPILER_PARAMS,
+        compiler_params=_bwd_compiler_params(
+            hb * s * (2 * 2 * (2 * geo.d + geo.dv) + 4 * geo.d)),
         interpret=interpret,
         name=_call_name("flash_bwd", window),
     )(q, do, lse, delta.reshape(b, h, nq, block_q), k, v, bias[:, :, None])

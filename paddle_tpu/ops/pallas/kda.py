@@ -1,5 +1,8 @@
-"""The chunked gated delta rule (``paddle_tpu/ops/kda.py``) as two Mosaic
-kernels, ``kda_fwd`` and ``kda_bwd``, tied by a ``jax.custom_vjp``.
+"""The chunked gated delta rule (``paddle_tpu/ops/kda.py``) as Mosaic kernels,
+a forward and a backward one tied by a ``jax.custom_vjp``: ``kda_fwd`` and
+``kda_bwd`` for a decay a channel, which the first paragraphs describe, and
+``gdn_fwd`` and ``gdn_bwd`` for a decay a head (below). The rank of ``g``
+chooses.
 
 **Grid and layout.** A grid step is a batch row, ``_HEADS`` heads and a
 *unit* of ``_UNIT`` = 128 positions; the units of a head are a sequential
@@ -54,6 +57,22 @@ run as fast, 6.9 | 16.7 ms a layer for 7.0 | 16.8, and cost every set-up 6 s
 of trace and lowering: the ledger's line for PR 31; PERF.md section 6,
 PR 32.) Only the running sum, whose left operand is the same triangle of ones
 for every head, is one product over the heads side by side along the lanes.
+
+**A decay a head** (``g`` of rank 3; Gated DeltaNet) has kernels of its own,
+``gdn_fwd`` and ``gdn_bwd``, on the same grid and the same walk over the
+state (``_replay``, ``_walk_back``), because its scores are other work: with
+one decay a row, ``P_ij = (a_i . b_j) exp(G_i - G_j)`` is ONE product a key
+head, ``[q; k] k^T`` with operands in the inputs' dtype, times a ``[128, 128]``
+mask of exponentials a value head. The exponent is the difference itself, so
+it is never positive and needs no levels and no reference rows; the running
+sum is a masked lane reduction of the head's lane-dense row of ``g`` (as
+beta's), exact in float32. Where ``n`` value heads share a key head, q and k
+come ``heads / n`` key heads wide (the index maps put block ``ih`` of both
+widths side by side: nothing is repeated in HBM), a value head takes its key
+head's tile in VMEM, and the backward adds a group's parts before it writes
+them. It keeps the state a unit starts from and the inverse, 537 MB a layer at
+``[1, 16384, 32, 128]``, and forms the scores again. A chunk is ``CHUNK_HEAD``
+positions: the levels of the inverse are what a larger chunk costs.
 
 **Kept for the backward**, a unit and head: the state the unit starts from,
 and three ``[128, 128]`` tiles: ``a_qk``, ``P_kk`` and the inverse. 402 MB a
@@ -149,7 +168,7 @@ class _Masks:
     """The unit's constant [128, 128] masks, from two iotas; they broadcast
     over the heads."""
 
-    def __init__(self, chunk, dk):
+    def __init__(self, chunk, dk=None):
         i = lax.broadcasted_iota(jnp.int32, (_UNIT, _UNIT), 0)
         j = lax.broadcasted_iota(jnp.int32, (_UNIT, _UNIT), 1)
         self.eye = i == j
@@ -158,11 +177,14 @@ class _Masks:
         self.level = {
             s: ((i ^ j) < 2 * s) & ((i & s) != 0) & ((j & s) == 0)
             for s in _levels(chunk)}
-        #: +1 on a level's queries, -1 on its keys, for every channel
-        row = lax.broadcasted_iota(jnp.int32, (_UNIT, dk), 0)
-        self.side = {s: jnp.where((row & s) != 0, 1.0, -1.0).astype(_F32)
-                     for s in _levels(chunk)}
-        chunk_mate = (i ^ j) < chunk
+        if dk is not None:
+            #: +1 on a level's queries, -1 on its keys, for every channel (a
+            #: decay a channel's scores alone have levels)
+            row = lax.broadcasted_iota(jnp.int32, (_UNIT, dk), 0)
+            self.side = {
+                s: jnp.where((row & s) != 0, 1.0, -1.0).astype(_F32)
+                for s in _levels(chunk)}
+        chunk_mate = self.mate = (i ^ j) < chunk
         self.strict = chunk_mate & (j < i)
         self.lower = chunk_mate & (j <= i)
         #: the running sum inside a chunk as a product
@@ -343,30 +365,18 @@ def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, st_ref,
         .astype(o_ref.dtype)
 
 
-def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, st_ref, a_qk_ref,
-                p_kk_ref, inv_ref, do_ref, dq_ref, dk_ref, dv_ref, dg_ref,
-                dbeta_ref, dstate, *, chunk):
-    """The same grid, a head's units last first: ``dstate`` is the gradient
-    of the state the unit hands on."""
-    heads = beta_ref.shape[1]
-    dv = v_ref.shape[-1] // heads
-    dt = q_ref.dtype
-    masks = _Masks(chunk, q_ref.shape[-1] // heads)
-    last_row = lax.broadcasted_iota(jnp.int32, (chunk, 1), 0) == chunk - 1
+def _walk_back(x, st_ref, do_ref, dstate, chunk):
+    """The backward kernels' walk: the states inside the unit, replayed from
+    the one it was found in (``st_ref``); then the chunks backwards: the
+    state's gradient (read from the scratch ``dstate`` and left there for
+    the unit before), and those of u, w and the three operands that meet the
+    state. Returns (the output's gradient a head, u, the gradient of ``[u0 |
+    w]``, of ``q_in``, of ``k_out``, and a chunk an entry what the chunk's
+    whole decay gets through the state, [H, 1, dk])."""
+    dt = x["w"].dtype
     starts = list(enumerate(range(0, _UNIT, chunk)))
-
-    @pl.when(pl.program_id(2) == 0)
-    def _():
-        dstate[...] = jnp.zeros_like(dstate)
-
-    x = _unit_operands((q_ref, k_ref, v_ref, g_ref, beta_ref),
-                       (a_qk_ref, p_kk_ref, inv_ref), masks, chunk)
-
-    # the states inside the unit, from the one it was found in; then the
-    # chunks backwards: the state's gradient, and those of u, w and the
-    # three operands that meet the state
     _, states, u, _ = _replay(x, st_ref[0, :, 0], chunk)
-    do = _heads(do_ref[0], heads).astype(dt)
+    do = _heads(do_ref[0], x["w"].shape[0]).astype(dt)
     du_scores = _dot(x["a_qk"], do, _TN)                     # a_qk^T do
     d_state = dstate[...]
     du, d_read, dk_out, dg_end = [], [], [], []
@@ -391,9 +401,14 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, st_ref, a_qk_ref,
         [jnp.concatenate(du, axis=1),
          jnp.concatenate([t[:, chunk:] for t in d_read], axis=1)], axis=2)
     dq_in = jnp.concatenate([t[:, :chunk] for t in d_read], axis=1)
-    dk_out = jnp.concatenate(dk_out, axis=1)
+    return do, u, d_solved, dq_in, jnp.concatenate(dk_out, axis=1), dg_end
 
-    # [u0 | w] = inv (beta * [V | K exp(G)]), inv = (I + beta * P_kk)^-1
+
+def _solve_back(x, do, u, d_solved, masks, dv):
+    """Back through ``[u0 | w] = inv (beta * [V | K exp(G)])`` with ``inv =
+    (I + beta * P_kk)^-1`` and through the output's ``a_qk u``: the gradients
+    of P_qk, P_kk and beta, of ``[V | K exp(G)]`` and its ``K exp(G)`` half."""
+    dt = do.dtype
     beta_col = x["beta_col"]
     d_rhs = _dot(x["inv"], d_solved, _TN, _HI)
     dp_qk = jnp.where(masks.lower, _dot(do, u.astype(dt), _NT), 0.0)
@@ -402,7 +417,32 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, st_ref, a_qk_ref,
         + jnp.sum(d_rhs * x["rhs"], axis=2, keepdims=True)
     d_rhs = beta_col * d_rhs
     dkg = d_rhs[:, :, dv:]
-    dp_kk = beta_col * d_a
+    return dp_qk, beta_col * d_a, d_beta, d_rhs, dkg
+
+
+def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, st_ref, a_qk_ref,
+                p_kk_ref, inv_ref, do_ref, dq_ref, dk_ref, dv_ref, dg_ref,
+                dbeta_ref, dstate, *, chunk):
+    """The same grid, a head's units last first: ``dstate`` is the gradient
+    of the state the unit hands on."""
+    heads = beta_ref.shape[1]
+    dv = v_ref.shape[-1] // heads
+    masks = _Masks(chunk, q_ref.shape[-1] // heads)
+    last_row = lax.broadcasted_iota(jnp.int32, (chunk, 1), 0) == chunk - 1
+    starts = list(enumerate(range(0, _UNIT, chunk)))
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        dstate[...] = jnp.zeros_like(dstate)
+
+    x = _unit_operands((q_ref, k_ref, v_ref, g_ref, beta_ref),
+                       (a_qk_ref, p_kk_ref, inv_ref), masks, chunk)
+
+    do, u, d_solved, dq_in, dk_out, dg_end = _walk_back(
+        x, st_ref, do_ref, dstate, chunk)
+
+    dp_qk, dp_kk, d_beta, d_rhs, dkg = _solve_back(x, do, u, d_solved, masks,
+                                                   dv)
 
     q32, k32 = x["q32"], x["k32"]
     out_term = dk_out * (k32 * x["e_out"])
@@ -437,16 +477,155 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, st_ref, a_qk_ref,
     dbeta_ref[0, :, 0] = _row(d_beta, masks.eye)
 
 
-def _specs(s, heads, dk, dv, backward):
+# ---------------------------------------------------------------------------
+# a decay a head (g of rank 3) and grouped key heads
+# ---------------------------------------------------------------------------
+def _head_decay_operands(refs, inv_ref, masks, chunk, n):
+    """``_unit_operands`` where the decay is one number a head and position
+    (``g_ref`` a lane-dense row a head, as beta's) and ``n`` value heads
+    share a key head: q and k come as the grid step's ``heads / n`` key
+    heads and a value head takes its key head's tile in VMEM. A unit's
+    scores are ONE product a key head, ``[q; k] k^T`` with operands in the
+    inputs' dtype, times ``exp(G_i - G_j)`` a value head, the exponent a
+    difference of running sums and so never positive: no levels, no
+    reference rows. ``inv_ref`` is the inverse where the forward kernel kept
+    it, else None."""
+    q_ref, k_ref, v_ref, g_ref, beta_ref = refs
+    heads = beta_ref.shape[1]
+    dt = q_ref.dtype
+    q, k = (_heads(ref[0], heads // n) for ref in (q_ref, k_ref))
+    v32 = _heads(v_ref[0], heads).astype(_F32)
+    dv = v32.shape[-1]
+
+    def per_value_head(t):
+        return t if n == 1 else jnp.stack([t[h // n] for h in range(heads)])
+
+    q32, k32 = (per_value_head(t.astype(_F32)) for t in (q, k))
+    beta_col = _col(beta_ref[0, :, 0], masks.eye)
+    g_row = g_ref[0, :, 0]                                   # [H, 1, 128]
+    # the running sum inside a chunk and the chunk's whole sum, on every row
+    G = jnp.sum(jnp.where(masks.lower, g_row, 0.0), axis=2, keepdims=True)
+    g_end = jnp.sum(jnp.where(masks.mate, g_row, 0.0), axis=2, keepdims=True)
+    decay = jnp.where(masks.lower, jnp.exp(jnp.minimum(
+        G - _row(G, masks.eye), 0.0)), 0.0)                  # [H, 128, 128]
+    operand = jnp.concatenate([q, k], axis=1)                # [H / n, 256, d]
+    raw = per_value_head(_dot(operand, k, _NT))              # [H, 256, 128]
+    p_qk = raw[:, :_UNIT] * decay
+    p_kk = jnp.where(masks.eye, 0.0, raw[:, _UNIT:] * decay)
+    inv = _inverse(beta_col * p_kk, masks, chunk) if inv_ref is None \
+        else inv_ref[0, :, 0]
+    e_in = jnp.exp(G)
+    e_out = jnp.exp(g_end - G)
+    kg = k32 * e_in
+    rhs = jnp.concatenate([v32, kg], axis=2)
+    solved = _dot(inv, beta_col * rhs, _NN, _HI)             # [u0 | w]
+    return dict(
+        k=k, operand=operand, q32=q32, k32=k32, e_in=e_in, e_out=e_out, kg=kg,
+        decay_mask=decay, p_qk=p_qk, p_kk=p_kk, a_qk=p_qk.astype(dt), rhs=rhs,
+        beta_col=beta_col, inv=inv, solved=solved, u0=solved[:, :, :dv],
+        w=solved[:, :, dv:].astype(dt), q_in=(q32 * e_in).astype(dt),
+        k_out=(k32 * e_out).astype(dt),
+        decay=[jnp.exp(G[:, c + chunk - 1:c + chunk])
+               for c in range(0, _UNIT, chunk)])
+
+
+def _head_decay_fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref,
+                           st_ref, inv_ref, state, *, chunk, n):
+    """``_fwd_kernel`` for a decay a head: it keeps the state a unit starts
+    from and the inverse; the scores are one product to form again."""
+    dt = q_ref.dtype
+    masks = _Masks(chunk)
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        state[...] = jnp.zeros_like(state)
+
+    x = _head_decay_operands((q_ref, k_ref, v_ref, g_ref, beta_ref), None,
+                             masks, chunk, n)
+    st_ref[0, :, 0] = state[...]
+    inv_ref[0, :, 0] = x["inv"]
+    state[...], _, u, read = _replay(x, state[...], chunk)
+    o_ref[0] = _wide(read + _dot(x["a_qk"], u.astype(dt), _NN)) \
+        .astype(o_ref.dtype)
+
+
+def _head_decay_bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, st_ref,
+                           inv_ref, do_ref, dq_ref, dk_ref, dv_ref, dg_ref,
+                           dbeta_ref, dstate, *, chunk, n):
+    """``_bwd_kernel`` for a decay a head: the decay's gradient is one number
+    a row (the channels' sum), the scores' gradient one product a key head on
+    the sum of its value heads' parts."""
+    heads = beta_ref.shape[1]
+    dv = v_ref.shape[-1] // heads
+    dt = q_ref.dtype
+    masks = _Masks(chunk)
+    last_row = lax.broadcasted_iota(jnp.int32, (chunk, 1), 0) == chunk - 1
+    starts = list(enumerate(range(0, _UNIT, chunk)))
+
+    def per_key_head(t):
+        """The sum of a key head's value heads' parts: [H / n, ., .]."""
+        return t if n == 1 else jnp.stack(
+            [sum((t[h + i] for i in range(1, n)), t[h])
+             for h in range(0, heads, n)])
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        dstate[...] = jnp.zeros_like(dstate)
+
+    x = _head_decay_operands((q_ref, k_ref, v_ref, g_ref, beta_ref), inv_ref,
+                             masks, chunk, n)
+    do, u, d_solved, dq_in, dk_out, dg_end = _walk_back(
+        x, st_ref, do_ref, dstate, chunk)
+
+    dp_qk, dp_kk, d_beta, d_rhs, dkg = _solve_back(x, do, u, d_solved, masks,
+                                                   dv)
+
+    q32, k32 = x["q32"], x["k32"]
+    out_term = dk_out * (k32 * x["e_out"])
+    dq = dq_in * x["e_in"]
+    dk = dkg * x["e_in"] + dk_out * x["e_out"]
+    dG = jnp.sum(dkg * x["kg"] + dq_in * (q32 * x["e_in"]) - out_term,
+                 axis=2, keepdims=True)                      # [H, 128, 1]
+    # a chunk's last row of G is also its whole decay and the origin of
+    # k_out's exponents
+    dG = dG + jnp.concatenate(
+        [jnp.where(last_row, jnp.sum(
+            dg_end[i] + jnp.sum(out_term[:, c:c + chunk], axis=1,
+                                keepdims=True), axis=2, keepdims=True), 0.0)
+         for i, c in starts], axis=1)
+    # P = raw * exp(G_i - G_j): a row's G gets its row of dP * P, a column's
+    # loses its column
+    moved = dp_qk * x["p_qk"] + dp_kk * x["p_kk"]
+    dG = dG + jnp.sum(moved, axis=2, keepdims=True) \
+        - _col(jnp.sum(moved, axis=1, keepdims=True), masks.eye)
+    d_raw = per_key_head(jnp.concatenate(
+        [dp_qk * x["decay_mask"], dp_kk * x["decay_mask"]], axis=1)) \
+        .astype(dt)                                          # [H / n, 256, 128]
+    d_query = _dot(d_raw, x["k"], _NN)                       # [H / n, 256, d]
+    d_key = _dot(d_raw, x["operand"], _TN)                   # [H / n, 128, d]
+
+    dq_ref[0] = _wide(per_key_head(dq) + d_query[:, :_UNIT]) \
+        .astype(dq_ref.dtype)
+    dk_ref[0] = _wide(per_key_head(dk) + d_query[:, _UNIT:] + d_key) \
+        .astype(dk_ref.dtype)
+    dv_ref[0] = _wide(d_rhs[:, :, :dv]).astype(dv_ref.dtype)
+    # g_j reaches every later G of its chunk
+    dg_ref[0, :, 0] = jnp.sum(jnp.where(masks.lower, dG, 0.0), axis=1,
+                              keepdims=True)
+    dbeta_ref[0, :, 0] = _row(d_beta, masks.eye)
+
+
+def _specs(s, heads, dk, dv, backward, n=1):
     """Block specs of a grid step of ``heads`` heads and one unit: (q, k, g
-    and their gradients; v, o and theirs; then a unit and head: beta's row,
-    the state, a [128, 128] tile). Backward the units come last first."""
+    and their gradients, ``heads / n`` key heads wide; v, o and theirs; then
+    a unit and head: a lane-dense row (beta's; a decay a head's), the state,
+    a [128, 128] tile). Backward the units come last first."""
     units = s // _UNIT
 
     def at(t):
         return units - 1 - t if backward else t
 
-    def stream(d):
+    def stream(d, heads=heads):
         return _vmem_spec((1, _UNIT, heads * d),
                           lambda ib, ih, t: (ib, at(t), ih))
 
@@ -454,12 +633,16 @@ def _specs(s, heads, dk, dv, backward):
         return _vmem_spec((1, heads, 1, *tile),
                           lambda ib, ih, t: (ib, ih, at(t)) + (0,) * len(tile))
 
-    return stream(dk), stream(dv), per_unit(1, _UNIT), per_unit(dv, dk), \
-        per_unit(_UNIT, _UNIT)
+    return stream(dk, heads // n), stream(dv), per_unit(1, _UNIT), \
+        per_unit(dv, dk), per_unit(_UNIT, _UNIT)
 
 
-def _heads_per_step(h):
-    return max(n for n in range(1, min(h, _HEADS) + 1) if h % n == 0)
+def _heads_per_step(h, n=1):
+    """Heads a grid step of ``h``: the most up to ``_HEADS`` that divide
+    them, in whole groups of the ``n`` value heads that share a key head;
+    None where no such number is."""
+    return max((m for m in range(1, min(h, _HEADS) + 1)
+                if h % m == 0 and m % n == 0), default=None)
 
 
 def _flat(t):
@@ -540,12 +723,90 @@ def _kda_vjp_fwd(q, k, v, g, beta_rows, chunk, interpret):
 _kda.defvjp(_kda_vjp_fwd, _kda_bwd)
 
 
+@functools.partial(jax.jit, static_argnums=(5, 6))
+def _gdn_fwd(q, k, v, g_rows, beta_rows, chunk, interpret):
+    """``_kda_fwd`` for a decay a head: q, k [B, S, H / n, dk], v [B, S, H,
+    dv], g_rows and beta_rows [B, H, S / 128, 1, 128] float32. Returns (o,
+    then a unit and head the state it starts from and the inverse)."""
+    b, s, hk, dk = q.shape
+    h, dv = v.shape[2:]
+    n = h // hk
+    heads = _heads_per_step(h, n)
+    wide, wide_v, rows, states, tiles = _specs(s, heads, dk, dv, False, n)
+    o, *kept = pl.pallas_call(
+        functools.partial(_head_decay_fwd_kernel, chunk=chunk, n=n),
+        grid=(b, h // heads, s // _UNIT),
+        in_specs=[wide, wide, wide_v, rows, rows],
+        out_specs=[wide_v, states, tiles],
+        out_shape=[
+            jax.ShapeDtypeStruct((b, s, h * dv), v.dtype),
+            jax.ShapeDtypeStruct((b, h, s // _UNIT, dv, dk), _F32),
+            jax.ShapeDtypeStruct((b, h, s // _UNIT, _UNIT, _UNIT), _F32),
+        ],
+        scratch_shapes=[pltpu.VMEM((heads, dv, dk), _F32)],
+        compiler_params=_COMPILER_PARAMS,
+        interpret=interpret,
+        name="gdn_fwd",
+    )(_flat(q), _flat(k), _flat(v), g_rows, beta_rows)
+    return o.reshape(b, s, h, dv), *kept
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def _gdn_bwd(chunk, interpret, res, do):
+    q, k, v, g_rows, beta_rows, *kept = res
+    b, s, hk, dk = q.shape
+    h, dv = v.shape[2:]
+    n = h // hk
+    heads = _heads_per_step(h, n)
+    wide, wide_v, rows, states, tiles = _specs(s, heads, dk, dv, True, n)
+    dq, dk_, dv_, dg, dbeta = pl.pallas_call(
+        functools.partial(_head_decay_bwd_kernel, chunk=chunk, n=n),
+        grid=(b, h // heads, s // _UNIT),
+        in_specs=[wide, wide, wide_v, rows, rows, states, tiles, wide_v],
+        out_specs=[wide, wide, wide_v, rows, rows],
+        out_shape=[
+            jax.ShapeDtypeStruct((b, s, hk * dk), q.dtype),
+            jax.ShapeDtypeStruct((b, s, hk * dk), k.dtype),
+            jax.ShapeDtypeStruct((b, s, h * dv), v.dtype),
+            jax.ShapeDtypeStruct(g_rows.shape, _F32),
+            jax.ShapeDtypeStruct(beta_rows.shape, _F32),
+        ],
+        scratch_shapes=[pltpu.VMEM((heads, dv, dk), _F32)],
+        compiler_params=_COMPILER_PARAMS,
+        interpret=interpret,
+        name="gdn_bwd",
+    )(_flat(q), _flat(k), _flat(v), g_rows, beta_rows, *kept, _flat(do))
+    return (dq.reshape(q.shape), dk_.reshape(k.shape), dv_.reshape(v.shape),
+            dg, dbeta)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _gdn(q, k, v, g_rows, beta_rows, chunk, interpret):
+    return _gdn_fwd(q, k, v, g_rows, beta_rows, chunk, interpret)[0]
+
+
+def _gdn_vjp_fwd(q, k, v, g_rows, beta_rows, chunk, interpret):
+    o, *kept = _gdn_fwd(q, k, v, g_rows, beta_rows, chunk, interpret)
+    return o, (q, k, v, g_rows, beta_rows, *kept)
+
+
+_gdn.defvjp(_gdn_vjp_fwd, _gdn_bwd)
+
+
 def _kda_chunked_pallas(q, k, v, g, beta, chunk, interpret=False):
-    """Pallas body: the shape rule, the padding to whole units, beta as a
-    lane-dense row a unit and head."""
-    b, s, h, dk = q.shape
+    """Pallas body: the shape rule, the padding to whole units, beta (and a
+    decay a head) as a lane-dense row a unit and head. ``g``'s rank says
+    which pair of kernels: ``kda_fwd`` / ``kda_bwd`` for a decay a channel,
+    ``gdn_fwd`` / ``gdn_bwd`` for a decay a head, which read a group's key
+    head in place; a decay a channel under grouped key heads (no model has
+    that pair) takes a value head's own copy of q and k."""
+    h = v.shape[2]
+    head_decay = g.ndim == 3
+    if not head_decay and q.shape[2] != h:
+        q, k = (jnp.repeat(t, h // t.shape[2], axis=2) for t in (q, k))
+    b, s, hk, dk = q.shape
     if dk % 128 or v.shape[-1] % 128 or _UNIT % chunk or chunk < 2 \
-            or q.dtype != k.dtype:
+            or q.dtype != k.dtype or _heads_per_step(h, h // hk) is None:
         # a head that is no whole lane tile (kimi_linear_tiny: 16)
         return _reference._kda_chunked(q, k, v, g, beta, chunk)
     pad = (-s) % _UNIT
@@ -553,11 +814,16 @@ def _kda_chunked_pallas(q, k, v, g, beta, chunk, interpret=False):
     beta = beta.astype(_F32)
     if pad:
         # a padded position neither decays (g = 0) nor writes (beta = 0)
-        q, k, v, g = (jnp.pad(t, ((0, 0), (0, pad), (0, 0), (0, 0)))
-                      for t in (q, k, v, g))
-        beta = jnp.pad(beta, ((0, 0), (0, pad), (0, 0)))
-    beta_rows = beta.transpose(0, 2, 1).reshape(b, h, -1, 1, _UNIT)
-    return _kda(q, k, v, g, beta_rows, chunk, interpret)[:, :s]
+        q, k, v, g, beta = (
+            jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
+            for t in (q, k, v, g, beta))
+
+    def rows(t):
+        return t.transpose(0, 2, 1).reshape(b, h, -1, 1, _UNIT)
+
+    if head_decay:
+        return _gdn(q, k, v, rows(g), rows(beta), chunk, interpret)[:, :s]
+    return _kda(q, k, v, g, rows(beta), chunk, interpret)[:, :s]
 
 
 _registry.register_kernel(

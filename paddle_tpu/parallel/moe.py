@@ -385,8 +385,10 @@ def dropless_moe_ffn(params, x, top_k, mesh=None, scoring=Scoring(),
     ``router_w`` [D, E], ``w_gate`` and ``w_up`` [n, D, F], ``w_down``
     [n, F, D]; no biases. Optionally ``router_bias`` [E], the selection
     bias of ``route``, and ``shared_gate`` / ``shared_up`` / ``shared_down``
-    ([D, F], [D, F], [F, D]), a gated expert every token takes with weight
-    1. Per token ``sum_e w_e * w_down_e (silu(w_gate_e x) * w_up_e x)`` over
+    ([D, F], [D, F], [F, D]), a gated expert every token takes: with weight
+    1, or, where the parameters have ``shared_scale_w`` [D], with the weight
+    ``sigmoid(x . shared_scale_w)``, a scalar a token (float32; no relation
+    of ``shared_gate``, which is the shared expert's SiLU branch). Per token ``sum_e w_e * w_down_e (silu(w_gate_e x) * w_up_e x)`` over
     its ``top_k`` experts, chosen and weighted as ``scoring`` says (default:
     the largest softmax probabilities, NOT renormalised). Router in float32,
     experts in ``x.dtype``.
@@ -447,6 +449,11 @@ def dropless_moe_ffn(params, x, top_k, mesh=None, scoring=Scoring(),
                           counts[first:first + n], top_k, mesh, tile)
     if "shared_gate" in params:
         with jax.named_scope("moe_shared"):
-            y = y + gated_ffn(xt, params["shared_gate"], params["shared_up"],
-                              params["shared_down"]).astype(jnp.float32)
+            shared = gated_ffn(xt, params["shared_gate"], params["shared_up"],
+                               params["shared_down"]).astype(jnp.float32)
+            if "shared_scale_w" in params:
+                shared = shared * jax.nn.sigmoid(jnp.dot(
+                    xt, params["shared_scale_w"].astype(xt.dtype),
+                    preferred_element_type=jnp.float32))[:, None]
+            y = y + shared
     return y.reshape(shape).astype(x.dtype), aux

@@ -91,3 +91,35 @@ def test_causal_attention_takes_a_value_head_size_of_its_own(impl):
     want = jnp.einsum("bnqk,bknd->bqnd", jax.nn.softmax(scores, axis=-1), v)
     assert got.shape == (1, 96, 2, 16)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
+
+
+def test_a_head_of_256_over_two_key_value_heads_matches_the_dense_body():
+    """Two lane tiles a head and groups of 8: 16 query heads on 2 key/value
+    heads of 256, as Qwen3-Next's attention layers have them; 640 positions
+    are five query blocks of 128, so a key/value head is read by several
+    programs. Outputs and the three gradients, the key/value gradients the
+    sum of their group's."""
+    ks = jax.random.split(jax.random.PRNGKey(3), 4)
+    q, w = (jax.random.normal(key, (1, 16, 640, 256)) for key in ks[:2])
+    k, v = (jax.random.normal(key, (1, 2, 640, 256)) for key in ks[2:])
+
+    def f(q, k, v, mode):
+        with plk.override(mode):
+            return plk.flash_attention(q, k, v, causal=True)
+
+    out = f(q, k, v, "on")
+    assert out.shape == (1, 16, 640, 256)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(f(q, k, v, "off")),
+                               atol=2e-5)
+    got, want = (jax.grad(lambda q, k, v: jnp.sum(f(q, k, v, mode) * w),
+                          (0, 1, 2))(q, k, v) for mode in ("on", "off"))
+    for name, a, b in zip("qkv", got, want):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-4,
+                                   err_msg=f"d{name}")
+    # and through the decoders' entry, [B, S, N, D] operands
+    with plk.override("on"):
+        ctx = blocks.causal_attention(*(t.transpose(0, 2, 1, 3)
+                                        for t in (q, k, v)), impl="flash")
+    np.testing.assert_allclose(np.asarray(ctx.transpose(0, 2, 1, 3)),
+                               np.asarray(out), atol=2e-5)

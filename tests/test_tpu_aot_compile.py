@@ -28,7 +28,8 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
 import paddle_tpu as pt
-from paddle_tpu.models import bert, kimi_linear, laguna, olmoe, transformer
+from paddle_tpu.models import (bert, kimi_linear, laguna, olmoe, qwen3_next,
+                               transformer)
 from paddle_tpu.ops import kda
 from paddle_tpu.ops import pallas as plk
 from paddle_tpu.ops.pallas import registry
@@ -723,6 +724,121 @@ def test_laguna_step_at_published_widths_fits_a_v5e(laguna_full_size):
     assert not any("attention_window" in name
                    for name in op_names.splitlines()
                    if "/flash_fwd/" in name or "/flash_bwd/" in name)
+
+
+# ---------------------------------------------------------------------------
+# (c4) Qwen3-Next: the delta rule with a decay a head over grouped key heads,
+# flash at a head of 256, and the step of qwen3_next_80b_a3b.lm_s16384
+# ---------------------------------------------------------------------------
+def test_a_decay_a_head_is_two_mosaic_calls_that_read_a_key_head_in_place(
+        one_chip):
+    """[1, 16384, 16 | 32, 128] with a rank-3 decay, forward and backward:
+    ``gdn_fwd`` and ``gdn_bwd`` and no other custom call. The arguments are
+    the 16 key heads of q and k as they are: no [1, 16384, 32, 128] copy of
+    them and no decay a channel exists (the only arrays of the value heads'
+    shape are v, the output, its cotangent and dv)."""
+    keys = _abstract((1, 16384, 16, 128), BF16, one_chip)
+    values = _abstract((1, 16384, 32, 128), BF16, one_chip)
+    scalar = _abstract((1, 16384, 32), F32, one_chip)
+    compiled = _compile(lambda *a: jax.value_and_grad(
+        lambda *b: jnp.sum(kda.kda_chunked(*b).astype(F32)), range(5))(*a),
+        keys, keys, values, scalar, scalar)
+    assert sorted(_mosaic_call_stems(compiled)) == ["gdn_bwd", "gdn_fwd"]
+    assert compiled.as_text().count("custom-call(") == 2
+    per_head = 16384 * 128 * 2
+    assert compiled.memory_analysis().argument_size_in_bytes \
+        == (2 * 16 + 32) * per_head + 2 * 16384 * 32 * 4
+    entry = _entry_text(compiled)
+    assert "f32[1,16384,32,128]" not in entry          # no decay a channel
+    calls = [line for line in entry.splitlines() if "tpu_custom_call" in line]
+    # each call reads q and k at their 16 heads' width
+    assert all(line.count("bf16[1,16384,2048]") >= 2 for line in calls)
+
+
+def test_flash_compiles_at_a_head_of_256_over_2_key_value_heads(one_chip):
+    """The attention of a Qwen3-Next full layer: 16 causal query heads of 256
+    over 2 key/value heads at 16 384 positions, forward and the one backward
+    call. The backward's whole-sequence operands (Q, dO, dQ twice over and
+    dQ's float32 accumulator: 64 MiB) pass the 64 MiB every other call asks,
+    and the call asks what they need (``_bwd_compiler_params``); Laguna's
+    heads of 128 keep their limit."""
+    import importlib
+    fa = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
+    q = _abstract((1, 16, 16384, 256), BF16, one_chip)
+    kv = _abstract((1, 2, 16384, 256), BF16, one_chip)
+
+    def fn(q, k, v):
+        return jax.value_and_grad(lambda *a: jnp.sum(plk.flash_attention(
+            *a, causal=True).astype(F32)), (0, 1, 2))(q, k, v)
+    compiled = _compile(fn, q, kv, kv)
+    assert sorted(_mosaic_call_stems(compiled)) == ["flash_bwd", "flash_fwd"]
+    assert compiled.memory_analysis().argument_size_in_bytes \
+        == (16 + 2 * 2) * 16384 * 256 * 2
+    entry = _entry_text(compiled)
+    calls = [line for line in entry.splitlines() if "tpu_custom_call" in line]
+    assert all(line.count("bf16[1,2,16384,256]") >= 2 for line in calls)
+    resident = 16384 * (2 * 2 * 3 * 256 + 4 * 256)
+    assert resident == 64 << 20
+    assert fa._bwd_compiler_params(resident).vmem_limit_bytes == 80 << 20
+    assert fa._bwd_compiler_params(resident // 2) \
+        is fa._FLASH_BWD_COMPILER_PARAMS                # a head of 128
+    assert fa._FLASH_BWD_COMPILER_PARAMS.vmem_limit_bytes == 64 << 20
+
+
+@pytest.fixture(scope="module")
+def qwen3_next_full_size(topo):
+    """The step of the cell qwen3_next_80b_a3b.lm_s16384: the published
+    layers 0 to 3 at the published widths, 32 of 512 experts, the
+    vocabulary's padded eighth, batch 1 x 16384."""
+    cfg = qwen3_next.qwen3_next_80b_a3b(num_layers=4, vocab_size=19072,
+                                        experts_held=(0, 32))
+    return _lower_replicated(
+        qwen3_next.make_train_step, qwen3_next.init_params, cfg,
+        qwen3_next.synthetic_batch(cfg, 1, 16384), topo)
+
+
+@pytest.mark.timeout(900)
+def test_qwen3_next_step_at_published_widths_fits_a_v5e(
+        qwen3_next_full_size):
+    """626.0 M parameters with their two Adam moments are 7.0 GiB of the
+    step's arguments; with the Gated DeltaNet mixers recomputed the whole
+    step needs less than the 15.75 GiB a v5e gives a program (PERF.md section
+    6, PR 38, has the other choices). Its Mosaic calls: the delta rule's two
+    kernels (three layers: the forward once for the pass and once where the
+    mixer is recomputed, the backward once), the causal flash kernels (one
+    layer, once each: its mixer keeps what it computed), the grouped matmuls
+    of the four expert layers' loops and the cross-entropy, each under its
+    scope."""
+    compiled, pshape, _ = qwen3_next_full_size
+    assert sum(int(np.prod(a.shape)) for a in jax.tree.leaves(pshape)) \
+        == 625_994_816
+    ma = compiled.memory_analysis()
+    assert ma.argument_size_in_bytes > 6.9 * 2**30
+    need = _need_bytes(compiled)
+    assert 0.25 * 15.75 * 2**30 < need < 15.3 * 2**30, need / 2**30  # 15.04
+    stems = _mosaic_call_stems(compiled)
+    assert set(stems) == {"gdn_fwd", "gdn_bwd", "flash_fwd", "flash_bwd",
+                          "softmax_xent_fwd", "grouped_matmul",
+                          "grouped_matmul_dw"}
+    # a call a layer: XLA inlines the jitted calls the layers share
+    assert stems.count("gdn_fwd") == 6 and stems.count("gdn_bwd") == 3
+    assert stems.count("flash_fwd") == 1 and stems.count("flash_bwd") == 1
+    calls = [line for line in compiled.as_text().splitlines()
+             if "tpu_custom_call" in line]
+    op_names = "\n".join(re.findall(r'op_name="([^"]*)"', "\n".join(calls)))
+    for scope, kernel in (
+            ("gdn_core", "gdn_fwd"), ("gdn_core", "gdn_bwd"),
+            ("attention_core", "flash_fwd"), ("attention_core", "flash_bwd"),
+            ("moe_experts", "grouped_matmul"),
+            ("moe_experts", "grouped_matmul_dw"),
+            ("loss", "softmax_xent_fwd")):
+        assert re.search(rf"{scope}[^\n]*/{kernel}/pallas_call", op_names), \
+            (scope, kernel)
+    # the 16 key heads are read in place: no array of q's or k's 32-head
+    # width, and no decay a channel, exists in the program
+    entry = _entry_text(compiled)
+    assert "f32[1,16384,32,128]{3,2,1,0" not in "".join(
+        line for line in entry.splitlines() if "broadcast" in line)
 
 
 # ---------------------------------------------------------------------------
