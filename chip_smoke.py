@@ -245,6 +245,21 @@ def phase_kernels():
                                   bf16(1, 2, 4 * SEQ, 256),
                                   bf16(1, 2, 4 * SEQ, 256)),
                                  {"causal": True}, (0, 1, 2), 3e-2),
+        # Gated DeltaNet's [q | k | v] in one array, 4 key heads under 8
+        # value heads of 128, 4 taps, 1200 positions (three blocks with
+        # padding): q and k normed a head, v not; x, then the taps
+        "short_conv_norm": ((bf16(2, 1200, 2048), f32(4, 2048, scale=0.3)),
+                            {"head_dim": 128,
+                             "parts": ((512, 128 ** -0.5), (512, 1.0),
+                                       (1024, None))}, (0, 1), 2e-2),
+        # the rule's output, its gate and the gain a channel of the head
+        "gated_head_norm": ((bf16(2, 1200, 1024), bf16(2, 1200, 1024),
+                             f32(128) + 1.0),
+                            {"eps": 1e-6, "act": "silu"}, (0, 1, 2), 2e-2),
+        "gated_head_norm/sigmoid": ((bf16(2, 1200, 1024),
+                                     bf16(2, 1200, 1024), f32(128) + 1.0),
+                                    {"eps": 1e-5, "act": "sigmoid"},
+                                    (0, 1, 2), 2e-2),
     }
     missing = set(plk.list_kernels()) ^ {c.split("/")[0] for c in cases}
     if missing:

@@ -1,6 +1,7 @@
 """Pieces of a decoder block that more than one model can use: RMSNorm,
-rotary positions, causal attention, the gated feed-forward, and a delta-rule
-mixer's short convolution and L2 norm.
+rotary positions, causal attention and the gated feed-forward. (A delta-rule
+mixer's short convolution and L2 norm are ``ops/pallas/delta_glue.py``'s
+since the two became one kernel.)
 
 ``models/olmoe.py``, ``models/kimi_linear.py``, ``models/laguna.py`` and
 ``models/qwen3_next.py`` are built from them.
@@ -8,7 +9,7 @@ mixer's short convolution and L2 norm.
 ``models/transformer.py`` carry their own layer norm and attention and are
 not moved here yet (ROADMAP C10: their cells repeat to 0.004%, so a change
 to their HLO is a PR judged on its own). Every piece enters the named scope
-a profile of the step is read by (``layer_norm``, ``rope``, ``short_conv``,
+a profile of the step is read by (``layer_norm``, ``rope``,
 ``attention_core`` and, inside it, ``attention_window`` where a call has a
 window; the callers enter ``attention`` and ``ffn``).
 """
@@ -22,9 +23,8 @@ from jax import lax
 
 from paddle_tpu.ops.pallas.registry import mesh_scope, selected_body
 
-__all__ = ["rms_norm", "rms_normalize", "l2_normalize", "short_conv",
-           "yarn_inv_freq", "rope_angles", "apply_rope", "attention_body",
-           "causal_attention", "gated_ffn"]
+__all__ = ["rms_norm", "rms_normalize", "yarn_inv_freq", "rope_angles",
+           "apply_rope", "attention_body", "causal_attention", "gated_ffn"]
 
 #: from this many positions on, ``auto`` takes the flash kernels where the
 #: Pallas body runs (one chip). Measured on a v5e at equal tokens a step
@@ -76,27 +76,6 @@ def rms_normalize(x, gain, eps=1e-5):
 def rms_norm(x, gain, eps=1e-5):
     """``rms_normalize`` under the scope ``layer_norm``: a block's norms."""
     return rms_normalize(x, gain, eps)
-
-
-def l2_normalize(x, scale=1.0):
-    """``x / sqrt(sum(x^2) + 1e-6) * scale`` over the last axis (a head of
-    a delta-rule mixer's queries or keys): float32 inside, ``x.dtype`` out,
-    under the caller's scope."""
-    x32 = x.astype(jnp.float32)
-    inv = lax.rsqrt(jnp.sum(jnp.square(x32), axis=-1, keepdims=True) + 1e-6)
-    return (x32 * (inv * scale)).astype(x.dtype)
-
-
-@jax.named_scope("short_conv")
-def short_conv(x, taps):
-    """Causal depthwise convolution over positions, then SiLU: x [B, S, C],
-    taps [K, C]; ``y_t = sum_j taps_j x_{t - K + 1 + j}``, no bias."""
-    k = taps.shape[0]
-    s = x.shape[1]
-    padded = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))
-    y = sum(taps[j] * padded[:, j:j + s].astype(jnp.float32)
-            for j in range(k))
-    return jax.nn.silu(y).astype(x.dtype)
 
 
 def yarn_inv_freq(rot, theta, factor, original_positions, beta_fast,

@@ -11,11 +11,16 @@ feed-forward, the others the experts.
 
 - **KDA** (Kimi Delta Attention): q, k and v through a causal depthwise
   convolution of ``conv_size`` taps and SiLU; q and k L2-normalised a head,
-  q scaled by 1 / sqrt(d); a log decay per channel ``-exp(A_log) *
+  q scaled by 1 / sqrt(d) (the three in one pass a projection,
+  ``ops.pallas.short_conv_norm``); a log decay per channel ``-exp(A_log) *
   softplus(low-rank(x) + dt_bias)`` and a write strength per head
   ``sigmoid(x w_beta)``; the gated delta rule (``ops/kda.py``, chunked);
-  an RMSNorm a head gated by ``sigmoid(low-rank(x))``; the output
-  projection.
+  an RMSNorm a head gated by ``sigmoid(low-rank(x))``
+  (``ops.pallas.gated_head_norm``); the output projection. From the
+  projections to the rule and from the rule to ``o_w`` the activations
+  stay ``[B, S, H d]``, the projections' own layout, a head a lane tile:
+  the ``[B, S, H, d]`` the rule's entry point takes is a view that the
+  compiler folds away (PERF.md section 6, PR 39).
 - **MLA without positions**: queries of ``qk_nope + qk_rope`` channels a
   head; keys and values expanded per head from an RMS-normalised latent of
   ``kv_lora_rank``, with ``qk_rope`` more key channels shared by the heads
@@ -66,6 +71,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from paddle_tpu.models import blocks, lm_trainer
 from paddle_tpu.ops import kda
+from paddle_tpu.ops.pallas import gated_head_norm, short_conv_norm
 from paddle_tpu.ops.pallas.registry import mesh_scope
 from paddle_tpu.parallel import moe
 from paddle_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
@@ -233,10 +239,10 @@ def param_specs(cfg):
 # ---------------------------------------------------------------------------
 # Named scopes as models/olmoe.py (embed, attention, attention_core, ffn,
 # layer_norm, loss, moe_router, moe_dispatch, moe_experts) plus kda_core,
-# short_conv (blocks.short_conv enters it), kda_gate, mla_expand and
-# moe_shared: chipbench's per-layer metrics key on them.
+# short_conv, kda_gate, mla_expand and moe_shared: chipbench's per-layer
+# metrics key on them.
 @jax.named_scope("attention")
-def _kda(lp, x, cfg):
+def _kda(lp, x, cfg, mesh=None):
     b, s, _ = x.shape
     dt = x.dtype
     n, d = cfg.kda_heads, cfg.kda_head_dim
@@ -244,24 +250,30 @@ def _kda(lp, x, cfg):
     def heads(t):
         return t.reshape(b, s, n, d)
 
-    q, k, v = (heads(blocks.short_conv(x @ lp[f"{name}_w"].astype(dt),
-                                       lp[f"{name}_conv"]))
-               for name in "qkv")
-    decay_in = (x @ lp["f_a"].astype(dt)) @ lp["f_b"].astype(dt)
-    gate_in = (x @ lp["g_a"].astype(dt)) @ lp["g_b"].astype(dt)
-    beta_in = x @ lp["beta_w"].astype(dt)
-    with jax.named_scope("kda_gate"):
-        q = blocks.l2_normalize(q, d ** -0.5)
-        k = blocks.l2_normalize(k)
-        g = -jnp.exp(lp["A_log"])[:, None] * heads(jax.nn.softplus(
-            decay_in.astype(jnp.float32) + lp["dt_bias"]))
-        beta = jax.nn.sigmoid(beta_in.astype(jnp.float32))
-    with jax.named_scope("kda_core"):
-        o = kda.kda_chunked(q, k, v, g, beta)
-    with jax.named_scope("kda_gate"):
-        o = blocks.rms_normalize(o, lp["o_norm_g"], cfg.rms_eps) \
-            * jax.nn.sigmoid(heads(gate_in).astype(jnp.float32)).astype(dt)
-    return o.reshape(b, s, -1) @ lp["o_w"].astype(dt)
+    def conv(name, scale):
+        projected = x @ lp[f"{name}_w"].astype(dt)
+        with jax.named_scope("short_conv"):
+            return short_conv_norm(projected, lp[f"{name}_conv"], d,
+                                   ((n * d, scale),))[0]
+
+    with mesh_scope(mesh):
+        # rows-major from the projections to the rule and from the rule to
+        # o_w: the convolution, SiLU and the norm a head are one pass
+        q, k, v = (conv(name, scale) for name, scale in
+                   (("q", d ** -0.5), ("k", 1.0), ("v", None)))
+        decay_in = (x @ lp["f_a"].astype(dt)) @ lp["f_b"].astype(dt)
+        gate_in = (x @ lp["g_a"].astype(dt)) @ lp["g_b"].astype(dt)
+        beta_in = x @ lp["beta_w"].astype(dt)
+        with jax.named_scope("kda_gate"):
+            g = -jnp.exp(lp["A_log"])[:, None] * heads(jax.nn.softplus(
+                decay_in.astype(jnp.float32) + lp["dt_bias"]))
+            beta = jax.nn.sigmoid(beta_in.astype(jnp.float32))
+        with jax.named_scope("kda_core"):
+            o = kda.kda_chunked(heads(q), heads(k), heads(v), g, beta)
+        with jax.named_scope("kda_gate"):
+            o = gated_head_norm(o.reshape(b, s, -1), gate_in, lp["o_norm_g"],
+                                cfg.rms_eps, "sigmoid")
+    return o @ lp["o_w"].astype(dt)
 
 
 @jax.named_scope("attention")
@@ -301,7 +313,7 @@ def _block(lp, x, cfg, kind, mesh=None):
     else is."""
     def mix(lp, x):
         normed = blocks.rms_norm(x, lp["ln1_g"], cfg.rms_eps)
-        return x + (_kda(lp, normed, cfg) if kind == "kda"
+        return x + (_kda(lp, normed, cfg, mesh) if kind == "kda"
                     else _mla(lp, normed, cfg, mesh))
 
     h = (jax.checkpoint(mix) if kind == "kda" else mix)(lp, x)

@@ -10,16 +10,20 @@ called, ``blocks.rms_norm(x, 1 + w)``). Layer ``l`` has full attention where
 ``(l + 1) % full_attention_interval == 0``, else Gated DeltaNet.
 
 - **Gated DeltaNet** (Yang et al. 2024, arXiv:2412.06464): ``[q | k | v |
-  z] = x W_qkvz`` and ``[b | a] = x W_ba``; q, k and v through one causal
-  depthwise convolution of ``conv_size`` taps and SiLU
-  (``blocks.short_conv``); ``linear_key_heads`` heads of q and k under
-  ``linear_value_heads`` of v, value head h on key head ``h // n``; q and k
-  L2-normalised a head, q scaled by 1 / sqrt(d); the write strength
-  ``sigmoid(b)`` and the log decay ``-exp(A_log) * softplus(a + dt_bias)``,
-  **one number a value head and position**, in float32; the gated delta
-  rule (``ops/kda.kda_chunked``: the decay's rank 3 and the head counts are
-  all it is told); an RMSNorm a head (plain gain) times ``silu(z)``; the
-  output projection.
+  z] = x W_qkvz`` (two products, ``[q | k | v]`` and ``z``: the weight's
+  columns are cut, never the activations') and ``[b | a] = x W_ba``; q, k
+  and v through one causal depthwise convolution of ``conv_size`` taps and
+  SiLU; ``linear_key_heads`` heads of q and k under ``linear_value_heads``
+  of v, value head h on key head ``h // n``; q and k L2-normalised a head,
+  q scaled by 1 / sqrt(d) (convolution, SiLU and norm in one pass over
+  column ranges of the one array, ``ops.pallas.short_conv_norm``); the
+  write strength ``sigmoid(b)`` and the log decay ``-exp(A_log) *
+  softplus(a + dt_bias)``, **one number a value head and position**, in
+  float32; the gated delta rule (``ops/kda.kda_chunked``: the decay's rank
+  3 and the head counts are all it is told); an RMSNorm a head (plain
+  gain) times ``silu(z)`` (``ops.pallas.gated_head_norm``); the output
+  projection. Between the projections nothing is viewed a head at a time
+  in HBM: the arrays stay ``[B, S, H d]``, a head a lane tile.
 - **gated attention**: ``[q | gate] = x W_q``, a head's ``head_dim`` query
   channels then its ``head_dim`` gate channels; ``kv_heads`` key/value
   heads under ``num_heads`` query heads; q and k normed a head (``1 + w``);
@@ -45,11 +49,11 @@ called, ``blocks.rms_norm(x, 1 + w)``). Layer ``l`` has full attention where
 the backward pass keeps its input and forms the projections, the
 convolution, the gates and the delta rule again. It is the least that lets
 one sequence of 16 384 positions fit a v5e beside 7.0 GiB of parameters and
-Adam state: 15.04 GiB of the 15.75 a program may take by the compiler's
-account, where the compiler refuses the program with nothing recomputed, or
-the attention mixer alone, at 18.2 GiB and more; with the attention mixer
-recomputed as well it is 14.75 GiB and a step 5% longer (PERF.md section 6,
-PR 38). The attention mixer keeps what it computed; the experts' rows are
+Adam state: 14.09 GiB of the 15.75 a program may take by the compiler's
+account (15.04 before the passes around the rule were kernels, PR 39), where the compiler refuses the program with nothing recomputed, or
+the attention mixer alone, at 18.2 GiB and more (PR 38's tree); with the
+attention mixer recomputed as well it was 14.75 GiB there and a step 5%
+longer (PERF.md section 6, PR 38). The attention mixer keeps what it computed; the experts' rows are
 formed again by ``moe.dropless_moe_ffn`` itself; the router and the shared
 expert keep what they computed. No option chooses any of it.
 
@@ -69,6 +73,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from paddle_tpu.models import blocks, lm_trainer
 from paddle_tpu.ops import kda
+from paddle_tpu.ops.pallas import gated_head_norm, short_conv_norm
 from paddle_tpu.ops.pallas.registry import mesh_scope
 from paddle_tpu.parallel import moe
 from paddle_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
@@ -228,31 +233,36 @@ def param_specs(cfg):
 # loss, moe_router, moe_dispatch, moe_experts, moe_shared) plus gdn_core and
 # gdn_gate: chipbench's per-layer metrics key on them.
 @jax.named_scope("attention")
-def _gated_delta_net(lp, x, cfg):
+def _gated_delta_net(lp, x, cfg, mesh=None):
     b, s, _ = x.shape
     dt = x.dtype
     nk, nv = cfg.linear_key_heads, cfg.linear_value_heads
     dk, dv = cfg.linear_key_dim, cfg.linear_value_dim
     kw, vw = nk * dk, nv * dv
-    qkv, z = jnp.split(x @ lp["qkvz_w"].astype(dt), [2 * kw + vw], axis=-1)
+    # the weight's columns are cut, not the product's: [q | k | v] goes into
+    # the convolution as it is and z into the gate, rows-major both
+    qkv_w, z_w = jnp.split(lp["qkvz_w"].astype(dt), [2 * kw + vw], axis=-1)
     ba = jnp.dot(x, lp["ba_w"].astype(dt),
                  preferred_element_type=jnp.float32)           # [B, S, 2 nv]
-    q, k, v = jnp.split(blocks.short_conv(qkv, lp["conv"]), [kw, 2 * kw],
-                        axis=-1)
-    with jax.named_scope("gdn_gate"):
-        q = blocks.l2_normalize(q.reshape(b, s, nk, dk), dk ** -0.5)
-        k = blocks.l2_normalize(k.reshape(b, s, nk, dk))
-        beta = jax.nn.sigmoid(ba[..., :nv])
-        g = -jnp.exp(lp["A_log"]) * jax.nn.softplus(ba[..., nv:]
-                                                    + lp["dt_bias"])
-    with jax.named_scope("gdn_core"):
-        o = kda.kda_chunked(q, k, v.reshape(b, s, nv, dv), g, beta)
-    with jax.named_scope("gdn_gate"):
-        o = (blocks.rms_normalize(o.astype(jnp.float32), lp["o_norm_g"],
-                                  cfg.rms_eps)
-             * jax.nn.silu(z.reshape(b, s, nv, dv).astype(jnp.float32))) \
-            .astype(dt)
-    return o.reshape(b, s, -1) @ lp["out_w"].astype(dt)
+    z = x @ z_w
+    qkv = x @ qkv_w
+    with mesh_scope(mesh):
+        with jax.named_scope("short_conv"):
+            q, k, v = short_conv_norm(
+                qkv, lp["conv"], dk,
+                ((kw, dk ** -0.5), (kw, 1.0), (vw, None)))
+        with jax.named_scope("gdn_gate"):
+            beta = jax.nn.sigmoid(ba[..., :nv])
+            g = -jnp.exp(lp["A_log"]) * jax.nn.softplus(ba[..., nv:]
+                                                        + lp["dt_bias"])
+        with jax.named_scope("gdn_core"):
+            o = kda.kda_chunked(q.reshape(b, s, nk, dk),
+                                k.reshape(b, s, nk, dk),
+                                v.reshape(b, s, nv, dv), g, beta)
+        with jax.named_scope("gdn_gate"):
+            o = gated_head_norm(o.reshape(b, s, -1), z, lp["o_norm_g"],
+                                cfg.rms_eps, "silu")
+    return o @ lp["out_w"].astype(dt)
 
 
 @jax.named_scope("attention")
@@ -282,7 +292,8 @@ def _block(lp, x, cfg, kind, angles, mesh=None):
     def mix(lp, x):
         normed = blocks.rms_norm(x, 1.0 + lp["ln1_w"], cfg.rms_eps)
         return x + (_gated_attention(lp, normed, cfg, angles, mesh)
-                    if kind == FULL else _gated_delta_net(lp, normed, cfg))
+                    if kind == FULL
+                    else _gated_delta_net(lp, normed, cfg, mesh))
 
     h = (jax.checkpoint(mix) if kind == LINEAR else mix)(lp, x)
     with jax.named_scope("ffn"):

@@ -5,9 +5,10 @@ Importing this package registers every built-in kernel, one module each:
 fused_matmul / fused_matmul_int8 (matmul.py), embedding_scatter_add
 (embedding.py), grouped_matmul (grouped_matmul.py), flash_attention
 (flash_attention.py), fused_layer_norm (layer_norm.py),
-softmax_cross_entropy (softmax_xent.py) and kda_chunked (kda.py; its entry
+softmax_cross_entropy (softmax_xent.py), kda_chunked (kda.py; its entry
 point is ``paddle_tpu.ops.kda.kda_chunked``, beside the reference body and
-the recurrence it stands for). The other entry points the models call
+the recurrence it stands for) and the two passes around it, short_conv_norm
+and gated_head_norm (delta_glue.py). The other entry points the models call
 are names of this package; ``flash_attention`` and ``grouped_matmul`` here
 are therefore the functions, not the modules of the same name (import a
 module's own names with ``from paddle_tpu.ops.pallas.<module> import ...``)."""
@@ -17,6 +18,7 @@ from paddle_tpu.ops.pallas.registry import (  # noqa: F401
     dispatch, get_body, selected_body, use_pallas, selection_mode,
     override, mesh_scope, platform, within_vmem_budget,
 )
+from paddle_tpu.ops.pallas.delta_glue import gated_head_norm, short_conv_norm
 from paddle_tpu.ops.pallas import embedding as _embedding  # noqa: F401
 from paddle_tpu.ops.pallas.flash_attention import flash_attention
 from paddle_tpu.ops.pallas.grouped_matmul import grouped_matmul
@@ -31,5 +33,5 @@ __all__ = [
     "override", "mesh_scope", "platform", "try_fused_matmul",
     "within_vmem_budget", "DEFAULT_VMEM_BUDGET",
     "flash_attention", "fused_layer_norm", "softmax_cross_entropy",
-    "grouped_matmul",
+    "grouped_matmul", "short_conv_norm", "gated_head_norm",
 ]

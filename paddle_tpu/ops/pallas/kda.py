@@ -10,8 +10,13 @@ axis and its state (kept transposed, ``[dv, dk]``, float32) lives in a VMEM
 scratch across them. ``[B, S, H, d]`` is read as ``[B, S, H * d]`` with a
 block ``(1, 128, heads * d)``, a head a lane tile, so the kernels read q, k,
 v, g once and nothing is transposed around them. (The reshape is a bitcast for
-float32 and a relayout for bfloat16, whose tiles pair rows: 0.3 ms an array
-at [1, 8192, 32, 128]; PERF.md section 7.) The ``128 / chunk`` chunks of a
+float32 and, alone, a relayout for bfloat16, whose tiles pair rows: 0.3 ms an
+array at [1, 8192, 32, 128]. Since PR 39 the mixers hand over what
+``delta_glue.py``'s kernels wrote, ``[B, S, H * d]`` viewed as ``[B, S, H,
+d]``, and take the result the same way: the compiler folds each pair of
+reshapes to nothing, and the compiled steps hold no copy or reshape of q,
+k, v, o or their gradients; ``tests/test_tpu_aot_compile.py`` holds that.)
+The ``128 / chunk`` chunks of a
 unit share every matmul, their score matrices and inverses being the
 diagonal blocks of one ``[128, 128]`` tile.
 
@@ -712,11 +717,13 @@ def _kda_bwd(chunk, interpret, res, do):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
 def _kda(q, k, v, g, beta_rows, chunk, interpret):
-    return _kda_fwd(q, k, v, g, beta_rows, chunk, interpret)[0]
+    return _kda_vjp_fwd(q, k, v, g, beta_rows, chunk, interpret)[0]
 
 
 def _kda_vjp_fwd(q, k, v, g, beta_rows, chunk, interpret):
-    o, *kept = _kda_fwd(q, k, v, g, beta_rows, chunk, interpret)
+    # one trace for the pass and for the recomputed mixer's JVP
+    o, *kept = _registry.traced_once(_kda_fwd, q, k, v, g, beta_rows, chunk,
+                                     interpret)
     return o, (q, k, v, g, beta_rows, *kept)
 
 
@@ -782,11 +789,12 @@ def _gdn_bwd(chunk, interpret, res, do):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
 def _gdn(q, k, v, g_rows, beta_rows, chunk, interpret):
-    return _gdn_fwd(q, k, v, g_rows, beta_rows, chunk, interpret)[0]
+    return _gdn_vjp_fwd(q, k, v, g_rows, beta_rows, chunk, interpret)[0]
 
 
 def _gdn_vjp_fwd(q, k, v, g_rows, beta_rows, chunk, interpret):
-    o, *kept = _gdn_fwd(q, k, v, g_rows, beta_rows, chunk, interpret)
+    o, *kept = _registry.traced_once(_gdn_fwd, q, k, v, g_rows, beta_rows,
+                                     chunk, interpret)
     return o, (q, k, v, g_rows, beta_rows, *kept)
 
 

@@ -17,7 +17,9 @@ trace/compile time, from what the code observes:
   shard at a time inside ``jax.shard_map`` over that axis, under the name
   ``pallas_per_shard``. ``flash_attention`` declares it, and on ``data=4``
   at 512 positions the BERT-base step is 14% shorter for it (PERF.md
-  section 6, PR 34).
+  section 6, PR 34); the delta-rule mixers' two passes
+  (``delta_glue.py``) declare it too, their taps and gain ``whole``:
+  arrays every shard reads whole.
 
 Nothing a user sets takes part: no flag, no environment variable. A body
 that loses on the chip is deleted, not switched off. Tests and A/B
@@ -50,7 +52,7 @@ __all__ = [
     "register_kernel", "get_kernel", "list_kernels", "dispatch",
     "get_body", "selected_body", "use_pallas", "selection_mode",
     "override", "mesh_scope", "platform", "within_vmem_budget",
-    "vmem_spec", "DEFAULT_VMEM_BUDGET",
+    "vmem_spec", "traced_once", "DEFAULT_VMEM_BUDGET",
 ]
 
 #: fp32 elements one operand may hold whole in VMEM: 8 MiB, half of
@@ -76,21 +78,23 @@ class Kernel:
     leading dimension is the batch, the first of them never ``None``; the
     result leads with the batch too, and no row of it reads another row's
     operands. A kernel that says so can run a shard of the batch at a
-    time. ``layout``, of a kernel whose Pallas body has blocks for more
-    than one layout of its operands, takes a call's arguments and names the
-    layout they run in ("" for the first there was): the gauge's body name
-    carries it, and nothing else reads it."""
+    time; ``whole`` names its array parameters without a batch (weights),
+    which every shard reads whole. ``layout``, of a kernel whose Pallas body
+    has blocks for more than one layout of its operands, takes a call's
+    arguments and names the layout they run in ("" for the first there
+    was): the gauge's body name carries it, and nothing else reads it."""
 
     __slots__ = ("name", "reference", "pallas", "doc", "batch_leading",
-                 "layout")
+                 "whole", "layout")
 
     def __init__(self, name, reference, pallas=None, doc="",
-                 batch_leading=(), layout=None):
+                 batch_leading=(), whole=(), layout=None):
         self.name = name
         self.reference = reference
         self.pallas = pallas
         self.doc = doc
         self.batch_leading = tuple(batch_leading)
+        self.whole = tuple(whole)
         self.layout = layout
 
     def __repr__(self):
@@ -99,10 +103,10 @@ class Kernel:
 
 
 def register_kernel(name, reference, pallas=None, doc="",
-                    batch_leading=(), layout=None):
+                    batch_leading=(), whole=(), layout=None):
     """Register (or re-register) a kernel. Mirrors ``register_op``:
     last registration wins, so tests can shadow a body."""
-    k = Kernel(name, reference, pallas, doc, batch_leading, layout)
+    k = Kernel(name, reference, pallas, doc, batch_leading, whole, layout)
     with _lock:
         _REGISTRY[name] = k
     return k
@@ -262,6 +266,27 @@ def vmem_spec(*args, **kwargs):
     return pl.BlockSpec(*args, **kwargs)
 
 
+_NO_MESH = jax.sharding.AbstractMesh((), ())
+
+
+def traced_once(jitted, *args):
+    """``jitted(*args)`` under one trace context wherever no mesh is in
+    scope: for a kernel module's jitted call of its ``pallas_call``. jax keys
+    a jitted function's trace on the context it is called in, and the JVP of
+    a ``jax.checkpoint`` (the delta-rule mixers are under one) sets an empty
+    abstract mesh where the pass itself has none, so a forward kernel was
+    traced and lowered to Mosaic once for each: 0.2 s a kernel on the chip's
+    host, in every set-up (PERF.md section 6, PR 39). Inside a ``shard_map``
+    the mesh in scope stays the key, as it has to. Both facts are jax
+    0.9.0's and no part of its interface: the counts of kernel bodies
+    entered in ``tests/test_kimi_linear.py`` and ``tests/test_qwen3_next.py``
+    are what says so when jax moves."""
+    if not jax.sharding.get_abstract_mesh().empty:
+        return jitted(*args)
+    with jax.sharding.use_abstract_mesh(_NO_MESH):
+        return jitted(*args)
+
+
 def within_vmem_budget(kernel, elements, budget=None):
     """True when a kernel body planning to hold ``elements`` fp32
     elements whole in VMEM fits under ``budget`` (default
@@ -296,9 +321,10 @@ def get_body(name, which):
 
 
 @functools.lru_cache(maxsize=64)
-def _per_shard_call(kernel, mesh, arrays, static):
+def _per_shard_call(kernel, mesh, arrays, whole, static):
     """``kernel``'s Pallas body on the operands named ``arrays``, a shard
-    of the batch at a time over ``mesh``'s data axis; ``static`` holds its
+    of the batch at a time over ``mesh``'s data axis, and on those named
+    ``whole``, which every shard reads as they are; ``static`` holds its
     other arguments. A jitted function of its own, and one a (kernel, mesh,
     arguments): a model's layers call it with the same shapes and share one
     trace of the ``shard_map`` and of what it holds, as they share the
@@ -307,13 +333,16 @@ def _per_shard_call(kernel, mesh, arrays, static):
     rows = PartitionSpec(DATA_AXIS)
 
     def shard(*operands):
-        return kernel.pallas(**dict(zip(arrays, operands)), **dict(static))
+        return kernel.pallas(**dict(zip(arrays + whole, operands)),
+                             **dict(static))
 
     shard.__name__ = kernel.name + "_per_shard"
     # check_vma off: a pallas_call says nothing of how its results vary
     # over the mesh; every operand and result here varies over the data axis
-    return jax.jit(jax.shard_map(shard, mesh=mesh, in_specs=rows,
-                                 out_specs=rows, check_vma=False))
+    return jax.jit(jax.shard_map(
+        shard, mesh=mesh,
+        in_specs=(rows,) * len(arrays) + (PartitionSpec(),) * len(whole),
+        out_specs=rows, check_vma=False))
 
 
 def _dispatch_per_shard(kernel, interpret, args, kwargs):
@@ -321,9 +350,10 @@ def _dispatch_per_shard(kernel, interpret, args, kwargs):
         *args, interpret=interpret, **kwargs).arguments
     arrays = {n: bound.pop(n) for n in kernel.batch_leading
               if bound.get(n) is not None}
+    whole = {n: bound.pop(n) for n in kernel.whole}
     call = _per_shard_call(kernel, _partitioning_mesh(), tuple(arrays),
-                           tuple(sorted(bound.items())))
-    return call(*arrays.values())
+                           tuple(whole), tuple(sorted(bound.items())))
+    return call(*arrays.values(), *whole.values())
 
 
 def dispatch(name, *args, **kwargs):

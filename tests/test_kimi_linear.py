@@ -404,14 +404,17 @@ def test_a_step_traces_a_kernel_body_once_however_many_layers_call_it(
         kda_layers, monkeypatch):
     """Every Pallas call is a jitted function of its own that the layers
     share, so tracing a step enters a kernel's body once for each context jax
-    traces in (the pass; and, for the delta rule's forward, the JVP of the
-    mixer's ``jax.checkpoint``, which jax traces under a mesh context of its
-    own), never once a layer: a body traced anew a layer costs every set-up
+    traces in (the pass; and the JVP of the mixer's ``jax.checkpoint``, which
+    jax traces under a mesh context of its own unless the call goes through
+    ``registry.traced_once``, as the delta-rule mixer's do: jax 0.9.0's
+    behaviour, which this count guards when jax moves), never once a
+    layer: a body traced anew a layer costs every set-up
     its trace and its lowering to Mosaic a layer (PERF.md section 6, PR 29
     and PR 32: 5.2 s in the BERT cells, 6 s in the Kimi Linear cell). Heads
     of 128 channels, so that the delta rule takes its kernels; one dense
     layer and ``kda_layers`` expert layers, three grouped matmuls each."""
     from paddle_tpu.ops import pallas as plk
+    from paddle_tpu.ops.pallas import delta_glue as glue_mod
     from paddle_tpu.ops.pallas import kda as kda_mod
     from paddle_tpu.parallel.mesh import MeshConfig, make_mesh
 
@@ -429,7 +432,10 @@ def test_a_step_traces_a_kernel_body_once_however_many_layers_call_it(
         monkeypatch.setattr(module, name, enter)
 
     for module, names in ((kda_mod, ("_fwd_kernel", "_bwd_kernel")),
-                          (gmm_mod, ("_gmm_kernel", "_tgmm_kernel"))):
+                          (gmm_mod, ("_gmm_kernel", "_tgmm_kernel")),
+                          (glue_mod, ("_conv_fwd_kernel", "_conv_bwd_kernel",
+                                      "_gate_fwd_kernel",
+                                      "_gate_bwd_kernel"))):
         for name in names:
             counted(module, name)
     layers = kda_layers + 1
@@ -447,6 +453,13 @@ def test_a_step_traces_a_kernel_body_once_however_many_layers_call_it(
     # the grouped product at two shapes (gate and up; down), each in the
     # pass, in the experts' own backward (``moe._held_bwd`` makes its rows'
     # products again under ``jax.vjp``) and transposed for the rows'
-    # gradient; the weights' gradient at the two shapes
-    assert entered == {"_fwd_kernel": 2, "_bwd_kernel": 1,
-                       "_gmm_kernel": 6, "_tgmm_kernel": 2}, entered
+    # gradient; the weights' gradient at the two shapes. The delta rule's
+    # kernels and the passes around it (PR 39: the convolution's kernel for
+    # q and k, normed a head, their scale an operand, and for v, plain: two
+    # programs each way) are entered once a program:
+    # ``registry.traced_once`` gives the pass and the checkpoint's JVP one
+    # trace context (the rule's forward was entered twice before)
+    assert entered == {"_fwd_kernel": 1, "_bwd_kernel": 1,
+                       "_gmm_kernel": 6, "_tgmm_kernel": 2,
+                       "_conv_fwd_kernel": 2, "_conv_bwd_kernel": 2,
+                       "_gate_fwd_kernel": 1, "_gate_bwd_kernel": 1}, entered

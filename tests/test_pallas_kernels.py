@@ -52,6 +52,12 @@ ENTRY_POINTS = {
     "kda_chunked": lambda: kda.kda_chunked(
         *(jnp.ones((1, 128, 1, 128)),) * 3, -jnp.ones((1, 128, 1, 128)),
         jnp.ones((1, 128, 1))),
+    "short_conv_norm": lambda: K.short_conv_norm(
+        jnp.ones((1, 16, 256)), jnp.ones((4, 256)), 128,
+        ((128, 1.0), (128, None))),
+    "gated_head_norm": lambda: K.gated_head_norm(
+        jnp.ones((1, 16, 128)), jnp.ones((1, 16, 128)), jnp.ones((128,)),
+        1e-6, "silu"),
 }
 
 

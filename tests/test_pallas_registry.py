@@ -26,8 +26,9 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 RNG = np.random.RandomState(42)
 
 KERNELS = ["embedding_scatter_add", "flash_attention", "fused_layer_norm",
-           "fused_matmul", "fused_matmul_int8", "grouped_matmul",
-           "kda_chunked", "softmax_cross_entropy"]
+           "fused_matmul", "fused_matmul_int8", "gated_head_norm",
+           "grouped_matmul", "kda_chunked", "short_conv_norm",
+           "softmax_cross_entropy"]
 
 
 def _f(shape, dtype=jnp.float32, scale=1.0):

@@ -259,25 +259,27 @@ def test_rotation_touches_64_of_256_channels():
 
 
 def test_the_short_convolution_and_the_l2_norm_are_the_blocks_own():
-    """Both delta-rule models call ``blocks.short_conv`` and
-    ``blocks.l2_normalize``; neither keeps a copy."""
+    """Both delta-rule models call ``ops.pallas.short_conv_norm``, whose
+    reference body is ``short_conv`` then ``l2_normalize``
+    (``ops/pallas/delta_glue.py``); neither keeps a copy."""
     from paddle_tpu.models import kimi_linear
+    from paddle_tpu.ops.pallas import delta_glue
     for module in (qn, kimi_linear):
         assert not hasattr(module, "_short_conv")
         assert not hasattr(module, "_l2_normalize")
     x = jax.random.normal(jax.random.PRNGKey(0), (2, 9, 6))
     taps = jax.random.normal(jax.random.PRNGKey(1), (4, 6))
-    got = blocks.short_conv(x, taps)
+    got = delta_glue.short_conv(x, taps)
     for b in range(2):
         np.testing.assert_allclose(
             np.asarray(got[b]), np.asarray(reference._conv_silu(x[b], taps)),
             rtol=1e-5, atol=1e-6)
     # causal: position t sees t - 3 .. t
-    moved = blocks.short_conv(x.at[:, 5].add(1.0), taps)
+    moved = delta_glue.short_conv(x.at[:, 5].add(1.0), taps)
     assert np.array_equal(np.asarray(moved[:, :5]), np.asarray(got[:, :5]))
     assert not np.allclose(np.asarray(moved[:, 5:9]), np.asarray(got[:, 5:9]))
     np.testing.assert_allclose(
-        np.asarray(blocks.l2_normalize(x, 0.5)),
+        np.asarray(delta_glue.l2_normalize(x, 0.5)),
         0.5 * np.asarray(reference._l2(x)), rtol=1e-5)
 
 
@@ -500,6 +502,55 @@ def test_a_step_traces_each_kernel_once_a_layer_type(layers, monkeypatch):
     for jitted in (fa._flash_fwd, pk._gdn_fwd):
         jitted.clear_cache()
     assert sorted(traced) == ["_flash_fwd_kernel", "_head_decay_fwd_kernel"]
+
+
+@pytest.mark.parametrize("layers", [4, 8])
+def test_a_training_step_enters_each_kernel_body_once(layers, monkeypatch):
+    """The whole step, backward and the recomputed mixers included: the
+    delta rule's kernels and the passes around it are entered once a
+    program however many Gated DeltaNet layers call them (the convolution's
+    for q and k, normed and 128 wide, and for v, plain and 256 wide: two
+    programs each way). The mixers are under ``jax.checkpoint``, whose JVP
+    jax traces under an empty abstract mesh, a trace context of its own, so a
+    forward kernel would be traced and lowered twice (the cell's ``setup_s``
+    read +14% with that, over its bound: PERF.md section 6, PR 39);
+    ``registry.traced_once`` gives both one context. Observed on jax 0.9.0:
+    this count is the guard when jax moves."""
+    from paddle_tpu.ops.pallas import delta_glue as glue_mod
+    from paddle_tpu.ops.pallas import kda as kda_mod
+    from paddle_tpu.parallel.mesh import MeshConfig, make_mesh
+
+    entered = {}
+
+    def counted(module, name):
+        body = getattr(module, name)
+
+        def enter(*args, **kw):
+            entered[name] = entered.get(name, 0) + 1
+            return body(*args, **kw)
+
+        monkeypatch.setattr(module, name, enter)
+
+    for module, names in ((kda_mod, ("_head_decay_fwd_kernel",
+                                     "_head_decay_bwd_kernel")),
+                          (glue_mod, ("_conv_fwd_kernel", "_conv_bwd_kernel",
+                                      "_gate_fwd_kernel",
+                                      "_gate_bwd_kernel"))):
+        for name in names:
+            counted(module, name)
+    cfg = qn.qwen3_next_tiny(num_layers=layers, linear_key_dim=128,
+                             linear_value_dim=128, experts_held=(0, 4))
+    mesh = make_mesh(MeshConfig(data=1), devices=jax.devices()[:1])
+    init_fn, step_fn = qn.make_train_step(cfg, pt.optimizer.Adam(1e-3), mesh)
+    params, opt_state = jax.eval_shape(init_fn, jax.random.PRNGKey(0))
+    batch = jax.eval_shape(step_fn.place, qn.synthetic_batch(cfg, 1, 256))
+    jax.clear_caches()            # what earlier tests of this process traced
+    with plk.override("on"):
+        step_fn.jitted.trace(params, opt_state, batch)
+    assert entered == {"_head_decay_fwd_kernel": 1,
+                       "_head_decay_bwd_kernel": 1,
+                       "_conv_fwd_kernel": 2, "_conv_bwd_kernel": 2,
+                       "_gate_fwd_kernel": 1, "_gate_bwd_kernel": 1}, entered
 
 
 # ---------------------------------------------------------------------------

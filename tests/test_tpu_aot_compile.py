@@ -123,6 +123,15 @@ KERNEL_SHAPES = {
     "kda_chunked": ([((1, 8192, 32, 128), BF16)] * 3
                     + [((1, 8192, 32, 128), F32), ((1, 8192, 32), F32)],
                     {"chunk": 32}, True),
+    # a Gated DeltaNet layer of qwen3_next_80b_a3b.lm_s16384: x @ w's
+    # [q | k | v] and the taps; q and k normed a head of 128, v not
+    "short_conv_norm": ([((1, 16384, 8192), BF16), ((4, 8192), F32)],
+                        {"head_dim": 128,
+                         "parts": ((2048, 128 ** -0.5), (2048, 1.0),
+                                   (4096, None))}, True),
+    # its output, the gate z and the gain a channel of the head
+    "gated_head_norm": ([((1, 16384, 4096), BF16)] * 2 + [((128,), F32)],
+                        {"eps": 1e-6, "act": "silu"}, True),
 }
 
 
@@ -143,7 +152,9 @@ def test_pallas_body_compiles_for_the_chip(name, one_chip):
 
         def fn(*a):
             return jax.value_and_grad(
-                lambda *b: jnp.sum(body(*b).astype(F32)), floats)(*a)
+                lambda *b: sum(jnp.sum(t.astype(F32))
+                               for t in jax.tree.leaves(body(*b))),
+                floats)(*a)
     assert _mosaic_calls(_compile(fn, *args)) >= 1
 
 
@@ -538,6 +549,61 @@ def test_the_delta_rule_is_two_mosaic_calls_where_a_head_is_a_lane_tile(
     assert _mosaic_calls(compiled(16)) == 0
 
 
+def _no_head_view_around_the_rule(compiled, positions):
+    """From the projections to the rule and from the rule to the output
+    projection a delta-rule mixer's activations stay rows-major: the step
+    holds no copy and no transpose of an array of the mixer's [B, S, H, 128]
+    or [B, S, H 128] sizes under a delta-rule scope (the compiler's
+    relayouts for a norm a head were 27.9 ms a step in the Qwen3-Next cell
+    and 31.7 in Kimi Linear's: PERF.md section 6, PR 39)."""
+    moved = [line for line in _entry_text(compiled).splitlines()
+             if re.search(r" (copy|transpose)\(", line)
+             and re.search(rf"\[1,{positions},(\d+,128|\d{{4}})\]", line)
+             and re.search(r"short_conv|gdn_gate|gdn_core|kda_gate|kda_core",
+                           line)]
+    assert not moved, moved[:3]
+
+
+#: name -> (the op's Pallas body's arguments, its statics): a Kimi Linear
+#: layer's calls at [1, 8192, 4096] (the Qwen3-Next layer's at [1, 16384,
+#: 8192] are ``KERNEL_SHAPES``')
+DELTA_GLUE_AT_8192 = {
+    "conv_q_normed": ("short_conv_norm",
+                      [((1, 8192, 4096), BF16), ((4, 4096), F32)],
+                      {"head_dim": 128, "parts": ((4096, 128 ** -0.5),)}),
+    "conv_v_plain": ("short_conv_norm",
+                     [((1, 8192, 4096), BF16), ((4, 4096), F32)],
+                     {"head_dim": 128, "parts": ((4096, None),)}),
+    "gate_sigmoid": ("gated_head_norm",
+                     [((1, 8192, 4096), BF16)] * 2 + [((128,), F32)],
+                     {"eps": 1e-5, "act": "sigmoid"}),
+}
+
+
+@pytest.mark.parametrize("case", sorted(DELTA_GLUE_AT_8192))
+def test_the_passes_around_the_rule_compile_at_a_kimi_layers_shapes(
+        case, one_chip):
+    """Forward and backward, one Mosaic call each way and nothing between
+    the operands and the kernels: no copy, no reshape that moves bytes. The
+    gate's backward writes do and dz over o and z, which a recomputed mixer
+    has no further use for: here they are the program's arguments, donated
+    as a temporary is."""
+    name, shapes, kw = DELTA_GLUE_AT_8192[case]
+    body = functools.partial(plk.get_body(name, "pallas"), interpret=False,
+                             **kw)
+    compiled = _compile(
+        lambda *a: jax.value_and_grad(
+            lambda *b: sum(jnp.sum(t.astype(F32))
+                           for t in jax.tree.leaves(body(*b))),
+            range(len(shapes)))(*a),
+        *[_abstract(s, d, one_chip) for s, d in shapes],
+        donate_argnums=(0, 1) if name == "gated_head_norm" else ())
+    stem = "conv_norm" if name == "short_conv_norm" else "gated_norm"
+    assert sorted(_mosaic_call_stems(compiled)) == [stem + "_bwd",
+                                                    stem + "_fwd"]
+    assert not re.search(r" copy\(", _entry_text(compiled))
+
+
 @pytest.fixture(scope="module")
 def kimi_linear_full_size(topo):
     """The step of the cell kimi_linear_48b_a3b.lm_s8192: the published
@@ -570,16 +636,28 @@ def test_kimi_linear_step_at_published_widths_fits_a_v5e(
     assert ma.argument_size_in_bytes > 6.7 * 2**30
     need = _need_bytes(compiled)
     assert need < 15.75 * 2**30, need / 2**30
-    # 11.07 GiB; 14.59 with the scan as a jax.numpy body, which kept six
-    # prepared arrays of the inputs' size and a state a chunk (PR 30)
-    assert need < 11.5 * 2**30, need / 2**30
+    # 10.38 GiB: 11.26 before the passes around the rule were kernels
+    # (PR 39), 11.85 with them until the gate's backward wrote do and dz
+    # over o and z (the heap packed a layer's long-lived dz, which waits
+    # for the last weight-gradient products, above every other layer's
+    # backward: PERF.md section 6, PR 39); 14.59 with the scan as a
+    # jax.numpy body (PR 30)
+    assert need < 10.5 * 2**30, need / 2**30
     stems = _mosaic_call_stems(compiled)
     assert set(stems) == {"flash_fwd", "flash_bwd", "softmax_xent_fwd",
                           "grouped_matmul", "grouped_matmul_dw",
-                          "kda_fwd", "kda_bwd"}
+                          "kda_fwd", "kda_bwd", "conv_norm_fwd",
+                          "conv_norm_bwd", "gated_norm_fwd",
+                          "gated_norm_bwd"}
     assert stems.count("flash_fwd") == stems.count("flash_bwd") == 1
     # a call a layer: XLA inlines the jitted calls the layers share
     assert stems.count("kda_fwd") == 8 and stems.count("kda_bwd") == 4
+    # the passes around the rule (PR 39): q, k and v a call each, the
+    # output's norm and gate one, forward twice as the rule's
+    assert stems.count("conv_norm_fwd") == 24
+    assert stems.count("conv_norm_bwd") == 12
+    assert stems.count("gated_norm_fwd") == 8
+    assert stems.count("gated_norm_bwd") == 4
     calls = [line for line in compiled.as_text().splitlines()
              if "tpu_custom_call" in line]
     op_names = "\n".join(re.findall(r'op_name="([^"]*)"', "\n".join(calls)))
@@ -589,15 +667,20 @@ def test_kimi_linear_step_at_published_widths_fits_a_v5e(
                           ("moe_experts", "grouped_matmul_dw"),
                           ("loss", "softmax_xent_fwd"),
                           ("kda_core", "kda_fwd"),
-                          ("kda_core", "kda_bwd")):
+                          ("kda_core", "kda_bwd"),
+                          ("short_conv", "conv_norm_fwd"),
+                          ("short_conv", "conv_norm_bwd"),
+                          ("kda_gate", "gated_norm_fwd"),
+                          ("kda_gate", "gated_norm_bwd")):
         assert re.search(rf"{scope}[^\n]*/{kernel}/pallas_call", op_names), \
             (scope, kernel)
     # the scan is the kernels': every Mosaic call of the delta rule is under
     # ``kda_core``, and XLA's triangular solve and its loop over the chunks
     # are gone from the program
     assert all("kda_core" in name for name in op_names.splitlines()
-               if "/kda_" in name)
+               if "/kda_fwd" in name or "/kda_bwd" in name)
     assert "triangular" not in compiled.as_text().lower()
+    _no_head_view_around_the_rule(compiled, 8192)
 
 
 # (b2) the flash kernels on rows-major operands ([B, S, heads D]: PR 36)
@@ -815,19 +898,32 @@ def test_qwen3_next_step_at_published_widths_fits_a_v5e(
     ma = compiled.memory_analysis()
     assert ma.argument_size_in_bytes > 6.9 * 2**30
     need = _need_bytes(compiled)
-    assert 0.25 * 15.75 * 2**30 < need < 15.3 * 2**30, need / 2**30  # 15.04
+    # 14.09 GiB; 15.04 before the passes around the rule were kernels
+    # (PR 39): no float32 [16384, 8192] residual and no second layout of
+    # o, z and the convolution's output at the peak
+    assert 0.25 * 15.75 * 2**30 < need < 14.2 * 2**30, need / 2**30
     stems = _mosaic_call_stems(compiled)
     assert set(stems) == {"gdn_fwd", "gdn_bwd", "flash_fwd", "flash_bwd",
                           "softmax_xent_fwd", "grouped_matmul",
-                          "grouped_matmul_dw"}
+                          "grouped_matmul_dw", "conv_norm_fwd",
+                          "conv_norm_bwd", "gated_norm_fwd",
+                          "gated_norm_bwd"}
     # a call a layer: XLA inlines the jitted calls the layers share
     assert stems.count("gdn_fwd") == 6 and stems.count("gdn_bwd") == 3
+    # the passes around the rule: a call a column range of [q | k | v], the
+    # output's norm and gate one, forward twice as the rule's
+    assert stems.count("conv_norm_fwd") == 18
+    assert stems.count("conv_norm_bwd") == 9
+    assert stems.count("gated_norm_fwd") == 6
+    assert stems.count("gated_norm_bwd") == 3
     assert stems.count("flash_fwd") == 1 and stems.count("flash_bwd") == 1
     calls = [line for line in compiled.as_text().splitlines()
              if "tpu_custom_call" in line]
     op_names = "\n".join(re.findall(r'op_name="([^"]*)"', "\n".join(calls)))
     for scope, kernel in (
             ("gdn_core", "gdn_fwd"), ("gdn_core", "gdn_bwd"),
+            ("short_conv", "conv_norm_fwd"), ("short_conv", "conv_norm_bwd"),
+            ("gdn_gate", "gated_norm_fwd"), ("gdn_gate", "gated_norm_bwd"),
             ("attention_core", "flash_fwd"), ("attention_core", "flash_bwd"),
             ("moe_experts", "grouped_matmul"),
             ("moe_experts", "grouped_matmul_dw"),
@@ -839,6 +935,7 @@ def test_qwen3_next_step_at_published_widths_fits_a_v5e(
     entry = _entry_text(compiled)
     assert "f32[1,16384,32,128]{3,2,1,0" not in "".join(
         line for line in entry.splitlines() if "broadcast" in line)
+    _no_head_view_around_the_rule(compiled, 16384)
 
 
 # ---------------------------------------------------------------------------
