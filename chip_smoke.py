@@ -260,6 +260,16 @@ def phase_kernels():
                                      bf16(2, 1200, 1024), f32(128) + 1.0),
                                     {"eps": 1e-5, "act": "sigmoid"},
                                     (0, 1, 2), 2e-2),
+        # LFM2's [B | C | u] in one array of three ranges of 1024 channels,
+        # 3 taps, 1200 positions (five blocks with padding): the array, then
+        # the taps
+        "gated_short_conv": ((bf16(2, 1200, 3072), f32(3, 1024, scale=0.3)),
+                             {}, (0, 1), 2e-2),
+        # heads of 64, half a lane tile: 32 query heads on 8 key/value heads
+        "flash_attention/d64_gqa": ((bf16(1, 32, 4 * SEQ, 64),
+                                     bf16(1, 8, 4 * SEQ, 64),
+                                     bf16(1, 8, 4 * SEQ, 64)),
+                                    {"causal": True}, (0, 1, 2), 3e-2),
     }
     missing = set(plk.list_kernels()) ^ {c.split("/")[0] for c in cases}
     if missing:

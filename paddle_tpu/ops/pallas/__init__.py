@@ -8,7 +8,8 @@ fused_matmul / fused_matmul_int8 (matmul.py), embedding_scatter_add
 softmax_cross_entropy (softmax_xent.py), kda_chunked (kda.py; its entry
 point is ``paddle_tpu.ops.kda.kda_chunked``, beside the reference body and
 the recurrence it stands for) and the two passes around it, short_conv_norm
-and gated_head_norm (delta_glue.py). The other entry points the models call
+and gated_head_norm (delta_glue.py), and gated_short_conv (gated_conv.py),
+the LFM2 family's double-gated convolution. The other entry points the models call
 are names of this package; ``flash_attention`` and ``grouped_matmul`` here
 are therefore the functions, not the modules of the same name (import a
 module's own names with ``from paddle_tpu.ops.pallas.<module> import ...``)."""
@@ -19,6 +20,7 @@ from paddle_tpu.ops.pallas.registry import (  # noqa: F401
     override, mesh_scope, platform, within_vmem_budget,
 )
 from paddle_tpu.ops.pallas.delta_glue import gated_head_norm, short_conv_norm
+from paddle_tpu.ops.pallas.gated_conv import gated_short_conv
 from paddle_tpu.ops.pallas import embedding as _embedding  # noqa: F401
 from paddle_tpu.ops.pallas.flash_attention import flash_attention
 from paddle_tpu.ops.pallas.grouped_matmul import grouped_matmul
@@ -34,4 +36,5 @@ __all__ = [
     "within_vmem_budget", "DEFAULT_VMEM_BUDGET",
     "flash_attention", "fused_layer_norm", "softmax_cross_entropy",
     "grouped_matmul", "short_conv_norm", "gated_head_norm",
+    "gated_short_conv",
 ]

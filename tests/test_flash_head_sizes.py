@@ -123,3 +123,43 @@ def test_a_head_of_256_over_two_key_value_heads_matches_the_dense_body():
                                         for t in (q, k, v)), impl="flash")
     np.testing.assert_allclose(np.asarray(ctx.transpose(0, 2, 1, 3)),
                                np.asarray(out), atol=2e-5)
+
+
+def test_a_head_of_64_with_32_heads_over_8_matches_the_dense_body():
+    """Half a lane tile a head and groups of 4: 32 causal query heads on 8
+    key/value heads of 64, as LFM2's attention layers have them; 640
+    positions are five query blocks of 128, so a key/value head is read by
+    several programs of each of its group's four heads. Outputs and the
+    three gradients, the key/value gradients the sum of their group's."""
+    ks = jax.random.split(jax.random.PRNGKey(4), 4)
+    q, w = (jax.random.normal(key, (1, 32, 640, 64)) for key in ks[:2])
+    k, v = (jax.random.normal(key, (1, 8, 640, 64)) for key in ks[2:])
+
+    def f(q, k, v, mode):
+        with plk.override(mode):
+            return plk.flash_attention(q, k, v, causal=True)
+
+    out = f(q, k, v, "on")
+    assert out.shape == (1, 32, 640, 64)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(f(q, k, v, "off")),
+                               atol=2e-5)
+    got, want = (jax.grad(lambda q, k, v: jnp.sum(f(q, k, v, mode) * w),
+                          (0, 1, 2))(q, k, v) for mode in ("on", "off"))
+    for name, a, b in zip("qkv", got, want):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-4,
+                                   err_msg=f"d{name}")
+    # query head i reads key/value head i // 4: the dense scores written out
+    scores = jnp.einsum("bnqd,bnkd->bnqk", q, jnp.repeat(k, 4, axis=1)) / 8.0
+    scores = jnp.where(jnp.tril(jnp.ones((640, 640), bool)), scores,
+                       -jnp.inf)
+    by_hand = jnp.einsum("bnqk,bnkd->bnqd", jax.nn.softmax(scores, axis=-1),
+                         jnp.repeat(v, 4, axis=1))
+    np.testing.assert_allclose(np.asarray(out), np.asarray(by_hand),
+                               atol=2e-5)
+    # and through the decoders' entry, [B, S, N, D] operands
+    with plk.override("on"):
+        ctx = blocks.causal_attention(*(t.transpose(0, 2, 1, 3)
+                                        for t in (q, k, v)), impl="flash")
+    np.testing.assert_allclose(np.asarray(ctx.transpose(0, 2, 1, 3)),
+                               np.asarray(out), atol=2e-5)

@@ -58,6 +58,8 @@ ENTRY_POINTS = {
     "gated_head_norm": lambda: K.gated_head_norm(
         jnp.ones((1, 16, 128)), jnp.ones((1, 16, 128)), jnp.ones((128,)),
         1e-6, "silu"),
+    "gated_short_conv": lambda: K.gated_short_conv(
+        jnp.ones((1, 16, 384)), jnp.ones((3, 128))),
 }
 
 

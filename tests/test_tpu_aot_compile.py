@@ -28,8 +28,8 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
 import paddle_tpu as pt
-from paddle_tpu.models import (bert, kimi_linear, laguna, olmoe, qwen3_next,
-                               transformer)
+from paddle_tpu.models import (bert, kimi_linear, laguna, lfm2, olmoe,
+                               qwen3_next, transformer)
 from paddle_tpu.ops import kda
 from paddle_tpu.ops import pallas as plk
 from paddle_tpu.ops.pallas import registry
@@ -132,6 +132,10 @@ KERNEL_SHAPES = {
     # its output, the gate z and the gain a channel of the head
     "gated_head_norm": ([((1, 16384, 4096), BF16)] * 2 + [((128,), F32)],
                         {"eps": 1e-6, "act": "silu"}, True),
+    # a convolution layer of lfm2_24b_a2b.lm_b4_s8192: x @ W_in's
+    # [B | C | u] and the 3 taps
+    "gated_short_conv": ([((4, 8192, 6144), BF16), ((3, 2048), F32)], {},
+                         True),
 }
 
 
@@ -936,6 +940,107 @@ def test_qwen3_next_step_at_published_widths_fits_a_v5e(
     assert "f32[1,16384,32,128]{3,2,1,0" not in "".join(
         line for line in entry.splitlines() if "broadcast" in line)
     _no_head_view_around_the_rule(compiled, 16384)
+
+
+# ---------------------------------------------------------------------------
+# (c5) LFM2: the double-gated convolution's two kernels, flash at a head of
+# 64 with 32 over 8, and the step of lfm2_24b_a2b.lm_b4_s8192
+# ---------------------------------------------------------------------------
+def test_the_gated_convolution_is_two_mosaic_calls_on_the_projection_s_array(
+        one_chip):
+    """[4, 8192, 3 x 2048] and 3 taps, forward and backward: ``gated_conv_fwd``
+    and ``gated_conv_bwd`` and no other custom call; the ranges are read in
+    place and the gradient is one array: no [4, 8192, 2048] slice or
+    concatenate exists beside the op's own result and its cotangent."""
+    bcu = _abstract((4, 8192, 6144), BF16, one_chip)
+    taps = _abstract((3, 2048), F32, one_chip)
+    w = _abstract((4, 8192, 2048), BF16, one_chip)
+    compiled = _compile(lambda x, t, w: jax.value_and_grad(
+        lambda x, t: jnp.sum((plk.gated_short_conv(x, t) * w).astype(F32)),
+        (0, 1))(x, t), bcu, taps, w)
+    assert sorted(_mosaic_call_stems(compiled)) == ["gated_conv_bwd",
+                                                    "gated_conv_fwd"]
+    assert compiled.as_text().count("custom-call(") == 2
+    entry = _entry_text(compiled)
+    assert "concatenate(" not in entry and " slice(" not in entry
+    calls = [line for line in entry.splitlines() if "tpu_custom_call" in line]
+    assert all("bf16[4,8192,6144]" in line for line in calls)
+
+
+def test_flash_compiles_at_a_head_of_64_with_32_heads_over_8(one_chip):
+    """The attention of an LFM2 full layer through the heads-major blocks:
+    32 causal query heads of 64 over 8 key/value heads at 4 x 8192
+    positions, forward and the one backward call, each reading the 8
+    key/value heads as they are."""
+    q = _abstract((4, 32, 8192, 64), BF16, one_chip)
+    kv = _abstract((4, 8, 8192, 64), BF16, one_chip)
+
+    def fn(q, k, v):
+        return jax.value_and_grad(lambda *a: jnp.sum(plk.flash_attention(
+            *a, causal=True).astype(F32)), (0, 1, 2))(q, k, v)
+    compiled = _compile(fn, q, kv, kv)
+    assert sorted(_mosaic_call_stems(compiled)) == ["flash_bwd", "flash_fwd"]
+    calls = [line for line in _entry_text(compiled).splitlines()
+             if "tpu_custom_call" in line]
+    assert all(line.count("bf16[4,8,8192,64]") >= 2 for line in calls)
+
+
+@pytest.fixture(scope="module")
+def lfm2_full_size(topo):
+    """The step of the cell lfm2_24b_a2b.lm_b4_s8192: the published layers 1
+    to 5 at the published widths, 8 of 64 experts, an eighth of the
+    vocabulary tied with the head, batch 4 x 8192."""
+    cfg = lfm2.lfm2_24b_a2b(
+        num_layers=5, layer_types=lfm2.lfm2_24b_a2b().layer_types[1:6],
+        num_dense_layers=1, vocab_size=8192, experts_held=(0, 8))
+    return _lower_replicated(
+        lfm2.make_train_step, lfm2.init_params, cfg,
+        lfm2.synthetic_batch(cfg, 4, 8192), topo)
+
+
+@pytest.mark.timeout(900)
+def test_lfm2_step_at_published_widths_fits_a_v5e(lfm2_full_size):
+    """469.3 M parameters with their two Adam moments are 5.24 GiB of the
+    step's arguments; with nothing recomputed the whole step needs less than
+    the 15.75 GiB a v5e gives a program (PERF.md section 6, PR 40, has what
+    each choice of recomputation costs). Its Mosaic calls: the convolution's two kernels
+    (four layers, once each way), the causal flash kernels (one layer), the
+    grouped matmuls of the four expert layers' loops and the cross-entropy,
+    each under its scope; the tied table is one argument and one Adam
+    leaf."""
+    compiled, pshape, oshape = lfm2_full_size
+    assert sum(int(np.prod(a.shape)) for a in jax.tree.leaves(pshape)) \
+        == 469_285_248
+    assert "head_w" not in pshape
+    ma = compiled.memory_analysis()
+    assert 5.2 * 2**30 < ma.argument_size_in_bytes < 5.3 * 2**30
+    need = _need_bytes(compiled)
+    assert 0.25 * 15.75 * 2**30 < need < 15.3 * 2**30, need / 2**30          # 15.17 GiB
+    stems = _mosaic_call_stems(compiled)
+    assert set(stems) == {"gated_conv_fwd", "gated_conv_bwd", "flash_fwd",
+                          "flash_bwd", "softmax_xent_fwd", "grouped_matmul",
+                          "grouped_matmul_dw"}
+    # a call a layer: XLA inlines the jitted calls the layers share
+    assert stems.count("gated_conv_fwd") == 4       # nothing is recomputed
+    assert stems.count("gated_conv_bwd") == 4
+    assert stems.count("flash_fwd") == 1 and stems.count("flash_bwd") == 1
+    calls = [line for line in compiled.as_text().splitlines()
+             if "tpu_custom_call" in line]
+    op_names = "\n".join(re.findall(r'op_name="([^"]*)"', "\n".join(calls)))
+    for scope, kernel in (
+            ("gated_conv", "gated_conv_fwd"), ("gated_conv", "gated_conv_bwd"),
+            ("attention_core", "flash_fwd"), ("attention_core", "flash_bwd"),
+            ("moe_experts", "grouped_matmul"),
+            ("moe_experts", "grouped_matmul_dw"),
+            ("loss", "softmax_xent_fwd")):
+        assert re.search(rf"{scope}[^\n]*/{kernel}/pallas_call", op_names), \
+            (scope, kernel)
+    # the convolution's ranges are read in place: no array of one range's
+    # width is cut out of the projection's product or joined into its gradient
+    entry = _entry_text(compiled)
+    assert not [line for line in entry.splitlines()
+                if "gated_conv" in line and ("concatenate(" in line
+                                             or " slice(" in line)]
 
 
 # ---------------------------------------------------------------------------
