@@ -224,6 +224,14 @@ def phase_kernels():
         "grouped_matmul": ((f32(4096, h), f32(8, h, ffn, scale=0.02),
                             jnp.asarray([0, 1000, 7, 300, 1500, 0, 1289, 0],
                                         jnp.int32)), {}, (0, 1), 2e-2),
+        # a pass of a layer that holds a share of the experts: 2048 rows in
+        # expert order into 4096 tokens, four a token at most, the last 548
+        # rows of weight 0 (past the rows held); y, rows, tokens, weights
+        "moe_combine": ((f32(4096, 1024), bf16(2048, 1024),
+                         jnp.asarray(rs.permutation(4 * 4096)[:2048] // 4,
+                                     jnp.int32),
+                         jnp.abs(f32(2048)) * (jnp.arange(2048) < 1500)),
+                        {}, None, 1e-5),
         # two heads of 128, ten grid steps of 128 positions with padding; q, k
         # of unit norm, decays in (-1, 0], beta in (0, 1), as a KDA mixer
         # hands them over; both bodies round the same operands to bf16

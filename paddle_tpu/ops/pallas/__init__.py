@@ -8,8 +8,15 @@ fused_matmul / fused_matmul_int8 (matmul.py), embedding_scatter_add
 softmax_cross_entropy (softmax_xent.py), kda_chunked (kda.py; its entry
 point is ``paddle_tpu.ops.kda.kda_chunked``, beside the reference body and
 the recurrence it stands for) and the two passes around it, short_conv_norm
-and gated_head_norm (delta_glue.py), and gated_short_conv (gated_conv.py),
-the LFM2 family's double-gated convolution. The other entry points the models call
+and gated_head_norm (delta_glue.py), gated_short_conv (gated_conv.py),
+the LFM2 family's double-gated convolution, and moe_combine
+(moe_combine.py), the way back of an expert layer that holds a share of the
+experts: a pass's rows, each times its float32 weight, summed into their
+tokens' rows. Its reference body is XLA's scatter-add (the CPU, and any mesh
+of more than one device); its Pallas body puts the rows in token order and
+sums them on the MXU, for ``y`` [T, D] float32 with D in whole lane tiles
+and T in whole sublane tiles (any other shape: the reference body). The
+other entry points the models call
 are names of this package; ``flash_attention`` and ``grouped_matmul`` here
 are therefore the functions, not the modules of the same name (import a
 module's own names with ``from paddle_tpu.ops.pallas.<module> import ...``)."""
@@ -26,6 +33,7 @@ from paddle_tpu.ops.pallas.flash_attention import flash_attention
 from paddle_tpu.ops.pallas.grouped_matmul import grouped_matmul
 from paddle_tpu.ops.pallas import kda as _kda  # noqa: F401
 from paddle_tpu.ops.pallas.layer_norm import fused_layer_norm
+from paddle_tpu.ops.pallas.moe_combine import moe_combine
 from paddle_tpu.ops.pallas.matmul import try_fused_matmul
 from paddle_tpu.ops.pallas.softmax_xent import softmax_cross_entropy
 
@@ -36,5 +44,5 @@ __all__ = [
     "within_vmem_budget", "DEFAULT_VMEM_BUDGET",
     "flash_attention", "fused_layer_norm", "softmax_cross_entropy",
     "grouped_matmul", "short_conv_norm", "gated_head_norm",
-    "gated_short_conv",
+    "gated_short_conv", "moe_combine",
 ]

@@ -267,14 +267,28 @@ def _gated_experts(rows, weights, sizes, mesh):
                          matmul=lambda a, w: grouped_matmul(a, w, sizes))
 
 
+def _sum_back(y, rows, token, weight, mesh):
+    """``y`` [T, D] float32 with a pass's ``rows``, each times its float32
+    ``weight``, added into the rows of their tokens (``ops/pallas/
+    moe_combine.py``: on one chip the rows in token order summed on the MXU,
+    XLA's scatter-add under a mesh and on the CPU)."""
+    from paddle_tpu.ops.pallas.moe_combine import moe_combine
+    from paddle_tpu.ops.pallas.registry import mesh_scope
+
+    with jax.named_scope("moe_dispatch"), mesh_scope(mesh):
+        return moe_combine(y, rows, token, weight)
+
+
 #: Rows of the held experts' assignments one pass of ``_held_experts``
 #: takes. A pass gathers its rows, runs the three grouped matmuls on them
 #: and adds their weighted outputs into the tokens' rows; the number of
 #: passes follows the rows held (``ceil(rows / HELD_ROW_TILE)``), so nothing
 #: is sized for the router's worst case and nothing is dropped. A blocking
-#: size, not a limit: what a pass pays whatever its fill (the gather and the
-#: sum back of a full tile, the gate's elementwise pass, the weights'
-#: gradients added into their sum) is paid once for up to 8192 rows, four
+#: size, not a limit: what a pass pays whatever its fill (the sort of a tile
+#: of keys on the way back, the tiles the grouped matmuls zero, the gate's
+#: elementwise pass, the weights' gradients added into their sum; the
+#: gathers and the sum back, ``ops/pallas/moe_combine.py``, follow the rows
+#: held, 4096 and 128 at a time) is paid once for up to 8192 rows, four
 #: times what a balanced router sends to 8 of 256 experts from 8192 tokens,
 #: and a pass's rows and products stay under 0.2 GiB. With 14% of the
 #: assignments held (9218 rows in the fullest layer: two passes) the Kimi
@@ -292,10 +306,11 @@ def _held_row_tile(assignments, held, experts):
     the row, and the batch's own noise (120 rows) then decides between two
     passes and three; a layer's share of the assignments also differs by
     two or three points from batch to batch and by seed around its 12.5%,
-    and a second pass of 24 576 rows is 14 ms of a 705 ms step in the Laguna
-    cell whatever it holds (PERF.md section 6, PR 33). With 32 768 rows a
-    pass it is one pass up to a share of 25%. 8 of 256 and 8 x 8192 are 2048
-    rows at par: one tile, as ever."""
+    and a second pass of 24 576 rows was 14 ms of a 705 ms step in the Laguna
+    cell whatever it holds (PERF.md section 6, PR 33; a nearly empty second
+    pass of 32 768 rows is 13 ms a layer since PR 41, section 6). With
+    32 768 rows a pass it is one pass up to a share of 25%. 8 of 256 and
+    8 x 8192 are 2048 rows at par: one tile, as ever."""
     par = assignments * held // experts
     tiles = max(1, -(-2 * par // HELD_ROW_TILE))
     return min(HELD_ROW_TILE * tiles, assignments)
@@ -303,8 +318,8 @@ def _held_row_tile(assignments, held, experts):
 
 def _held_pass(i, order, top_p, sizes, tile):
     """What pass ``i`` works on: the places [tile] its assignments have in
-    (token, choice) order, their weights (0 past the rows held), and the
-    rows each held expert has inside this pass [n]."""
+    (token, choice) order, their weights (0 past the rows held), the rows
+    each held expert has inside this pass [n], and how many that is."""
     lo = i * tile
     at = jax.lax.dynamic_slice(order, (lo,), (tile,))
     ends = jnp.cumsum(sizes)
@@ -312,7 +327,7 @@ def _held_pass(i, order, top_p, sizes, tile):
                        jnp.take(top_p.reshape(-1), at), 0.0)
     part = jnp.clip(jnp.minimum(ends, lo + tile)
                     - jnp.maximum(ends - sizes, lo), 0, None)
-    return at, weight, part
+    return at, weight, part, jnp.clip(ends[-1] - lo, 0, tile)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
@@ -323,18 +338,23 @@ def _held_experts(xt, top_p, weights, order, sizes, top_k, mesh, tile):
     expert took. The rows are worked ``tile`` at a time in a loop whose trip
     count is ``ceil(sum(sizes) / tile)``, forward and backward, so the time
     follows the rows held. The backward pass keeps the tokens, the order and
-    the scores and makes each pass's rows and products again."""
+    the scores and makes each pass's rows and products again. A pass's rows
+    come back into their tokens' rows through ``_sum_back``, forward (the
+    experts' outputs times their scores into ``y``) and backward (the rows'
+    gradients into ``dx``): on one chip no XLA scatter of rows is left in
+    either direction, only the scores' gradient's scatter of a tile of
+    scalars (0.13 to 0.32 ms a pass: PERF.md section 6, PR 41)."""
+    from paddle_tpu.ops.pallas.moe_combine import rows_held
+
     passes = -(-jnp.sum(sizes) // tile)
 
     def one(i, y):
-        at, weight, part = _held_pass(i, order, top_p, sizes, tile)
+        at, weight, part, held = _held_pass(i, order, top_p, sizes, tile)
         with jax.named_scope("moe_dispatch"):
             token = at // top_k
-            rows = jnp.take(xt, token, axis=0)
+            rows = rows_held(xt, token, held)
         out = _gated_experts(rows, weights, part, mesh)
-        with jax.named_scope("moe_dispatch"):
-            return y.at[token].add(out.astype(jnp.float32)
-                                   * weight[:, None])
+        return _sum_back(y, out, token, weight, mesh)
 
     return jax.lax.fori_loop(0, passes, one,
                              jnp.zeros(xt.shape, jnp.float32))
@@ -346,17 +366,18 @@ def _held_fwd(xt, top_p, weights, order, sizes, top_k, mesh, tile):
 
 
 def _held_bwd(top_k, mesh, tile, kept, dy):
+    from paddle_tpu.ops.pallas.moe_combine import rows_held
+
     xt, top_p, weights, order, sizes = kept
     passes = -(-jnp.sum(sizes) // tile)
-    dy = dy.astype(jnp.float32)
 
     def one(i, grads):
         dx, dp, dw = grads
-        at, weight, part = _held_pass(i, order, top_p, sizes, tile)
+        at, weight, part, held = _held_pass(i, order, top_p, sizes, tile)
         with jax.named_scope("moe_dispatch"):
             token = at // top_k
-            rows = jnp.take(xt, token, axis=0)
-            dy_rows = jnp.take(dy, token, axis=0)
+            rows = rows_held(xt, token, held)
+            dy_rows = rows_held(dy, token, held).astype(jnp.float32)
         out, back = jax.vjp(
             lambda r, w: _gated_experts(r, w, part, mesh), rows, weights)
         d_rows, dw_pass = back((dy_rows * weight[:, None]).astype(out.dtype))
@@ -364,7 +385,9 @@ def _held_bwd(top_k, mesh, tile, kept, dy):
             # a row past the rows held came out zero: its score gets nothing
             dp = dp.at[at].add(jnp.sum(out.astype(jnp.float32) * dy_rows,
                                        axis=-1))
-            dx = dx.at[token].add(d_rows.astype(jnp.float32))
+        # and a zero cotangent went back through it: its gradient is zero
+        dx = _sum_back(dx, d_rows, token, (weight != 0).astype(jnp.float32),
+                       mesh)
         return dx, dp, jax.tree.map(jnp.add, dw, dw_pass)
 
     dx, dp, dw = jax.lax.fori_loop(0, passes, one, (

@@ -60,6 +60,10 @@ ENTRY_POINTS = {
         1e-6, "silu"),
     "gated_short_conv": lambda: K.gated_short_conv(
         jnp.ones((1, 16, 384)), jnp.ones((3, 128))),
+    "moe_combine": lambda: K.moe_combine(
+        jnp.zeros((16, 128)), jnp.ones((8, 128), jnp.bfloat16),
+        jnp.asarray([3, 3, 0, 9, 15, 2, 2, 2], jnp.int32),
+        jnp.asarray([1, .5, 2, 1, 1, 0, 0, 0], jnp.float32)),
 }
 
 

@@ -115,6 +115,10 @@ KERNEL_SHAPES = {
     # OLMoE's gate/up product: 8 x 8192 assignments, 64 experts of 2048x1024
     "grouped_matmul": ([((65536, 2048), BF16), ((64, 2048, 1024), BF16),
                         ((64,), I32)], {}, True),
+    # a pass of lfm2_24b_a2b.lm_b4_s8192's expert layers: the 32 768 rows of
+    # a pass summed into the 32 768 tokens' rows
+    "moe_combine": ([((32768, 2048), F32), ((32768, 2048), BF16),
+                     ((32768,), I32), ((32768,), F32)], {}, False),
     "fused_layer_norm": ([((64, 512, H), BF16), ((H,), F32), ((H,), F32)],
                          {}, True),
     "softmax_cross_entropy": ([((5120, VOCAB), F32), ((5120,), I32)], {},
@@ -531,6 +535,39 @@ def test_grouped_matmul_compiles_for_a_share_of_the_experts(one_chip):
     assert _mosaic_calls(compiled) == 3    # product, rows' and weights' grad
 
 
+@pytest.mark.parametrize("rows, tokens, width", [
+    (32768, 32768, 2048), (8192, 8192, 2304), (24576, 16384, 2048)],
+    ids=["lfm2_and_laguna_s_pass", "kimi_s", "qwen3_next_s"])
+def test_the_way_back_compiles_at_the_held_cells_passes(one_chip, rows,
+                                                        tokens, width):
+    """A pass's rows into the tokens' rows, updated in place: one Mosaic
+    call, no XLA scatter, and no second [T, D] float32 array beside the
+    donated one (the token-ordered rows are the one temporary of the rows'
+    size, in their own dtype, beside the chunk of them being gathered)."""
+    args = [_abstract((tokens, width), F32, one_chip),
+            _abstract((rows, width), BF16, one_chip),
+            _abstract((rows,), I32, one_chip),
+            _abstract((rows,), F32, one_chip)]
+    assert plk.selected_body("moe_combine") == "pallas"
+    compiled = _compile(plk.moe_combine, *args, donate_argnums=(0,))
+    assert _mosaic_call_stems(compiled) == ["moe_combine"]
+    assert " scatter(" not in compiled.as_text()
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 1.25 * rows * width * 2, temp / 2**20
+
+
+def _no_row_scatter_under_dispatch(compiled, width):
+    """The held experts' way back is the kernel's: no XLA scatter of rows
+    ``width`` wide is left under ``moe_dispatch``, forward or backward (the
+    scores' gradient is a scatter of scalars and stays)."""
+    scatters = [line for line in compiled.as_text().splitlines()
+                if " scatter(" in line and "moe_dispatch" in line]
+    assert not [line for line in scatters
+                if re.search(rf"= \(?\w+\[\d+,{width}\]", line)], scatters
+    assert len(scatters) == 4 and all("transpose(jvp(" in line
+                                      for line in scatters)
+
+
 def test_the_delta_rule_is_two_mosaic_calls_where_a_head_is_a_lane_tile(
         one_chip):
     """[1, 8192, 32, 128], forward and backward: ``kda_fwd`` and ``kda_bwd``
@@ -650,10 +687,13 @@ def test_kimi_linear_step_at_published_widths_fits_a_v5e(
     stems = _mosaic_call_stems(compiled)
     assert set(stems) == {"flash_fwd", "flash_bwd", "softmax_xent_fwd",
                           "grouped_matmul", "grouped_matmul_dw",
-                          "kda_fwd", "kda_bwd", "conv_norm_fwd",
-                          "conv_norm_bwd", "gated_norm_fwd",
+                          "moe_combine", "kda_fwd", "kda_bwd",
+                          "conv_norm_fwd", "conv_norm_bwd", "gated_norm_fwd",
                           "gated_norm_bwd"}
     assert stems.count("flash_fwd") == stems.count("flash_bwd") == 1
+    # the way back of a pass, forward and backward, four expert layers
+    assert stems.count("moe_combine") == 8
+    _no_row_scatter_under_dispatch(compiled, 2304)
     # a call a layer: XLA inlines the jitted calls the layers share
     assert stems.count("kda_fwd") == 8 and stems.count("kda_bwd") == 4
     # the passes around the rule (PR 39): q, k and v a call each, the
@@ -669,6 +709,7 @@ def test_kimi_linear_step_at_published_widths_fits_a_v5e(
                           ("attention_core", "flash_bwd"),
                           ("moe_experts", "grouped_matmul"),
                           ("moe_experts", "grouped_matmul_dw"),
+                          ("moe_dispatch", "moe_combine"),
                           ("loss", "softmax_xent_fwd"),
                           ("kda_core", "kda_fwd"),
                           ("kda_core", "kda_bwd"),
@@ -789,7 +830,10 @@ def test_laguna_step_at_published_widths_fits_a_v5e(laguna_full_size):
     stems = _mosaic_call_stems(compiled)
     assert set(stems) == {"flash_fwd", "flash_bwd", "flash_fwd_window",
                           "flash_bwd_window", "softmax_xent_fwd",
-                          "grouped_matmul", "grouped_matmul_dw"}
+                          "grouped_matmul", "grouped_matmul_dw",
+                          "moe_combine"}
+    assert stems.count("moe_combine") == 8
+    _no_row_scatter_under_dispatch(compiled, 2048)
     # a call a layer: XLA inlines the jitted calls the layers share
     assert stems.count("flash_fwd") == 4 and stems.count("flash_bwd") == 2
     assert stems.count("flash_fwd_window") == 6
@@ -803,6 +847,7 @@ def test_laguna_step_at_published_widths_fits_a_v5e(laguna_full_size):
             ("attention_core/attention_window", "flash_bwd_window"),
             ("moe_experts", "grouped_matmul"),
             ("moe_experts", "grouped_matmul_dw"),
+            ("moe_dispatch", "moe_combine"),
             ("loss", "softmax_xent_fwd")):
         assert re.search(rf"{scope}[^\n]*/{kernel}/pallas_call", op_names), \
             (scope, kernel)
@@ -909,9 +954,11 @@ def test_qwen3_next_step_at_published_widths_fits_a_v5e(
     stems = _mosaic_call_stems(compiled)
     assert set(stems) == {"gdn_fwd", "gdn_bwd", "flash_fwd", "flash_bwd",
                           "softmax_xent_fwd", "grouped_matmul",
-                          "grouped_matmul_dw", "conv_norm_fwd",
-                          "conv_norm_bwd", "gated_norm_fwd",
+                          "grouped_matmul_dw", "moe_combine",
+                          "conv_norm_fwd", "conv_norm_bwd", "gated_norm_fwd",
                           "gated_norm_bwd"}
+    assert stems.count("moe_combine") == 8
+    _no_row_scatter_under_dispatch(compiled, 2048)
     # a call a layer: XLA inlines the jitted calls the layers share
     assert stems.count("gdn_fwd") == 6 and stems.count("gdn_bwd") == 3
     # the passes around the rule: a call a column range of [q | k | v], the
@@ -931,6 +978,7 @@ def test_qwen3_next_step_at_published_widths_fits_a_v5e(
             ("attention_core", "flash_fwd"), ("attention_core", "flash_bwd"),
             ("moe_experts", "grouped_matmul"),
             ("moe_experts", "grouped_matmul_dw"),
+            ("moe_dispatch", "moe_combine"),
             ("loss", "softmax_xent_fwd")):
         assert re.search(rf"{scope}[^\n]*/{kernel}/pallas_call", op_names), \
             (scope, kernel)
@@ -1019,7 +1067,9 @@ def test_lfm2_step_at_published_widths_fits_a_v5e(lfm2_full_size):
     stems = _mosaic_call_stems(compiled)
     assert set(stems) == {"gated_conv_fwd", "gated_conv_bwd", "flash_fwd",
                           "flash_bwd", "softmax_xent_fwd", "grouped_matmul",
-                          "grouped_matmul_dw"}
+                          "grouped_matmul_dw", "moe_combine"}
+    assert stems.count("moe_combine") == 8
+    _no_row_scatter_under_dispatch(compiled, 2048)
     # a call a layer: XLA inlines the jitted calls the layers share
     assert stems.count("gated_conv_fwd") == 4       # nothing is recomputed
     assert stems.count("gated_conv_bwd") == 4
@@ -1032,6 +1082,7 @@ def test_lfm2_step_at_published_widths_fits_a_v5e(lfm2_full_size):
             ("attention_core", "flash_fwd"), ("attention_core", "flash_bwd"),
             ("moe_experts", "grouped_matmul"),
             ("moe_experts", "grouped_matmul_dw"),
+            ("moe_dispatch", "moe_combine"),
             ("loss", "softmax_xent_fwd")):
         assert re.search(rf"{scope}[^\n]*/{kernel}/pallas_call", op_names), \
             (scope, kernel)
