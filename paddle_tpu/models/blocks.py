@@ -21,10 +21,13 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from paddle_tpu.ops.pallas.flash_attention import KEPT as _FLASH_KEPT
+from paddle_tpu.ops.pallas.kda import KEPT as _KDA_KEPT
 from paddle_tpu.ops.pallas.registry import mesh_scope, selected_body
 
 __all__ = ["rms_norm", "rms_normalize", "yarn_inv_freq", "rope_angles",
-           "apply_rope", "attention_body", "causal_attention", "gated_ffn"]
+           "apply_rope", "attention_body", "causal_attention", "gated_ffn",
+           "recomputed"]
 
 #: from this many positions on, ``auto`` takes the flash kernels where the
 #: Pallas body runs (one chip). Measured on a v5e at equal tokens a step
@@ -177,6 +180,24 @@ def causal_attention(q, k, v, impl="auto", mesh=None, window=None):
             keep &= ~jnp.tril(jnp.ones((s, s), bool), -window)
         probs = jax.nn.softmax(jnp.where(keep, scores, -1e30), axis=-1)
         return jnp.einsum("bnqk,bknd->bqnd", probs.astype(q.dtype), v)
+
+
+def recomputed(mixer):
+    """``mixer`` under a ``jax.checkpoint`` that keeps, beside the mixer's
+    inputs, what its attention kernel's forward call hands the backward one
+    and nothing but that kernel can make: the names the flash calls and the
+    delta rule put on those residuals inside their ``custom_vjp`` forward
+    rules (``flash_attention.KEPT``: o and lse; ``kda.KEPT``: o, the state a
+    unit starts from and its three tiles). The backward pass forms the cheap
+    passes around the kernel again (norms, projections, rotation,
+    convolutions, gates: the kernel's operands, which the weights' gradients
+    need anyway) and the forward kernel, whose outputs are all kept, is not
+    in the recomputation: no Mosaic forward kernel runs twice. Where no such
+    name is in the trace (the reference bodies; a mixer with no kernel) it
+    is the plain ``jax.checkpoint``."""
+    return jax.checkpoint(
+        mixer, policy=jax.checkpoint_policies.save_only_these_names(
+            _FLASH_KEPT, _KDA_KEPT))
 
 
 def gated_ffn(x, w_gate, w_up, w_down, matmul=jnp.matmul):

@@ -48,13 +48,21 @@ feed-forward, the others the experts.
   loss are then over the slice.
 
 **Recomputation.** Every KDA mixer is under ``jax.checkpoint``: the backward
-pass keeps its input and forms the projections, convolutions, gates and the
-chunked scan again. It is the least that lets one sequence of 8192 positions
-fit a v5e beside 6.7 GiB of parameters and Adam state: 14.95 GiB by the
-compiler's account, where nothing recomputed is 20.8 and the scan alone
-15.1 with 0.7 to spare (PERF.md section 6, PR 30). The MLA mixer and the
-feed-forwards keep what they computed; the experts' rows are formed again
-by ``moe.dropless_moe_ffn`` itself. No option chooses any of it.
+pass keeps its input and forms the projections, convolutions and gates
+again. The checkpoint (``blocks.recomputed``) also keeps what the delta
+rule's forward kernel hands its backward one, by the name the kernel's
+forward rule gives those arrays: the output, the state a unit starts from
+and three tiles a unit and head, 0.5 GiB a layer (``ops/pallas/kda.py``).
+With every output of ``kda_fwd`` kept, the backward pass does not run it
+again: one call a layer in the step, not two (PERF.md section 6, PR 42).
+Recomputing the mixers is the least that lets one sequence of 8192
+positions fit a v5e beside 6.7 GiB of parameters and Adam state: nothing
+recomputed was 20.8 GiB by the compiler's account and the scan alone 15.1
+(PERF.md section 6, PR 30, when the rule was a ``jax.numpy`` body); the
+step is 13.28 GiB now, 10.39 with the forward kernel run again. The MLA
+mixer and the feed-forwards keep what they computed; the experts' rows are
+formed again by ``moe.dropless_moe_ffn`` itself. No option chooses any of
+it.
 
 Built like ``models/olmoe.py``: float32 master parameters, ``cfg.dtype``
 (bfloat16) activations and matmul operands, one jitted step
@@ -265,7 +273,14 @@ def _kda(lp, x, cfg, mesh=None):
         gate_in = (x @ lp["g_a"].astype(dt)) @ lp["g_b"].astype(dt)
         beta_in = x @ lp["beta_w"].astype(dt)
         with jax.named_scope("kda_gate"):
-            g = -jnp.exp(lp["A_log"])[:, None] * heads(jax.nn.softplus(
+            # the decay too stays [B, S, H d] up to the rule's view a head:
+            # multiplied a head in the four-dimensional view, the compiler
+            # gave the recomputed decay and its gradient positions-minor
+            # layouts and copied both, float32, for ``kda_bwd`` (2.6 ms a
+            # step; PERF.md section 6, PR 42)
+            rate = jnp.broadcast_to(-jnp.exp(lp["A_log"])[:, None],
+                                    (n, d)).reshape(-1)
+            g = heads(rate * jax.nn.softplus(
                 decay_in.astype(jnp.float32) + lp["dt_bias"]))
             beta = jax.nn.sigmoid(beta_in.astype(jnp.float32))
         with jax.named_scope("kda_core"):
@@ -308,15 +323,16 @@ def _feed_forward(lp, x, cfg, mesh=None):
 def _block(lp, x, cfg, kind, mesh=None):
     """One layer: (the stream after the mixer, after the feed-forward, the
     expert layer's aux terms or None). A KDA mixer is recomputed in the
-    backward pass from its input (the module docstring says why); the
-    experts recompute their own part (``moe.dropless_moe_ffn``); nothing
-    else is."""
+    backward pass from its input, but for the delta rule's forward kernel,
+    whose outputs it keeps (the module docstring says why); the experts
+    recompute their own part (``moe.dropless_moe_ffn``); nothing else
+    is."""
     def mix(lp, x):
         normed = blocks.rms_norm(x, lp["ln1_g"], cfg.rms_eps)
         return x + (_kda(lp, normed, cfg, mesh) if kind == "kda"
                     else _mla(lp, normed, cfg, mesh))
 
-    h = (jax.checkpoint(mix) if kind == "kda" else mix)(lp, x)
+    h = (blocks.recomputed(mix) if kind == "kda" else mix)(lp, x)
     m, aux = _feed_forward(lp, blocks.rms_norm(h, lp["ln2_g"], cfg.rms_eps),
                            cfg, mesh)
     return h, h + m, aux

@@ -37,15 +37,32 @@ What a layer is comes from the configuration's lists, a layer an entry
 
 **Recomputation.** Every mixer and the dense feed-forward are under
 ``jax.checkpoint``: the backward pass keeps their inputs and forms the
-projections, rotary positions, attention, gate and the feed-forward's three
-products again. It is the least, of the subsets tried, that lets one
-sequence of 16 384 positions fit a v5e beside 7.73 GiB of parameters and
-Adam state: 12.03 GiB by the compiler's account (11.92 at 8192 rows a pass
-of the held experts, as the subsets were compiled), where nothing recomputed
-is 16.05 and every proper subset (the sliding mixers, the full ones, the dense
-feed-forward, any two of them, all three with the flash calls' outputs kept)
-stays between 15.88 and 16.55 of the 15.75 a program may take (PERF.md
-section 6, PR 33, has the table). The experts' rows are formed again by
+projections, rotary positions, gate and the feed-forward's three products
+again. A mixer's checkpoint (``blocks.recomputed``) keeps two arrays more,
+by the name its flash call's forward rule gives them: the context ``o`` and
+the logsumexp, which only the kernel can make (192 | 256 MiB and 3 | 4 MiB a
+full | sliding layer, 1.15 GiB over the five). With every output of the
+forward kernel kept, the backward pass does not run it again: ``flash_fwd``
+and ``flash_fwd_window`` are one call a layer in the step, not two, 65.8 ms
+of a 704.6 ms step (PERF.md section 6, PR 42); q, k and v, which the
+weights' gradients need in any case, are still formed again. Full
+recomputation is the least, of the subsets tried, that lets one sequence of
+16 384 positions fit a v5e beside 7.73 GiB of parameters and Adam state:
+nothing recomputed is 16.05 GiB by the compiler's account and every proper
+subset (the sliding mixers, the full ones, the dense feed-forward, any two
+of them) between 15.88 and 16.55 of the 15.75 a program may take (PERF.md
+section 6, PR 33, has the table). That account is not a sum of live bytes:
+with the mixers' outputs kept it reads 15.88 GiB (12.04 without; PR 33 read
+the same 15.88 for "the flash calls' outputs kept"), of which 1.15 are the
+kept arrays. The rest is the order of the step. XLA's memory scheduler
+takes the cheapest of three orders by an estimate of its own; without the
+kept arrays the list order wins by 0.12 GiB (13.90 against 14.02, and 11.89
+once buffers are assigned), with them the depth-first order does (14.38
+against 15.04), which updates ``head_w`` after the backward pass and so
+holds 0.77 GiB of logits through it, and packs with 1.03 GiB of holes that
+the account counts twice. The compiler's own total is 15.18 GiB, and the
+step runs (PERF.md section 6, PR 42, has the numbers and what
+would free them). The experts' rows are formed again by
 ``moe.dropless_moe_ffn`` itself; the router and the shared expert keep what
 they computed. No option chooses any of it.
 
@@ -262,7 +279,8 @@ def _feed_forward(lp, x, cfg, mesh=None):
 def _block(lp, x, cfg, kind, angles, mesh=None):
     """One layer: (the stream after the mixer, after the feed-forward, the
     expert layer's aux terms or None). The mixer and the dense feed-forward
-    are recomputed in the backward pass from their inputs (the module
+    are recomputed in the backward pass from their inputs, the mixer but for
+    its flash call's forward kernel, whose outputs it keeps (the module
     docstring says why); the experts recompute their own part."""
     def mix(lp, x):
         normed = blocks.rms_norm(x, lp["ln1_g"], cfg.rms_eps)
@@ -272,7 +290,7 @@ def _block(lp, x, cfg, kind, angles, mesh=None):
         return _feed_forward(lp, blocks.rms_norm(h, lp["ln2_g"], cfg.rms_eps),
                              cfg, mesh)
 
-    h = jax.checkpoint(mix)(lp, x)
+    h = blocks.recomputed(mix)(lp, x)
     m, aux = (jax.checkpoint(feed) if "ffn_gate" in lp else feed)(lp, h)
     return h, h + m, aux
 

@@ -53,7 +53,12 @@ Adam state: 14.09 GiB of the 15.75 a program may take by the compiler's
 account (15.04 before the passes around the rule were kernels, PR 39), where the compiler refuses the program with nothing recomputed, or
 the attention mixer alone, at 18.2 GiB and more (PR 38's tree); with the
 attention mixer recomputed as well it was 14.75 GiB there and a step 5%
-longer (PERF.md section 6, PR 38). The attention mixer keeps what it computed; the experts' rows are
+longer (PERF.md section 6, PR 38). The checkpoint is the plain one, which
+runs ``gdn_fwd`` again in the backward pass, and not ``blocks.recomputed``:
+what that kernel hands its backward is 0.62 GiB a layer (the state a unit
+starts from and the inverse, 537 MB, beside 128 MiB of ``o``), 1.9 GiB on
+13.73, and Kimi Linear's step grew by 0.9 GiB more than it kept (PERF.md
+section 6, PR 42). The attention mixer keeps what it computed; the experts' rows are
 formed again by ``moe.dropless_moe_ffn`` itself; the router and the shared
 expert keep what they computed. No option chooses any of it.
 

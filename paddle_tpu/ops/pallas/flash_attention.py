@@ -68,13 +68,22 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from paddle_tpu.ops.pallas import registry as _registry
 from paddle_tpu.ops.pallas.registry import vmem_spec as _vmem_spec
 
-__all__ = ["flash_attention", "tiles_visited_pct"]
+__all__ = ["flash_attention", "tiles_visited_pct", "KEPT"]
+
+#: the ``jax.ad_checkpoint.checkpoint_name`` of what a forward call hands its
+#: backward that only the kernel makes: the context and the logsumexp. A
+#: ``jax.checkpoint`` whose policy saves this name keeps the two and does not
+#: run the forward kernel again (``models/blocks.recomputed``); q, k, v and
+#: the bias are not named, so they are formed again from the checkpoint's
+#: inputs. Under any other checkpoint, or none, the name does nothing.
+KEPT = "flash_attention_kept"
 
 _NEG_INF = -1e30
 
@@ -450,8 +459,14 @@ def _flash_attention(operands, bias, sm_scale, causal, block_q, block_k,
 
 def _flash_attention_fwd(operands, bias, sm_scale, causal, block_q, block_k,
                          interpret, window=None, heads=None):
-    o, lse = _flash_fwd(operands, bias, sm_scale, causal, block_q, block_k,
-                        interpret, window, heads)
+    """The forward rule: (o, what the backward reads). o and lse carry the
+    name ``KEPT``, here on the residuals themselves, because lse never
+    leaves the rule: a caller's ``jax.checkpoint`` can keep the pair by a
+    policy over that name, and its recomputation then has no use for the
+    forward call (the operands and the bias it forms again)."""
+    o, lse = (checkpoint_name(t, KEPT) for t in _flash_fwd(
+        operands, bias, sm_scale, causal, block_q, block_k, interpret,
+        window, heads))
     return o, (operands, bias, o, lse)
 
 

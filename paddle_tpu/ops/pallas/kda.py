@@ -79,10 +79,15 @@ them. It keeps the state a unit starts from and the inverse, 537 MB a layer at
 ``[1, 16384, 32, 128]``, and forms the scores again. A chunk is ``CHUNK_HEAD``
 positions: the levels of the inverse are what a larger chunk costs.
 
-**Kept for the backward**, a unit and head: the state the unit starts from,
-and three ``[128, 128]`` tiles: ``a_qk``, ``P_kk`` and the inverse. 402 MB a
-layer at [1, 8192, 32, 128] (a state a chunk alone would be 537), transient
-under the mixer's ``jax.checkpoint``. The backward kernel runs the same
+**Kept for the backward**, a unit and head: the state the unit starts from
+(float32), and three ``[128, 128]`` tiles: ``a_qk`` (the inputs' dtype),
+``P_kk`` and the inverse (float32). 470 MB a layer at [1, 8192, 32, 128] (a
+state a chunk alone would be 537). ``kda_fwd``'s forward rule names them
+and the output (``KEPT``), so a caller's ``jax.checkpoint`` can keep them
+by a policy and leave the forward kernel out of its recomputation, as Kimi
+Linear's mixers do (``models/blocks.recomputed``); what ``gdn_fwd`` keeps
+has no name and is transient under Qwen3-Next's plain ``jax.checkpoint``.
+The backward kernel runs the same
 grid backwards with the state's gradient in the scratch: it forms ``G``, the
 levels' operands and ``[u0 | w]`` again, replays the unit's chunks for the
 states inside it, walks them backwards, and takes all five gradients.
@@ -93,6 +98,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -100,7 +106,15 @@ from paddle_tpu.ops import kda as _reference
 from paddle_tpu.ops.pallas import registry as _registry
 from paddle_tpu.ops.pallas.registry import vmem_spec as _vmem_spec
 
-__all__ = []
+__all__ = ["KEPT"]
+
+#: the ``jax.ad_checkpoint.checkpoint_name`` of what ``kda_fwd`` hands
+#: ``kda_bwd`` that only the kernel makes: the output and the four arrays
+#: kept a unit and head. As ``flash_attention.KEPT``: a ``jax.checkpoint``
+#: whose policy saves the name (``models/blocks.recomputed``) does not run
+#: the forward kernel again. ``gdn_fwd``'s are not named: what it keeps does
+#: not fit beside the step that calls it (``models/qwen3_next.py``).
+KEPT = "kda_kept"
 
 #: positions a grid step, a *unit*: their chunks share one [128, 128] tile of
 #: scores and inverse
@@ -721,9 +735,10 @@ def _kda(q, k, v, g, beta_rows, chunk, interpret):
 
 
 def _kda_vjp_fwd(q, k, v, g, beta_rows, chunk, interpret):
-    # one trace for the pass and for the recomputed mixer's JVP
-    o, *kept = _registry.traced_once(_kda_fwd, q, k, v, g, beta_rows, chunk,
-                                     interpret)
+    # one trace for the pass and for the recomputed mixer's JVP. What only
+    # the kernel makes carries the name KEPT; the operands do not.
+    o, *kept = (checkpoint_name(t, KEPT) for t in _registry.traced_once(
+        _kda_fwd, q, k, v, g, beta_rows, chunk, interpret))
     return o, (q, k, v, g, beta_rows, *kept)
 
 

@@ -11,6 +11,7 @@ scoring rule and its share of the experts against the uncut layer, and the
 counter the benchmark reads.
 """
 
+import collections
 import dataclasses
 import importlib
 import importlib.util
@@ -394,6 +395,104 @@ def test_the_train_step_moves_the_selection_bias_against_the_load(tiny):
     np.testing.assert_allclose(
         np.asarray(moe.bias_step(jnp.zeros(3), jnp.asarray([5, 1, 3]), 0.5)),
         [-0.5, 0.5, 0.0])
+
+
+# ---------------------------------------------------------------------------
+# recomputation: a KDA mixer keeps what ``kda_fwd`` hands ``kda_bwd``, by name
+# ---------------------------------------------------------------------------
+def equations(jaxpr, found=None):
+    """How often each primitive stands in ``jaxpr`` and the jaxprs inside
+    it; a ``pallas_call`` under its kernel's name."""
+    found = collections.Counter() if found is None else found
+    for eqn in jaxpr.eqns:
+        pallas = eqn.primitive.name == "pallas_call"
+        found[eqn.params["name"] if pallas else eqn.primitive.name] += 1
+        for inner in jax.core.jaxprs_in_params(eqn.params):
+            equations(inner, found)
+    return found
+
+
+@pytest.fixture(scope="module")
+def lane_tile_heads():
+    """Three KDA layers and an MLA one (the dense layer first), heads of 128
+    channels so that the delta rule takes its kernels, 256 positions."""
+    cfg = kl.kimi_linear_tiny(
+        num_layers=4, kda_layers=(1, 2, 3), full_attn_layers=(4,),
+        kda_heads=2, kda_head_dim=128, experts_held=(4, 4),
+        dtype=jnp.float32)
+    return (cfg,) + seeded(cfg, rows=1, seq=256)
+
+
+def plain_checkpoint(monkeypatch):
+    """The mixers as they were: everything formed again."""
+    from paddle_tpu.models import blocks
+    monkeypatch.setattr(blocks, "recomputed", jax.checkpoint)
+
+
+@pytest.mark.parametrize("mixers", ["kept_by_name", "plain_checkpoint"])
+def test_a_gradient_holds_the_rule_s_forward_once_a_kda_layer(
+        lane_tile_heads, mixers, monkeypatch):
+    """Under ``blocks.recomputed`` the output and the four arrays ``kda_fwd``
+    keeps a unit and head are residuals of the mixer's checkpoint, so the
+    recomputation has no use for the kernel: the gradient's jaxpr holds it
+    once a KDA layer, where the plain ``jax.checkpoint`` held it twice (20.4
+    ms of the cell's 310.0 ms step: PERF.md section 6, PR 42). The passes
+    around the rule are formed again either way; every backward kernel is
+    once a layer."""
+    from paddle_tpu.ops import pallas as plk
+    cfg, params, batch = lane_tile_heads
+    if mixers == "plain_checkpoint":
+        plain_checkpoint(monkeypatch)
+    with plk.override("on"):
+        found = equations(jax.make_jaxpr(jax.grad(
+            lambda p: kl.lm_loss(p, cfg, batch)))(params).jaxpr)
+    assert {k: found[k] for k in ("kda_fwd", "kda_bwd", "conv_norm_fwd",
+                                  "conv_norm_bwd", "gated_norm_fwd",
+                                  "gated_norm_bwd")} \
+        == {"kda_fwd": 3 if mixers == "kept_by_name" else 6, "kda_bwd": 3,
+            "conv_norm_fwd": 18, "conv_norm_bwd": 9,
+            "gated_norm_fwd": 6, "gated_norm_bwd": 3}
+
+
+def test_keeping_the_rule_s_outputs_changes_no_bit_of_a_gradient(
+        lane_tile_heads, monkeypatch):
+    """The kept arrays are the ones the second forward would have made:
+    loss and every gradient leaf equal those of the plain ``jax.checkpoint``
+    bit for bit (the Pallas bodies in interpreter mode)."""
+    from paddle_tpu.ops import pallas as plk
+    cfg, params, batch = lane_tile_heads
+
+    def loss_and_grads():
+        with plk.override("on"):
+            return jax.jit(jax.value_and_grad(
+                lambda p: kl.lm_loss(p, cfg, batch)))(params)
+
+    kept = loss_and_grads()
+    plain_checkpoint(monkeypatch)
+    plain = loss_and_grads()
+    assert all(jax.tree.leaves(jax.tree.map(
+        lambda a, b: bool(jnp.array_equal(a, b)), kept, plain)))
+    assert float(kept[0]) > 0 and all(
+        float(jnp.abs(lp[name]).max()) > 0 for lp in kept[1]["layers"][:3]
+        for name in ("q_w", "k_w", "v_w", "A_log", "beta_w", "o_w", "ln1_g"))
+
+
+def test_without_a_kernel_in_the_trace_the_helper_is_the_plain_checkpoint(
+        lane_tile_heads, monkeypatch):
+    """The reference bodies (the CPU's selection) name nothing, so the
+    policy has nothing to keep: the gradient's jaxpr holds equation for
+    equation what the plain ``jax.checkpoint`` gives, and no name."""
+    cfg, params, batch = lane_tile_heads
+
+    def found():
+        return equations(jax.make_jaxpr(jax.grad(
+            lambda p: kl.lm_loss(p, cfg, batch)))(params).jaxpr)
+
+    kept = found()
+    plain_checkpoint(monkeypatch)
+    assert kept == found()
+    assert "name" not in kept and "pallas_call" not in kept
+    assert kept["remat2"] >= 3       # the KDA mixers (and the scan's own)
 
 
 # ---------------------------------------------------------------------------

@@ -11,6 +11,7 @@ the expert layer's share of the experts against the uncut layer, and the
 counters the benchmark reads.
 """
 
+import collections
 import dataclasses
 import importlib.util
 import math
@@ -362,6 +363,101 @@ def test_a_step_traces_a_flash_call_once_a_layer_type(tiny, layers,
     # in both layer types (``blocks.FLASH_FROM``): a full layer and, from the
     # second layer on, a sliding one: one trace each whatever the depth
     assert sorted(traced, key=str) == [512, None]
+
+
+# ---------------------------------------------------------------------------
+# recomputation: a mixer keeps its flash call's o and lse, by name
+# ---------------------------------------------------------------------------
+def equations(jaxpr, found=None):
+    """How often each primitive stands in ``jaxpr`` and the jaxprs inside
+    it; a ``pallas_call`` under its kernel's name."""
+    found = collections.Counter() if found is None else found
+    for eqn in jaxpr.eqns:
+        pallas = eqn.primitive.name == "pallas_call"
+        found[eqn.params["name"] if pallas else eqn.primitive.name] += 1
+        for inner in jax.core.jaxprs_in_params(eqn.params):
+            equations(inner, found)
+    return found
+
+
+@pytest.fixture(scope="module")
+def both_kinds(tiny):
+    """Two full layers and three sliding ones as the cell has them, at 1024
+    positions and a window of 512 so that ``auto`` takes the kernels in
+    both; every parameter has a gradient."""
+    cfg = dataclasses.replace(tiny, num_layers=5, window=512)
+    return (cfg,) + seeded(cfg, rows=1, seq=1024)
+
+
+def plain_checkpoint(monkeypatch):
+    """The mixers as they were: everything formed again."""
+    monkeypatch.setattr(blocks, "recomputed", jax.checkpoint)
+
+
+@pytest.mark.parametrize("mixers", ["kept_by_name", "plain_checkpoint"])
+def test_a_gradient_holds_a_flash_forward_once_a_layer(both_kinds, mixers,
+                                                       monkeypatch):
+    """Under ``blocks.recomputed`` the recomputation has no use for the
+    forward kernel, whose o and lse are residuals of the checkpoint: the
+    gradient's jaxpr holds ``flash_fwd`` once a full layer and
+    ``flash_fwd_window`` once a sliding one, where the plain
+    ``jax.checkpoint`` held each twice (53.8 + 17.2 ms of the cell's 701.8 ms
+    step: PERF.md section 6, PR 42). The backward kernels are once a layer
+    either way."""
+    from paddle_tpu.ops import pallas as plk
+    cfg, params, batch = both_kinds
+    if mixers == "plain_checkpoint":
+        plain_checkpoint(monkeypatch)
+    with plk.override("on"):
+        found = equations(jax.make_jaxpr(jax.grad(
+            lambda p: lg.lm_loss(p, cfg, batch)))(params).jaxpr)
+    forwards = 1 if mixers == "kept_by_name" else 2
+    assert cfg.layer_types[:cfg.num_layers].count(lg.FULL) == 2
+    assert {k: found[k] for k in ("flash_fwd", "flash_bwd",
+                                  "flash_fwd_window", "flash_bwd_window")} \
+        == {"flash_fwd": 2 * forwards, "flash_bwd": 2,
+            "flash_fwd_window": 3 * forwards, "flash_bwd_window": 3}
+
+
+def test_keeping_the_flash_outputs_changes_no_bit_of_a_gradient(both_kinds,
+                                                                monkeypatch):
+    """The kept arrays are the ones the second forward would have made:
+    loss and every gradient leaf equal those of the plain ``jax.checkpoint``
+    bit for bit (the Pallas bodies in interpreter mode)."""
+    from paddle_tpu.ops import pallas as plk
+    cfg, params, batch = both_kinds
+
+    def loss_and_grads():
+        with plk.override("on"):
+            return jax.jit(jax.value_and_grad(
+                lambda p: lg.lm_loss(p, cfg, batch)))(params)
+
+    kept = loss_and_grads()
+    plain_checkpoint(monkeypatch)
+    plain = loss_and_grads()
+    assert all(jax.tree.leaves(jax.tree.map(
+        lambda a, b: bool(jnp.array_equal(a, b)), kept, plain)))
+    assert float(kept[0]) > 0 and all(
+        float(jnp.abs(lp[name]).max()) > 0 for lp in kept[1]["layers"]
+        for name in ("q_w", "k_w", "v_w", "g_w", "o_w", "ln1_g"))
+
+
+def test_without_a_kernel_in_the_trace_the_helper_is_the_plain_checkpoint(
+        both_kinds, monkeypatch):
+    """The reference bodies (the CPU's selection) name nothing, so the
+    policy has nothing to keep: the gradient's jaxpr holds equation for
+    equation what the plain ``jax.checkpoint`` gives, and no name."""
+    cfg, params, batch = both_kinds
+
+    def found():
+        return equations(jax.make_jaxpr(jax.grad(
+            lambda p: lg.lm_loss(p, cfg, batch)))(params).jaxpr)
+
+    kept = found()
+    plain_checkpoint(monkeypatch)
+    assert kept == found()
+    assert "name" not in kept and "pallas_call" not in kept
+    assert kept["remat2"] == 5 + 1          # the mixers, the dense layer
 
 
 # ---------------------------------------------------------------------------
