@@ -3,15 +3,15 @@ rotary positions, causal attention and the gated feed-forward. (A delta-rule
 mixer's short convolution and L2 norm are ``ops/pallas/delta_glue.py``'s
 since the two became one kernel.)
 
-``models/olmoe.py``, ``models/kimi_linear.py``, ``models/laguna.py`` and
-``models/qwen3_next.py`` are built from them.
-``models/bert.py`` and
+``models/olmoe.py``, ``models/kimi_linear.py``, ``models/laguna.py``,
+``models/qwen3_next.py`` and ``models/lfm2.py`` are built from them, around
+the one decoder skeleton of ``models/lm_trainer.py``. ``models/bert.py`` and
 ``models/transformer.py`` carry their own layer norm and attention and are
-not moved here yet (ROADMAP C10: their cells repeat to 0.004%, so a change
-to their HLO is a PR judged on its own). Every piece enters the named scope
-a profile of the step is read by (``layer_norm``, ``rope``,
-``attention_core`` and, inside it, ``attention_window`` where a call has a
-window; the callers enter ``attention`` and ``ffn``).
+not moved here yet (ROADMAP C, "one trainer shape": their cells repeat to
+0.004%, so a change to their HLO is a PR judged on its own). Every piece
+enters the named scope a profile of the step is read by (``layer_norm``,
+``rope``, ``attention_core`` and, inside it, ``attention_window`` where a
+call has a window; the callers enter ``attention`` and ``ffn``).
 """
 
 import contextlib

@@ -76,13 +76,11 @@ import math
 
 import jax
 import jax.numpy as jnp
-import numpy as np
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import PartitionSpec as P
 
 from paddle_tpu.models import blocks, lm_trainer
-from paddle_tpu.ops.pallas.registry import mesh_scope
 from paddle_tpu.parallel import moe
-from paddle_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
+from paddle_tpu.parallel.mesh import MODEL_AXIS
 
 __all__ = ["RotaryLaw", "LagunaConfig", "laguna_xs2", "laguna_tiny",
            "init_params", "param_specs", "forward", "stages", "lm_loss",
@@ -266,131 +264,41 @@ def _attention(lp, x, cfg, kind, angles, mesh=None):
     return ctx.reshape(b, s, -1) @ lp["o_w"].astype(dt)
 
 
-def _feed_forward(lp, x, cfg, mesh=None):
-    with jax.named_scope("ffn"):
-        if "ffn_gate" in lp:
-            return blocks.gated_ffn(x, lp["ffn_gate"], lp["ffn_up"],
-                                    lp["ffn_down"]), None
-        return moe.dropless_moe_ffn(lp, x, cfg.experts_per_token, mesh=mesh,
-                                    scoring=cfg.scoring,
-                                    held=cfg.experts_held)
-
-
-def _block(lp, x, cfg, kind, angles, mesh=None):
+def _block(lp, x, cfg, layer, angles, mesh=None):
     """One layer: (the stream after the mixer, after the feed-forward, the
-    expert layer's aux terms or None). The mixer and the dense feed-forward
-    are recomputed in the backward pass from their inputs, the mixer but for
-    its flash call's forward kernel, whose outputs it keeps (the module
-    docstring says why); the experts recompute their own part."""
+    expert layer's aux terms or None); ``angles`` holds the rotary table of
+    each layer kind. The mixer and the dense feed-forward are recomputed in
+    the backward pass from their inputs, the mixer but for its flash call's
+    forward kernel, whose outputs it keeps (the module docstring says why);
+    the experts recompute their own part."""
+    kind = cfg.layer_types[layer]
+
     def mix(lp, x):
         normed = blocks.rms_norm(x, lp["ln1_g"], cfg.rms_eps)
-        return x + _attention(lp, normed, cfg, kind, angles, mesh)
+        return x + _attention(lp, normed, cfg, kind, angles[kind], mesh)
 
     def feed(lp, h):
-        return _feed_forward(lp, blocks.rms_norm(h, lp["ln2_g"], cfg.rms_eps),
-                             cfg, mesh)
+        return lm_trainer.feed_forward(
+            lp, blocks.rms_norm(h, lp["ln2_g"], cfg.rms_eps), cfg, mesh)
 
     h = blocks.recomputed(mix)(lp, x)
     m, aux = (jax.checkpoint(feed) if "ffn_gate" in lp else feed)(lp, h)
     return h, h + m, aux
 
 
-def _shard_act(x, mesh):
-    if mesh is None or mesh.shape.get(DATA_AXIS, 1) == 1:
-        return x
-    return jax.lax.with_sharding_constraint(
-        x, NamedSharding(mesh, P(DATA_AXIS, None, None)))
+def _angles(cfg, positions):
+    """The rotary table of each layer kind the model has."""
+    return {kind: cfg.rope(kind).angles(positions, cfg.head_dim)
+            for kind in dict.fromkeys(cfg.layer_types[:cfg.num_layers])}
 
 
-def _hidden_and_aux(params, cfg, input_ids, mesh=None):
-    """(final normed hidden states [B, S, H], the expert layers' aux terms
-    stacked over those layers, the residual stream after the embedding and
-    after every mixer and feed-forward, a list of 2 layers + 1)."""
-    with jax.named_scope("embed"):
-        x = jnp.take(params["embed"], input_ids, axis=0).astype(cfg.dtype)
-    x = _shard_act(x, mesh)
-    s = input_ids.shape[1]
-    angles = {kind: cfg.rope(kind).angles(s, cfg.head_dim)
-              for kind in dict.fromkeys(cfg.layer_types[:cfg.num_layers])}
-    auxes, stream = [], [x]
-    for layer, lp in enumerate(params["layers"]):
-        kind = cfg.layer_types[layer]
-        h, x, aux = _block(lp, x, cfg, kind, angles[kind], mesh)
-        x = _shard_act(x, mesh)
-        stream += [h, x]
-        if aux is not None:
-            auxes.append(aux)
-    hidden = blocks.rms_norm(x, params["final_norm_g"], cfg.rms_eps)
-    return hidden, jax.tree.map(lambda *a: jnp.stack(a), *auxes), stream
-
-
-def forward(params, cfg, input_ids, mesh=None):
-    """Decoder forward; returns the final normed hidden states [B, S, H]
-    in cfg.dtype (the head is applied in ``lm_loss``)."""
-    return _hidden_and_aux(params, cfg, input_ids, mesh)[0]
-
-
-def stages(params, cfg, input_ids, mesh=None):
-    """(what every part of the forward pass hands on, [2 layers + 2, B, S, H]
-    in cfg.dtype: the embedding, the residual stream after each layer's
-    mixer and after its feed-forward, and last the final normed hidden
-    states; the expert layers' aux terms of that same pass, stacked over
-    those layers: ``counts`` [layers, E], ``choice`` [layers, T, k]). As
-    ``kimi_linear.stages``, and for its reason."""
-    hidden, aux, stream = _hidden_and_aux(params, cfg, input_ids, mesh)
-    return jnp.stack(stream + [hidden]), aux
-
-
-def _loss_and_counts(params, cfg, batch, mesh=None):
-    """(``lm_loss``, the assignments each expert took [expert layers, E])."""
-    from paddle_tpu.ops import pallas as _pk
-    hidden, aux, _ = _hidden_and_aux(params, cfg, batch["input_ids"], mesh)
-    with jax.named_scope("loss"), mesh_scope(mesh):
-        logits = jnp.dot(hidden, params["head_w"].astype(hidden.dtype),
-                         preferred_element_type=jnp.float32)
-        return (jnp.mean(_pk.softmax_cross_entropy(logits, batch["labels"])),
-                aux["counts"])
-
-
-def lm_loss(params, cfg, batch, mesh=None):
-    """Mean next-token cross-entropy over every position of
-    dict(input_ids, labels) [B, S], over ``cfg.vocab_size`` ids. Logits and
-    loss in float32."""
-    return _loss_and_counts(params, cfg, batch, mesh)[0]
-
-
-def routing_stats(params, cfg, batch, mesh=None, choices=False):
-    """Assignments per expert of a batch over all ``num_experts``, [expert
-    layers, experts] on the host, as ``kimi_linear.routing_stats``."""
-    aux = jax.jit(lambda p, ids: _hidden_and_aux(p, cfg, ids, mesh)[1])(
-        params, batch["input_ids"])
-    counts = np.asarray(aux["counts"])
-    return (counts, np.asarray(aux["choice"])) if choices else counts
-
-
-# ---------------------------------------------------------------------------
-# train step
-# ---------------------------------------------------------------------------
-def make_train_step(cfg, optimizer, mesh=None):
-    """(init_fn, step_fn) of ``lm_trainer.make_train_step`` for this
-    model: step(params, opt_state, batch) -> (loss, params, opt_state).
-    After the optimizer's update every router's selection bias takes one
-    step of ``moe.bias_step`` on the load of this batch."""
-    def move_biases(params, counts):
-        routers = iter(counts)
-        layers = [dict(lp, router_bias=moe.bias_step(
-            lp["router_bias"], next(routers), cfg.bias_rate))
-            if "router_bias" in lp else lp for lp in params["layers"]]
-        return dict(params, layers=layers)
-
-    return lm_trainer.make_train_step(cfg, optimizer, mesh, init_params,
-                                      param_specs, _loss_and_counts,
-                                      after_update=move_biases)
-
-
-def synthetic_batch(cfg, batch_size, seq_len, seed=0):
-    """Random next-token batch: ``seq_len + 1`` uniform ids a row, inputs
-    the first ``seq_len``, labels the last."""
-    ids = np.random.RandomState(seed).randint(
-        0, cfg.vocab_size, (batch_size, seq_len + 1), dtype=np.int32)
-    return {"input_ids": ids[:, :-1], "labels": ids[:, 1:]}
+# everything around the block is the skeleton's (``lm_trainer.Decoder``)
+DECODER = lm_trainer.Decoder(init_params=init_params,
+                             param_specs=param_specs, block=_block,
+                             rotary=_angles)
+forward = DECODER.forward
+stages = DECODER.stages
+lm_loss = DECODER.lm_loss
+routing_stats = DECODER.routing_stats
+make_train_step = DECODER.make_train_step
+synthetic_batch = lm_trainer.synthetic_batch

@@ -20,20 +20,18 @@ Built like ``models/bert.py``: float32 master parameters, ``cfg.dtype``
 (bfloat16) activations and matmul operands, one jitted step = forward +
 backward + update, the mesh's ``data`` axis splits the batch and its
 ``model`` axis the attention projections and the vocabulary; the experts are
-replicated (sharding them is ROADMAP B4).
+replicated (sharding them is ROADMAP B, "experts over a mesh").
 """
 
 import dataclasses
 
 import jax
 import jax.numpy as jnp
-import numpy as np
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import PartitionSpec as P
 
 from paddle_tpu.models import blocks, lm_trainer
-from paddle_tpu.ops.pallas.registry import mesh_scope
 from paddle_tpu.parallel import moe
-from paddle_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
+from paddle_tpu.parallel.mesh import MODEL_AXIS
 
 __all__ = ["OlmoeConfig", "olmoe_1b_7b", "olmoe_tiny", "init_params",
            "param_specs", "forward", "lm_loss", "routing_stats",
@@ -144,87 +142,44 @@ def _attention(lp, x, rope, cfg, mesh=None):
     return ctx.reshape(b, s, -1) @ lp["o_w"].astype(dt)
 
 
-def _block(lp, x, rope, cfg, mesh=None):
+def _block(lp, x, cfg, layer, rope, mesh=None):
     h = x + _attention(lp, blocks.rms_norm(x, lp["ln1_g"], cfg.rms_eps),
                        rope, cfg, mesh)
     normed = blocks.rms_norm(h, lp["ln2_g"], cfg.rms_eps)
     with jax.named_scope("ffn"):
         m, aux = moe.dropless_moe_ffn(lp, normed, cfg.experts_per_token,
                                       mesh=mesh)
-    return h + m, aux
+    return h, h + m, aux
 
 
-def _shard_act(x, mesh):
-    if mesh is None or mesh.shape.get(DATA_AXIS, 1) == 1:
-        return x
-    return jax.lax.with_sharding_constraint(
-        x, NamedSharding(mesh, P(DATA_AXIS, None, None)))
-
-
-def _hidden_and_aux(params, cfg, input_ids, mesh=None):
-    """(final normed hidden states [B, S, H], the experts' aux terms
-    stacked over the layers)."""
-    with jax.named_scope("embed"):
-        x = jnp.take(params["embed"], input_ids, axis=0).astype(cfg.dtype)
-    x = _shard_act(x, mesh)
-    rope = blocks.rope_angles(input_ids.shape[1], cfg.head_dim,
-                              cfg.rope_theta)
-
-    auxes = []
-    for lp in params["layers"]:
-        x, aux = _block(lp, x, rope, cfg, mesh)
-        x = _shard_act(x, mesh)
-        auxes.append(aux)
-    hidden = blocks.rms_norm(x, params["final_norm_g"], cfg.rms_eps)
-    return hidden, jax.tree.map(lambda *a: jnp.stack(a), *auxes)
-
-
-def forward(params, cfg, input_ids, mesh=None):
-    """Decoder forward; returns the final normed hidden states [B, S, H]
-    in cfg.dtype (the head is applied in ``lm_loss``)."""
-    return _hidden_and_aux(params, cfg, input_ids, mesh)[0]
-
-
-def lm_loss(params, cfg, batch, mesh=None):
-    """Mean next-token cross-entropy over every position of
-    dict(input_ids, labels) [B, S], plus ``balance_weight`` times the
+def _add_aux(cfg, ce, aux):
+    """The loss: the cross-entropy plus ``balance_weight`` times the
     load-balancing loss and ``z_weight`` times the router z-loss (each the
-    mean over the layers). Logits and loss in float32."""
-    from paddle_tpu.ops import pallas as _pk
-    hidden, aux = _hidden_and_aux(params, cfg, batch["input_ids"], mesh)
-    with jax.named_scope("loss"), mesh_scope(mesh):
-        logits = jnp.dot(hidden, params["head_w"].astype(hidden.dtype),
-                         preferred_element_type=jnp.float32)
-        nll = _pk.softmax_cross_entropy(logits, batch["labels"])
-        return (jnp.mean(nll) + cfg.balance_weight * jnp.mean(aux["balance"])
-                + cfg.z_weight * jnp.mean(aux["z"]))
+    mean over the layers)."""
+    return (ce + cfg.balance_weight * jnp.mean(aux["balance"])
+            + cfg.z_weight * jnp.mean(aux["z"]))
 
 
-def routing_stats(params, cfg, batch, mesh=None, choices=False):
-    """Assignments per expert of a batch, [layers, experts] on the host:
-    each row sums to ``experts_per_token`` times the batch's tokens. The
-    counter a reader takes the experts' load from. With ``choices`` also
-    the experts of each token, [layers, tokens, experts_per_token]."""
-    aux = jax.jit(lambda p, ids: _hidden_and_aux(p, cfg, ids, mesh)[1])(
-        params, batch["input_ids"])
-    counts = np.asarray(aux["counts"])
-    return (counts, np.asarray(aux["choice"])) if choices else counts
+# everything around the block is the skeleton's (``lm_trainer.Decoder``)
+DECODER = lm_trainer.Decoder(
+    init_params=init_params, param_specs=param_specs, block=_block,
+    rotary=lambda cfg, positions: blocks.rope_angles(
+        positions, cfg.head_dim, cfg.rope_theta),
+    add_aux=_add_aux)
+forward = DECODER.forward
+lm_loss = DECODER.lm_loss
+routing_stats = DECODER.routing_stats
 
 
-# ---------------------------------------------------------------------------
-# train step
-# ---------------------------------------------------------------------------
 def make_train_step(cfg, optimizer, mesh=None):
     """(init_fn, step_fn) of ``lm_trainer.make_train_step`` for this
-    model: step(params, opt_state, batch) -> (loss, params, opt_state)."""
+    model: step(params, opt_state, batch) -> (loss, params, opt_state). No
+    router has a selection bias to move, and the step hands no counts out."""
     return lm_trainer.make_train_step(cfg, optimizer, mesh, init_params,
                                       param_specs, lm_loss)
 
 
 def synthetic_batch(cfg, batch_size, seq_len=None, seed=0):
-    """Random next-token batch: ``seq_len + 1`` uniform ids a row, inputs
-    the first ``seq_len``, labels the last."""
-    seq_len = seq_len or cfg.max_seq
-    ids = np.random.RandomState(seed).randint(
-        0, cfg.vocab_size, (batch_size, seq_len + 1), dtype=np.int32)
-    return {"input_ids": ids[:, :-1], "labels": ids[:, 1:]}
+    """``lm_trainer.synthetic_batch``; ``cfg.max_seq`` positions by default."""
+    return lm_trainer.synthetic_batch(cfg, batch_size,
+                                      seq_len or cfg.max_seq, seed)

@@ -73,15 +73,14 @@ import math
 
 import jax
 import jax.numpy as jnp
-import numpy as np
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import PartitionSpec as P
 
 from paddle_tpu.models import blocks, lm_trainer
 from paddle_tpu.ops import kda
 from paddle_tpu.ops.pallas import gated_head_norm, short_conv_norm
 from paddle_tpu.ops.pallas.registry import mesh_scope
 from paddle_tpu.parallel import moe
-from paddle_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
+from paddle_tpu.parallel.mesh import MODEL_AXIS
 
 __all__ = ["Qwen3NextConfig", "qwen3_next_80b_a3b", "qwen3_next_tiny",
            "init_params", "param_specs", "forward", "stages", "lm_loss",
@@ -289,11 +288,13 @@ def _gated_attention(lp, x, cfg, angles, mesh=None):
     return ctx.reshape(b, s, -1) @ lp["o_w"].astype(dt)
 
 
-def _block(lp, x, cfg, kind, angles, mesh=None):
+def _block(lp, x, cfg, layer, angles, mesh=None):
     """One layer: (the stream after the mixer, after the experts, the expert
     layer's aux terms). A Gated DeltaNet mixer is recomputed in the backward
     pass from its input (the module docstring says why); the experts
     recompute their own part; nothing else is."""
+    kind = cfg.mixer(layer)
+
     def mix(lp, x):
         normed = blocks.rms_norm(x, 1.0 + lp["ln1_w"], cfg.rms_eps)
         return x + (_gated_attention(lp, normed, cfg, angles, mesh)
@@ -309,98 +310,24 @@ def _block(lp, x, cfg, kind, angles, mesh=None):
     return h, h + m, aux
 
 
-def _shard_act(x, mesh):
-    if mesh is None or mesh.shape.get(DATA_AXIS, 1) == 1:
-        return x
-    return jax.lax.with_sharding_constraint(
-        x, NamedSharding(mesh, P(DATA_AXIS, None, None)))
+def _add_aux(cfg, ce, aux):
+    """The loss: the cross-entropy plus ``balance_weight`` times the
+    load-balancing term (the mean over the layers of ``moe.balance_loss``
+    over all the router's outputs)."""
+    return ce + cfg.balance_weight * jnp.mean(aux["balance"])
 
 
-def _hidden_and_aux(params, cfg, input_ids, mesh=None):
-    """(final normed hidden states [B, S, H], the layers' aux terms stacked
-    over the layers, the residual stream after the embedding and after every
-    mixer and expert layer, a list of 2 layers + 1)."""
-    with jax.named_scope("embed"):
-        x = jnp.take(params["embed"], input_ids, axis=0).astype(cfg.dtype)
-    x = _shard_act(x, mesh)
-    angles = blocks.rope_angles(
-        input_ids.shape[1], int(cfg.head_dim * cfg.rotary_factor),
-        cfg.rope_theta)
-    auxes, stream = [], [x]
-    for layer, lp in enumerate(params["layers"]):
-        h, x, aux = _block(lp, x, cfg, cfg.mixer(layer), angles, mesh)
-        x = _shard_act(x, mesh)
-        stream += [h, x]
-        auxes.append(aux)
-    hidden = blocks.rms_norm(x, 1.0 + params["final_norm_w"], cfg.rms_eps)
-    return hidden, jax.tree.map(lambda *a: jnp.stack(a), *auxes), stream
-
-
-def forward(params, cfg, input_ids, mesh=None):
-    """Decoder forward; returns the final normed hidden states [B, S, H]
-    in cfg.dtype (the head is applied in ``lm_loss``)."""
-    return _hidden_and_aux(params, cfg, input_ids, mesh)[0]
-
-
-def stages(params, cfg, input_ids, mesh=None):
-    """(what every part of the forward pass hands on, [2 layers + 2, B, S, H]
-    in cfg.dtype: the embedding, the residual stream after each layer's
-    mixer and after its experts, and last the final normed hidden states;
-    the layers' aux terms of that same pass, stacked over the layers:
-    ``counts`` [layers, E], ``choice`` [layers, T, k], ``balance``
-    [layers]). As ``kimi_linear.stages``, and for its reason."""
-    hidden, aux, stream = _hidden_and_aux(params, cfg, input_ids, mesh)
-    return jnp.stack(stream + [hidden]), aux
-
-
-def _loss_and_counts(params, cfg, batch, mesh=None):
-    """(``lm_loss``, the assignments each expert took [layers, E])."""
-    from paddle_tpu.ops import pallas as _pk
-    hidden, aux, _ = _hidden_and_aux(params, cfg, batch["input_ids"], mesh)
-    with jax.named_scope("loss"), mesh_scope(mesh):
-        logits = jnp.dot(hidden, params["head_w"].astype(hidden.dtype),
-                         preferred_element_type=jnp.float32)
-        nll = _pk.softmax_cross_entropy(logits, batch["labels"])
-        return (jnp.mean(nll)
-                + cfg.balance_weight * jnp.mean(aux["balance"]),
-                aux["counts"])
-
-
-def lm_loss(params, cfg, batch, mesh=None):
-    """Mean next-token cross-entropy over every position of
-    dict(input_ids, labels) [B, S], over ``cfg.vocab_size`` ids, plus
-    ``balance_weight`` times the load-balancing term (the mean over the
-    layers of ``moe.balance_loss`` over all the router's outputs). Logits
-    and loss in float32."""
-    return _loss_and_counts(params, cfg, batch, mesh)[0]
-
-
-def routing_stats(params, cfg, batch, mesh=None, choices=False):
-    """Assignments per expert of a batch over all ``num_experts``, [layers,
-    experts] on the host, as ``kimi_linear.routing_stats``."""
-    aux = jax.jit(lambda p, ids: _hidden_and_aux(p, cfg, ids, mesh)[1])(
-        params, batch["input_ids"])
-    counts = np.asarray(aux["counts"])
-    return (counts, np.asarray(aux["choice"])) if choices else counts
-
-
-# ---------------------------------------------------------------------------
-# train step
-# ---------------------------------------------------------------------------
-def make_train_step(cfg, optimizer, mesh=None):
-    """(init_fn, step_fn) of ``lm_trainer.make_train_step`` for this
-    model: step(params, opt_state, batch) -> (loss, params, opt_state).
-    No parameter moves outside the gradient; the step hands the routers'
-    counts out beside the loss (``step_fn.aux``), the counter a reader
-    takes a step's load from."""
-    return lm_trainer.make_train_step(
-        cfg, optimizer, mesh, init_params, param_specs, _loss_and_counts,
-        after_update=lambda params, counts: params)
-
-
-def synthetic_batch(cfg, batch_size, seq_len, seed=0):
-    """Random next-token batch: ``seq_len + 1`` uniform ids a row, inputs
-    the first ``seq_len``, labels the last."""
-    ids = np.random.RandomState(seed).randint(
-        0, cfg.vocab_size, (batch_size, seq_len + 1), dtype=np.int32)
-    return {"input_ids": ids[:, :-1], "labels": ids[:, 1:]}
+# everything around the block is the skeleton's (``lm_trainer.Decoder``); no
+# router has a selection bias, so its step moves nothing outside the gradient
+DECODER = lm_trainer.Decoder(
+    init_params=init_params, param_specs=param_specs, block=_block,
+    rotary=lambda cfg, positions: blocks.rope_angles(
+        positions, int(cfg.head_dim * cfg.rotary_factor), cfg.rope_theta),
+    final_gain=lambda params: 1.0 + params["final_norm_w"],
+    add_aux=_add_aux)
+forward = DECODER.forward
+stages = DECODER.stages
+lm_loss = DECODER.lm_loss
+routing_stats = DECODER.routing_stats
+make_train_step = DECODER.make_train_step
+synthetic_batch = lm_trainer.synthetic_batch

@@ -209,8 +209,11 @@ def test_train_step_lowers_the_loss(tiny):
 
 def test_olmoe_and_kimi_linear_share_one_train_step():
     from paddle_tpu.models import lm_trainer
-    assert olmoe.make_train_step.__code__.co_names[0] == "lm_trainer"
-    assert kl.make_train_step.__code__.co_names[0] == "lm_trainer"
+    # Kimi Linear's is the skeleton's own method; OLMoE's, which hands no
+    # counts out, is one hand-over to the function that method calls
+    assert kl.make_train_step.__func__ is lm_trainer.Decoder.make_train_step
+    assert olmoe.make_train_step.__code__.co_names[:2] == (
+        "lm_trainer", "make_train_step")
     assert callable(lm_trainer.make_train_step)
 
 

@@ -333,7 +333,8 @@ def test_train_step_lowers_the_loss_and_moves_the_selection_bias(tiny):
     moved = np.asarray(params["layers"][1]["router_bias"]) - before
     want = tiny.bias_rate * np.sign(counts[0].mean() - counts[0])
     np.testing.assert_allclose(moved, want, atol=1e-7)
-    assert "lm_trainer" in lg.make_train_step.__code__.co_names
+    from paddle_tpu.models import lm_trainer
+    assert lg.make_train_step.__func__ is lm_trainer.Decoder.make_train_step
 
 
 @pytest.mark.parametrize("layers", [2, 5])

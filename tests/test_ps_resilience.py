@@ -591,6 +591,7 @@ if role == "PSERVER":
     lst.bind((host, int(port)))
     lst.listen(8)
     lst.settimeout(0.1)
+    open(os.path.join(out, "listening"), "w").close()
     answered = False
     deadline = time.time() + 60
     while time.time() < deadline:
@@ -615,7 +616,13 @@ if role == "PSERVER":
 else:
     # long enough that a wedge-kill -> backoff -> respawn lands while
     # the job is still running (the supervisor rightly skips a pending
-    # respawn once every worker is done)
+    # respawn once every worker is done), counted from when the server
+    # listens: how long its imports take beside other test workers is
+    # not part of what is tested
+    deadline = time.time() + 60
+    while time.time() < deadline and not os.path.exists(
+            os.path.join(out, "listening")):
+        time.sleep(0.05)
     time.sleep(6.0)
     open(os.path.join(out, "done"), "w").close()
     sys.exit(0)
