@@ -15,19 +15,22 @@ call has a window; the callers enter ``attention`` and ``ffn``).
 """
 
 import contextlib
+import functools
 import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 from paddle_tpu.ops.pallas.flash_attention import KEPT as _FLASH_KEPT
 from paddle_tpu.ops.pallas.kda import KEPT as _KDA_KEPT
 from paddle_tpu.ops.pallas.registry import mesh_scope, selected_body
 
 __all__ = ["rms_norm", "rms_normalize", "yarn_inv_freq", "rope_angles",
-           "apply_rope", "attention_body", "causal_attention", "gated_ffn",
-           "recomputed"]
+           "apply_rope", "apply_rope_tail", "attention_body",
+           "causal_attention", "latent_attention", "gated_ffn", "recomputed"]
 
 #: from this many positions on, ``auto`` takes the flash kernels where the
 #: Pallas body runs (one chip). Measured on a v5e at equal tokens a step
@@ -137,6 +140,59 @@ def apply_rope(x, cos, sin):
     return jnp.concatenate(turned, axis=-1).astype(x.dtype)
 
 
+@jax.named_scope("rope")
+def apply_rope_tail(x, cos, sin, interleaved):
+    """Rotary positions on the LAST ``rot`` channels of x [B, S, N, D],
+    ``rot`` twice the width of ``cos``: the decoupled channels of a latent
+    attention head, behind the ones that carry no position. ``interleaved``
+    names the pairing: the neighbours (2i, 2i + 1) of the tail, turned where
+    they lie (the DeepSeek-V3 checkpoints' ``rope_interleave``), or the
+    halves (i, i + rot/2) as ``apply_rope`` pairs them; either by the angle
+    of position s and frequency i. In place means a score needs no second
+    operand laid out to match: queries and keys may be rotated apart.
+    Float32 inside, ``x.dtype`` out. The gradient is the same pass on the
+    cotangent with the angles' sign turned (a rotation's transpose), not
+    the transposes of this pass's slices, each of which is an array of x's
+    size in float32; the angles take none."""
+    return _turn_tail(x, cos, sin, interleaved)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _turn_tail(x, cos, sin, interleaved):
+    half = cos.shape[-1]
+    rot = 2 * half
+    # channel c of the tail is in pair ``pair[c]``, whose first channel is
+    # ``first`` and whose second ``second``
+    first = np.arange(0, rot, 2) if interleaved else np.arange(half)
+    second = first + (1 if interleaved else half)
+    pair = np.arange(rot) // 2 if interleaved else np.arange(rot) % half
+    # a channel's partner with its sign, (-x_second, x_first): the tail times
+    # a constant matrix of 0 and +-1 (exact in x.dtype: a sum of one term),
+    # so no lane is gathered or shifted
+    swap = np.zeros((rot, rot), np.float32)
+    swap[second, first], swap[first, second] = -1.0, 1.0
+    partner = jnp.dot(x[..., -rot:], jnp.asarray(swap, x.dtype),
+                      precision=lax.Precision.HIGHEST,
+                      preferred_element_type=jnp.float32)
+    x32 = x.astype(jnp.float32)
+    turned = x32[..., -rot:] * cos[None, :, None, pair] \
+        + partner * sin[None, :, None, pair]
+    return jnp.concatenate([x32[..., :-rot], turned], axis=-1).astype(x.dtype)
+
+
+def _turn_tail_fwd(x, cos, sin, interleaved):
+    return _turn_tail(x, cos, sin, interleaved), (cos, sin)
+
+
+def _turn_tail_bwd(interleaved, angles, dy):
+    cos, sin = angles
+    return (_turn_tail(dy, cos, -sin, interleaved), jnp.zeros_like(cos),
+            jnp.zeros_like(sin))
+
+
+_turn_tail.defvjp(_turn_tail_fwd, _turn_tail_bwd)
+
+
 def causal_attention(q, k, v, impl="auto", mesh=None, window=None):
     """Softmax of ``q k^T / sqrt(D)`` over the keys up to each query's own,
     times v; with ``window`` over the ``window`` keys that end at the
@@ -182,6 +238,47 @@ def causal_attention(q, k, v, impl="auto", mesh=None, window=None):
         return jnp.einsum("bnqk,bknd->bqnd", probs.astype(q.dtype), v)
 
 
+#: the ``checkpoint_name`` of a rotary latent-attention mixer's queries
+LATENT_KEPT = "latent_attention_queries"
+
+
+@jax.named_scope("attention")
+def latent_attention(lp, x, heads, rank, nope, eps, rotary=None, mesh=None):
+    """Multi-head latent attention (DeepSeek-V2, arXiv:2405.04434, sec.
+    2.1) of the normed stream x [B, S, H], as a training step computes it:
+    ``heads`` queries of ``nope`` channels and the decoupled ones behind
+    them from ``q_w``; ``kva_w`` gives a latent of ``rank`` and one key of
+    the decoupled width that every head shares; the latent, RMS-normed
+    (``kv_norm_g``, ``eps``), is expanded a head by ``kvb_w`` into ``nope``
+    key channels and the value; causal softmax over ``[k_nope | shared]``
+    through ``causal_attention`` (the score head wider than the value head),
+    then ``o_w``. ``rotary`` is None (the decoupled channels carry no
+    position: Kimi Linear's ``mla_use_nope``) or (cos, sin, interleaved) as
+    ``apply_rope_tail`` takes them: the queries' decoupled channels are
+    turned a head, the shared key once, before it is copied to the heads. No
+    weight absorption and no cache of the latent: those are serving forms.
+    The expansion is under the scope ``mla_expand``, the rotation under
+    ``rope``."""
+    b, s, _ = x.shape
+    dt = x.dtype
+    q = (x @ lp["q_w"].astype(dt)).reshape(b, s, heads, -1)
+    latent, k_shared = jnp.split(x @ lp["kva_w"].astype(dt), [rank], axis=-1)
+    if rotary is not None:
+        # the turned queries carry a name that ``recomputed`` keeps: the
+        # widest projection and its rotation are not formed twice a step
+        q = checkpoint_name(apply_rope_tail(q, *rotary), LATENT_KEPT)
+        k_shared = apply_rope_tail(k_shared[:, :, None, :], *rotary)[:, :, 0]
+    with jax.named_scope("mla_expand"):
+        kv = (rms_normalize(latent, lp["kv_norm_g"], eps)
+              @ lp["kvb_w"].astype(dt)).reshape(b, s, heads, -1)
+        k = jnp.concatenate(
+            [kv[..., :nope],
+             jnp.broadcast_to(k_shared[:, :, None, :],
+                              (b, s, heads, k_shared.shape[-1]))], axis=-1)
+    ctx = causal_attention(q, k, kv[..., nope:], mesh=mesh)
+    return ctx.reshape(b, s, -1) @ lp["o_w"].astype(dt)
+
+
 def recomputed(mixer):
     """``mixer`` under a ``jax.checkpoint`` that keeps, beside the mixer's
     inputs, what its attention kernel's forward call hands the backward one
@@ -192,12 +289,17 @@ def recomputed(mixer):
     passes around the kernel again (norms, projections, rotation,
     convolutions, gates: the kernel's operands, which the weights' gradients
     need anyway) and the forward kernel, whose outputs are all kept, is not
-    in the recomputation: no Mosaic forward kernel runs twice. Where no such
-    name is in the trace (the reference bodies; a mixer with no kernel) it
-    is the plain ``jax.checkpoint``."""
+    in the recomputation: no Mosaic forward kernel runs twice. One operand
+    is kept too, where a mixer names it: a rotary latent-attention mixer's
+    turned queries (``LATENT_KEPT``, 192 MiB a layer at 16 384 positions:
+    its widest projection and rotation, which the step has room for where
+    it has none for the expanded keys and values beside them). Where no
+    such name is in the trace (the reference bodies of a mixer that names
+    no operand; a mixer with no kernel) it is the plain
+    ``jax.checkpoint``."""
     return jax.checkpoint(
         mixer, policy=jax.checkpoint_policies.save_only_these_names(
-            _FLASH_KEPT, _KDA_KEPT))
+            _FLASH_KEPT, _KDA_KEPT, LATENT_KEPT))
 
 
 def gated_ffn(x, w_gate, w_up, w_down, matmul=jnp.matmul):

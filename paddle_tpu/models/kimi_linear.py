@@ -21,12 +21,14 @@ feed-forward, the others the experts.
   stay ``[B, S, H d]``, the projections' own layout, a head a lane tile:
   the ``[B, S, H, d]`` the rule's entry point takes is a view that the
   compiler folds away (PERF.md section 6, PR 39).
-- **MLA without positions**: queries of ``qk_nope + qk_rope`` channels a
-  head; keys and values expanded per head from an RMS-normalised latent of
-  ``kv_lora_rank``, with ``qk_rope`` more key channels shared by the heads
-  and, under ``mla_use_nope``, not rotated; causal softmax through
-  ``blocks.causal_attention`` (the flash kernels at score size 192 and
-  value size 128). No weight absorption: that is a serving form.
+- **MLA without positions** (``blocks.latent_attention``, which
+  ``models/deepseek_v3.py`` calls with a rotation): queries of ``qk_nope +
+  qk_rope`` channels a head; keys and values expanded per head from an
+  RMS-normalised latent of ``kv_lora_rank``, with ``qk_rope`` more key
+  channels shared by the heads and, under ``mla_use_nope``, not rotated;
+  causal softmax through ``blocks.causal_attention`` (the flash kernels at
+  score size 192 and value size 128). No weight absorption: that is a
+  serving form.
 - **experts**: a float32 sigmoid router over all ``num_experts`` with a
   selection bias, ``experts_per_token`` a token, renormalised and scaled by
   ``routed_scale``, plus one shared expert
@@ -290,25 +292,6 @@ def _kda(lp, x, cfg, mesh=None):
     return o @ lp["o_w"].astype(dt)
 
 
-@jax.named_scope("attention")
-def _mla(lp, x, cfg, mesh=None):
-    b, s, _ = x.shape
-    dt = x.dtype
-    n, nope = cfg.num_heads, cfg.qk_nope_head_dim
-    q = (x @ lp["q_w"].astype(dt)).reshape(b, s, n, -1)
-    latent, k_shared = jnp.split(x @ lp["kva_w"].astype(dt),
-                                 [cfg.kv_lora_rank], axis=-1)
-    with jax.named_scope("mla_expand"):
-        kv = (blocks.rms_normalize(latent, lp["kv_norm_g"], cfg.rms_eps)
-              @ lp["kvb_w"].astype(dt)).reshape(b, s, n, -1)
-        k = jnp.concatenate(
-            [kv[..., :nope],
-             jnp.broadcast_to(k_shared[:, :, None, :],
-                              (b, s, n, k_shared.shape[-1]))], axis=-1)
-    ctx = blocks.causal_attention(q, k, kv[..., nope:], mesh=mesh)
-    return ctx.reshape(b, s, -1) @ lp["o_w"].astype(dt)
-
-
 def _block(lp, x, cfg, layer, rotary, mesh=None):
     """One layer: (the stream after the mixer, after the feed-forward, the
     expert layer's aux terms or None); no mixer takes positions, ``rotary``
@@ -321,7 +304,9 @@ def _block(lp, x, cfg, layer, rotary, mesh=None):
     def mix(lp, x):
         normed = blocks.rms_norm(x, lp["ln1_g"], cfg.rms_eps)
         return x + (_kda(lp, normed, cfg, mesh) if kind == "kda"
-                    else _mla(lp, normed, cfg, mesh))
+                    else blocks.latent_attention(
+                        lp, normed, cfg.num_heads, cfg.kv_lora_rank,
+                        cfg.qk_nope_head_dim, cfg.rms_eps, mesh=mesh))
 
     h = (blocks.recomputed(mix) if kind == "kda" else mix)(lp, x)
     m, aux = lm_trainer.feed_forward(

@@ -12,8 +12,8 @@ import numpy as np
 import pytest
 
 import paddle_tpu as pt
-from paddle_tpu.models import (kimi_linear, laguna, lfm2, lm_trainer, olmoe,
-                               qwen3_next)
+from paddle_tpu.models import (deepseek_v3, kimi_linear, laguna, lfm2,
+                               lm_trainer, olmoe, qwen3_next)
 from paddle_tpu.parallel.mesh import MeshConfig, make_mesh
 
 FAMILIES = {
@@ -25,6 +25,8 @@ FAMILIES = {
     "qwen3_next": (qwen3_next, lambda: qwen3_next.qwen3_next_tiny(
         experts_held=(4, 4), dtype=jnp.float32)),
     "lfm2": (lfm2, lambda: lfm2.lfm2_tiny(
+        experts_held=(4, 4), dtype=jnp.float32)),
+    "deepseek_v3": (deepseek_v3, lambda: deepseek_v3.deepseek_v3_tiny(
         experts_held=(4, 4), dtype=jnp.float32)),
 }
 

@@ -28,8 +28,8 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
 import paddle_tpu as pt
-from paddle_tpu.models import (bert, kimi_linear, laguna, lfm2, olmoe,
-                               qwen3_next, transformer)
+from paddle_tpu.models import (bert, deepseek_v3, kimi_linear, laguna, lfm2,
+                               olmoe, qwen3_next, transformer)
 from paddle_tpu.ops import kda
 from paddle_tpu.ops import pallas as plk
 from paddle_tpu.ops.pallas import registry
@@ -1108,6 +1108,86 @@ def test_lfm2_step_at_published_widths_fits_a_v5e(lfm2_full_size):
     assert not [line for line in entry.splitlines()
                 if "gated_conv" in line and ("concatenate(" in line
                                              or " slice(" in line)]
+
+
+# ---------------------------------------------------------------------------
+# (c6) Kanana-2: rotary latent attention in every layer, flash at 192 / 128
+# over 16 384 positions, and the step of kanana_2_30b_a3b.lm_s16384
+# ---------------------------------------------------------------------------
+def test_flash_compiles_at_192_beside_128_over_16384_positions(one_chip):
+    """Latent attention at the Kanana-2 cell's length, 32 tiles a head: the
+    backward call's whole-sequence operands are 44 MiB a head by
+    ``_bwd_compiler_params``'s formula at 192 / 128, inside the 64 MiB every
+    call has had, and the compiler takes both calls."""
+    q = _abstract((1, 32, 16384, 192), BF16, one_chip)
+    v = _abstract((1, 32, 16384, 128), BF16, one_chip)
+
+    def fn(q, k, v):
+        return jax.value_and_grad(lambda *a: jnp.sum(plk.flash_attention(
+            *a, causal=True).astype(F32)), (0, 1, 2))(q, k, v)
+    compiled = _compile(fn, q, q, v)
+    assert sorted(_mosaic_call_stems(compiled)) == ["flash_bwd", "flash_fwd"]
+    assert 16384 * (2 * 2 * (2 * 192 + 128) + 4 * 192) == 44 * 2**20
+
+
+@pytest.fixture(scope="module")
+def kanana_2_full_size(topo):
+    """The step of the cell kanana_2_30b_a3b.lm_s16384: the published layers
+    0 to 4 at the published widths, 16 of 128 experts, the padded eighth of
+    the vocabulary, batch 1 x 16384."""
+    cfg = deepseek_v3.kanana_2_30b_a3b(num_layers=5, vocab_size=16128,
+                                       experts_held=(0, 16))
+    return _lower_replicated(
+        deepseek_v3.make_train_step, deepseek_v3.init_params, cfg,
+        deepseek_v3.synthetic_batch(cfg, 1, 16384), topo)
+
+
+@pytest.mark.timeout(900)
+def test_kanana_2_step_at_published_widths_fits_a_v5e(kanana_2_full_size):
+    """576.3 M parameters with their two Adam moments are 6.44 GiB of the
+    step's arguments; with every mixer recomputed but for its flash call's
+    outputs and its turned queries the whole step needs 14.1 GiB of the
+    15.75 a v5e gives a program (13.54 with the queries formed again, 15.78
+    to 15.90 with nothing recomputed: PERF.md section 6, PR 44). Its
+    Mosaic calls: the causal flash kernels at 192 / 128 (five layers, each
+    way once a layer), the grouped matmuls of the four expert layers' loops,
+    the way back of their held rows and the cross-entropy, each under its
+    scope; the rotation is under ``rope`` inside ``attention`` in the forward
+    pass, the recomputed one and the backward."""
+    compiled, pshape, _ = kanana_2_full_size
+    assert sum(int(np.prod(a.shape)) for a in jax.tree.leaves(pshape)) \
+        == 576_349_184
+    ma = compiled.memory_analysis()
+    assert 6.4 * 2**30 < ma.argument_size_in_bytes < 6.5 * 2**30
+    need = _need_bytes(compiled)
+    assert 0.25 * 15.75 * 2**30 < need < 14.3 * 2**30, need / 2**30   # 14.08 to 14.14
+    stems = _mosaic_call_stems(compiled)
+    assert set(stems) == {"flash_fwd", "flash_bwd", "softmax_xent_fwd",
+                          "grouped_matmul", "grouped_matmul_dw",
+                          "moe_combine"}
+    assert stems.count("moe_combine") == 8
+    _no_row_scatter_under_dispatch(compiled, 2048)
+    # a call a layer: no forward kernel is in the recomputation
+    assert stems.count("flash_fwd") == 5 and stems.count("flash_bwd") == 5
+    calls = [line for line in compiled.as_text().splitlines()
+             if "tpu_custom_call" in line]
+    assert all("bf16[1,32,16384,192]" in line for line in calls
+               if "flash_" in line.split(" = ")[0])
+    op_names = "\n".join(re.findall(r'op_name="([^"]*)"', "\n".join(calls)))
+    for scope, kernel in (
+            ("attention_core", "flash_fwd"), ("attention_core", "flash_bwd"),
+            ("moe_experts", "grouped_matmul"),
+            ("moe_experts", "grouped_matmul_dw"),
+            ("moe_dispatch", "moe_combine"),
+            ("loss", "softmax_xent_fwd")):
+        assert re.search(rf"{scope}[^\n]*/{kernel}/pallas_call", op_names), \
+            (scope, kernel)
+    every = "\n".join(re.findall(r'op_name="([^"]*)"', compiled.as_text()))
+    for scope in ("rope", "mla_expand"):
+        for where in (rf"jvp\(attention\)/{scope}/",
+                      rf"/rematted_computation/attention/{scope}/",
+                      rf"transpose\([^\n]*/checkpoint/attention/{scope}/"):
+            assert re.search(where, every), where
 
 
 # ---------------------------------------------------------------------------
