@@ -4,7 +4,8 @@ backward, with the dense attention it is held against.
 The [S, S] score matrix never exists in HBM (the reference materialises
 scores in operators/math/ softmax + matmul calls). Forward is one Pallas
 kernel and backward is one: it rebuilds each score tile once, from the
-saved logsumexp, and takes dQ, dK, dV and the key-bias gradient from it.
+saved logsumexp, and takes dQ, dK, dV and the key-bias gradient from it
+(no bias operand and no such gradient where the call gave no bias).
 Which body a call runs is the registry's choice (``ops/pallas/registry.py``).
 
 **Two operand layouts, one kernel body a direction.** What a program of the
@@ -46,7 +47,16 @@ the call and no option of the program:
   forward's key loop starts at the block that holds ``q0 - window + 1``, the
   backward's query loop for a key block ends at the block that holds ``k0 +
   block_k - 1 + window - 1`` (``_key_blocks``, ``_query_blocks``: the bounds
-  the loops run over, and the ones ``tiles_visited_pct`` counts).
+  the loops run over, and the ones ``tiles_visited_pct`` counts). Every
+  tile a loop visits is masked, also the ones no edge of the visible region
+  crosses: the mask is scheduled into vector slots the tile leaves empty,
+  and a loop of their own for the crossed tiles costs more than it saves
+  (``_FLASH_FWD_GROUP``'s table). What a causal tile cost was the chain from
+  product to softmax to product, one tile a trip: a block's tiles run in
+  groups of straight-line code (``_in_groups``: whole groups, then the
+  fewer than a group left, one a trip), but for a call none of whose blocks
+  has more tiles than a group (a window of one block), which keeps its one
+  loop (``_causal_group``).
 - fewer key/value heads than query heads: H a multiple of Hkv, and query
   head i reads key/value head ``i // (H / Hkv)``. The ``BlockSpec`` index
   maps send it there, so no repeated K or V exists in HBM; the backward
@@ -101,10 +111,34 @@ _FLASH_BWD_COMPILER_PARAMS = dataclasses.replace(
 #: block's tiles, the statistics' rows, Mosaic's own stack: 1.1 MiB counted
 #: by the compiler at S = 16 384, d = 256), generously
 _FLASH_BWD_HEADROOM = 16 << 20
-#: the backward lays up to this many query tiles of a key block out as
-#: straight-line code, so that one tile's matmuls run under the next one's
-#: elementwise work (v5e, S=4096: 8 tiles 12.0 ms a call, 4 12.6, 1 13.8)
+#: where the count of a key block's query tiles is the call's own (not
+#: causal), the backward lays up to this many of them out as straight-line
+#: code, so that one tile's matmuls run under the next one's elementwise
+#: work (v5e, S=4096: 8 tiles 12.0 ms a call, 4 12.6, 1 13.8)
 _FLASH_BWD_UNROLL = 8
+#: the tiles of a causal call run in groups of this many, straight-line code
+#: likewise, a direction. Their count follows the program id, so a block's
+#: loop is whole groups and then the fewer than a group that are left, one
+#: an iteration; the forward's last tile stands behind the loops. Kernels
+#: alone, v5e, ms a call, forward | backward (my chip runs, PR 46). Groups
+#: of 1, 2, 4, 8, measured with the tiles under the diagonal unmasked and in
+#: loops of their own (the parent's one loop first): Kanana's [1, 32, 16384,
+#: 192 / 128] 25.9 | 53.9, then 25.3 | 54.6, 24.2 | 52.5, 23.1 | 51.6, 22.9 |
+#: 51.7; Laguna's [1, 48 over 8, 16384, 128] 27.2 | 54.0, then 27.4 | 53.4,
+#: 26.0 | 50.7, 24.0 | 49.7, 23.8 | 49.9; Qwen3-Next's [1, 16 over 2, 16384,
+#: 256] 15.9 | 34.2, then 16.3 | 35.0, 15.1 | 33.7, 14.4 | 33.1, 14.4 | 39.4
+#: (eight tiles of a head of 256 no longer sit in the registers' shadow).
+#: Groups of 4 with that split (the last tile straight-line in the forward,
+#: the diagonal's in a loop of one trip in the backward) against groups of 4
+#: over every tile, all masked, as it is now: Kanana 22.29 | 51.58 and 22.61
+#: | 50.94, Laguna 22.85 | 49.75 and 23.09 | 49.13, Qwen3-Next 13.80 | 33.09
+#: and 13.77 | 32.65, OLMoE's [2, 16, 4096, 128] 1.323 | 2.692 and 1.342 |
+#: 2.595: the mask is 0 to 1.5% of a forward, the loop it saves 1.2 to 3.7%
+#: of a backward. The forward's also where the count is the call's own:
+#: BERT's [8, 4096, 3 x 768], two heads a lane tile, 6.82 ms at 1, 5.84 at
+#: 2, 5.45 at 4
+_FLASH_FWD_GROUP = 4
+_FLASH_BWD_GROUP = 4
 #: where a head is one tile (S <= 512: one query block, one key block) a
 #: program takes up to this many heads, their tiles as straight-line code
 #: like the backward's groups: a one-tile program has nothing of its own to
@@ -126,17 +160,19 @@ def _as_row(col):
 def _masked_scores(qs, k_blk, b_blk, q0, k0, causal, transposed=False,
                    window=None):
     """Scaled scores for one (q-block, k-block) tile: qs is pre-scaled
-    [bq, d], k_blk [bk, d], b_blk [bk] additive key bias; q0/k0 are the
-    tile's absolute row/col offsets for the causal mask. Shared by the
-    forward and the backward kernel so masking/bias can never drift
-    between them. ``transposed`` gives the tile as [bk, bq] (K Q^T, what
-    the backward wants); b_blk is then a [bk, 1] column. With ``window`` a
-    query sees only the ``window`` keys that end at its own."""
+    [bq, d], k_blk [bk, d], b_blk [bk] additive key bias, or None where the
+    call gave none; q0/k0 are the tile's absolute row/col offsets for the
+    causal mask. Shared by the forward and the backward kernel so masking/
+    bias can never drift between them. ``transposed`` gives the tile as
+    [bk, bq] (K Q^T, what the backward wants); b_blk is then a [bk, 1]
+    column. With ``window`` a query sees only the ``window`` keys that end
+    at its own."""
     rows, cols = (k_blk, qs) if transposed else (qs, k_blk)
     s = jax.lax.dot_general(
         rows, cols, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)            # [bq, bk] | [bk, bq]
-    s = s + (b_blk if transposed else b_blk[None, :])
+    if b_blk is not None:
+        s = s + (b_blk if transposed else b_blk[None, :])
     if causal:
         q_axis = 1 if transposed else 0
         qi = q0 + lax.broadcasted_iota(jnp.int32, s.shape, q_axis)
@@ -173,6 +209,48 @@ def _query_blocks(ik, nq, block_q, block_k, causal, window, xp=jnp):
     if window is not None:
         end = xp.minimum(nq, ((ik + 1) * block_k + window - 2) // block_q + 1)
     return first, end
+
+
+def _causal_group(blocks, programs, sizes, group):
+    """``group`` for a causal call, whose programs' counts of tiles follow
+    their ids, or 1 where none of its ``programs`` visits more tiles than
+    ``group`` (static, from the sizes alone): such a call (a window of one
+    block: two tiles a block) keeps its one loop (v5e, Laguna's [1, 64 over
+    8, 16384, 128] behind 512: 5.69 | 11.91 ms a call, in groups 5.57 |
+    12.28)."""
+    first, end = blocks(np.arange(programs), *sizes, xp=np)
+    return group if np.max(end - first) > group else 1
+
+
+def _in_groups(lo, hi, tile, carry, group):
+    """``carry = tile(j, carry)`` for j in [lo, hi) in order, ``group``
+    tiles an iteration as straight-line code, so that one tile's products
+    run under the next one's elementwise work. Static bounds: the largest
+    divisor of the count up to ``group``, and no loop where that is the
+    count. Traced bounds (a causal block's count follows its program id):
+    whole groups, then the fewer than ``group`` that are left singly;
+    nothing is padded with masked tiles to make the count even."""
+    count = None
+    if isinstance(lo, int) and isinstance(hi, int):
+        count = hi - lo
+        group = max(u for u in range(1, max(1, min(count, group)) + 1)
+                    if count % u == 0)
+    start = lo
+
+    def many(g, carry):
+        for i in range(group):
+            carry = tile(start + g * group + i, carry)
+        return carry
+
+    if group == count:
+        return many(0, carry)
+    if group > 1:
+        whole = (hi - lo) // group
+        carry = lax.fori_loop(0, whole, many, carry)
+        if count is not None:       # a divisor of it: nothing is left
+            return carry
+        lo = lo + whole * group
+    return lax.fori_loop(lo, hi, tile, carry)
 
 
 #: lanes of a vector register, and of a rows-major block's lane tile
@@ -280,18 +358,20 @@ def _merge_heads(parts):
     return out
 
 
-def _flash_fwd_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref, *,
-                      sm_scale, block_k, causal, seq_len, block_q,
-                      window=None, per_tile=1):
+def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, sm_scale, block_k, causal,
+                      seq_len, block_q, window=None, per_tile=1):
     """One (batch, lane tiles, q-block) cell: stream K/V blocks, keep running
     (max, sum, acc) a head — the online-softmax recurrence. The logsumexp
     goes out as row ``iq`` of the heads' [nq, bq] block, which stays in VMEM
     across the q-blocks. Where a tile is two heads (``per_tile``) a K/V block
-    is read once for both."""
+    is read once for both. ``rest`` is (bias_ref, o_ref, lse_ref), or the
+    last two alone where the call gave no bias."""
+    *bias_ref, o_ref, lse_ref = rest
     tiles, bq = _block_tiles(q_ref)
     dv = v_ref.shape[-1] if len(v_ref.shape) == 4 else _LANES
     nk = seq_len // block_k
     iq = pl.program_id(2)
+    sizes = (nk, block_q, block_k, causal, window)
 
     def tile(j):
         q = q_ref[_tile(q_ref, j)].astype(jnp.float32) * sm_scale  # [bq, d]
@@ -302,8 +382,8 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref, *,
                 .astype(jnp.float32)                       # [bk, d]
             v_blk = v_ref[_tile(v_ref, j, pl.ds(jk * block_k, block_k))] \
                 .astype(jnp.float32)
-            b_blk = bias_ref[0, 0, pl.ds(jk * block_k, block_k)] \
-                .astype(jnp.float32)                       # [bk]
+            b_blk = bias_ref[0][0, 0, pl.ds(jk * block_k, block_k)] \
+                .astype(jnp.float32) if bias_ref else None  # [bk]
             out = []
             for q_h, (m_prev, l_prev, acc) in zip(qs, carry):
                 s = _masked_scores(q_h, k_blk, b_blk, iq * block_q,
@@ -319,19 +399,26 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref, *,
                 out.append((m_new, l_new, acc))
             return tuple(out)
 
-        init = tuple((jnp.full((bq,), _NEG_INF, jnp.float32),
-                      jnp.zeros((bq,), jnp.float32),
-                      jnp.zeros((bq, dv), jnp.float32))
-                     for _ in range(per_tile))
+        stats = tuple((jnp.full((bq,), _NEG_INF, jnp.float32),
+                       jnp.zeros((bq,), jnp.float32),
+                       jnp.zeros((bq, dv), jnp.float32))
+                      for _ in range(per_tile))
         if nk == 1:
-            stats = body(0, init)
+            stats = body(0, stats)
         else:
             # causal: stop at the diagonal. K blocks entirely above it are
             # fully masked — skipping them halves causal attention FLOPs;
             # windowed: start where the band does
-            stats = lax.fori_loop(
-                *_key_blocks(iq, nk, block_q, block_k, causal, window),
-                body, init)
+            first, end = _key_blocks(iq, *sizes)
+            group = _FLASH_FWD_GROUP if not causal else _causal_group(
+                _key_blocks, seq_len // block_q, sizes, _FLASH_FWD_GROUP)
+            # a block whose count follows its program id closes with one
+            # tile as straight-line code beside the block's end, not one
+            # more trip of a loop
+            last = int(causal and group > 1)
+            stats = _in_groups(first, end - last, body, stats, group)
+            if last:
+                stats = body(end - 1, stats)
         l_safe = [jnp.maximum(l, 1e-30) for _, l, _ in stats]
         o_ref[_tile(o_ref, j)] = _merge_heads(
             [acc / l[:, None] for (_, _, acc), l in zip(stats, l_safe)]
@@ -425,6 +512,8 @@ def _flash_fwd(operands, bias, sm_scale, causal, block_q, block_k,
     # (8k, 128k)-divisible or equal to the array's — so the per-batch
     # bias rides as [B, 1, S] (block (1, 1, S)), and the heads' lse block
     # is the whole [nq, bq], written back when the heads change.
+    # A call without a bias has no such operand, and its tiles add none.
+    biased = bias is not None
     return pl.pallas_call(
         kernel,
         grid=(b, h // hb, nq),
@@ -432,8 +521,7 @@ def _flash_fwd(operands, bias, sm_scale, causal, block_q, block_k,
             _operand_spec(geo, hb, block_q, geo.d, 0),
             _operand_spec(geo, hb, s, geo.d, 1, blocked=False),
             _operand_spec(geo, hb, s, geo.dv, 2, blocked=False),
-            _vmem_spec((1, 1, s), lambda ib, ih, iq: (ib, 0, 0)),
-        ],
+        ] + [_vmem_spec((1, 1, s), lambda ib, ih, iq: (ib, 0, 0))] * biased,
         out_specs=[
             _operand_spec(geo, hb, block_q, geo.dv),
             _vmem_spec((1, hb, nq, block_q), whole),
@@ -445,7 +533,7 @@ def _flash_fwd(operands, bias, sm_scale, causal, block_q, block_k,
         compiler_params=_FLASH_COMPILER_PARAMS,
         interpret=interpret,
         name=_call_name("flash_fwd", window),
-    )(q, k, v, bias[:, None, :])
+    )(q, k, v, *([bias[:, None, :]] if biased else []))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4, 5, 6, 7, 8))
@@ -470,8 +558,7 @@ def _flash_attention_fwd(operands, bias, sm_scale, causal, block_q, block_k,
     return o, (operands, bias, o, lse)
 
 
-def _flash_bwd_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
-                      bias_ref, dq_ref, dk_ref, dv_ref, db_ref, dqt_acc, *,
+def _flash_bwd_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref, *rest,
                       sm_scale, block_q, block_k, causal, seq_len,
                       window=None, per_tile=1):
     """One (batch, lane tiles, k-block) cell: stream Q/dO blocks, rebuild
@@ -484,21 +571,23 @@ def _flash_bwd_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
     key-bias gradient goes out as one: row ``ik`` of the heads' [nk, bk]
     block). Scores never touch HBM, nor do partial dQs. Where a tile is two
     heads (``per_tile``) a Q/dO block is read once for both, and each adds
-    its 64 rows of the tile's dQ^T."""
+    its 64 rows of the tile's dQ^T. ``rest`` is (bias_ref, dq_ref, dk_ref,
+    dv_ref, db_ref, dqt_acc); a call without a bias has neither bias_ref nor
+    db_ref, and sums no bias gradient."""
+    biased = len(rest) == 6
+    bias_ref, db_ref = (rest[0], rest[4]) if biased else (None, None)
+    dq_ref, dk_ref, dv_ref = rest[1:4] if biased else rest[:3]
+    dqt_acc = rest[-1]
     ik = pl.program_id(2)
     tiles, _ = _block_tiles(q_ref)
     nq = seq_len // block_q
+    sizes = (nq, block_q, block_k, causal, window)
 
     @pl.when(ik == 0)
     def _():
         dqt_acc[...] = jnp.zeros_like(dqt_acc)
 
-    b_col = bias_ref[0].astype(jnp.float32)                # [bk, 1]
-    # causal: q-blocks strictly above the diagonal see only masked scores,
-    # so the loop starts at the diagonal and its length varies; otherwise
-    # the tiles go in groups of straight-line code
-    unroll = 1 if causal else max(
-        u for u in range(1, min(nq, _FLASH_BWD_UNROLL) + 1) if nq % u == 0)
+    b_col = bias_ref[0].astype(jnp.float32) if biased else None  # [bk, 1]
 
     def lane_tile(j):
         k_blk = k_ref[_tile(k_ref, j)].astype(jnp.float32)     # [bk, d]
@@ -522,7 +611,7 @@ def _flash_bwd_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
             do_blk = do_ref[_tile(do_ref, j, pl.ds(q0, block_q))] \
                 .astype(jnp.float32)
             out = []
-            for h, (dk_acc, dv_acc, db_acc) in enumerate(carry):
+            for h, (dk_acc, dv_acc, *db_acc) in enumerate(carry):
                 head = j * per_tile + h
                 lse_row = lse_ref[0, head, pl.ds(jq, 1), :]    # [1, bq]
                 d_row = delta_ref[0, head, pl.ds(jq, 1), :]
@@ -539,36 +628,35 @@ def _flash_bwd_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
                 dk_acc = dk_acc + jax.lax.dot_general(
                     dzt, qs, (((1,), (0,)), ((), ())),
                     preferred_element_type=jnp.float32)    # [bk, d]
-                db_acc = db_acc + jnp.sum(dzt, axis=1, keepdims=True)
+                db_acc = [acc + jnp.sum(dzt, axis=1, keepdims=True)
+                          for acc in db_acc]
                 dqt_acc[j, head_rows[h], pl.ds(q0, block_q)] += \
                     jax.lax.dot_general(
                         kts[h], dzt, (((1,), (0,)), ((), ())),
                         preferred_element_type=jnp.float32)    # [d, bq]
-                out.append((dk_acc, dv_acc, db_acc))
+                out.append((dk_acc, dv_acc, *db_acc))
             return tuple(out)
 
-        def group(g, carry):
-            for i in range(unroll):
-                carry = tile(g * unroll + i, carry)
-            return carry
-
-        init = tuple((jnp.zeros((bk, d), jnp.float32),
-                      jnp.zeros((bk, dv), jnp.float32),
-                      jnp.zeros((bk, 1), jnp.float32))
-                     for _ in range(per_tile))
-        if unroll == nq:
-            grads = group(0, init)
+        grads = tuple((jnp.zeros((bk, d), jnp.float32),
+                       jnp.zeros((bk, dv), jnp.float32),
+                       *([jnp.zeros((bk, 1), jnp.float32)] if biased else []))
+                      for _ in range(per_tile))
+        if nq == 1:
+            grads = tile(0, grads)
         else:
-            # causal: unroll is 1, the groups are the query blocks
-            grads = lax.fori_loop(
-                *_query_blocks(ik, nq // unroll, block_q, block_k, causal,
-                               window), group, init)
+            # causal: q-blocks strictly above the diagonal see only masked
+            # scores, so the loop starts at the diagonal; windowed: ends
+            # where the band does. Not causal: the count is the call's own
+            group = _FLASH_BWD_UNROLL if not causal else _causal_group(
+                _query_blocks, seq_len // block_k, sizes, _FLASH_BWD_GROUP)
+            grads = _in_groups(*_query_blocks(ik, *sizes), tile, grads, group)
         dk_ref[_tile(dk_ref, j)] = _merge_heads(
-            [dk for dk, _, _ in grads]).astype(dk_ref.dtype)
+            [g[0] for g in grads]).astype(dk_ref.dtype)
         dv_ref[_tile(dv_ref, j)] = _merge_heads(
-            [dv for _, dv, _ in grads]).astype(dv_ref.dtype)
-        for h, (_, _, db) in enumerate(grads):
-            db_ref[0, j * per_tile + h, pl.ds(ik, 1), :] = _as_row(db)
+            [g[1] for g in grads]).astype(dv_ref.dtype)
+        if biased:
+            for h, (_, _, db) in enumerate(grads):
+                db_ref[0, j * per_tile + h, pl.ds(ik, 1), :] = _as_row(db)
 
     for j in range(tiles):
         lane_tile(j)
@@ -601,12 +689,13 @@ def _head_sums(x, heads, factors):
 def _bwd_compiler_params(resident_bytes):
     """The backward call's compiler parameters where its whole-sequence
     operands (Q, dO and dQ in the operands' two-byte dtype, each in the
-    pipeline's two buffers, and dQ's float32 accumulator) take
-    ``resident_bytes`` of VMEM: the 64 MiB every call has had, and where
-    they and ``_FLASH_BWD_HEADROOM`` pass it (16 384 positions of a head of
-    256: 64 MiB resident, 65.12 counted by the compiler) that much, 80 MiB
-    there, of the 128 MiB a v5e core has. A shape that fitted keeps its
-    program."""
+    pipeline's two buffers and on whole rows of 128 lanes, and dQ's float32
+    accumulator) take ``resident_bytes`` of VMEM: the 64 MiB every call has
+    had, and where they and ``_FLASH_BWD_HEADROOM`` pass it (16 384
+    positions of a head of 256: 64 MiB resident, 65.12 counted by the
+    compiler, 80 MiB asked; of a head of 192 scored and 128 carried: 52
+    resident, since 192 lies on 256 lanes, 64.54 counted where the call has
+    a bias, 68 asked) that much, of the 128 MiB a v5e core has."""
     need = resident_bytes + _FLASH_BWD_HEADROOM
     if need <= _FLASH_BWD_COMPILER_PARAMS.vmem_limit_bytes:
         return _FLASH_BWD_COMPILER_PARAMS
@@ -643,10 +732,16 @@ def _flash_attention_bwd(sm_scale, causal, block_q, block_k, interpret,
     def whole(ib, ih, ik):
         return (ib, ih, 0, 0)
 
+    def lanes(width):
+        """What a head of ``width`` takes of VMEM's rows of 128 lanes."""
+        return width if geo.rows_major else -(-width // _LANES) * _LANES
+
     # lse/delta as one lane-dense row a query block ([B,H,nq,bq]), the
     # key-bias gradient as one a key block ([B,H,nk,bk]); the bias as a
-    # column ([B,S,1]), since it runs down the transposed tile's rows
-    dq, dk, dv, dbh = pl.pallas_call(
+    # column ([B,S,1]), since it runs down the transposed tile's rows. A
+    # call without a bias has neither, and its kernel sums no gradient of one
+    biased = bias is not None
+    dq, dk, dv, *dbh = pl.pallas_call(
         kernel,
         grid=(b, h // hb, nk),
         in_specs=[
@@ -656,28 +751,28 @@ def _flash_attention_bwd(sm_scale, causal, block_q, block_k, interpret,
             _vmem_spec((1, hb, nq, block_q), whole),
             _operand_spec(geo, hb, block_k, geo.d, 1),
             _operand_spec(geo, hb, block_k, geo.dv, 2),
-            _vmem_spec((1, block_k, 1), lambda ib, ih, ik: (ib, ik, 0)),
-        ],
+        ] + [_vmem_spec((1, block_k, 1), lambda ib, ih, ik: (ib, ik, 0))
+             ] * biased,
         out_specs=[
             _operand_spec(geo, hb, s, geo.d, blocked=False),
             _operand_spec(geo, hb, block_k, geo.d),
             _operand_spec(geo, hb, block_k, geo.dv),
-            _vmem_spec((1, hb, nk, block_k), whole),
-        ],
+        ] + [_vmem_spec((1, hb, nk, block_k), whole)] * biased,
         out_shape=[
             jax.ShapeDtypeStruct(_heads_shape(geo, h, geo.d), q.dtype),
             jax.ShapeDtypeStruct(_heads_shape(geo, h, geo.d), k.dtype),
             jax.ShapeDtypeStruct(_heads_shape(geo, h, geo.dv), v.dtype),
-            jax.ShapeDtypeStruct((b, h, nk, block_k), jnp.float32),
-        ],
+        ] + [jax.ShapeDtypeStruct((b, h, nk, block_k), jnp.float32)] * biased,
         scratch_shapes=[pltpu.VMEM(
             (hb // geo.per_tile, _LANES if geo.rows_major else geo.d, s),
             jnp.float32)],
         compiler_params=_bwd_compiler_params(
-            hb * s * (2 * 2 * (2 * geo.d + geo.dv) + 4 * geo.d)),
+            hb * s * (2 * 2 * (2 * lanes(geo.d) + lanes(geo.dv))
+                      + 4 * geo.d)),
         interpret=interpret,
         name=_call_name("flash_bwd", window),
-    )(q, do, lse, delta.reshape(b, h, nq, block_q), k, v, bias[:, :, None])
+    )(q, do, lse, delta.reshape(b, h, nq, block_q), k, v,
+      *([bias[:, :, None]] if biased else []))
     if group > 1:
         split, axis = ((b, s, geo.hkv, group, -1), 3) if geo.rows_major \
             else ((b, geo.hkv, group, s, -1), 2)
@@ -685,10 +780,11 @@ def _flash_attention_bwd(sm_scale, causal, block_q, block_k, interpret,
                   .astype(t.dtype) for t in (dk, dv))
         if geo.rows_major:
             dk, dv = dk.reshape(b, s, -1), dv.reshape(b, s, -1)
-    dbias = jnp.sum(dbh.reshape(b, h, s), axis=1)          # [B,S]
+    dbias = jnp.sum(dbh[0].reshape(b, h, s), axis=1).astype(bias.dtype) \
+        if biased else None                                # [B,S]
     grads = (dq, dk, dv) if len(operands) == 3 \
         else (jnp.concatenate([dq, dk, dv], axis=-1),)
-    return grads, dbias.astype(bias.dtype)
+    return grads, dbias
 
 
 _flash_attention.defvjp(_flash_attention_fwd, _flash_attention_bwd)
@@ -809,10 +905,12 @@ def _flash_attention_pallas(q, k=None, v=None, bias=None, causal=False,
     b, s = geo.b, geo.s
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(geo.d)
-    if bias is None:
-        bias = jnp.zeros((b, s), jnp.float32)
-    bias = jnp.asarray(bias, jnp.float32).reshape(b, s)
     block_q, block_k, pad = _blocks(s, block_q, block_k)
+    # no bias is a fact of the call: its kernels add none and sum no
+    # gradient of one. A padded call has one by construction
+    if bias is not None or pad:
+        bias = jnp.zeros((b, s), jnp.float32) if bias is None \
+            else jnp.asarray(bias, jnp.float32).reshape(b, s)
     positions = 1 if geo.rows_major else 2
     if pad:
         zf = [(0, 0)] * q.ndim

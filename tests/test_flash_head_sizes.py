@@ -79,6 +79,44 @@ def test_rows_major_operands_take_any_head_size(positions, d, dv):
                                    err_msg=f"d{name}")
 
 
+def test_tiles_in_groups_are_the_one_loop_to_the_bit(monkeypatch):
+    """192 beside 128 on square blocks of 16: ahead of its last tile a query
+    block's forward loop holds 0, 1, G - 1, G and G + 1 tiles, which run in
+    groups of G and then singly, and a key block's backward loop 1 to G + 2.
+    Output and the three gradients are the dense body's, and bit for bit
+    those of the kernels that visit their tiles in one loop, one a trip."""
+    import importlib
+    fa = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
+    groups = (fa._FLASH_FWD_GROUP, fa._FLASH_BWD_GROUP)
+    positions = 16 * (max(groups) + 2)
+    blocks_ = np.arange(positions // 16)
+    sizes = (positions // 16, 16, 16, True, None)
+    for blocks, g, last in zip((fa._key_blocks, fa._query_blocks), groups,
+                               (1, 0)):
+        first, end = blocks(blocks_, *sizes, xp=np)
+        assert {1, g - 1, g, g + 1} <= set((end - first - last).tolist())
+        assert fa._causal_group(blocks, positions // 16, sizes, g) == g
+    q, k, v, w = qkv(positions, 192, 128, seed=5)
+
+    def run(mode, **heads):
+        with plk.override(mode):
+            return jax.value_and_grad(
+                lambda *a: jnp.sum(plk.flash_attention(
+                    *a, causal=True, block_q=16, block_k=16, **heads) * w),
+                (0, 1, 2))(q, k, v)
+
+    got, want = run("on"), run("off")
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-4,
+                                   atol=5e-5)
+    monkeypatch.setattr(fa, "_causal_group", lambda *a: 1)
+    # the two calls are jitted on their static arguments: the count of
+    # heads, which heads-major operands do not read, makes these new ones
+    one_loop = run("on", num_heads=2)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(one_loop)):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+
+
 @pytest.mark.parametrize("impl", ["dense", "flash"])
 def test_causal_attention_takes_a_value_head_size_of_its_own(impl):
     """[B, S, N, 24] queries and keys, [B, S, N, 16] values, against the

@@ -22,19 +22,39 @@ from paddle_tpu.ops import pallas as plk
 fa = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
 
 
-def dense(q, k, v, window):
-    """softmax(q k^T / sqrt d) v over ``query - window < key <= query``,
-    query head i on key/value head i // group: [B, H, S, D] layout."""
+def _seen(s, window):
+    qi, ki = np.arange(s)[:, None], np.arange(s)[None, :]
+    seen = ki <= qi
+    if window is not None:
+        seen &= ki > qi - window
+    return jnp.asarray(seen)
+
+
+def _dense(q, k, v, seen):
     b, h, s, d = q.shape
     group = h // k.shape[1]
     k, v = (jnp.repeat(t, group, axis=1) for t in (k, v))
     scores = jnp.einsum("bhqd,bhkd->bhqk", q, k) / math.sqrt(d)
-    qi, ki = jnp.arange(s)[:, None], jnp.arange(s)[None, :]
-    seen = ki <= qi
-    if window is not None:
-        seen &= ki > qi - window
     probs = jax.nn.softmax(jnp.where(seen, scores, -jnp.inf), axis=-1)
     return jnp.einsum("bhqk,bhkd->bhqd", probs, v)
+
+
+def dense(q, k, v, window):
+    """softmax(q k^T / sqrt d) v over ``query - window < key <= query``,
+    query head i on key/value head i // group: [B, H, S, D] layout."""
+    return _dense(q, k, v, _seen(q.shape[2], window))
+
+
+@jax.jit
+def _dense_value_and_grads(q, k, v, w, seen):
+    return jax.value_and_grad(lambda *a: jnp.sum(_dense(*a, seen) * w),
+                              (0, 1, 2))(q, k, v)
+
+
+def dense_value_and_grads(q, k, v, w, window):
+    """``value_and_grads`` of ``dense``: one compiled program a shape,
+    whatever the window (the visible pairs are an operand)."""
+    return _dense_value_and_grads(q, k, v, w, _seen(q.shape[2], window))
 
 
 def arrays(seed, heads, kv_heads, s, d=16):
@@ -61,7 +81,95 @@ def test_kernels_match_the_dense_masked_softmax(window, group, s):
             lambda *a: plk.flash_attention(*a, causal=True, window=window,
                                            block_q=16, block_k=16),
             q, k, v, w)
-    want = value_and_grads(lambda *a: dense(*a, window), q, k, v, w)
+    want = dense_value_and_grads(q, k, v, w, window)
+    for g, r in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r), rtol=2e-4,
+                                   atol=2e-5)
+
+
+def _loop_counts(s, bq, bk, window):
+    """How many tiles each query block's forward loop and each key block's
+    backward loop visits, by the bounds the kernels run over."""
+    nq, nk = s // bq, s // bk
+    first, end = fa._key_blocks(np.arange(nq), nk, bq, bk, True, window,
+                                xp=np)
+    fwd = set(np.broadcast_to(end - first, (nq,)).tolist())
+    first, end = fa._query_blocks(np.arange(nk), nq, bq, bk, True, window,
+                                  xp=np)
+    return fwd, set(np.broadcast_to(end - first, (nk,)).tolist())
+
+
+#: enough tiles a side that some block has more than a group and one,
+#: whichever direction's groups are the longer
+GROUPS = (fa._FLASH_FWD_GROUP, fa._FLASH_BWD_GROUP)
+LONG = 16 * (max(GROUPS) + 2)
+
+
+@pytest.mark.parametrize("bq, bk, group", [(32, 16, 6), (16, 32, 8)])
+def test_causal_blocks_of_every_count_of_tiles(bq, bk, group):
+    """A causal block's tiles run in straight-line groups of G and then the
+    fewer than G left, one a trip; the forward's last stands behind the
+    loops. Oblong blocks both ways, so the diagonal crosses two tiles a
+    block of the longer side and the counts step by two (square blocks, one
+    query head a key/value head and every count: ``test_flash_head_sizes``).
+    Output and the three gradients."""
+    s = LONG * max(bq, bk) // 16
+    for counts, g in zip(_loop_counts(s, bq, bk, None), GROUPS):
+        assert min(counts) <= 2 and max(counts) > g + 1 \
+            and any(c % g for c in counts), (counts, g)
+    q, k, v, w = arrays(bq + group, group, 1, s, d=8)
+    with plk.override("on"):
+        got = value_and_grads(
+            lambda *a: plk.flash_attention(*a, causal=True, block_q=bq,
+                                           block_k=bk), q, k, v, w)
+    want = dense_value_and_grads(q, k, v, w, None)
+    for g, r in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r), rtol=2e-4,
+                                   atol=2e-5)
+
+
+def test_a_window_wider_than_a_group_of_blocks_runs_in_groups():
+    """Behind a window of 80 on blocks of 16 a block's loop holds up to six
+    tiles either direction (the band's far edge crosses the first, the
+    diagonal the last), more than a group: the windowed call takes the
+    groups, which Laguna's window of one block does not."""
+    s, window, bq, bk = LONG, 80, 16, 16
+    sizes = (bq, bk, True, window)
+    assert fa._causal_group(fa._key_blocks, s // bq, (s // bk,) + sizes,
+                            GROUPS[0]) == GROUPS[0]
+    assert fa._causal_group(fa._query_blocks, s // bk, (s // bq,) + sizes,
+                            GROUPS[1]) == GROUPS[1]
+    q, k, v, w = arrays(11, 2, 1, s, d=8)
+    with plk.override("on"):
+        got = value_and_grads(
+            lambda *a: plk.flash_attention(*a, causal=True, window=window,
+                                           block_q=bq, block_k=bk),
+            q, k, v, w)
+    want = dense_value_and_grads(q, k, v, w, window)
+    for g, r in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r), rtol=2e-4,
+                                   atol=2e-5)
+
+
+def test_a_call_without_a_bias_is_the_call_with_a_bias_of_zeros():
+    """To the bit: output, dq, dk and dv. The call without one has no bias
+    operand and sums no bias gradient; the call with one still returns the
+    dense body's."""
+    q, k, v, w = arrays(13, 4, 2, 64)
+    bias = jnp.asarray(np.random.RandomState(2).randn(1, 64), jnp.float32)
+
+    def run(mode, bias, argnums):
+        with plk.override(mode):
+            return jax.value_and_grad(
+                lambda q, k, v, bias: jnp.sum(plk.flash_attention(
+                    q, k, v, bias=bias, causal=True, block_q=16,
+                    block_k=16) * w), argnums)(q, k, v, bias)
+
+    none, zeros = run("on", None, (0, 1, 2)), run("on", 0 * bias, (0, 1, 2))
+    for g, r in zip(jax.tree.leaves(none), jax.tree.leaves(zeros)):
+        assert np.array_equal(np.asarray(g), np.asarray(r))
+    got, want = run("on", bias, (0, 1, 2, 3)), run("off", bias, (0, 1, 2, 3))
+    assert np.abs(np.asarray(want[1][3])).max() > 0.1
     for g, r in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         np.testing.assert_allclose(np.asarray(g), np.asarray(r), rtol=2e-4,
                                    atol=2e-5)
@@ -91,7 +199,7 @@ def test_the_reference_body_knows_windows_and_groups(window, group):
         got = value_and_grads(
             lambda *a: plk.flash_attention(*a, causal=True, window=window),
             q, k, v, w)
-    want = value_and_grads(lambda *a: dense(*a, window), q, k, v, w)
+    want = dense_value_and_grads(q, k, v, w, window)
     for g, r in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         np.testing.assert_allclose(np.asarray(g), np.asarray(r), rtol=2e-4,
                                    atol=2e-5)
@@ -153,6 +261,62 @@ def test_tiles_visited_pct_counts_a_band(monkeypatch):
     assert fa.tiles_visited_pct(16384, 512) == 100.0
 
 
+def test_a_call_with_no_more_tiles_than_a_group_keeps_its_one_loop():
+    """The groups are for calls some block of which has more tiles than one
+    holds, by the sizes alone: the cells' causal calls (32, 16 and 8 tiles at
+    most a block on blocks of 512) and not Laguna's window of one block (two
+    tiles a block), whose kernels keep the one loop they had."""
+    for blocks, g in zip((fa._key_blocks, fa._query_blocks), GROUPS):
+        for s, window, want in ((16384, None, g), (8192, None, g),
+                                (4096, None, g), (16384, 512, 1),
+                                (512 * g, None, 1), (512 * (g + 1), None, g),
+                                (16384, 512 * g, g)):
+            n = s // 512
+            assert fa._causal_group(blocks, n, (n, 512, 512, True, window),
+                                    g) == want, (blocks, s, window)
+
+
+@pytest.mark.parametrize("group", [1, 2, 3, 4, 8])
+def test_in_groups_visits_every_tile_once_and_in_order(group):
+    """Whole groups, then the fewer than a group left, whether the bounds
+    are the call's own (static: a divisor of the count, nothing left over)
+    or follow a program id (traced); an empty range visits nothing."""
+    def visit(lo, hi):
+        def tile(j, carry):
+            seen, at = carry
+            return seen.at[at].set(j), at + 1
+        return fa._in_groups(lo, hi, tile,
+                             (jnp.full((12,), -1, jnp.int32), 0), group)
+
+    traced = jax.jit(visit)
+    for lo, hi in ((0, 0), (0, 1), (3, 3), (2, 9), (0, 8), (1, 12), (5, 6)):
+        want = list(range(lo, hi)) + [-1] * (12 - (hi - lo))
+        for seen, at in (visit(lo, hi), traced(lo, hi)):
+            assert seen.tolist() == want and int(at) == hi - lo, (lo, hi)
+
+
+def test_the_four_calls_keep_their_names():
+    """The roofline readers and ``swa_core_ms`` find the device time by
+    them: one ``flash_fwd`` and one ``flash_bwd`` a call, ``_window`` behind
+    both where the call has a window, with a bias or without one."""
+    import re
+    q, k, v, w = arrays(1, 4, 2, 64)
+    bias = jnp.zeros((1, 64), jnp.float32)
+
+    def names(window, bias):
+        with plk.override("on"):
+            text = str(jax.make_jaxpr(lambda *a: value_and_grads(
+                lambda *b: plk.flash_attention(
+                    *b, bias=bias, causal=True, window=window, block_q=16,
+                    block_k=16), *a))(q, k, v, w))
+        return sorted(re.findall(r"name=(flash_(?:fwd|bwd)\w*)", text))
+
+    for b in (None, bias):
+        assert names(None, b) == ["flash_bwd", "flash_fwd"]
+        assert names(16, b) == ["flash_bwd_window", "flash_fwd_window"]
+        assert names(40, b) == ["flash_bwd_window", "flash_fwd_window"]
+
+
 def test_the_windowed_calls_have_names_of_their_own():
     q, k, v, w = arrays(1, 16, 2, 64)
 
@@ -180,7 +344,7 @@ def test_causal_attention_takes_a_window_and_groups(impl, window):
     got = jax.value_and_grad(lambda *a: jnp.sum(blocks.causal_attention(
         *map(to_bsnd, a), impl=impl, window=window) * to_bsnd(w)),
         (0, 1, 2))(q, k, v)
-    want = value_and_grads(lambda *a: dense(*a, window), q, k, v, w)
+    want = dense_value_and_grads(q, k, v, w, window)
     for g, r in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         np.testing.assert_allclose(np.asarray(g), np.asarray(r), rtol=2e-4,
                                    atol=2e-5)

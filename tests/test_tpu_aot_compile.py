@@ -1115,19 +1115,28 @@ def test_lfm2_step_at_published_widths_fits_a_v5e(lfm2_full_size):
 # over 16 384 positions, and the step of kanana_2_30b_a3b.lm_s16384
 # ---------------------------------------------------------------------------
 def test_flash_compiles_at_192_beside_128_over_16384_positions(one_chip):
-    """Latent attention at the Kanana-2 cell's length, 32 tiles a head: the
-    backward call's whole-sequence operands are 44 MiB a head by
-    ``_bwd_compiler_params``'s formula at 192 / 128, inside the 64 MiB every
-    call has had, and the compiler takes both calls."""
+    """Latent attention at the Kanana-2 cell's length, 32 tiles a head. The
+    backward call's whole-sequence operands are 52 MiB a head: 192 channels
+    lie on 256 lanes of VMEM, which ``_bwd_compiler_params``'s count knows
+    (44 MiB by the channels alone), so the call asks 68 MiB. The compiler
+    takes both calls, and with a key bias too, whose column block and the
+    groups' temporaries make it count 64.54 MiB (PR 46)."""
+    import importlib
+    fa = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
     q = _abstract((1, 32, 16384, 192), BF16, one_chip)
     v = _abstract((1, 32, 16384, 128), BF16, one_chip)
+    bias = _abstract((1, 16384), F32, one_chip)
 
-    def fn(q, k, v):
+    def fn(q, k, v, bias=None):
         return jax.value_and_grad(lambda *a: jnp.sum(plk.flash_attention(
-            *a, causal=True).astype(F32)), (0, 1, 2))(q, k, v)
-    compiled = _compile(fn, q, q, v)
-    assert sorted(_mosaic_call_stems(compiled)) == ["flash_bwd", "flash_fwd"]
-    assert 16384 * (2 * 2 * (2 * 192 + 128) + 4 * 192) == 44 * 2**20
+            *a, bias=bias, causal=True).astype(F32)), (0, 1, 2))(q, k, v)
+    for operands in ((q, q, v), (q, q, v, bias)):
+        compiled = _compile(fn, *operands)
+        assert sorted(_mosaic_call_stems(compiled)) == ["flash_bwd",
+                                                        "flash_fwd"]
+    resident = 16384 * (2 * 2 * (2 * 256 + 128) + 4 * 192)
+    assert resident == 52 * 2**20
+    assert fa._bwd_compiler_params(resident).vmem_limit_bytes == 68 << 20
 
 
 @pytest.fixture(scope="module")
