@@ -125,19 +125,14 @@ def rope_angles(positions, head_dim, theta=10000.0, inv_freq=None,
 
 @jax.named_scope("rope")
 def apply_rope(x, cos, sin):
-    """Rotary positions in the rotate-half convention on the first ``rot``
+    """Rotary positions in the rotate-half convention on the FIRST ``rot``
     channels of x [B, S, N, D], ``rot`` twice the width of ``cos``: the pair
     (x_i, x_{i + rot/2}) is turned by the angle of position s and frequency
-    i; the channels from ``rot`` on pass through. Float32 inside,
-    ``x.dtype`` out."""
-    half = cos.shape[-1]
-    x32 = x.astype(jnp.float32)
-    a, b = x32[..., :half], x32[..., half:2 * half]
-    cos, sin = cos[None, :, None, :], sin[None, :, None, :]
-    turned = [a * cos - b * sin, b * cos + a * sin]
-    if 2 * half < x.shape[-1]:
-        turned.append(x32[..., 2 * half:])
-    return jnp.concatenate(turned, axis=-1).astype(x.dtype)
+    i; the channels from ``rot`` on pass through. One entry of the one
+    rotary pass (``_turn``; ``apply_rope_tail`` is the other): float32
+    inside, ``x.dtype`` out, and the gradient is the same pass on the
+    cotangent with the sine's sign turned; the angles take none."""
+    return _turn(x, cos, sin, False, False)
 
 
 @jax.named_scope("rope")
@@ -149,48 +144,64 @@ def apply_rope_tail(x, cos, sin, interleaved):
     they lie (the DeepSeek-V3 checkpoints' ``rope_interleave``), or the
     halves (i, i + rot/2) as ``apply_rope`` pairs them; either by the angle
     of position s and frequency i. In place means a score needs no second
-    operand laid out to match: queries and keys may be rotated apart.
-    Float32 inside, ``x.dtype`` out. The gradient is the same pass on the
-    cotangent with the angles' sign turned (a rotation's transpose), not
-    the transposes of this pass's slices, each of which is an array of x's
-    size in float32; the angles take none."""
-    return _turn_tail(x, cos, sin, interleaved)
+    operand laid out to match: queries and keys may be rotated apart. The
+    other entry of the one rotary pass (``_turn``): float32 inside,
+    ``x.dtype`` out, the gradient the same pass with the sine's sign turned;
+    the angles take none."""
+    return _turn(x, cos, sin, True, interleaved)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _turn_tail(x, cos, sin, interleaved):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _turn(x, cos, sin, tail, interleaved):
+    """The rotary pass both entries share: the span ``[0, rot)`` of a head,
+    or ``[D - rot, D)`` with ``tail``, turned in halves or in interleaved
+    pairs, as one product and one elementwise pass over whole heads, dense
+    in the lanes: ``y = x cos' + (x M) sin'`` with M a constant D x D matrix
+    of 0 and +-1 that is zero outside the span and cos' = 1, sin' = 0 there.
+    No channel is sliced, gathered, shifted or concatenated. The transpose
+    of that map is itself with -sin (M is antisymmetric and the tables are
+    equal on the two channels of a pair, whatever scales them), which is the
+    gradient rule: nothing of x's size is kept, and the backward pass is not
+    the transposes of slices and pads in float32."""
+    d = x.shape[-1]
     half = cos.shape[-1]
     rot = 2 * half
-    # channel c of the tail is in pair ``pair[c]``, whose first channel is
-    # ``first`` and whose second ``second``
-    first = np.arange(0, rot, 2) if interleaved else np.arange(half)
+    start = d - rot if tail else 0
+    # the channels ``first[i]`` and ``second[i]`` are pair i
+    first = start + (np.arange(0, rot, 2) if interleaved else np.arange(half))
     second = first + (1 if interleaved else half)
-    pair = np.arange(rot) // 2 if interleaved else np.arange(rot) % half
-    # a channel's partner with its sign, (-x_second, x_first): the tail times
-    # a constant matrix of 0 and +-1 (exact in x.dtype: a sum of one term),
-    # so no lane is gathered or shifted
-    swap = np.zeros((rot, rot), np.float32)
+    # a channel's partner with its sign, (-x_second, x_first): the head times
+    # M on the MXU (exact in x.dtype: a sum of one term)
+    swap = np.zeros((d, d), np.float32)
     swap[second, first], swap[first, second] = -1.0, 1.0
-    partner = jnp.dot(x[..., -rot:], jnp.asarray(swap, x.dtype),
+
+    def table(t, outside):
+        """[S, D]: a pair's entry on both its channels, ``outside`` on the
+        channels that pass through."""
+        t = jnp.repeat(t, 2, axis=-1) if interleaved \
+            else jnp.concatenate([t, t], axis=-1)
+        return t if rot == d else jnp.pad(
+            t, ((0, 0), (start, d - rot - start)), constant_values=outside)
+
+    partner = jnp.dot(x, jnp.asarray(swap, x.dtype),
                       precision=lax.Precision.HIGHEST,
                       preferred_element_type=jnp.float32)
-    x32 = x.astype(jnp.float32)
-    turned = x32[..., -rot:] * cos[None, :, None, pair] \
-        + partner * sin[None, :, None, pair]
-    return jnp.concatenate([x32[..., :-rot], turned], axis=-1).astype(x.dtype)
+    turned = x.astype(jnp.float32) * table(cos, 1.0)[None, :, None, :] \
+        + partner * table(sin, 0.0)[None, :, None, :]
+    return turned.astype(x.dtype)
 
 
-def _turn_tail_fwd(x, cos, sin, interleaved):
-    return _turn_tail(x, cos, sin, interleaved), (cos, sin)
+def _turn_fwd(x, cos, sin, tail, interleaved):
+    return _turn(x, cos, sin, tail, interleaved), (cos, sin)
 
 
-def _turn_tail_bwd(interleaved, angles, dy):
+def _turn_bwd(tail, interleaved, angles, dy):
     cos, sin = angles
-    return (_turn_tail(dy, cos, -sin, interleaved), jnp.zeros_like(cos),
+    return (_turn(dy, cos, -sin, tail, interleaved), jnp.zeros_like(cos),
             jnp.zeros_like(sin))
 
 
-_turn_tail.defvjp(_turn_tail_fwd, _turn_tail_bwd)
+_turn.defvjp(_turn_fwd, _turn_bwd)
 
 
 def causal_attention(q, k, v, impl="auto", mesh=None, window=None):
