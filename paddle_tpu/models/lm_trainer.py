@@ -1,9 +1,13 @@
 """The decoder skeleton: what the language models built from
 ``models/blocks.py`` have in common, written once. A model file
 (``olmoe.py``, ``kimi_linear.py``, ``laguna.py``, ``qwen3_next.py``,
-``lfm2.py``) keeps its config, its parameters and its mixers and hands them
-over as a :class:`Decoder`, whose methods are that module's ``forward``,
-``stages``, ``lm_loss``, ``routing_stats`` and ``make_train_step``.
+``lfm2.py``, ``deepseek_v3.py``, ``nemotron_h.py``) keeps its config, its
+parameters and its mixers and hands them over as a :class:`Decoder`, whose
+methods are that module's ``forward``, ``stages``, ``lm_loss``,
+``routing_stats`` and ``make_train_step``. A layer is a mixer and a
+feed-forward, or a mixer alone; where the parameters hold a
+multi-token-prediction module (``params["mtp"]``) the pass runs it behind
+the last layer and the loss gains its term.
 ``models/bert.py`` and ``models/transformer.py`` carry their own pass and
 step (ROADMAP C, "one trainer shape").
 """
@@ -45,6 +49,11 @@ def untied_head(params, hidden):
                    preferred_element_type=jnp.float32)
 
 
+def _stacked(auxes):
+    """The layers' aux terms, each stacked over the layers."""
+    return jax.tree.map(lambda *a: jnp.stack(a), *auxes)
+
+
 def _shard_act(x, mesh):
     if mesh is None or mesh.shape.get(DATA_AXIS, 1) == 1:
         return x
@@ -59,7 +68,9 @@ class Decoder:
     init_params: Callable   # (rng, cfg) -> float32 parameters
     param_specs: Callable   # cfg -> their PartitionSpecs over ("model",)
     #: (lp, x, cfg, layer, rotary, mesh) -> (the stream after the layer's
-    #: mixer, after its feed-forward, the expert layer's aux terms or None)
+    #: mixer, after its feed-forward, the expert layer's aux terms or None);
+    #: a layer that is one part (a mixer alone: Nemotron-H's) returns (the
+    #: stream after it, its aux terms or None)
     block: Callable
     #: (cfg, positions) -> what ``block`` takes as ``rotary``, made once a
     #: pass: a table of angles, one a layer kind, or None
@@ -70,56 +81,128 @@ class Decoder:
     #: loss: the cross-entropy and what this model's loss adds to it
     add_aux: Callable = lambda cfg, ce, aux: ce
 
-    def _pass(self, params, cfg, input_ids, mesh=None):
+    def _layers(self, layers, first, x, cfg, rotary, mesh):
+        """``x`` through ``layers``, numbered from ``first``: (the stream
+        after every part of them, a list; the aux terms of the layers that
+        returned some, a list)."""
+        auxes, stream = [], []
+        for layer, lp in enumerate(layers, first):
+            *parts, aux = self.block(lp, x, cfg, layer, rotary, mesh)
+            x = parts[-1] = _shard_act(parts[-1], mesh)
+            stream += parts
+            if aux is not None:
+                auxes.append(aux)
+        return stream, auxes
+
+    def _pass(self, params, cfg, input_ids, mesh=None, next_ids=None):
         """(final normed hidden states [B, S, H], the aux terms stacked over
         the layers that returned some, the residual stream after the
-        embedding and after every mixer and feed-forward, a list of
-        2 layers + 1)."""
+        embedding and after every part of every layer (a mixer and a
+        feed-forward, or a mixer alone), a list; what a
+        multi-token-prediction module hands on, a list (``_predict_further``:
+        with ``next_ids`` [B, S], the id that follows each position, and such
+        a module in the parameters, ``params["mtp"]``; its expert layers' aux
+        terms are then stacked behind the main ones), else empty)."""
         with jax.named_scope("embed"):
             x = jnp.take(params["embed"], input_ids,
                          axis=0).astype(cfg.dtype)
         x = _shard_act(x, mesh)
         rotary = self.rotary(cfg, input_ids.shape[1])
-        auxes, stream = [], [x]
-        for layer, lp in enumerate(params["layers"]):
-            h, x, aux = self.block(lp, x, cfg, layer, rotary, mesh)
-            x = _shard_act(x, mesh)
-            stream += [h, x]
-            if aux is not None:
-                auxes.append(aux)
-        hidden = blocks.rms_norm(x, self.final_gain(params), cfg.rms_eps)
-        return hidden, jax.tree.map(lambda *a: jnp.stack(a), *auxes), stream
+        stream, auxes = self._layers(params["layers"], 0, x, cfg, rotary,
+                                     mesh)
+        stream = [x] + stream
+        hidden = blocks.rms_norm(stream[-1], self.final_gain(params),
+                                 cfg.rms_eps)
+        further = []
+        if next_ids is not None and "mtp" in params:
+            further, more = self._predict_further(
+                params, cfg, stream[-1], next_ids, rotary, mesh)
+            auxes = auxes + more
+        return hidden, _stacked(auxes), stream, further
+
+    def _predict_further(self, params, cfg, x, next_ids, rotary, mesh):
+        """The multi-token-prediction module (DeepSeek-V3, arXiv:2412.19437,
+        sec. 2.2, one module deep): the main model's last state ``x``,
+        before its final norm, and the embedding of the NEXT token, each
+        RMS-normed, merged by ``eh_w`` [2 H, H] (the state's rows first),
+        through the module's own layers (numbered on from the main ones, so
+        the model's ``block`` finds their kinds) and its own final norm;
+        the embedding, and the head in ``_loss_and_counts``, are the main
+        model's. Returns (what the module hands on, a list: the merged
+        state, the stream after every part of its layers, its final normed
+        hidden states, whose logits predict the token after next; its
+        layers' aux terms, a list)."""
+        mp = params["mtp"]
+        with jax.named_scope("embed"):
+            e = jnp.take(params["embed"], next_ids, axis=0).astype(cfg.dtype)
+        with jax.named_scope("mtp_merge"):
+            # two products and no [B, S, 2 H] concatenation
+            w = mp["eh_w"].astype(cfg.dtype)
+            h = x.shape[-1]
+            x = blocks.rms_normalize(x, mp["hnorm_g"], cfg.rms_eps) @ w[:h] \
+                + blocks.rms_normalize(e, mp["enorm_g"], cfg.rms_eps) @ w[h:]
+        x = _shard_act(x, mesh)
+        stream, auxes = self._layers(mp["layers"], len(params["layers"]), x,
+                                     cfg, rotary, mesh)
+        stream = [x] + stream
+        return stream + [blocks.rms_norm(stream[-1], mp["final_norm_g"],
+                                         cfg.rms_eps)], auxes
 
     def forward(self, params, cfg, input_ids, mesh=None):
         """Decoder forward; returns the final normed hidden states [B, S, H]
         in cfg.dtype (the head is applied in ``lm_loss``)."""
         return self._pass(params, cfg, input_ids, mesh)[0]
 
-    def stages(self, params, cfg, input_ids, mesh=None):
-        """(what every part of the forward pass hands on, [2 layers + 2, B,
-        S, H] in cfg.dtype: the embedding, the residual stream after each
-        layer's mixer and after its feed-forward, and last the final normed
-        hidden states (``forward``'s); the expert layers' aux terms of that
-        same pass, stacked over those layers: ``counts`` [layers, E],
-        ``choice`` [layers, T, k] and what else the model's router returns).
-        For a check that holds each part to a reference on that part's own
-        input: the choices are those made on the states returned, which two
+    def stages(self, params, cfg, input_ids, mesh=None, next_ids=None):
+        """(what every part of the forward pass hands on, [parts, B, S, H]
+        in cfg.dtype: the embedding, the residual stream after each layer's
+        mixer and after its feed-forward (2 layers + 2 parts; a layer that
+        is a mixer alone is one part), then the final normed hidden states
+        (``forward``'s), and, with ``next_ids`` and a multi-token-prediction
+        module, what that module hands on behind them: its merged state, the
+        stream after its layers' parts, its own final normed hidden states;
+        the expert layers' aux terms of that same pass, stacked over those
+        layers, the module's last: ``counts`` [layers, E], ``choice``
+        [layers, T, k] and what else the model's router returns). For a
+        check that holds each part to a reference on that part's own input:
+        the choices are those made on the states returned, which two
         separately compiled passes do not promise."""
-        hidden, aux, stream = self._pass(params, cfg, input_ids, mesh)
-        return jnp.stack(stream + [hidden]), aux
+        hidden, aux, stream, further = self._pass(params, cfg, input_ids,
+                                                  mesh, next_ids)
+        return jnp.stack(stream + [hidden] + further), aux
 
     def _loss_and_counts(self, params, cfg, batch, mesh=None):
-        """(``lm_loss``, the counts each expert took [expert layers, E])."""
-        hidden, aux, _ = self._pass(params, cfg, batch["input_ids"], mesh)
+        """(``lm_loss``, the counts each expert took [expert layers, E]);
+        with a multi-token-prediction module the second is a pair: the
+        counts, the module's layers last, and the two cross-entropies
+        [2] (next token, token after next) the loss is made of."""
+        hidden, aux, _, further = self._pass(
+            params, cfg, batch["input_ids"], mesh, batch["labels"])
         with jax.named_scope("loss"), mesh_scope(mesh):
             logits = self.logits(params, hidden)
             nll = softmax_cross_entropy(logits, batch["labels"])
-            return self.add_aux(cfg, jnp.mean(nll), aux), aux["counts"]
+            ce = jnp.mean(nll)
+            loss = self.add_aux(cfg, ce, aux)
+            if not further:
+                return loss, aux["counts"]
+            # position t's merged state predicts id t + 2, the label of
+            # position t + 1; the last position has none
+            after_next = jnp.roll(batch["labels"], -1, axis=1)
+            nll = softmax_cross_entropy(
+                self.logits(params, further[-1]), after_next)
+            has_one = jnp.arange(nll.shape[1]) < nll.shape[1] - 1
+            further_ce = jnp.sum(nll * has_one) \
+                / (nll.shape[0] * (nll.shape[1] - 1))
+            return loss + cfg.mtp_weight * further_ce, (
+                aux["counts"], jnp.stack([ce, further_ce]))
 
     def lm_loss(self, params, cfg, batch, mesh=None):
         """Mean next-token cross-entropy over every position of
         dict(input_ids, labels) [B, S], over ``cfg.vocab_size`` ids, plus
-        what the model's ``add_aux`` adds. Logits and loss in float32."""
+        what the model's ``add_aux`` adds, plus, where the parameters hold a
+        multi-token-prediction module, ``cfg.mtp_weight`` times the mean
+        cross-entropy of the token after next over the S - 1 positions that
+        have one. Logits and loss in float32."""
         return self._loss_and_counts(params, cfg, batch, mesh)[0]
 
     def routing_stats(self, params, cfg, batch, mesh=None, choices=False):
@@ -130,8 +213,11 @@ class Decoder:
         rows this chip computes. The counter a reader takes the experts'
         load from. With ``choices`` also the experts of each token, [expert
         layers, tokens, experts_per_token]."""
-        aux = jax.jit(lambda p, ids: self._pass(p, cfg, ids, mesh)[1])(
-            params, batch["input_ids"])
+        aux = jax.jit(
+            lambda p, ids, next_ids: self._pass(p, cfg, ids, mesh,
+                                                next_ids)[1])(
+            params, batch["input_ids"],
+            batch["labels"] if "mtp" in params else None)
         counts = np.asarray(aux["counts"])
         return (counts, np.asarray(aux["choice"])) if choices else counts
 
@@ -149,12 +235,20 @@ class Decoder:
 def move_biases(cfg, params, counts):
     """``params`` with every router's selection bias one step of
     ``moe.bias_step`` on; ``counts`` [expert layers, E] in the layers'
-    order. Only a layer that holds a ``router_bias`` moves."""
+    order, a multi-token-prediction module's layers behind the main ones.
+    Only a layer that holds a ``router_bias`` moves."""
     routers = iter(counts)
-    layers = [dict(lp, router_bias=moe.bias_step(
-        lp["router_bias"], next(routers), cfg.bias_rate))
-        if "router_bias" in lp else lp for lp in params["layers"]]
-    return dict(params, layers=layers)
+
+    def moved(layers):
+        return [dict(lp, router_bias=moe.bias_step(
+            lp["router_bias"], next(routers), cfg.bias_rate))
+            if "router_bias" in lp else lp for lp in layers]
+
+    params = dict(params, layers=moved(params["layers"]))
+    if "mtp" in params:       # its routers' counts are the last rows
+        params["mtp"] = dict(params["mtp"],
+                             layers=moved(params["mtp"]["layers"]))
+    return params
 
 
 def make_train_step(cfg, optimizer, mesh, init_params, param_specs, loss_fn,
@@ -172,7 +266,11 @@ def make_train_step(cfg, optimizer, mesh, init_params, param_specs, loss_fn,
     ``bert.make_train_step`` hands them out. With ``after_update`` the
     jitted step returns the aux as a fourth result and ``step_fn.aux`` holds
     that of the last step enqueued (device arrays: the counter a reader
-    takes a step's routing from; reading it waits for that step)."""
+    takes a step's routing from; reading it waits for that step). Where the
+    aux is a tuple, ``after_update`` is given its first and the step
+    returns each as a result of its own, ``step_fn.aux`` the list of them
+    (a decoder with a multi-token-prediction module: the routers' counts,
+    then the two cross-entropies of its loss)."""
     mesh = mesh or get_mesh()
     pspecs = param_specs(cfg)
     if mesh.shape.get(MODEL_AXIS, 1) == 1:
@@ -200,7 +298,10 @@ def make_train_step(cfg, optimizer, mesh, init_params, param_specs, loss_fn,
             params, grads, opt_state)
         if after_update is None:
             return loss, new_params, new_opt
-        return loss, after_update(new_params, aux), new_opt, aux
+        # a loss function may hand out more than the rule reads: the first
+        # of a tuple is the rule's, the others follow it out of the step
+        aux = aux if isinstance(aux, tuple) else (aux,)
+        return loss, after_update(new_params, aux[0]), new_opt, *aux
 
     jit_step = jax.jit(step, donate_argnums=(0, 1))
     dshard = NamedSharding(mesh, P(DATA_AXIS))

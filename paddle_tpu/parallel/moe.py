@@ -18,13 +18,20 @@ selected experts. An auxiliary load-balancing loss (mean gate fraction
 x mean dispatch fraction x num_experts, Switch eq. 4) is returned for
 the caller to add.
 
-``dropless_moe_ffn`` is the other layer: gated experts, top-k, **no
-capacity and no dropped token**. The k T (token, expert) assignments are
-sorted by expert, the rows gathered in that order, and the experts are
-three grouped matmuls over ragged groups (``ops/pallas/grouped_matmul.py``).
-How a token's scores become its experts and their weights is data of the
-call (``Scoring``: a softmax as it is, or a sigmoid with a selection bias,
-renormalised and scaled). The layer holds every expert (one device, or
+``dropless_moe_ffn`` is the other layer: top-k experts, **no capacity and no
+dropped token**. The k T (token, expert) assignments are sorted by expert,
+the rows gathered in that order, and the experts are grouped matmuls over
+ragged groups (``ops/pallas/grouped_matmul.py``): three where the
+parameters hold a gate beside the two projections (``w_gate``: the
+SiLU-gated feed-forward of the Llama family), two where they do not
+(``W2 act(W1 x)``, the activation named by the call: Nemotron-H's
+``relu2``). Where the parameters hold a latent's two projections
+(``latent_down``, ``latent_up``: LatentMoE) the experts work on ``x
+latent_down`` and their weighted sum goes back through ``latent_up``, so
+the rows gathered, multiplied and summed back are the latent's width and
+not the hidden size. How a token's scores become its experts and their
+weights is data of the call (``Scoring``: a softmax as it is, or a sigmoid
+with a selection bias, renormalised and scaled). The layer holds every expert (one device, or
 experts replicated under a ``data`` mesh), or the range of experts it is
 told it holds, as one chip of an expert-parallel job does: it routes over
 all of them, computes the part of the result its own experts give and
@@ -255,16 +262,37 @@ _rows_in_token_order.defvjp(
     lambda order, dy: (jnp.take(dy, order, axis=0), None, None))
 
 
-def _gated_experts(rows, weights, sizes, mesh):
-    """Rows sorted by expert through their experts' gated feed-forward:
-    three grouped matmuls over the groups ``sizes``."""
+#: what ``activation`` may name beside "silu", the gate of a gated expert:
+#: the non-linearity of an expert (and of the shared expert) that has no gate
+ACTIVATIONS = {"relu2": lambda h: jnp.square(jax.nn.relu(h))}
+
+
+def _feed(x, weights, activation, matmul=jnp.matmul):
+    """``x`` through one feed-forward, or rows through their experts' where
+    ``matmul`` is the grouped one. ``weights`` says which form: (gate, up,
+    down) the SiLU-gated one, ``(silu(x gate) * (x up)) down``; (up, down)
+    the plain one, ``activation(x up) down``."""
     from paddle_tpu.models.blocks import gated_ffn
+
+    if len(weights) == 3:
+        if activation != "silu":
+            raise ValueError(f"a gated expert's gate is SiLU, not "
+                             f"{activation!r}")
+        return gated_ffn(x, *weights, matmul=matmul)
+    up, down = (w.astype(x.dtype) for w in weights)
+    return matmul(ACTIVATIONS[activation](matmul(x, up)), down)
+
+
+def _experts(rows, weights, sizes, mesh, activation):
+    """Rows sorted by expert through their experts' feed-forward (``_feed``:
+    gated, three grouped matmuls over the groups ``sizes``, or plain,
+    two)."""
     from paddle_tpu.ops.pallas.grouped_matmul import grouped_matmul
     from paddle_tpu.ops.pallas.registry import mesh_scope
 
     with jax.named_scope("moe_experts"), mesh_scope(mesh):
-        return gated_ffn(rows, *weights,
-                         matmul=lambda a, w: grouped_matmul(a, w, sizes))
+        return _feed(rows, weights, activation,
+                     matmul=lambda a, w: grouped_matmul(a, w, sizes))
 
 
 def _sum_back(y, rows, token, weight, mesh):
@@ -280,8 +308,8 @@ def _sum_back(y, rows, token, weight, mesh):
 
 
 #: Rows of the held experts' assignments one pass of ``_held_experts``
-#: takes. A pass gathers its rows, runs the three grouped matmuls on them
-#: and adds their weighted outputs into the tokens' rows; the number of
+#: takes. A pass gathers its rows, runs the grouped matmuls on them (three
+#: for gated experts, two for plain ones) and adds their weighted outputs into the tokens' rows; the number of
 #: passes follows the rows held (``ceil(rows / HELD_ROW_TILE)``), so nothing
 #: is sized for the router's worst case and nothing is dropped. A blocking
 #: size, not a limit: what a pass pays whatever its fill (the sort of a tile
@@ -290,7 +318,10 @@ def _sum_back(y, rows, token, weight, mesh):
 #: gathers and the sum back, ``ops/pallas/moe_combine.py``, follow the rows
 #: held, 4096 and 128 at a time) is paid once for up to 8192 rows, four
 #: times what a balanced router sends to 8 of 256 experts from 8192 tokens,
-#: and a pass's rows and products stay under 0.2 GiB. With 14% of the
+#: and a pass's rows and products stay under 0.2 GiB (rows of the hidden
+#: size; where the experts work on a latent the rows are that wide: 1024
+#: for Nemotron-H's, 16 MiB a pass in bfloat16 beside products of 2688).
+#: With 14% of the
 #: assignments held (9218 rows in the fullest layer: two passes) the Kimi
 #: Linear step is 5.6 ms longer than with 4.4%, 4.2 ms of it the experts'
 #: products on their rows (PERF.md section 6, PR 30).
@@ -330,8 +361,9 @@ def _held_pass(i, order, top_p, sizes, tile):
     return at, weight, part, jnp.clip(ends[-1] - lo, 0, tile)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
-def _held_experts(xt, top_p, weights, order, sizes, top_k, mesh, tile):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
+def _held_experts(xt, top_p, weights, order, sizes, top_k, mesh, tile,
+                  activation):
     """The experts' part for a layer that holds a share of them, [T, D]
     float32. ``order`` [a multiple of ``tile``] lists the assignments with
     the held ones first, sorted by expert; ``sizes`` [n] how many each held
@@ -353,19 +385,21 @@ def _held_experts(xt, top_p, weights, order, sizes, top_k, mesh, tile):
         with jax.named_scope("moe_dispatch"):
             token = at // top_k
             rows = rows_held(xt, token, held)
-        out = _gated_experts(rows, weights, part, mesh)
+        out = _experts(rows, weights, part, mesh, activation)
         return _sum_back(y, out, token, weight, mesh)
 
     return jax.lax.fori_loop(0, passes, one,
                              jnp.zeros(xt.shape, jnp.float32))
 
 
-def _held_fwd(xt, top_p, weights, order, sizes, top_k, mesh, tile):
+def _held_fwd(xt, top_p, weights, order, sizes, top_k, mesh, tile,
+              activation):
     return (_held_experts(xt, top_p, weights, order, sizes, top_k, mesh,
-                          tile), (xt, top_p, weights, order, sizes))
+                          tile, activation),
+            (xt, top_p, weights, order, sizes))
 
 
-def _held_bwd(top_k, mesh, tile, kept, dy):
+def _held_bwd(top_k, mesh, tile, activation, kept, dy):
     from paddle_tpu.ops.pallas.moe_combine import rows_held
 
     xt, top_p, weights, order, sizes = kept
@@ -379,7 +413,8 @@ def _held_bwd(top_k, mesh, tile, kept, dy):
             rows = rows_held(xt, token, held)
             dy_rows = rows_held(dy, token, held).astype(jnp.float32)
         out, back = jax.vjp(
-            lambda r, w: _gated_experts(r, w, part, mesh), rows, weights)
+            lambda r, w: _experts(r, w, part, mesh, activation), rows,
+            weights)
         d_rows, dw_pass = back((dy_rows * weight[:, None]).astype(out.dtype))
         with jax.named_scope("moe_dispatch"):
             # a row past the rows held came out zero: its score gets nothing
@@ -401,20 +436,29 @@ _held_experts.defvjp(_held_fwd, _held_bwd)
 
 
 def dropless_moe_ffn(params, x, top_k, mesh=None, scoring=Scoring(),
-                     held=None):
-    """Gated top-k experts with every assignment computed.
+                     held=None, activation="silu"):
+    """Top-k experts with every assignment computed.
 
     x: [..., d_model], leading dims flattened as T tokens. ``params``:
-    ``router_w`` [D, E], ``w_gate`` and ``w_up`` [n, D, F], ``w_down``
-    [n, F, D]; no biases. Optionally ``router_bias`` [E], the selection
-    bias of ``route``, and ``shared_gate`` / ``shared_up`` / ``shared_down``
-    ([D, F], [D, F], [F, D]), a gated expert every token takes: with weight
-    1, or, where the parameters have ``shared_scale_w`` [D], with the weight
+    ``router_w`` [D, E], and the experts' stacks, no biases: ``w_gate`` and
+    ``w_up`` [n, D, F] with ``w_down`` [n, F, D], SiLU-gated experts; or,
+    without ``w_gate``, plain ones whose non-linearity ``activation`` names
+    (``ACTIVATIONS``). Optionally ``router_bias`` [E], the selection bias of
+    ``route``; a shared expert every token takes, of the same two forms
+    (``shared_gate`` / ``shared_up`` / ``shared_down``, [D, F], [D, F],
+    [F, D], or ``shared_up`` and ``shared_down`` alone): with weight 1, or,
+    where the parameters have ``shared_scale_w`` [D], with the weight
     ``sigmoid(x . shared_scale_w)``, a scalar a token (float32; no relation
-    of ``shared_gate``, which is the shared expert's SiLU branch). Per token ``sum_e w_e * w_down_e (silu(w_gate_e x) * w_up_e x)`` over
-    its ``top_k`` experts, chosen and weighted as ``scoring`` says (default:
-    the largest softmax probabilities, NOT renormalised). Router in float32,
-    experts in ``x.dtype``.
+    of ``shared_gate``, which is the shared expert's SiLU branch); and
+    ``latent_down`` [D, L] with ``latent_up`` [L, D] (LatentMoE): the routed
+    experts then work on ``l = x latent_down``, their stacks are [n, L, F]
+    and [n, F, L], and the weighted sum of their outputs goes back through
+    ``latent_up``; the router and the shared expert stay on x. Per token
+    ``sum_e w_e * w_down_e (silu(w_gate_e x) * w_up_e x)``, or ``sum_e w_e *
+    w_down_e act(w_up_e x)``, over its ``top_k`` experts, chosen and
+    weighted as ``scoring`` says (default: the largest softmax
+    probabilities, NOT renormalised). Router in float32, experts in
+    ``x.dtype``.
 
     ``held`` = (first, n): this layer holds the experts ``first`` to
     ``first + n - 1`` of the router's E, and the stacks have n matrices. It
@@ -434,9 +478,8 @@ def dropless_moe_ffn(params, x, top_k, mesh=None, scoring=Scoring(),
 
     Under a mesh the grouped matmul takes the body GSPMD can partition
     (``mesh_scope``). Named scopes, inside the caller's ``ffn``:
-    ``moe_router``, ``moe_dispatch``, ``moe_experts``, ``moe_shared``."""
-    from paddle_tpu.models.blocks import gated_ffn
-
+    ``moe_router``, ``moe_dispatch``, ``moe_experts``, ``moe_shared``, and
+    ``moe_latent`` around the latent's two projections."""
     shape = x.shape
     xt = x.reshape(-1, shape[-1])
     e = params["router_w"].shape[-1]
@@ -449,16 +492,21 @@ def dropless_moe_ffn(params, x, top_k, mesh=None, scoring=Scoring(),
                "z": jnp.mean(jnp.square(
                    jax.nn.logsumexp(logits, axis=-1))),
                "counts": counts, "choice": top_e}
-    weights = (params["w_gate"], params["w_up"], params["w_down"])
+    weights = tuple(params[name] for name in ("w_gate", "w_up", "w_down")
+                    if name in params)
+    rows_of = xt                      # what the routed experts work on
+    if "latent_down" in params:
+        with jax.named_scope("moe_latent"):
+            rows_of = xt @ params["latent_down"].astype(xt.dtype)
     if held is None:
         with jax.named_scope("moe_dispatch"):
             order = jnp.argsort(top_e.reshape(-1), stable=True)    # [k T]
             inverse = jnp.argsort(order)
-            rows = _rows_in_expert_order(xt, order, inverse, top_k)
-        out = _gated_experts(rows, weights, counts, mesh)
+            rows = _rows_in_expert_order(rows_of, order, inverse, top_k)
+        out = _experts(rows, weights, counts, mesh, activation)
         with jax.named_scope("moe_dispatch"):
             out = _rows_in_token_order(out, order, inverse)
-            y = jnp.sum(out.reshape(-1, top_k, shape[-1])
+            y = jnp.sum(out.reshape(-1, top_k, rows_of.shape[-1])
                         .astype(jnp.float32) * top_p[..., None], axis=1)
     else:
         first, n = held
@@ -468,12 +516,20 @@ def dropless_moe_ffn(params, x, top_k, mesh=None, scoring=Scoring(),
             order = jnp.argsort(key.reshape(-1), stable=True)
             tile = _held_row_tile(order.shape[0], n, e)
             order = jnp.pad(order, (0, -order.shape[0] % tile))
-        y = _held_experts(xt, top_p, weights, order,
-                          counts[first:first + n], top_k, mesh, tile)
-    if "shared_gate" in params:
+        y = _held_experts(rows_of, top_p, weights, order,
+                          counts[first:first + n], top_k, mesh, tile,
+                          activation)
+    if "latent_up" in params:
+        with jax.named_scope("moe_latent"):
+            y = jnp.dot(y.astype(xt.dtype),
+                        params["latent_up"].astype(xt.dtype),
+                        preferred_element_type=jnp.float32)
+    if "shared_up" in params:
         with jax.named_scope("moe_shared"):
-            shared = gated_ffn(xt, params["shared_gate"], params["shared_up"],
-                               params["shared_down"]).astype(jnp.float32)
+            shared = _feed(xt, tuple(
+                params[name] for name in ("shared_gate", "shared_up",
+                                          "shared_down") if name in params),
+                activation).astype(jnp.float32)
             if "shared_scale_w" in params:
                 shared = shared * jax.nn.sigmoid(jnp.dot(
                     xt, params["shared_scale_w"].astype(xt.dtype),

@@ -235,7 +235,7 @@ def test_dy_gathered_before_the_cast_gives_the_same_gradients(body):
     kept = (xt, top_p, weights, order, sizes)
     # op by op on both sides, so that the two differ in the gather alone
     with plk.override(body), jax.disable_jit():
-        narrow = moe._held_bwd(4, None, tile, kept, dy)
+        narrow = moe._held_bwd(4, None, tile, "silu", kept, dy)
         wide = _held_bwd_float32_gather(4, tile, kept,
                                         dy.astype(jnp.float32))
     for a, b in zip(jax.tree.leaves(narrow), jax.tree.leaves(wide)):
@@ -272,7 +272,8 @@ def _held_bwd_float32_gather(top_k, tile, kept, dy):
         rows = jnp.take(xt, token, axis=0)
         dy_rows = jnp.take(dy, token, axis=0)
         out, back = jax.vjp(
-            lambda r, w: moe._gated_experts(r, w, part, None), rows, weights)
+            lambda r, w: moe._experts(r, w, part, None, "silu"), rows,
+            weights)
         d_rows, dw_pass = back((dy_rows * weight[:, None]).astype(out.dtype))
         dp = dp.at[at].add(jnp.sum(out.astype(jnp.float32) * dy_rows,
                                    axis=-1))
