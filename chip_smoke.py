@@ -273,6 +273,15 @@ def phase_kernels():
         # the taps
         "gated_short_conv": ((bf16(2, 1200, 3072), f32(3, 1024, scale=0.3)),
                              {}, (0, 1), 2e-2),
+        # Mamba-2's scan as a Nemotron-H mixer hands it over: 8 heads of 64
+        # under 2 groups of B and C with a state of 128, 1200 positions
+        # (five grid steps of two chunks with padding), steps in (0, 0.7)
+        # and a log-decay down to -11 a position; x, dt, a, B, C, D
+        "ssd": ((bf16(2, 1200, 8, 64), jax.nn.softplus(f32(2, 1200, 8) - 2.0),
+                 -jnp.exp(jnp.linspace(0.0, 2.7, 8))
+                 * jax.nn.softplus(f32(2, 1200, 8) - 2.0),
+                 bf16(2, 1200, 2, 128), bf16(2, 1200, 2, 128), f32(8)),
+                {"chunk": 128}, (0, 1, 2, 3, 4, 5), 3e-2),
         # heads of 64, half a lane tile: 32 query heads on 8 key/value heads
         "flash_attention/d64_gqa": ((bf16(1, 32, 4 * SEQ, 64),
                                      bf16(1, 8, 4 * SEQ, 64),

@@ -26,6 +26,7 @@ from jax.ad_checkpoint import checkpoint_name
 
 from paddle_tpu.ops.pallas.flash_attention import KEPT as _FLASH_KEPT
 from paddle_tpu.ops.pallas.kda import KEPT as _KDA_KEPT
+from paddle_tpu.ops.pallas.ssd import KEPT as _SSD_KEPT
 from paddle_tpu.ops.pallas.registry import mesh_scope, selected_body
 
 __all__ = ["rms_norm", "rms_normalize", "yarn_inv_freq", "rope_angles",
@@ -294,9 +295,10 @@ def recomputed(mixer):
     """``mixer`` under a ``jax.checkpoint`` that keeps, beside the mixer's
     inputs, what its attention kernel's forward call hands the backward one
     and nothing but that kernel can make: the names the flash calls and the
-    delta rule put on those residuals inside their ``custom_vjp`` forward
-    rules (``flash_attention.KEPT``: o and lse; ``kda.KEPT``: o, the state a
-    unit starts from and its three tiles). The backward pass forms the cheap
+    delta rule and the state-space scan put on those residuals inside their
+    ``custom_vjp`` forward rules (``flash_attention.KEPT``: o and lse;
+    ``kda.KEPT``: o, the state a unit starts from and its three tiles;
+    ``ssd.KEPT``: y and the state a chunk starts from). The backward pass forms the cheap
     passes around the kernel again (norms, projections, rotation,
     convolutions, gates: the kernel's operands, which the weights' gradients
     need anyway) and the forward kernel, whose outputs are all kept, is not
@@ -310,7 +312,7 @@ def recomputed(mixer):
     ``jax.checkpoint``."""
     return jax.checkpoint(
         mixer, policy=jax.checkpoint_policies.save_only_these_names(
-            _FLASH_KEPT, _KDA_KEPT, LATENT_KEPT))
+            _FLASH_KEPT, _KDA_KEPT, _SSD_KEPT, LATENT_KEPT))
 
 
 def gated_ffn(x, w_gate, w_up, w_down, matmul=jnp.matmul):

@@ -17,7 +17,9 @@ no feed-forward behind attention. Then a final RMSNorm and an untied head.
   ``ssd_gate``, float32); the state-space scan with the skip ``D x``
   (``ops/ssd.ssd_chunked`` in chunks of ``chunk_size``, scope ``ssd_core``:
   a state of ``[mamba_head_dim, state_size]`` a head, ``B`` and ``C`` shared
-  by the heads of a group); ``RMSNorm(y * silu(z))`` with the mean square
+  by the heads of a group; the registry's kernel ``ssd``: on one chip the
+  Mosaic kernels of ``ops/pallas/ssd.py``, which read ``x``, ``B`` and ``C``
+  as the convolution leaves them, elsewhere the ``jax.numpy`` body); ``RMSNorm(y * silu(z))`` with the mean square
   taken over each group's channels (``ssd_gate`` again: the gate before the
   norm, Mamba-2's ``norm_before_gate`` false); the output product.
 - **\\*, attention**: ``num_heads`` queries of ``head_dim`` over
@@ -44,14 +46,17 @@ rows of the heads here, and what they give is this chip's part of the sum,
 as the held experts' is. Nothing stands in for the absent heads.
 
 Every M and ``*`` mixer is recomputed in the backward pass from its input
-(``blocks.recomputed``, which keeps the flash call's outputs); the experts
-recompute their own part. Built like ``models/deepseek_v3.py``: float32
+(``blocks.recomputed``, which keeps what the flash call and, where the
+registry selects the scan's Mosaic kernels, ``ssd_fwd`` hand their backward
+kernels: no forward kernel runs twice); the experts recompute their own
+part. Built like ``models/deepseek_v3.py``: float32
 master parameters, ``cfg.dtype`` (bfloat16) activations and matmul operands,
 one jitted step (``models/lm_trainer.py``). No router, attention or trainer
 code of its own.
 """
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -252,6 +257,23 @@ def _causal_conv(x, taps, bias):
     return jax.nn.silu(y).astype(x.dtype)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def _column_ranges(x, sizes):
+    """``jnp.split`` of x [.., C] into column ranges of ``sizes``, whose
+    gradient is the ranges' gradients side by side: one concatenation, where
+    autodiff's is a padded copy a range and their sum. The scan's kernels
+    read the ranges where they lie and write one array's gradient
+    (``ops/pallas/ssd.py``); with this rule the compiler sees a
+    concatenation of that array's own ranges and folds it away."""
+    at = [sum(sizes[:i + 1]) for i in range(len(sizes) - 1)]
+    return tuple(jnp.split(x, at, axis=-1))
+
+
+_column_ranges.defvjp(
+    lambda x, sizes: (_column_ranges(x, sizes), None),
+    lambda sizes, _, parts: (jnp.concatenate(parts, axis=-1),))
+
+
 @jax.named_scope("attention")
 def _mamba(lp, x, cfg):
     b, s, _ = x.shape
@@ -260,8 +282,8 @@ def _mamba(lp, x, cfg):
     bc = cfg.mamba_groups * cfg.state_size
     z, xbc, step = jnp.split(x @ lp["in_w"].astype(dt),
                              [inner, 2 * inner + 2 * bc], axis=-1)
-    xs, B, C = jnp.split(_causal_conv(xbc, lp["conv_w"], lp["conv_b"]),
-                         [inner, inner + bc], axis=-1)
+    xs, B, C = _column_ranges(_causal_conv(xbc, lp["conv_w"], lp["conv_b"]),
+                              (inner, bc, bc))
     with jax.named_scope("ssd_gate"):
         step = jax.nn.softplus(step.astype(jnp.float32) + lp["dt_bias"])
         decay = -jnp.exp(lp["A_log"]) * step             # its log, <= 0
@@ -273,10 +295,15 @@ def _mamba(lp, x, cfg):
     with jax.named_scope("ssd_gate"):
         gated = y.reshape(b, s, inner).astype(jnp.float32) \
             * jax.nn.silu(z.astype(jnp.float32))
-        y = blocks.rms_normalize(
-            gated.reshape(b, s, cfg.mamba_groups, -1),
-            lp["norm_g"].reshape(cfg.mamba_groups, -1),
-            cfg.rms_eps).reshape(b, s, inner).astype(dt)
+        # the norm a group on the group's own columns of the rows-major
+        # array: a [.., groups, channels] view of it is a relayout on the
+        # chip wherever the scan's kernels, which write rows, produced y
+        y = jnp.concatenate(
+            [blocks.rms_normalize(part, gain, cfg.rms_eps)
+             for part, gain in zip(
+                 jnp.split(gated, cfg.mamba_groups, axis=-1),
+                 jnp.split(lp["norm_g"], cfg.mamba_groups))],
+            axis=-1).astype(dt)
     return y @ lp["out_w"].astype(dt)
 
 
@@ -294,7 +321,8 @@ def _block(lp, x, cfg, layer, rotary, mesh=None):
     """One layer, a mixer alone: (the stream after it, an expert layer's aux
     terms or None). ``rotary`` is None: no mixer takes positions. The M and
     ``*`` mixers are recomputed in the backward pass from their input, but
-    for the flash call's outputs; the experts recompute their own part."""
+    for what their forward kernels (the flash call, the scan's) hand the
+    backward ones; the experts recompute their own part."""
     kind = cfg.kind(layer)
     if kind == "E":
         with jax.named_scope("ffn"):

@@ -11,7 +11,10 @@ the recurrence it stands for) and the two passes around it, short_conv_norm
 and gated_head_norm (delta_glue.py), gated_short_conv (gated_conv.py),
 the LFM2 family's double-gated convolution, and moe_combine
 (moe_combine.py), the way back of an expert layer that holds a share of the
-experts: a pass's rows, each times its float32 weight, summed into their
+experts, and ssd (ssd.py; its entry point is
+``paddle_tpu.ops.ssd.ssd_chunked``, beside the reference body and the
+recurrence it stands for), Mamba-2's chunked state-space scan. Of
+moe_combine: a pass's rows, each times its float32 weight, summed into their
 tokens' rows. Its reference body is XLA's scatter-add (the CPU, and any mesh
 of more than one device); its Pallas body puts the rows in token order and
 sums them on the MXU, for ``y`` [T, D] float32 with D in whole lane tiles
@@ -36,6 +39,7 @@ from paddle_tpu.ops.pallas.layer_norm import fused_layer_norm
 from paddle_tpu.ops.pallas.moe_combine import moe_combine
 from paddle_tpu.ops.pallas.matmul import try_fused_matmul
 from paddle_tpu.ops.pallas.softmax_xent import softmax_cross_entropy
+from paddle_tpu.ops.pallas import ssd as _ssd  # noqa: F401
 
 __all__ = [
     "register_kernel", "get_kernel", "list_kernels", "dispatch",

@@ -52,7 +52,7 @@ __all__ = [
     "register_kernel", "get_kernel", "list_kernels", "dispatch",
     "get_body", "selected_body", "use_pallas", "selection_mode",
     "override", "mesh_scope", "platform", "within_vmem_budget",
-    "vmem_spec", "traced_once", "DEFAULT_VMEM_BUDGET",
+    "vmem_spec", "traced_once", "lowered_once", "DEFAULT_VMEM_BUDGET",
 ]
 
 #: fp32 elements one operand may hold whole in VMEM: 8 MiB, half of
@@ -285,6 +285,34 @@ def traced_once(jitted, *args):
         return jitted(*args)
     with jax.sharding.use_abstract_mesh(_NO_MESH):
         return jitted(*args)
+
+
+def lowered_once(jitted, arrays, static=()):
+    """``traced_once(jitted, *arrays, *static)`` as one equation no transform
+    opens: for a forward kernel's jitted call inside a ``custom_vjp`` forward
+    rule that a ``jax.checkpoint`` differentiates (``blocks.recomputed``).
+    The checkpoint's partial evaluation has a rule for a ``jit`` equation and
+    applies it to one whose inputs are all known too: it cuts the inner jaxpr
+    into a known and a staged part anew at every call site, and the lowering,
+    which keys a jitted function on its jaxpr, then lowers the kernel to
+    Mosaic once a layer where the backward call, untouched, is one function
+    called from every layer (0.15 s a call site on the chip's host: PERF.md
+    section 6, PR 50). A ``custom_jvp`` call has no such rule and is lowered
+    inline, so the ``jit`` inside reaches the lowering with the jaxpr it was
+    traced to. It is never differentiated: the ``custom_vjp`` around it is.
+    jax 0.9.0's behaviour; ``tests/test_nemotron_h_aot.py`` counts the
+    functions when jax moves."""
+    @jax.custom_jvp
+    def call(*arrays):
+        return traced_once(jitted, *arrays, *static)
+
+    @call.defjvp
+    def _(primals, tangents):
+        raise NotImplementedError(
+            f"{jitted} is the forward call of a custom_vjp: differentiate "
+            "that")
+
+    return call(*arrays)
 
 
 def within_vmem_budget(kernel, elements, budget=None):

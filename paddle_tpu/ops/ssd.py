@@ -36,10 +36,19 @@ dtype (bfloat16 in a model) with float32 accumulation.
 
 A length that is no multiple of the chunk is padded with positions that
 change nothing (``a`` = 0, ``dt`` = 0) and the padding's outputs are cut
-off. The backward pass is autodiff through this code; a caller that cannot
-keep a layer's [chunks, H, chunk, chunk] decays wraps the mixer in
-``jax.checkpoint`` (``models/nemotron_h.py`` does). One body, ``jax.numpy``:
-a Mosaic body is ROADMAP B's.
+off.
+
+Two bodies, chosen by the kernel registry (``ops/pallas/registry.py``:
+platform and mesh, nothing a user sets), kernel ``ssd``. The ``jax.numpy``
+body here, ``_ssd_chunked``, is the reference: the CPU's, a multi-device
+mesh's, and that of a shape the kernels cannot tile. Its backward pass is
+autodiff through this code, so a caller that cannot keep a layer's [chunks,
+H, chunk, chunk] decays wraps the mixer in ``jax.checkpoint``. On one chip
+the Mosaic kernels ``ssd_fwd`` and ``ssd_bwd`` of ``ops/pallas/ssd.py`` run
+the same sums a chunk at a time with the states in VMEM, read the operands
+in the layouts above and keep ``y`` and every second chunk's starting state for
+their own backward under a name (``ops/pallas/ssd.KEPT``), which
+``models/blocks.recomputed`` saves: the forward runs once a layer.
 """
 
 import functools
@@ -47,6 +56,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from paddle_tpu.ops.pallas import registry as _registry
 
 __all__ = ["CHUNK", "ssd_chunked", "ssd_recurrent"]
 
@@ -133,9 +144,10 @@ def _ssd_chunked(x, dt, a, B, C, D, chunk):
 
 def ssd_chunked(x, dt, a, B, C, D=None, chunk=CHUNK):
     """The chunked form (module docstring); returns y [b, S, H, P] in
-    ``x.dtype``. Jitted, so a model's layers, which call it with the same
-    shapes, share one trace."""
+    ``x.dtype``, by the body the registry selects (kernel ``ssd``). Either
+    body is jitted, so a model's layers, which call it with the same shapes,
+    share one trace."""
     if x.shape[2] % B.shape[2]:
         raise ValueError(f"{B.shape[2]} groups of B and C do not divide "
                          f"{x.shape[2]} heads")
-    return _ssd_chunked(x, dt, a, B, C, D, chunk)
+    return _registry.dispatch("ssd", x, dt, a, B, C, D, chunk)

@@ -17,6 +17,7 @@ parameter count, the counters the benchmark reads, and the lowered step of a
 decoder this PR does not touch.
 """
 
+import collections
 import dataclasses
 import hashlib
 import importlib.util
@@ -578,6 +579,75 @@ def test_a_decoder_without_the_new_mechanisms_lowers_as_before():
     assert len(step_fn.jitted.eval_shape(params, opt_state,
                                          step_fn.place(batch))) == 4
     assert hashlib.sha256(text.encode()).hexdigest() == KANANA_TINY_STEP
+
+
+#: the same of ``kimi_linear_tiny``'s train step (experts 4 to 7 held, Adam,
+#: batch 2 x 48, one device) on PR 48's tree, the parent of the PR that gave
+#: the state-space scan its Mosaic body: the delta-rule decoders share
+#: ``blocks.recomputed``, whose policy gained a name their traces do not
+#: hold, and the registry, which gained a kernel they never dispatch.
+KIMI_TINY_STEP = \
+    "e8f8dcc8b3c9208e2c99d1d1a977608d0481752ac9d0482ec356ef1dd9c8105f"
+
+
+def test_a_delta_rule_decoder_lowers_as_before_the_scan_s_kernels():
+    from paddle_tpu.models import kimi_linear
+    cfg = kimi_linear.kimi_linear_tiny(experts_held=(4, 4))
+    init_fn, step_fn = kimi_linear.make_train_step(
+        cfg, pt.optimizer.Adam(learning_rate=1e-3), one_device())
+    params, opt_state = jax.eval_shape(init_fn, jax.random.PRNGKey(0))
+    batch = kimi_linear.synthetic_batch(cfg, 2, 48)
+    text = step_fn.jitted.lower(params, opt_state,
+                                step_fn.place(batch)).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() == KIMI_TINY_STEP
+
+
+# ---------------------------------------------------------------------------
+# the set-up: the scan's kernels are traced for the step, not for every layer
+# ---------------------------------------------------------------------------
+def test_a_step_traces_the_scan_s_kernel_bodies_once_each(monkeypatch):
+    """Three Mamba layers, each recomputed in the backward pass, with the
+    Pallas bodies forced: tracing the step enters ``ssd_fwd``'s body once
+    and ``ssd_bwd``'s once. The calls are jitted functions the layers
+    share, entered through ``registry.traced_once`` so that the pass and
+    the checkpoint's JVP share a trace context (``tests/test_kimi_linear.py``
+    keeps the same count for the delta rule): a body traced and lowered to
+    Mosaic anew a call site is what PR 49's set-up paid. The gradient holds
+    the forward kernel once a layer: ``blocks.recomputed`` keeps what it
+    hands the backward one."""
+    from paddle_tpu.ops import pallas as plk
+    from paddle_tpu.ops.pallas import ssd as ssd_kernels
+    entered = {}
+    for name in ("_fwd_kernel", "_bwd_kernel"):
+        def enter(*args, _body=getattr(ssd_kernels, name), _name=name, **kw):
+            entered[_name] = entered.get(_name, 0) + 1
+            return _body(*args, **kw)
+
+        monkeypatch.setattr(ssd_kernels, name, enter)
+    # heads of 64 under groups with a state of 128: shapes the blocks tile
+    cfg = nemotron_h.nemotron_h_tiny(
+        pattern="MEMM*", mamba_heads=4, mamba_head_dim=64, mamba_groups=2,
+        state_size=128, experts_held=(4, 4))
+    init_fn, step_fn = nemotron_h.make_train_step(
+        cfg, pt.optimizer.Adam(1e-3), one_device())
+    params, opt_state = jax.eval_shape(init_fn, jax.random.PRNGKey(0))
+    batch = jax.eval_shape(step_fn.place,
+                           nemotron_h.synthetic_batch(cfg, 1, 64))
+    jax.clear_caches()            # what earlier tests of this process traced
+    with plk.override("on"):
+        traced = step_fn.jitted.trace(params, opt_state, batch)
+    assert entered == {"_fwd_kernel": 1, "_bwd_kernel": 1}, entered
+    calls = collections.Counter()
+
+    def count(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                calls[eqn.params["name"]] += 1
+            for inner in jax.core.jaxprs_in_params(eqn.params):
+                count(inner)
+
+    count(traced.jaxpr.jaxpr)
+    assert calls["ssd_fwd"] == 3 == calls["ssd_bwd"], calls
 
 
 def test_blocks_rms_normalize_takes_a_gain_a_group():

@@ -12,7 +12,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from paddle_tpu.ops import kda
+from paddle_tpu.ops import kda, ssd
 from paddle_tpu.ops import pallas as K
 from paddle_tpu.ops.selected_rows import (
     SelectedRows, get_tensor_from_selected_rows,
@@ -64,6 +64,9 @@ ENTRY_POINTS = {
         jnp.zeros((16, 128)), jnp.ones((8, 128), jnp.bfloat16),
         jnp.asarray([3, 3, 0, 9, 15, 2, 2, 2], jnp.int32),
         jnp.asarray([1, .5, 2, 1, 1, 0, 0, 0], jnp.float32)),
+    "ssd": lambda: ssd.ssd_chunked(
+        jnp.ones((1, 32, 2, 64)), jnp.ones((1, 32, 2)), -jnp.ones((1, 32, 2)),
+        jnp.ones((1, 32, 1, 16)), jnp.ones((1, 32, 1, 16)), chunk=16),
 }
 
 

@@ -28,7 +28,7 @@ RNG = np.random.RandomState(42)
 KERNELS = ["embedding_scatter_add", "flash_attention", "fused_layer_norm",
            "fused_matmul", "fused_matmul_int8", "gated_head_norm",
            "gated_short_conv", "grouped_matmul", "kda_chunked", "moe_combine",
-           "short_conv_norm", "softmax_cross_entropy"]
+           "short_conv_norm", "softmax_cross_entropy", "ssd"]
 
 
 def _f(shape, dtype=jnp.float32, scale=1.0):

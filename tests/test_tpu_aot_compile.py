@@ -140,6 +140,11 @@ KERNEL_SHAPES = {
     # [B | C | u] and the 3 taps
     "gated_short_conv": ([((4, 8192, 6144), BF16), ((3, 2048), F32)], {},
                          True),
+    # a Mamba-2 layer of nemotron_3_super_120b_a12b.lm_s8192: x, dt, a, B,
+    # C, D: 32 heads of 64 under 2 groups with a state of 128
+    "ssd": ([((1, 8192, 32, 64), BF16), ((1, 8192, 32), F32),
+             ((1, 8192, 32), F32), ((1, 8192, 2, 128), BF16),
+             ((1, 8192, 2, 128), BF16), ((32,), F32)], {"chunk": 128}, True),
 }
 
 
