@@ -36,10 +36,10 @@ experts replicated under a ``data`` mesh), or the range of experts it is
 told it holds, as one chip of an expert-parallel job does: it routes over
 all of them, computes the part of the result its own experts give and
 leaves the rest out (the exchange that would bring the other chips' parts
-is ROADMAP B4; nothing here stands in for it). A shared expert, where the
+is ROADMAP B1; nothing here stands in for it). A shared expert, where the
 parameters have one, is applied to every token. Both layers take their gate
 from ``route`` and their balance term from ``balance_loss``. The capacity
-path goes when the sharded dropless exchange lands (ROADMAP B4).
+path goes when the sharded dropless exchange lands (ROADMAP B1).
 """
 
 import dataclasses
@@ -54,7 +54,7 @@ from paddle_tpu.parallel.mesh import EXPERT_AXIS
 
 __all__ = ["MoEConfig", "init_moe_params", "moe_ffn",
            "moe_param_specs", "Scoring", "route", "bias_step", "balance_loss",
-           "dropless_moe_ffn"]
+           "dropless_moe_ffn", "held_passes"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -129,21 +129,28 @@ def route(x32, gate_w, top_k, scoring=Scoring(), bias=None):
     the ``top_k`` experts of each token with their weights. With ``bias``
     [E] the experts are the largest ``scores + bias`` and the weights are
     taken from the scores without it; the bias is outside the gradient.
-    Returns (logits, scores, top_p [T, k], top_e [T, k])."""
-    logits = jnp.dot(x32, gate_w.astype(jnp.float32),
-                     precision=jax.lax.Precision.HIGHEST)
-    probs = jax.nn.softmax(logits, axis=-1) \
-        if scoring.activation == "softmax" else jax.nn.sigmoid(logits)
-    if bias is None:
-        top_p, top_e = jax.lax.top_k(probs, top_k)
-    else:
-        _, top_e = jax.lax.top_k(
-            probs + jax.lax.stop_gradient(bias.astype(jnp.float32)), top_k)
-        top_p = jnp.take_along_axis(probs, top_e, axis=-1)
-    if scoring.renormalize:
-        top_p = top_p / (jnp.sum(top_p, axis=-1, keepdims=True) + 1e-20)
-    if scoring.scale != 1.0:
-        top_p = top_p * scoring.scale
+    Returns (logits, scores, top_p [T, k], top_e [T, k]). Three stage scopes
+    (``dropless_moe_ffn`` lists all seven): ``router_logits``,
+    ``router_scores``, ``router_select``."""
+    with jax.named_scope("router_logits"):
+        logits = jnp.dot(x32, gate_w.astype(jnp.float32),
+                         precision=jax.lax.Precision.HIGHEST)
+    with jax.named_scope("router_scores"):
+        probs = jax.nn.softmax(logits, axis=-1) \
+            if scoring.activation == "softmax" else jax.nn.sigmoid(logits)
+    with jax.named_scope("router_select"):
+        if bias is None:
+            top_p, top_e = jax.lax.top_k(probs, top_k)
+        else:
+            _, top_e = jax.lax.top_k(
+                probs + jax.lax.stop_gradient(bias.astype(jnp.float32)),
+                top_k)
+            top_p = jnp.take_along_axis(probs, top_e, axis=-1)
+    with jax.named_scope("router_scores"):
+        if scoring.renormalize:
+            top_p = top_p / (jnp.sum(top_p, axis=-1, keepdims=True) + 1e-20)
+        if scoring.scale != 1.0:
+            top_p = top_p * scoring.scale
     return logits, probs, top_p, top_e
 
 
@@ -303,7 +310,8 @@ def _sum_back(y, rows, token, weight, mesh):
     from paddle_tpu.ops.pallas.moe_combine import moe_combine
     from paddle_tpu.ops.pallas.registry import mesh_scope
 
-    with jax.named_scope("moe_dispatch"), mesh_scope(mesh):
+    with jax.named_scope("moe_dispatch"), \
+            jax.named_scope("dispatch_combine"), mesh_scope(mesh):
         return moe_combine(y, rows, token, weight)
 
 
@@ -347,6 +355,15 @@ def _held_row_tile(assignments, held, experts):
     return min(HELD_ROW_TILE * tiles, assignments)
 
 
+def held_passes(rows, tile):
+    """Passes of ``tile`` rows that ``rows`` held assignments take,
+    ``ceil(rows / tile)``: the trip count of ``_held_experts``' loop, forward
+    and backward, and what a reader of a step's counts (``step_fn.aux``)
+    calls to say how many passes that step ran. ``rows`` a jax or a NumPy
+    value, a scalar or one number an expert layer."""
+    return -(-rows // tile)
+
+
 def _held_pass(i, order, top_p, sizes, tile):
     """What pass ``i`` works on: the places [tile] its assignments have in
     (token, choice) order, their weights (0 past the rows held), the rows
@@ -378,13 +395,16 @@ def _held_experts(xt, top_p, weights, order, sizes, top_k, mesh, tile,
     scalars (0.13 to 0.32 ms a pass: PERF.md section 6, PR 41)."""
     from paddle_tpu.ops.pallas.moe_combine import rows_held
 
-    passes = -(-jnp.sum(sizes) // tile)
+    passes = held_passes(jnp.sum(sizes), tile)
 
     def one(i, y):
-        at, weight, part, held = _held_pass(i, order, top_p, sizes, tile)
         with jax.named_scope("moe_dispatch"):
-            token = at // top_k
-            rows = rows_held(xt, token, held)
+            with jax.named_scope("dispatch_order"):
+                at, weight, part, held = _held_pass(i, order, top_p, sizes,
+                                                    tile)
+            with jax.named_scope("dispatch_gather"):
+                token = at // top_k
+                rows = rows_held(xt, token, held)
         out = _experts(rows, weights, part, mesh, activation)
         return _sum_back(y, out, token, weight, mesh)
 
@@ -403,20 +423,24 @@ def _held_bwd(top_k, mesh, tile, activation, kept, dy):
     from paddle_tpu.ops.pallas.moe_combine import rows_held
 
     xt, top_p, weights, order, sizes = kept
-    passes = -(-jnp.sum(sizes) // tile)
+    passes = held_passes(jnp.sum(sizes), tile)
 
     def one(i, grads):
         dx, dp, dw = grads
-        at, weight, part, held = _held_pass(i, order, top_p, sizes, tile)
         with jax.named_scope("moe_dispatch"):
-            token = at // top_k
-            rows = rows_held(xt, token, held)
-            dy_rows = rows_held(dy, token, held).astype(jnp.float32)
+            with jax.named_scope("dispatch_order"):
+                at, weight, part, held = _held_pass(i, order, top_p, sizes,
+                                                    tile)
+            with jax.named_scope("dispatch_gather"):
+                token = at // top_k
+                rows = rows_held(xt, token, held)
+                dy_rows = rows_held(dy, token, held).astype(jnp.float32)
         out, back = jax.vjp(
             lambda r, w: _experts(r, w, part, mesh, activation), rows,
             weights)
         d_rows, dw_pass = back((dy_rows * weight[:, None]).astype(out.dtype))
-        with jax.named_scope("moe_dispatch"):
+        with jax.named_scope("moe_dispatch"), \
+                jax.named_scope("dispatch_combine"):
             # a row past the rows held came out zero: its score gets nothing
             dp = dp.at[at].add(jnp.sum(out.astype(jnp.float32) * dy_rows,
                                        axis=-1))
@@ -479,19 +503,35 @@ def dropless_moe_ffn(params, x, top_k, mesh=None, scoring=Scoring(),
     Under a mesh the grouped matmul takes the body GSPMD can partition
     (``mesh_scope``). Named scopes, inside the caller's ``ffn``:
     ``moe_router``, ``moe_dispatch``, ``moe_experts``, ``moe_shared``, and
-    ``moe_latent`` around the latent's two projections."""
+    ``moe_latent`` around the latent's two projections. Every operation
+    under ``moe_router`` or ``moe_dispatch`` is under one stage scope
+    besides, forward and backward. In ``moe_router``: ``router_logits`` (the
+    float32 product at ``HIGHEST``; its two gradient products),
+    ``router_scores`` (the softmax or sigmoid, the renormalisation and the
+    scale of the chosen scores), ``router_select`` (the bias, ``top_k``,
+    the chosen scores taken; backward the scatter of their gradient),
+    ``router_stats`` (the counts, the balance term, the z term). In
+    ``moe_dispatch``: ``dispatch_order`` (the keys and their sorts, and in
+    every pass over held rows what the pass works on, ``_held_pass``),
+    ``dispatch_gather`` (the rows, and their gradients, in expert order),
+    ``dispatch_combine`` (the rows back in token order and summed by their
+    scores, ``_sum_back`` both ways, the scores' gradient). The passes a
+    step ran over held rows are ``held_passes`` of its counts."""
     shape = x.shape
     xt = x.reshape(-1, shape[-1])
     e = params["router_w"].shape[-1]
     with jax.named_scope("moe_router"):
+        with jax.named_scope("router_logits"):
+            x32 = xt.astype(jnp.float32)
         logits, probs, top_p, top_e = route(
-            xt.astype(jnp.float32), params["router_w"], top_k, scoring,
+            x32, params["router_w"], top_k, scoring,
             params.get("router_bias"))
-        counts = jnp.bincount(top_e.reshape(-1), length=e)
-        aux = {"balance": balance_loss(probs, counts),
-               "z": jnp.mean(jnp.square(
-                   jax.nn.logsumexp(logits, axis=-1))),
-               "counts": counts, "choice": top_e}
+        with jax.named_scope("router_stats"):
+            counts = jnp.bincount(top_e.reshape(-1), length=e)
+            aux = {"balance": balance_loss(probs, counts),
+                   "z": jnp.mean(jnp.square(
+                       jax.nn.logsumexp(logits, axis=-1))),
+                   "counts": counts, "choice": top_e}
     weights = tuple(params[name] for name in ("w_gate", "w_up", "w_down")
                     if name in params)
     rows_of = xt                      # what the routed experts work on
@@ -500,17 +540,21 @@ def dropless_moe_ffn(params, x, top_k, mesh=None, scoring=Scoring(),
             rows_of = xt @ params["latent_down"].astype(xt.dtype)
     if held is None:
         with jax.named_scope("moe_dispatch"):
-            order = jnp.argsort(top_e.reshape(-1), stable=True)    # [k T]
-            inverse = jnp.argsort(order)
-            rows = _rows_in_expert_order(rows_of, order, inverse, top_k)
+            with jax.named_scope("dispatch_order"):
+                order = jnp.argsort(top_e.reshape(-1), stable=True)  # [k T]
+                inverse = jnp.argsort(order)
+            with jax.named_scope("dispatch_gather"):
+                rows = _rows_in_expert_order(rows_of, order, inverse, top_k)
         out = _experts(rows, weights, counts, mesh, activation)
-        with jax.named_scope("moe_dispatch"):
+        with jax.named_scope("moe_dispatch"), \
+                jax.named_scope("dispatch_combine"):
             out = _rows_in_token_order(out, order, inverse)
             y = jnp.sum(out.reshape(-1, top_k, rows_of.shape[-1])
                         .astype(jnp.float32) * top_p[..., None], axis=1)
     else:
         first, n = held
-        with jax.named_scope("moe_dispatch"):
+        with jax.named_scope("moe_dispatch"), \
+                jax.named_scope("dispatch_order"):
             here = (top_e >= first) & (top_e < first + n)
             key = jnp.where(here, top_e - first, n)     # the others: last
             order = jnp.argsort(key.reshape(-1), stable=True)

@@ -1,8 +1,10 @@
 """The names the train step gives its own parts, as a profile reads them.
 
-``models/bert.py``, ``models/transformer.py``, ``models/olmoe.py`` and
-``optimizer.py`` wrap their parts in ``jax.named_scope`` (one vocabulary for
-the three models; OLMoE nests four names of its own inside it), the step
+``models/bert.py``, ``models/transformer.py``, ``lm_trainer.Decoder``'s
+models and ``optimizer.py`` wrap their parts in ``jax.named_scope`` (one
+vocabulary for all of them; a decoder nests names of its own inside it, and
+``parallel/moe.py`` seven stage names inside ``moe_router`` and
+``moe_dispatch``), the step
 functions write two host spans through ``profiler.RecordEvent``, and
 ``RecordEvent`` is also a ``jax.profiler.TraceAnnotation``. chipbench's
 per-layer metrics key on all three; these tests hold them in place on the
@@ -16,6 +18,7 @@ import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 import paddle_tpu as pt
@@ -23,18 +26,28 @@ from paddle_tpu import profiler
 from paddle_tpu.core import compile_cache
 from paddle_tpu.models import bert, kimi_linear, olmoe, transformer
 from paddle_tpu.monitor import flight_recorder
+from paddle_tpu.parallel import moe
 from paddle_tpu.parallel.mesh import MeshConfig, make_mesh
 
 MODEL_SCOPES = ("embed", "attention", "attention_core", "ffn", "layer_norm",
                 "loss")
+#: the stage scopes of ``parallel/moe.py``: every operation under
+#: ``moe_router`` or ``moe_dispatch`` is under exactly one of them
+ROUTER_STAGES = ("router_logits", "router_scores", "router_select",
+                 "router_stats")
+DISPATCH_STAGES = ("dispatch_order", "dispatch_gather", "dispatch_combine")
 #: what a family's program nests inside those (its chipbench configuration
-#: lists them under "scopes")
+#: lists them under "scopes") and, beside them, the expert layer's stages
+#: that have a backward in that step: OLMoE sorts integers once, outside the
+#: gradient; Kimi Linear's loss has no balance or z term
 FAMILY_SCOPES = {"bert": (), "transformer": (),
                  "olmoe": ("rope", "moe_router", "moe_dispatch",
-                           "moe_experts"),
+                           "moe_experts", *ROUTER_STAGES,
+                           *DISPATCH_STAGES[1:]),
                  "kimi_linear": ("kda_core", "short_conv", "kda_gate",
                                  "mla_expand", "moe_router", "moe_dispatch",
-                                 "moe_experts", "moe_shared")}
+                                 "moe_experts", "moe_shared",
+                                 *ROUTER_STAGES[:3], *DISPATCH_STAGES)}
 
 
 def _tiny(family):
@@ -88,6 +101,143 @@ def test_lowered_step_names_every_scope_forward_and_backward(family):
     assert not any(scope in s for s in stacks
                    if s.startswith("jit(step)/optimizer/")
                    for scope in MODEL_SCOPES)
+
+
+def _expert_params(held, gated, latent):
+    """Parameters of one tiny expert layer: a router 8 wide on a hidden of
+    16, the held experts' stacks (all 8 where ``held`` is None), a selection
+    bias where a share is held, a latent's projections where asked."""
+    keys = iter(jax.random.split(jax.random.PRNGKey(0), 8))
+    n, width = (8 if held is None else held[1]), latent or 16
+
+    def normal(*shape):
+        return 0.1 * jax.random.normal(next(keys), shape)
+
+    params = {"router_w": normal(16, 8), "w_up": normal(n, width, 32),
+              "w_down": normal(n, 32, width)}
+    if gated:
+        params["w_gate"] = normal(n, width, 32)
+    if latent:
+        params.update(latent_down=normal(16, latent),
+                      latent_up=normal(latent, 16))
+    if held is not None:
+        params["router_bias"] = jnp.zeros((8,))
+    return params
+
+
+@pytest.mark.parametrize("held,gated,latent", [
+    (None, True, None), (None, False, None), (None, True, 8),
+    ((2, 4), True, None), ((2, 4), False, None), ((2, 4), False, 8)],
+    ids=["all-gated", "all-plain", "all-gated-latent", "held-gated",
+         "held-plain", "held-plain-latent"])
+def test_every_router_and_dispatch_operation_is_under_one_stage(
+        held, gated, latent):
+    """The lowered value-and-grad of ``dropless_moe_ffn`` inside ``ffn``:
+    a name stack that holds ``moe_router`` holds exactly one router stage
+    behind it, one that holds ``moe_dispatch`` exactly one dispatch stage,
+    and no stage is anywhere else. Each stage is on a forward and on a
+    backward stack (``dispatch_order`` has no backward where nothing is
+    held: sorts of integers; where a share is held the backward's loop
+    enters it again). ``_held_pass``'s operations (the one ``cumsum`` of
+    the layer among them) are under ``moe_dispatch/dispatch_order``."""
+    scoring = moe.Scoring() if held is None else moe.Scoring(
+        "sigmoid", renormalize=True, scale=2.5)
+
+    def loss(params, x):
+        with jax.named_scope("ffn"):
+            y, aux = moe.dropless_moe_ffn(
+                params, x, 2, scoring=scoring, held=held,
+                activation="silu" if gated else "relu2")
+        return jnp.sum(y) + aux["balance"] + aux["z"]
+
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+        _expert_params(held, gated, latent),
+        jnp.ones((2, 12, 16))).as_text(debug_info=True)
+    stacks = [re.split(r"[/()]", s)
+              for s in set(re.findall(r'loc\("(jit\(loss\)/[^"]*)"', text))]
+    seen = set()
+    for names in stacks:
+        stages = [n for n in names if n in ROUTER_STAGES + DISPATCH_STAGES]
+        outer = [n for n in names if n in ("moe_router", "moe_dispatch")]
+        if not outer:
+            assert not stages, names
+            continue
+        assert len(outer) == 1 and len(stages) == 1, names
+        assert stages[0] in (ROUTER_STAGES if outer == ["moe_router"]
+                             else DISPATCH_STAGES), names
+        assert names.index(stages[0]) == names.index(outer[0]) + 1, names
+        seen.add((stages[0], "transpose" in names))
+    both = set(ROUTER_STAGES + DISPATCH_STAGES)
+    assert {s for s, backward in seen if not backward} == both
+    assert {s for s, backward in seen if backward} == (
+        both - {"dispatch_order"} if held is None else both)
+    passes = [names for names in stacks if "cumsum" in names]
+    assert bool(passes) == (held is not None)
+    assert all("dispatch_order" in names for names in passes)
+
+
+def test_the_capacity_layer_gets_the_router_s_first_three_stages():
+    """``moe_ffn`` calls ``route`` too: logits, scores and selection carry
+    their stage names there, with no ``moe_router`` around them."""
+    cfg = moe.MoEConfig(d_model=16, d_hidden=32, num_experts=4)
+    params = moe.init_moe_params(jax.random.PRNGKey(0), cfg)
+    text = jax.jit(lambda p, x: moe.moe_ffn(p, cfg, x)).lower(
+        params, jnp.ones((2, 8, 16))).as_text(debug_info=True)
+    for stage in ROUTER_STAGES[:3]:
+        assert re.search(rf'loc\("jit\([^"]*/{stage}/', text), stage
+    assert "moe_router" not in text and "router_stats" not in text
+
+
+@pytest.mark.parametrize("array", [np.asarray, jnp.asarray],
+                         ids=["numpy", "jax"])
+@pytest.mark.parametrize("rows", [0, 1, 8, 9],
+                         ids=["none", "one-row", "a-tile", "a-tile-and-one"])
+def test_held_passes_is_the_trip_count_of_the_loop(rows, array, monkeypatch):
+    """``moe.held_passes`` on a NumPy and on a jax value against the passes
+    ``_held_experts`` runs, forward and backward, on a layer that holds
+    ``rows`` assignments with a tile of 8: each entry of ``_held_pass``
+    is counted on the host as the loops run."""
+    tile, top_k, first, n = 8, 2, 2, 4
+    entered = []
+    held_pass = moe._held_pass
+
+    def counted(i, *args):
+        jax.debug.callback(lambda i: entered.append(int(i)), i)
+        return held_pass(i, *args)
+
+    monkeypatch.setattr(moe, "_held_pass", counted)
+    # 12 tokens, two choices each: the first ``rows`` assignments fall on
+    # the held experts 2 to 5 in turn, the others on expert 0
+    top_e = np.zeros(12 * top_k, np.int32)
+    top_e[:rows] = first + np.arange(rows) % n
+    top_e = top_e.reshape(12, top_k)
+    key = np.where((top_e >= first) & (top_e < first + n), top_e - first, n)
+    order = np.argsort(key.reshape(-1), kind="stable")
+    order = np.pad(order, (0, -order.size % tile))
+    sizes = np.bincount(top_e.reshape(-1), minlength=8)[first:first + n]
+    params = _expert_params((first, n), True, None)
+    weights = tuple(params[w] for w in ("w_gate", "w_up", "w_down"))
+
+    def total(xt, top_p):
+        return jnp.sum(moe._held_experts(
+            xt, top_p, weights, jnp.asarray(order, jnp.int32),
+            jnp.asarray(sizes, jnp.int32), top_k, None, tile, "silu"))
+
+    want = moe.held_passes(array(np.int32(rows)), tile)
+    assert int(want) == (rows + tile - 1) // tile
+    jax.block_until_ready(jax.grad(total, argnums=(0, 1))(
+        jnp.ones((12, 16)), jnp.full((12, top_k), 0.5)))
+    jax.effects_barrier()
+    # the forward loop and the backward's, ``want`` passes each
+    assert sorted(entered) == sorted(2 * list(range(int(want))))
+
+
+def test_held_passes_takes_a_layer_s_rows_at_once():
+    """One number an expert layer, as a reader of ``step_fn.aux`` has them:
+    at par (one pass), a share past a pass's rows (two), nothing held."""
+    rows = np.array([16384, 34000, 0])
+    assert moe.held_passes(rows, 32768).tolist() == [1, 2, 0]
+    assert moe.held_passes(jnp.asarray(rows), 32768).tolist() == [1, 2, 0]
 
 
 def test_transformer_step_hands_out_its_jit_and_its_placement():
