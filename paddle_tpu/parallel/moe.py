@@ -122,13 +122,55 @@ class Scoring:
     scale: float = 1.0
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _biased_top_k(probs, bias, top_k):
+    """The ``top_k`` largest of ``probs + bias`` along the last axis in
+    ``lax.top_k``'s order (the total order of floats, the lower index first
+    among equals), and ``probs`` at them: (top_p, top_e), bit for bit
+    ``take_along_axis(probs, top_e)``. ``lax.top_k`` is a sort of (score,
+    index) rows on the chip; this is that sort with ``probs`` carried as one
+    more operand, so the chosen scores need no gather of k T scalars (10 ns
+    each on a v5e: PERF.md section 6, PR 51 and PR 52). The key is the
+    biased score as the integer that orders as it does, inverted: a sort of
+    integers compares with one instruction, where jax's order of floats
+    (zeros alike, NaNs last: not ``lax.top_k``'s) takes six. The gradient
+    reaches ``probs`` alone, through the chosen scores; jax's own rule for a
+    carried operand would scatter whole rows."""
+    bits = jax.lax.bitcast_convert_type(probs + bias, jnp.int32)
+    key = ~jnp.where(bits < 0, bits ^ 0x7FFFFFFF, bits)
+    index = jax.lax.broadcasted_iota(jnp.int32, probs.shape, probs.ndim - 1)
+    _, scores, index = jax.lax.sort(
+        (key, probs, index), dimension=-1, num_keys=1, is_stable=True)
+    return scores[..., :top_k], index[..., :top_k]
+
+
+def _biased_top_k_fwd(probs, bias, top_k):
+    top_p, top_e = _biased_top_k(probs, bias, top_k)
+    return (top_p, top_e), (top_e, bias)
+
+
+def _biased_top_k_bwd(top_k, kept, cotangents):
+    top_e, bias = kept
+    # a row's experts are distinct, so each score's gradient is one term of
+    # the sum and the others are zeros: the bits of a scatter-add, and a
+    # pass over [T, k, E] in place of XLA's sort and scatter of k T scalars
+    chosen = top_e[..., None] == jnp.arange(bias.shape[0], dtype=top_e.dtype)
+    d_probs = jnp.sum(jnp.where(chosen, cotangents[0][..., None], 0.0),
+                      axis=-2)
+    return d_probs, jnp.zeros_like(bias)
+
+
+_biased_top_k.defvjp(_biased_top_k_fwd, _biased_top_k_bwd)
+
+
 def route(x32, gate_w, top_k, scoring=Scoring(), bias=None):
     """The gate both layers share: float32 logits ``x gate_w`` [T, E] at
     full precision (on a TPU a float32 product is otherwise rounded to
     bfloat16, which flips close choices), their scores (``scoring``), and
     the ``top_k`` experts of each token with their weights. With ``bias``
     [E] the experts are the largest ``scores + bias`` and the weights are
-    taken from the scores without it; the bias is outside the gradient.
+    the scores without it, carried through the selection's own sort
+    (``_biased_top_k``); the bias is outside the gradient.
     Returns (logits, scores, top_p [T, k], top_e [T, k]). Three stage scopes
     (``dropless_moe_ffn`` lists all seven): ``router_logits``,
     ``router_scores``, ``router_select``."""
@@ -142,16 +184,23 @@ def route(x32, gate_w, top_k, scoring=Scoring(), bias=None):
         if bias is None:
             top_p, top_e = jax.lax.top_k(probs, top_k)
         else:
-            _, top_e = jax.lax.top_k(
-                probs + jax.lax.stop_gradient(bias.astype(jnp.float32)),
-                top_k)
-            top_p = jnp.take_along_axis(probs, top_e, axis=-1)
+            top_p, top_e = _biased_top_k(probs, bias.astype(jnp.float32),
+                                         top_k)
     with jax.named_scope("router_scores"):
         if scoring.renormalize:
             top_p = top_p / (jnp.sum(top_p, axis=-1, keepdims=True) + 1e-20)
         if scoring.scale != 1.0:
             top_p = top_p * scoring.scale
     return logits, probs, top_p, top_e
+
+
+def _counts(top_e, experts):
+    """The assignments each of ``experts`` experts took, [E] int32:
+    ``bincount`` of ``top_e`` as a compare against the experts' numbers
+    summed over tokens and choices, one fused pass where ``bincount`` is a
+    scatter-add of k T indices one at a time (9 ns each on a v5e)."""
+    chosen = top_e[..., None] == jnp.arange(experts, dtype=top_e.dtype)
+    return jnp.sum(chosen, axis=tuple(range(top_e.ndim)), dtype=jnp.int32)
 
 
 def bias_step(bias, counts, rate):
@@ -508,9 +557,11 @@ def dropless_moe_ffn(params, x, top_k, mesh=None, scoring=Scoring(),
     besides, forward and backward. In ``moe_router``: ``router_logits`` (the
     float32 product at ``HIGHEST``; its two gradient products),
     ``router_scores`` (the softmax or sigmoid, the renormalisation and the
-    scale of the chosen scores), ``router_select`` (the bias, ``top_k``,
-    the chosen scores taken; backward the scatter of their gradient),
-    ``router_stats`` (the counts, the balance term, the z term). In
+    scale of the chosen scores), ``router_select`` (``top_k``, or with a
+    bias the one sort that hands out the chosen experts and their scores;
+    backward their gradient spread over [T, E] by a compare: no gather, no
+    scatter), ``router_stats`` (the counts, a compare and a sum, the balance
+    term, the z term). In
     ``moe_dispatch``: ``dispatch_order`` (the keys and their sorts, and in
     every pass over held rows what the pass works on, ``_held_pass``),
     ``dispatch_gather`` (the rows, and their gradients, in expert order),
@@ -527,7 +578,7 @@ def dropless_moe_ffn(params, x, top_k, mesh=None, scoring=Scoring(),
             x32, params["router_w"], top_k, scoring,
             params.get("router_bias"))
         with jax.named_scope("router_stats"):
-            counts = jnp.bincount(top_e.reshape(-1), length=e)
+            counts = _counts(top_e, e)
             aux = {"balance": balance_loss(probs, counts),
                    "z": jnp.mean(jnp.square(
                        jax.nn.logsumexp(logits, axis=-1))),

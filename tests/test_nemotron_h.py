@@ -562,9 +562,10 @@ def test_the_shares_add_up_to_the_uncut_layer(kind):
 #: the PR that brought the one-part layers, the plain experts, the latent and
 #: the MTP module to ``lm_trainer.py`` and ``parallel/moe.py``: the other
 #: decoders reach none of it. A PR that changes Kanana-2's step on purpose
-#: recomputes it (the test prints the text's hash).
+#: recomputes it (the test prints the text's hash); PR 52 did, for the
+#: router's selection and counts (``moe._biased_top_k``, ``moe._counts``).
 KANANA_TINY_STEP = \
-    "731b6b277f31e781595f84721f436077c33e363ee61c7eee1eae38b7f4f533ce"
+    "fa955133ffabe0d0bcf15166a4549ba903d4fb484a1dc0e3e5ee3fe6962f060c"
 
 
 def test_a_decoder_without_the_new_mechanisms_lowers_as_before():
@@ -585,9 +586,10 @@ def test_a_decoder_without_the_new_mechanisms_lowers_as_before():
 #: batch 2 x 48, one device) on PR 48's tree, the parent of the PR that gave
 #: the state-space scan its Mosaic body: the delta-rule decoders share
 #: ``blocks.recomputed``, whose policy gained a name their traces do not
-#: hold, and the registry, which gained a kernel they never dispatch.
+#: hold, and the registry, which gained a kernel they never dispatch; renewed
+#: by PR 52 with the router's text, as Kanana-2's.
 KIMI_TINY_STEP = \
-    "e8f8dcc8b3c9208e2c99d1d1a977608d0481752ac9d0482ec356ef1dd9c8105f"
+    "1350dbd9f0a63eb8a6bddf33695dcf54db57cefc371c5731f3de401d14f473d9"
 
 
 def test_a_delta_rule_decoder_lowers_as_before_the_scan_s_kernels():

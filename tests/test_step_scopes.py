@@ -106,7 +106,7 @@ def test_lowered_step_names_every_scope_forward_and_backward(family):
 def _expert_params(held, gated, latent):
     """Parameters of one tiny expert layer: a router 8 wide on a hidden of
     16, the held experts' stacks (all 8 where ``held`` is None), a selection
-    bias where a share is held, a latent's projections where asked."""
+    bias, a latent's projections where asked."""
     keys = iter(jax.random.split(jax.random.PRNGKey(0), 8))
     n, width = (8 if held is None else held[1]), latent or 16
 
@@ -120,26 +120,21 @@ def _expert_params(held, gated, latent):
     if latent:
         params.update(latent_down=normal(16, latent),
                       latent_up=normal(latent, 16))
-    if held is not None:
-        params["router_bias"] = jnp.zeros((8,))
+    params["router_bias"] = jnp.zeros((8,))
     return params
 
 
-@pytest.mark.parametrize("held,gated,latent", [
-    (None, True, None), (None, False, None), (None, True, 8),
-    ((2, 4), True, None), ((2, 4), False, None), ((2, 4), False, 8)],
-    ids=["all-gated", "all-plain", "all-gated-latent", "held-gated",
-         "held-plain", "held-plain-latent"])
-def test_every_router_and_dispatch_operation_is_under_one_stage(
-        held, gated, latent):
-    """The lowered value-and-grad of ``dropless_moe_ffn`` inside ``ffn``:
-    a name stack that holds ``moe_router`` holds exactly one router stage
-    behind it, one that holds ``moe_dispatch`` exactly one dispatch stage,
-    and no stage is anywhere else. Each stage is on a forward and on a
-    backward stack (``dispatch_order`` has no backward where nothing is
-    held: sorts of integers; where a share is held the backward's loop
-    enters it again). ``_held_pass``'s operations (the one ``cumsum`` of
-    the layer among them) are under ``moe_dispatch/dispatch_order``."""
+EXPERT_LAYERS = {"all-gated": (None, True, None),
+                 "all-plain": (None, False, None),
+                 "all-gated-latent": (None, True, 8),
+                 "held-gated": ((2, 4), True, None),
+                 "held-plain": ((2, 4), False, None),
+                 "held-plain-latent": ((2, 4), False, 8)}
+
+
+def _lowered_expert_layer(held, gated, latent):
+    """The lowered value-and-grad of ``dropless_moe_ffn`` inside ``ffn``,
+    with every operation's name stack."""
     scoring = moe.Scoring() if held is None else moe.Scoring(
         "sigmoid", renormalize=True, scale=2.5)
 
@@ -150,9 +145,62 @@ def test_every_router_and_dispatch_operation_is_under_one_stage(
                 activation="silu" if gated else "relu2")
         return jnp.sum(y) + aux["balance"] + aux["z"]
 
-    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+    return jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
         _expert_params(held, gated, latent),
         jnp.ones((2, 12, 16))).as_text(debug_info=True)
+
+
+def _stacks_of(text, *operations):
+    """[(operation, name stack)] of the ``stablehlo`` operations of those
+    names that the lowered text's public function runs. An operation with a
+    region (a scatter's update, a sort's order) carries its location where
+    the region closes; one inside a private function (``jnp.take``,
+    ``jnp.bincount`` and their like are functions of their own) runs under
+    the stack of every call that reaches it, with its own behind it."""
+    named = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', text, re.M))
+    lines = text.split("\n")
+    inside, public, function = {}, None, None
+    for i, line in enumerate(lines):
+        head = re.match(r"  func\.func (public|private) @([\w.]+)\(", line)
+        if head:
+            function = head.group(2)
+            inside[function] = []
+            public = function if head.group(1) == "public" else public
+            continue
+        op = re.search(r'= "?(?:stablehlo\.(\w+)"?|call @([\w.]+)\()', line)
+        if not op or not (op.group(2) or op.group(1) in operations):
+            continue
+        if line.rstrip().endswith("({"):
+            close = line[:len(line) - len(line.lstrip())] + "})"
+            line = next(l for l in lines[i + 1:] if l.startswith(close))
+        at = re.search(r"loc\((#loc\d+)\)\s*$", line)
+        inside[function].append((op.group(1), op.group(2),
+                                 named.get(at.group(1), "") if at else ""))
+
+    def reached(function, under):
+        for operation, callee, stack in inside[function]:
+            stack = f"{under}/{stack}" if under else stack
+            if callee:
+                yield from reached(callee, stack)
+            else:
+                yield operation, stack
+
+    return list(reached(public, ""))
+
+
+@pytest.mark.parametrize("held,gated,latent", EXPERT_LAYERS.values(),
+                         ids=EXPERT_LAYERS)
+def test_every_router_and_dispatch_operation_is_under_one_stage(
+        held, gated, latent):
+    """The lowered value-and-grad of ``dropless_moe_ffn`` inside ``ffn``:
+    a name stack that holds ``moe_router`` holds exactly one router stage
+    behind it, one that holds ``moe_dispatch`` exactly one dispatch stage,
+    and no stage is anywhere else. Each stage is on a forward and on a
+    backward stack (``dispatch_order`` has no backward where nothing is
+    held: sorts of integers; where a share is held the backward's loop
+    enters it again). ``_held_pass``'s operations (the one ``cumsum`` of
+    the layer among them) are under ``moe_dispatch/dispatch_order``."""
+    text = _lowered_expert_layer(held, gated, latent)
     stacks = [re.split(r"[/()]", s)
               for s in set(re.findall(r'loc\("(jit\(loss\)/[^"]*)"', text))]
     seen = set()
@@ -174,6 +222,36 @@ def test_every_router_and_dispatch_operation_is_under_one_stage(
     passes = [names for names in stacks if "cumsum" in names]
     assert bool(passes) == (held is not None)
     assert all("dispatch_order" in names for names in passes)
+
+
+@pytest.mark.parametrize("program", [*EXPERT_LAYERS, "olmoe",
+                                     "kimi_linear"])
+def test_selection_and_counts_gather_and_scatter_nothing(program):
+    """On the six expert layers and on the two tiny steps: no
+    ``stablehlo.gather`` and no ``stablehlo.scatter`` is under
+    ``router_select`` forward (the chosen scores come out of the selection's
+    own sort: ``moe._biased_top_k``; backward, ``lax.top_k``'s own rule
+    scatters where a router adds no bias) or under ``router_stats`` (the
+    counts are a compare and a sum: ``moe._counts``); XLA runs either a
+    scalar at a time on the chip. The steps still gather and scatter
+    elsewhere, so the reading finds what it looks for."""
+    if program in EXPERT_LAYERS:
+        text = _lowered_expert_layer(*EXPERT_LAYERS[program])
+    else:
+        step_fn, params, opt_state, batch = _tiny(program)
+        text = step_fn.jitted.lower(
+            params, opt_state, step_fn.place(batch)).as_text(debug_info=True)
+    moved = _stacks_of(text, "gather", "scatter")
+    assert moved and all("/" in stack for _, stack in moved)
+    for operation, stack in moved:
+        names = re.split(r"[/()]", stack)
+        assert "router_stats" not in names, (operation, stack)
+        assert "router_select" not in names or "transpose" in names, (
+            operation, stack)
+    sorts = [re.split(r"[/()]", stack)
+             for _, stack in _stacks_of(text, "sort")]
+    if program != "olmoe":         # a selection bias: the sort carries scores
+        assert any("router_select" in names for names in sorts)
 
 
 def test_the_capacity_layer_gets_the_router_s_first_three_stages():
