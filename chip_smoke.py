@@ -282,6 +282,13 @@ def phase_kernels():
                  * jax.nn.softplus(f32(2, 1200, 8) - 2.0),
                  bf16(2, 1200, 2, 128), bf16(2, 1200, 2, 128), f32(8)),
                 {"chunk": 128}, (0, 1, 2, 3, 4, 5), 3e-2),
+        # EVA's aggregation: four windows of 2 SEQ positions, chunks of 16,
+        # 4 heads of 128; q, k, v, then the chunks' summaries
+        "eva_attention": ((bf16(1, 4, 8 * SEQ, 128), bf16(1, 4, 8 * SEQ, 128),
+                           bf16(1, 4, 8 * SEQ, 128), bf16(1, 4, SEQ // 2, 128),
+                           bf16(1, 4, SEQ // 2, 128)),
+                          {"window": 2 * SEQ, "chunk": 16}, (0, 1, 2, 3, 4),
+                          3e-2),
         # heads of 64, half a lane tile: 32 query heads on 8 key/value heads
         "flash_attention/d64_gqa": ((bf16(1, 32, 4 * SEQ, 64),
                                      bf16(1, 8, 4 * SEQ, 64),

@@ -1,13 +1,19 @@
 """The decoder skeleton: what the language models built from
 ``models/blocks.py`` have in common, written once. A model file
 (``olmoe.py``, ``kimi_linear.py``, ``laguna.py``, ``qwen3_next.py``,
-``lfm2.py``, ``deepseek_v3.py``, ``nemotron_h.py``) keeps its config, its
-parameters and its mixers and hands them over as a :class:`Decoder`, whose
+``lfm2.py``, ``deepseek_v3.py``, ``nemotron_h.py``, ``evabyte.py``) keeps
+its config, its parameters and its mixers and hands them over as a
+:class:`Decoder`, whose
 methods are that module's ``forward``, ``stages``, ``lm_loss``,
 ``routing_stats`` and ``make_train_step``. A layer is a mixer and a
 feed-forward, or a mixer alone; where the parameters hold a
 multi-token-prediction module (``params["mtp"]``) the pass runs it behind
-the last layer and the loss gains its term.
+the last layer and the loss gains its term. A model with no expert layer
+says so (``routed=False``: no counts leave its step and nothing moves after
+the update); a config may carry the residual stream in a dtype of its own
+(``cfg.stream_dtype``, where the layers compute in ``cfg.dtype``) and name
+several prediction heads (``cfg.pred_heads``: head i at position t predicts
+the id i + 1 positions on).
 ``models/bert.py`` and ``models/transformer.py`` carry their own pass and
 step (ROADMAP C, "one trainer shape").
 """
@@ -50,8 +56,24 @@ def untied_head(params, hidden):
 
 
 def _stacked(auxes):
-    """The layers' aux terms, each stacked over the layers."""
-    return jax.tree.map(lambda *a: jnp.stack(a), *auxes)
+    """The layers' aux terms, each stacked over the layers; none where no
+    layer returned any."""
+    return jax.tree.map(lambda *a: jnp.stack(a), *auxes) if auxes else {}
+
+
+def _ce_ahead(labels, ahead, logits):
+    """The mean cross-entropy of ``logits()`` [B, S, V] at position t against
+    the id ``ahead`` positions after its label, ``labels[t + ahead]``, over
+    the S - ``ahead`` positions that have one: the masking the
+    multi-token-prediction term and the prediction heads share. ``logits``
+    is called behind the labels' shift."""
+    if not ahead:
+        return jnp.mean(softmax_cross_entropy(logits(), labels))
+    # position t's label lies ``ahead`` further on; the last have none
+    further = jnp.roll(labels, -ahead, axis=1)
+    nll = softmax_cross_entropy(logits(), further)
+    has_one = jnp.arange(nll.shape[1]) < nll.shape[1] - ahead
+    return jnp.sum(nll * has_one) / (nll.shape[0] * (nll.shape[1] - ahead))
 
 
 def _shard_act(x, mesh):
@@ -80,6 +102,9 @@ class Decoder:
     #: (cfg, mean cross-entropy, aux terms stacked over the layers) -> the
     #: loss: the cross-entropy and what this model's loss adds to it
     add_aux: Callable = lambda cfg, ce, aux: ce
+    #: whether a layer has a router: its counts leave the step, and its
+    #: selection bias moves after the update
+    routed: bool = True
 
     def _layers(self, layers, first, x, cfg, rotary, mesh):
         """``x`` through ``layers``, numbered from ``first``: (the stream
@@ -104,15 +129,16 @@ class Decoder:
         a module in the parameters, ``params["mtp"]``; its expert layers' aux
         terms are then stacked behind the main ones), else empty)."""
         with jax.named_scope("embed"):
-            x = jnp.take(params["embed"], input_ids,
-                         axis=0).astype(cfg.dtype)
+            x = jnp.take(params["embed"], input_ids, axis=0).astype(
+                getattr(cfg, "stream_dtype", cfg.dtype))
         x = _shard_act(x, mesh)
         rotary = self.rotary(cfg, input_ids.shape[1])
         stream, auxes = self._layers(params["layers"], 0, x, cfg, rotary,
                                      mesh)
         stream = [x] + stream
+        # a head's operand is in cfg.dtype, whatever carries the stream
         hidden = blocks.rms_norm(stream[-1], self.final_gain(params),
-                                 cfg.rms_eps)
+                                 cfg.rms_eps).astype(cfg.dtype)
         further = []
         if next_ids is not None and "mtp" in params:
             further, more = self._predict_further(
@@ -171,28 +197,48 @@ class Decoder:
                                                   mesh, next_ids)
         return jnp.stack(stream + [hidden] + further), aux
 
+    def _head_losses(self, params, cfg, hidden, labels):
+        """[cfg.pred_heads] the cross-entropy of each prediction head: the
+        head's product views as [B, S, heads, V], head i at position t
+        against ``labels[t + i]``, the mean over its own S - i positions.
+        Under the scope ``multibyte_head``, inside the caller's ``loss``."""
+        with jax.named_scope("multibyte_head"):
+            logits = self.logits(params, hidden)
+            logits = logits.reshape(*labels.shape, cfg.pred_heads, -1)
+            return jnp.stack([
+                _ce_ahead(labels, i, lambda i=i: logits[:, :, i])
+                for i in range(cfg.pred_heads)])
+
+    def head_losses(self, params, cfg, batch, mesh=None):
+        """The ``cfg.pred_heads`` cross-entropies ``lm_loss`` is the mean
+        of, [heads] float32."""
+        hidden = self._pass(params, cfg, batch["input_ids"], mesh)[0]
+        with jax.named_scope("loss"), mesh_scope(mesh):
+            return self._head_losses(params, cfg, hidden, batch["labels"])
+
     def _loss_and_counts(self, params, cfg, batch, mesh=None):
-        """(``lm_loss``, the counts each expert took [expert layers, E]);
-        with a multi-token-prediction module the second is a pair: the
-        counts, the module's layers last, and the two cross-entropies
-        [2] (next token, token after next) the loss is made of."""
+        """(``lm_loss``, the counts each expert took [expert layers, E],
+        None where no layer has a router); with a multi-token-prediction
+        module the second is a pair: the counts, the module's layers last,
+        and the two cross-entropies [2] (next token, token after next) the
+        loss is made of."""
         hidden, aux, _, further = self._pass(
             params, cfg, batch["input_ids"], mesh, batch["labels"])
         with jax.named_scope("loss"), mesh_scope(mesh):
+            if getattr(cfg, "pred_heads", 1) > 1:
+                ce = jnp.mean(self._head_losses(params, cfg, hidden,
+                                                batch["labels"]))
+                return self.add_aux(cfg, ce, aux), aux.get("counts")
             logits = self.logits(params, hidden)
-            nll = softmax_cross_entropy(logits, batch["labels"])
-            ce = jnp.mean(nll)
+            ce = _ce_ahead(batch["labels"], 0, lambda: logits)
             loss = self.add_aux(cfg, ce, aux)
             if not further:
-                return loss, aux["counts"]
+                return loss, aux.get("counts")
             # position t's merged state predicts id t + 2, the label of
-            # position t + 1; the last position has none
-            after_next = jnp.roll(batch["labels"], -1, axis=1)
-            nll = softmax_cross_entropy(
-                self.logits(params, further[-1]), after_next)
-            has_one = jnp.arange(nll.shape[1]) < nll.shape[1] - 1
-            further_ce = jnp.sum(nll * has_one) \
-                / (nll.shape[0] * (nll.shape[1] - 1))
+            # position t + 1
+            further_ce = _ce_ahead(
+                batch["labels"], 1,
+                lambda: self.logits(params, further[-1]))
             return loss + cfg.mtp_weight * further_ce, (
                 aux["counts"], jnp.stack([ce, further_ce]))
 
@@ -202,7 +248,8 @@ class Decoder:
         what the model's ``add_aux`` adds, plus, where the parameters hold a
         multi-token-prediction module, ``cfg.mtp_weight`` times the mean
         cross-entropy of the token after next over the S - 1 positions that
-        have one. Logits and loss in float32."""
+        have one. With ``cfg.pred_heads`` heads the cross-entropy is the
+        mean of theirs (``head_losses``). Logits and loss in float32."""
         return self._loss_and_counts(params, cfg, batch, mesh)[0]
 
     def routing_stats(self, params, cfg, batch, mesh=None, choices=False):
@@ -225,7 +272,11 @@ class Decoder:
         """(init_fn, step_fn) of ``make_train_step`` below for this model.
         After the optimizer's update every router's selection bias takes one
         step on the load of this batch (``move_biases``), and the step hands
-        the routers' counts out, the counter of a step's load."""
+        the routers' counts out, the counter of a step's load; a model with
+        no router (``routed`` false) has neither."""
+        if not self.routed:
+            return make_train_step(cfg, optimizer, mesh, self.init_params,
+                                   self.param_specs, self.lm_loss)
         return make_train_step(
             cfg, optimizer, mesh, self.init_params, self.param_specs,
             self._loss_and_counts,

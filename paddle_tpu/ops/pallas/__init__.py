@@ -13,7 +13,11 @@ the LFM2 family's double-gated convolution, and moe_combine
 (moe_combine.py), the way back of an expert layer that holds a share of the
 experts, and ssd (ssd.py; its entry point is
 ``paddle_tpu.ops.ssd.ssd_chunked``, beside the reference body and the
-recurrence it stands for), Mamba-2's chunked state-space scan. Of
+recurrence it stands for), Mamba-2's chunked state-space scan, and
+eva_attention (eva.py; its entry point is
+``paddle_tpu.ops.eva.eva_attention``, beside the reference body and the
+chunk summaries), EvaByte's aggregation over a window's tokens and the
+earlier windows' summaries. Of
 moe_combine: a pass's rows, each times its float32 weight, summed into their
 tokens' rows. Its reference body is XLA's scatter-add (the CPU, and any mesh
 of more than one device); its Pallas body puts the rows in token order and
@@ -32,6 +36,7 @@ from paddle_tpu.ops.pallas.registry import (  # noqa: F401
 from paddle_tpu.ops.pallas.delta_glue import gated_head_norm, short_conv_norm
 from paddle_tpu.ops.pallas.gated_conv import gated_short_conv
 from paddle_tpu.ops.pallas import embedding as _embedding  # noqa: F401
+from paddle_tpu.ops.pallas import eva as _eva  # noqa: F401
 from paddle_tpu.ops.pallas.flash_attention import flash_attention
 from paddle_tpu.ops.pallas.grouped_matmul import grouped_matmul
 from paddle_tpu.ops.pallas import kda as _kda  # noqa: F401

@@ -12,7 +12,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from paddle_tpu.ops import kda, ssd
+from paddle_tpu.ops import eva, kda, ssd
 from paddle_tpu.ops import pallas as K
 from paddle_tpu.ops.selected_rows import (
     SelectedRows, get_tensor_from_selected_rows,
@@ -67,6 +67,9 @@ ENTRY_POINTS = {
     "ssd": lambda: ssd.ssd_chunked(
         jnp.ones((1, 32, 2, 64)), jnp.ones((1, 32, 2)), -jnp.ones((1, 32, 2)),
         jnp.ones((1, 32, 1, 16)), jnp.ones((1, 32, 1, 16)), chunk=16),
+    "eva_attention": lambda: eva.eva_attention(
+        *(jnp.ones((1, 64, 1, 16)),) * 3, *(jnp.ones((1, 8, 1, 16)),) * 2,
+        window=32, chunk=8),
 }
 
 

@@ -25,7 +25,8 @@ import paddle_tpu.ops.pallas as plk
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 RNG = np.random.RandomState(42)
 
-KERNELS = ["embedding_scatter_add", "flash_attention", "fused_layer_norm",
+KERNELS = ["embedding_scatter_add", "eva_attention", "flash_attention",
+           "fused_layer_norm",
            "fused_matmul", "fused_matmul_int8", "gated_head_norm",
            "gated_short_conv", "grouped_matmul", "kda_chunked", "moe_combine",
            "short_conv_norm", "softmax_cross_entropy", "ssd"]

@@ -28,8 +28,8 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
 import paddle_tpu as pt
-from paddle_tpu.models import (bert, deepseek_v3, kimi_linear, laguna, lfm2,
-                               olmoe, qwen3_next, transformer)
+from paddle_tpu.models import (bert, deepseek_v3, evabyte, kimi_linear, laguna,
+                               lfm2, olmoe, qwen3_next, transformer)
 from paddle_tpu.ops import kda
 from paddle_tpu.ops import pallas as plk
 from paddle_tpu.ops.pallas import registry
@@ -112,6 +112,11 @@ KERNEL_SHAPES = {
     "embedding_scatter_add": ([((100000, 16), F32), ((16384,), I32),
                                ((16384, 16), F32)], {}, False),
     "flash_attention": ([((8, 12, 2048, 64), BF16)] * 3, {}, True),
+    # a layer of evabyte.lm_s16384: q, k, v heads-major and the summaries of
+    # the 1024 chunks of 16, 128 a window of 2048
+    "eva_attention": ([((1, 32, 16384, 128), BF16)] * 3
+                      + [((1, 32, 1024, 128), BF16)] * 2,
+                      {"window": 2048, "chunk": 16}, True),
     # OLMoE's gate/up product: 8 x 8192 assignments, 64 experts of 2048x1024
     "grouped_matmul": ([((65536, 2048), BF16), ((64, 2048, 1024), BF16),
                         ((64,), I32)], {}, True),
@@ -1202,6 +1207,61 @@ def test_kanana_2_step_at_published_widths_fits_a_v5e(kanana_2_full_size):
                       rf"/rematted_computation/attention/{scope}/",
                       rf"transpose\([^\n]*/checkpoint/attention/{scope}/"):
             assert re.search(where, every), where
+
+
+@pytest.fixture(scope="module")
+def evabyte_full_size(topo):
+    """The step of the cell evabyte.lm_s16384: four whole layers at the
+    published widths, the 320 ids whole, eight heads, batch 1 x 16384."""
+    cfg = evabyte.evabyte_6b5(num_layers=4)
+    return _lower_replicated(
+        evabyte.make_train_step, evabyte.init_params, cfg,
+        evabyte.synthetic_batch(cfg, 1, 16384), topo)
+
+
+@pytest.mark.timeout(900)
+def test_evabyte_step_at_published_widths_fits_a_v5e(evabyte_full_size):
+    """821.4 M parameters with their two Adam moments are 9.18 GiB of the
+    step's arguments; with every mixer recomputed but for its aggregation
+    kernel's outputs and every feed-forward recomputed the whole step needs
+    13.8 GiB of the 15.75 a v5e gives a program. Its Mosaic calls: the EVA
+    kernels (four layers, each way once a layer: no forward kernel is in the
+    recomputation) under ``eva_core`` inside ``attention_core``, and the
+    eight heads' cross-entropies under ``multibyte_head`` inside ``loss``;
+    none of the four flash calls the other cells stand on; the summaries are
+    under ``eva_summary`` in the forward pass, the recomputed one and the
+    backward; the optimizer is the stock rule on every leaf."""
+    compiled, pshape, _ = evabyte_full_size
+    assert sum(int(np.prod(a.shape)) for a in jax.tree.leaves(pshape)) \
+        == 821_366_784
+    ma = compiled.memory_analysis()
+    assert 9.1 * 2**30 < ma.argument_size_in_bytes < 9.3 * 2**30
+    need = _need_bytes(compiled)
+    assert 0.25 * 15.75 * 2**30 < need < 14.2 * 2**30, need / 2**30  # 13.78
+    stems = _mosaic_call_stems(compiled)
+    assert set(stems) == {"flash_fwd_eva", "flash_bwd_eva",
+                          "softmax_xent_fwd"}
+    assert stems.count("flash_fwd_eva") == 4 == stems.count("flash_bwd_eva")
+    assert stems.count("softmax_xent_fwd") == 8
+    calls = [line for line in compiled.as_text().splitlines()
+             if "tpu_custom_call" in line]
+    assert all("bf16[1,32,16384,128]" in line and "bf16[1,32,1024,128]" in line
+               for line in calls if "_eva" in line.split(" = ")[0])
+    op_names = "\n".join(re.findall(r'op_name="([^"]*)"', "\n".join(calls)))
+    for scope, kernel in (
+            ("attention_core/eva_core", "flash_fwd_eva"),
+            ("attention_core/eva_core", "flash_bwd_eva"),
+            (r"jvp\(loss\)/multibyte_head", "softmax_xent_fwd")):
+        assert re.search(rf"{scope}[^\n]*/{kernel}/pallas_call", op_names), \
+            (scope, kernel)
+    every = "\n".join(re.findall(r'op_name="([^"]*)"', compiled.as_text()))
+    for scope in ("rope", "eva_summary"):
+        for where in (rf"jvp\(attention\)/{scope}/",
+                      rf"/rematted_computation/attention/{scope}/",
+                      rf"transpose\([^\n]*/checkpoint/attention/{scope}/"):
+            assert re.search(where, every), where
+    under = _under_optimizer(compiled)
+    assert not [u for u in under if u[0] == "custom-call"]
 
 
 # ---------------------------------------------------------------------------
